@@ -28,30 +28,6 @@ automatically off-TPU, mirroring Apex's graceful-degradation invariant
 (reference README.md:90-95).
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax<0.5 compat: the codebase (and its tests) target the stable
-    # ``jax.shard_map`` entry point with its ``check_vma`` kwarg; on
-    # older jax fall back to the experimental version, mapping
-    # check_vma to its pre-rename name check_rep.
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=None, **kw):
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # same vintage gap: lax.axis_size (static size of a mapped axis)
-    # predates this jax; jax.core.axis_frame returns exactly that int
-    # (and raises NameError for an unbound axis, matching semantics)
-    _jax.lax.axis_size = _jax.core.axis_frame
-
 from . import nn
 from . import amp
 from . import multi_tensor_apply

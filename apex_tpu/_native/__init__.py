@@ -1,10 +1,13 @@
 """apex_tpu._native — ctypes bindings for the C++ host runtime.
 
-Loads libapex_tpu_C.so (built by build.sh / `python setup.py build_native`),
-auto-building it on first import when a compiler is available.  Every entry
-point has a numpy fallback, so a Python-only environment keeps working —
-the reference's graceful-degradation invariant (README.md:90-95) applied
-to the host runtime.
+Loads libapex_tpu_C.so, building it from apex_tpu_C.cpp (build.sh, plain
+g++) on first use when it is not there — the library is never committed,
+so a fresh clone and a long-lived checkout take the same path.  Every
+entry point has a numpy fallback, so a host without a compiler keeps
+working — the reference's graceful-degradation invariant
+(README.md:90-95) applied to the host runtime.  A build that was
+attempted and failed, or a library built from another revision of the
+source, says so on stderr once; it is not silently replaced by numpy.
 
 API:
   available() -> bool
@@ -20,26 +23,28 @@ from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
+import sys
 from typing import List, Optional, Sequence
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libapex_tpu_C.so")
+# what apex_native_version() in apex_tpu_C.cpp returns at this revision
+_ABI_VERSION = 3
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_SO):
-        return True
-    src = os.path.join(_HERE, "apex_tpu_C.cpp")
-    try:  # rebuild when the source is newer than the binary
-        return os.path.getmtime(src) > os.path.getmtime(_SO)
-    except OSError:
-        return False
+def _give_up(why: str) -> None:
+    """Fall back to numpy for the rest of the process, and say why."""
+    global _load_failed
+    _load_failed = True
+    print(f"apex_tpu._native: {why}; using the numpy fallbacks",
+          file=sys.stderr)
 
 
 def _try_load() -> Optional[ctypes.CDLL]:
@@ -48,17 +53,32 @@ def _try_load() -> Optional[ctypes.CDLL]:
         return _lib
     if _load_failed:  # don't shell out to the compiler on every call
         return None
-    if _needs_build():
+    if not os.path.exists(_SO):
+        if shutil.which("g++") is None:
+            _load_failed = True     # no toolchain: the library is absent
+            return None
         try:
             subprocess.run(["bash", os.path.join(_HERE, "build.sh")],
-                           check=True, capture_output=True, timeout=120)
-        except Exception:
-            _load_failed = True
+                           check=True, capture_output=True, text=True,
+                           timeout=120)
+        except subprocess.CalledProcessError as e:
+            tail = (e.stderr or "").strip().splitlines()[-3:]
+            _give_up(f"build.sh exited {e.returncode}: "
+                     + " | ".join(tail))
+            return None
+        except (OSError, subprocess.TimeoutExpired) as e:
+            _give_up(f"build.sh did not run to an end ({e})")
             return None
     try:
         lib = ctypes.CDLL(_SO)
-    except OSError:
-        _load_failed = True
+        lib.apex_native_version.restype = ctypes.c_int
+        found = int(lib.apex_native_version())
+    except (OSError, AttributeError) as e:
+        _give_up(f"cannot load {_SO} ({e})")
+        return None
+    if found != _ABI_VERSION:
+        _give_up(f"{_SO} is ABI v{found} but apex_tpu_C.cpp is "
+                 f"v{_ABI_VERSION} — delete it to rebuild")
         return None
     lib.apex_flatten.argtypes = [
         ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int64),
@@ -74,24 +94,13 @@ def _try_load() -> Optional[ctypes.CDLL]:
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
         ctypes.c_int64, ctypes.c_int64, ctypes.POINTER(ctypes.c_float),
         ctypes.POINTER(ctypes.c_float)]
-    try:
-        lib.apex_preprocess_nhwc_u8_to_nhwc_f32.argtypes = \
-            lib.apex_preprocess_nhwc_u8_to_nchw_f32.argtypes
-    except AttributeError:
-        pass    # stale v2 .so; version() gates the NHWC paths below
-    lib.apex_native_version.restype = ctypes.c_int
-    # ABI v2's create takes 13 args; v3 appended a data_format int.
-    # Declare exactly what the loaded .so expects — passing a surplus
-    # trailing int to a v2 library happens to work on x86-64/aarch64
-    # calling conventions but is not something to rely on.
-    _loader_args = [
+    lib.apex_preprocess_nhwc_u8_to_nhwc_f32.argtypes = \
+        lib.apex_preprocess_nhwc_u8_to_nchw_f32.argtypes
+    lib.apex_loader_create.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
         ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
         ctypes.c_int, ctypes.c_uint64, ctypes.POINTER(ctypes.c_float),
-        ctypes.POINTER(ctypes.c_float), ctypes.c_int]
-    if int(lib.apex_native_version()) >= 3:
-        _loader_args.append(ctypes.c_int)
-    lib.apex_loader_create.argtypes = _loader_args
+        ctypes.POINTER(ctypes.c_float), ctypes.c_int, ctypes.c_int]
     lib.apex_loader_create.restype = ctypes.c_void_p
     lib.apex_loader_next.argtypes = [
         ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
@@ -185,8 +194,7 @@ def preprocess_images(images_u8: np.ndarray, mean: Sequence[float],
     n, h, w, c = images_u8.shape
     nhwc_out = data_format == "NHWC"
     lib = _try_load()
-    # the NHWC entry point needs ABI v3 — a stale v2 .so falls back
-    if lib is None or (nhwc_out and version() < 3):
+    if lib is None:
         f = images_u8.astype(np.float32)
         f = (f - np.asarray(mean, np.float32)) / np.asarray(std, np.float32)
         return np.ascontiguousarray(f if nhwc_out

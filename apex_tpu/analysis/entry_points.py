@@ -71,26 +71,10 @@ class EntryPoint:
             # at trace time scope it explicitly via _scoped().
             from ..amp import policy as amp_policy
             base = amp_policy.current_policy()
-            # same discipline for the thread-local mesh context: a
-            # builder that raises mid-``with mesh:`` (the device-count
-            # skip gate fires INSIDE some builders) or that forgets to
-            # exit would otherwise leak a physical mesh into every
-            # graph traced after it — which silently changes what the
-            # sharding propagator sees as the ambient mesh
-            try:
-                from jax.interpreters import pxla
-                mesh_env = pxla.thread_resources.env
-            except Exception:        # pragma: no cover - jax internals
-                pxla = mesh_env = None
             try:
                 self._graph = self._build(self)
             finally:
                 amp_policy.set_policy(base)
-                if mesh_env is not None:
-                    try:
-                        pxla.thread_resources.env = mesh_env
-                    except Exception:   # pragma: no cover
-                        pass
         return self._graph
 
     def cost(self):

@@ -1,5 +1,5 @@
 """Graph plumbing for the static analyzer: jaxpr walking, primitive
-taxonomies, and compat helpers over lowered StableHLO modules.
+vocabularies, and compat helpers over lowered StableHLO modules.
 
 Everything here is *description*, not judgement: these helpers surface
 what a traced/lowered graph contains (host-transfer primitives,

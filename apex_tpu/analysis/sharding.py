@@ -15,11 +15,11 @@ singleton cells.  The replication factor of an array is
 ``local_bytes * (world - n_cells)`` — exactly the fp32 master/optimizer
 state ZeRO-2/3 (ROADMAP item 2) will shard away.
 
-Propagation rules (validated against the jax 0.4.37 jaxprs the entry
+Propagation rules (validated against the jax 0.9.0 jaxprs the entry
 points actually trace):
 
 - ``shard_map`` body inputs: partition keyed by each rank's coordinates
-  along the axes named in ``in_names`` (``{}`` -> replicated).
+  along the axes named in ``in_specs`` (``P()`` -> replicated).
 - default eqn: outputs get the meet (common refinement) of the input
   partitions — sound for any deterministic op (same inputs, same
   outputs).
@@ -522,6 +522,13 @@ class ShardMapAnalysis:
         return out
 
 
+def _spec_names(spec) -> Dict[int, Tuple[str, ...]]:
+    """A shard_map eqn's ``PartitionSpec`` as ``{dim: (axis, ...)}`` —
+    the form the partition model is keyed on (unsharded dims absent)."""
+    return {d: (entry,) if isinstance(entry, str) else tuple(entry)
+            for d, entry in enumerate(spec) if entry is not None}
+
+
 def _names_spec_str(names_dict: Dict[int, Tuple[str, ...]]) -> str:
     if not names_dict:
         return "replicated"
@@ -539,8 +546,8 @@ def shard_map_eqns(jaxpr) -> List[Any]:
 def analyze_shard_map(eqn) -> ShardMapAnalysis:
     """Propagate partitions through one shard_map eqn's body.
 
-    Body input partitions come from ``in_names`` alone (shard_map
-    semantics: the names say how the global operand is laid out across
+    Body input partitions come from ``in_specs`` alone (shard_map
+    semantics: the specs say how the global operand is laid out across
     the mesh, independent of outer context); captured consts are
     replicated."""
     params = eqn.params
@@ -548,13 +555,13 @@ def analyze_shard_map(eqn) -> ShardMapAnalysis:
     axis_sizes = dict(mesh.shape)
     ctx = _MeshCtx(axis_sizes)
     body = params["jaxpr"]                       # open Jaxpr, LOCAL shapes
-    in_names = params["in_names"]
-    out_names = params["out_names"]
+    in_names = [_spec_names(s) for s in params["in_specs"]]
+    out_names = [_spec_names(s) for s in params["out_specs"]]
 
     in_parts = []
     for nm in in_names:
         try:
-            in_parts.append(ctx.names_partition(dict(nm)))
+            in_parts.append(ctx.names_partition(nm))
         except KeyError:
             # axis name not in the mesh — spec rule reports it
             in_parts.append(Partition.varying(ctx.world))
@@ -573,7 +580,7 @@ def analyze_shard_map(eqn) -> ShardMapAnalysis:
             local_bytes=_aval_bytes(v.aval),
             n_cells=part.n_cells,
             replication_factor=part.replication_factor(),
-            spec=_names_spec_str(dict(in_names[i]))))
+            spec=_names_spec_str(in_names[i])))
     for j, v in enumerate(body.constvars):
         args.append(ArgSharding(
             index=len(in_parts) + j,
@@ -586,7 +593,7 @@ def analyze_shard_map(eqn) -> ShardMapAnalysis:
 
     return ShardMapAnalysis(
         world=ctx.world, mesh_axes=dict(ctx.axis_sizes), args=args,
-        out_parts=out_parts, out_names=tuple(dict(n) for n in out_names),
+        out_parts=out_parts, out_names=tuple(out_names),
         sites=sites)
 
 
@@ -625,10 +632,9 @@ def check_shard_map_specs(eqn,
             f"shard_map mesh axes {axis_sizes} != expected "
             f"{dict(expected_mesh_axes)}")
 
-    def _check_names(kind, names, vars_, global_shapes: bool):
-        for i, (nm, v) in enumerate(zip(names, vars_)):
-            nm = dict(nm)
-            for d, axes in nm.items():
+    def _check_names(kind, specs, vars_, global_shapes: bool):
+        for i, (spec, v) in enumerate(zip(specs, vars_)):
+            for d, axes in _spec_names(spec).items():
                 missing = [a for a in axes if a not in axis_sizes]
                 if missing:
                     problems.append(
@@ -644,8 +650,8 @@ def check_shard_map_specs(eqn,
                             f"{kind}[{i}] dim {d} (= {dim}) not divisible "
                             f"by axes {tuple(axes)} (x{factor})")
 
-    _check_names("in_specs", params["in_names"], eqn.invars, True)
-    _check_names("out_specs", params["out_names"], eqn.outvars, True)
+    _check_names("in_specs", params["in_specs"], eqn.invars, True)
+    _check_names("out_specs", params["out_specs"], eqn.outvars, True)
     return problems
 
 

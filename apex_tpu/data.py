@@ -135,11 +135,6 @@ class DataLoader:
             # portable python permutation (the state-protocol stream);
             # the native ring knows neither shards nor record checks
             use_native = False
-        if use_native and data_format == "NHWC" and _native.version() < 3:
-            # stale v2 .so has the 13-arg create: it would silently fill
-            # NCHW slots that we'd reshape as NHWC — scrambled pixels.
-            # The numpy fallback is correct, just slower.
-            use_native = False
         if use_native:
             lib = _native._try_load()
             if lib is not None:
@@ -153,12 +148,8 @@ class DataLoader:
                         ctypes.POINTER(ctypes.c_float)),
                     self.std.ctypes.data_as(
                         ctypes.POINTER(ctypes.c_float)),
-                    1 if shuffle else 0]
-                if _native.version() >= 3:
-                    # the data_format arg exists only in the v3 ABI; the
-                    # NHWC-on-v2 case was already routed to the numpy
-                    # fallback above
-                    create_args.append(1 if data_format == "NHWC" else 0)
+                    1 if shuffle else 0,
+                    1 if data_format == "NHWC" else 0]
                 self._handle = lib.apex_loader_create(*create_args)
         # python fallback state: the checkpointable cursor walk.
         # (epoch, cursor) name a position in the epoch-concatenated

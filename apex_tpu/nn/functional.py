@@ -220,7 +220,7 @@ def batch_norm_stats(x: jax.Array, axes: Tuple[int, ...]
     formulation): the mean and mean-of-squares reductions share one loop,
     which XLA fuses into a single HBM traversal; a shifted two-pass
     variance would serialize a second full read of ``x`` behind the mean
-    (measured ~3 ms/step on ResNet-50 B=128, artifacts/PERF_NOTES_r3.md).
+    (measured ~3 ms/step on ResNet-50 B=128 on a v5e, 2026-07-30).
     It also makes local BN bitwise-consistent with the distributed path,
     which psums (count, Σx, Σx²) in the same form (parallel/
     sync_batchnorm.py; the local half of csrc/welford.cu:259-294).

@@ -1,8 +1,8 @@
 """Analytic cost model over jaxprs: FLOPs, transcendentals, and bytes.
 
 This is the machine-checked version of the hand-rolled roofline math in
-``artifacts/ROOFLINE_r5.md`` / ``artifacts/step_probe.py`` (which now
-import it instead of re-deriving conv FLOPs ad hoc): walk a traced
+``artifacts/step_probe.py`` (which now imports it instead of
+re-deriving conv FLOPs ad hoc): walk a traced
 jaxpr, count the arithmetic each primitive performs, and report totals
 plus per-primitive / per-dtype breakdowns.  ``bench.py`` turns the
 totals into ``mfu`` / ``achieved_tflops`` fields on every train-step
@@ -54,11 +54,10 @@ __all__ = ["Cost", "jaxpr_cost", "eqn_flops", "conv_flops", "dot_flops",
 # -- peak-FLOPs table ------------------------------------------------------
 #
 # Per-chip peak arithmetic rates by ``jax.devices()[0].device_kind``
-# (substring-matched, case-insensitive) and matmul operand dtype.
+# (exact match, case-insensitive) and matmul operand dtype.
 # Sources:
 #  - TPU v5-lite (v5e): 197 bf16 TFLOP/s, 394 int8 TOP/s per chip
-#    (public v5e spec; the value artifacts/ROOFLINE_r5.md's 11.4%-MFU
-#    headline was derived against).  fp32 has no published MXU rate;
+#    (public v5e spec).  fp32 has no published MXU rate;
 #    ~1/4 of bf16 is the engineering estimate used for fp32 matmuls.
 #  - cpu: a NOMINAL 100 GFLOP/s smoke constant.  CPU-host MFU is not a
 #    hardware statement — the constant exists so CPU smoke rounds
@@ -76,11 +75,8 @@ PEAK_FLOPS: Dict[str, Dict[str, float]] = {
 def peak_flops(arch: str, dtype: str) -> Optional[float]:
     """Peak FLOP/s for a device kind + matmul dtype, or None when the
     table has no entry (unknown hardware must not fabricate an MFU)."""
-    a = str(arch).lower()
-    for key, rates in PEAK_FLOPS.items():
-        if key in a or a in key:
-            return rates.get(str(dtype))
-    return None
+    rates = PEAK_FLOPS.get(str(arch).lower())
+    return rates.get(str(dtype)) if rates else None
 
 
 def mfu(flops_per_step: float, step_seconds: float, arch: str,
@@ -444,13 +440,9 @@ def jaxpr_cost(jaxpr, xla_parity: bool = False) -> Cost:
 
 
 def xla_cost(stage) -> Dict[str, float]:
-    """Normalize ``Lowered.cost_analysis()`` / ``Compiled.
-    cost_analysis()`` output (list-wrapped on some jax versions) to a
+    """``Lowered.cost_analysis()`` / ``Compiled.cost_analysis()`` as a
     flat dict with at least ``flops``/``transcendentals`` keys."""
-    ca = stage.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    out = dict(ca)
+    out = dict(stage.cost_analysis())
     out.setdefault("flops", 0.0)
     out.setdefault("transcendentals", 0.0)
     return out

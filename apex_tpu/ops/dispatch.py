@@ -23,7 +23,10 @@ _KERNELS_AVAILABLE = None
 
 def kernels_available() -> bool:
     """True iff the Pallas kernel modules import cleanly (the analogue of
-    the reference's `import amp_C` probe, multi_tensor_apply/__init__.py:1-4)."""
+    the reference's `import amp_C` probe, multi_tensor_apply/__init__.py:1-4).
+    Off-TPU a failed import selects the jnp path; on TPU it raises — a
+    whole kernel family silently running jnp there is a defect, not a
+    degradation."""
     global _KERNELS_AVAILABLE
     if _KERNELS_AVAILABLE is None:
         try:
@@ -35,6 +38,8 @@ def kernels_available() -> bool:
             from . import pallas_flash_attention  # noqa: F401
             _KERNELS_AVAILABLE = True
         except ImportError:
+            if backend() == "tpu":
+                raise
             _KERNELS_AVAILABLE = False
     return _KERNELS_AVAILABLE
 

@@ -44,10 +44,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax<0.5 compat: CompilerParams was still named TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 from .pallas_common import LANES, interpret
 
 _VMEM_BUDGET = 12 * 1024 * 1024
@@ -282,6 +278,7 @@ def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret(),
+        name="flash_fwd",
     )(*operands)
     return o[:, :T, :D], lse[:, :T, 0]
 
@@ -470,6 +467,7 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
         scratch_shapes=[pltpu.VMEM((blk, Dp), jnp.float32)],
         compiler_params=sem,
         interpret=interpret(),
+        name="flash_dq",
     )(*dq_ops)
 
     dkv_specs = [colj, rowi, rowi, colj, statj, statj]
@@ -499,6 +497,7 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
                         pltpu.VMEM((blk, Dp), jnp.float32)],
         compiler_params=sem,
         interpret=interpret(),
+        name="flash_dkv",
     )(*dkv_ops)
     return dq[:, :T, :D], dk[:, :T, :D], dv[:, :T, :D]
 

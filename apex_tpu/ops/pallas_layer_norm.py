@@ -83,6 +83,7 @@ def _fwd(x2, w, b, *, n2, eps, out_dtype):
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         interpret=interpret(),
+        name="layer_norm_fwd",
     )(xp, wp, bp)
     return y[:n1, :n2], mean[:n1, 0], inv[:n1, 0]
 
@@ -150,7 +151,12 @@ def _bwd(dy2, x2, w, mean, inv, *, n2, in_dtype):
         out_shape=[jax.ShapeDtypeStruct((R, C), in_dtype),
                    jax.ShapeDtypeStruct((1, C), jnp.float32),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)],
+        # dw/db are revisited by every grid step: the row axis must run
+        # sequentially on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret(),
+        name="layer_norm_bwd",
     )(dyp, xp, wp, meanp, invp)
     return dx[:n1, :n2], dwa[0, :n2], dba[0, :n2]
 

@@ -101,6 +101,7 @@ def _fwd(x4, mean, var, w, b, *, eps):
         out_specs=row_blk,
         out_shape=jax.ShapeDtypeStruct((R, Cpad), x4.dtype),
         interpret=interpret(),
+        name="bn_apply_fwd",
     )(xp, *cols)
     return y[:rows, :hw].reshape(N, Cch, H, W)
 
@@ -130,6 +131,7 @@ def _bwd(x4, mean, var, w, dy4, *, eps):
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         interpret=interpret(),
+        name="bn_apply_bwd",
     )(dyp, xp, *cols)
     dx = dx[:rows, :hw].reshape(N, Cch, H, W)
     # per-channel epilogue: (N*C, 1) partials -> (C,) (the reference's

@@ -1554,7 +1554,7 @@ class DistributedDataParallel:
         ``steps_per_call > 1`` wraps ``step_fn`` in a ``lax.scan`` over a
         leading micro-batch axis (batch shaped ``(K, per_step...)``) so
         one dispatch runs K optimizer steps — amortizes host→device
-        dispatch latency, which on tunneled TPU runtimes is ~ms-scale.
+        dispatch latency.
         The aux output then carries the K per-step values."""
         if mesh is None:
             mesh = Mesh(jax.devices(), (self.axis_name,))

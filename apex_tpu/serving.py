@@ -93,8 +93,8 @@ __all__ = ["Engine", "PagedEngine", "Seq2SeqEngine",
 # Argument names the engine jits must NEVER donate: per-slot length
 # vectors.  Donating `_sstep`'s cur_len made executables RELOADED from
 # the persistent XLA:CPU compile cache decode garbage (fresh compiles
-# fine — single runs pass, the next warm run hangs; jax 0.4.37 AOT
-# quirk, PR 2).  apex_tpu.analysis's donation rule enforces this
+# fine — single runs pass, the next warm run hangs; seen under jax
+# 0.4.37, PR 2; tests/ci/double_run.py is the runtime gate).  apex_tpu.analysis's donation rule enforces this
 # blocklist over every registered serving entry point, so the gotcha
 # stays pinned even if the inline comments rot.  kv_len (positions
 # prefilled so far) and n_blk (blocks held) are the paged engine's
@@ -964,7 +964,7 @@ class Engine(_SlotScheduler):
 
             # NOT cur_len (argnum 1): donating it corrupts the
             # executable when reloaded from the persistent XLA:CPU
-            # compilation cache (jax 0.4.37 AOT quirk — fresh compiles
+            # compilation cache (seen under jax 0.4.37 — fresh compiles
             # are fine, cache loads decode garbage; pinned by running
             # the serving suite twice against one cache dir).  The
             # multi-GB wins are the two cache trees; ids rides along.

@@ -14,10 +14,11 @@ from .profiler import (range_push, range_pop, nvtx_range, annotate,
                        last_capture_dir, AverageMeter)
 from .checkpoint import (save_checkpoint, restore_checkpoint, latest_step,
                          available_steps)
+from .compile_cache import configure_compile_cache
 from . import ema
 
 __all__ = ["ema", "range_push", "range_pop", "nvtx_range", "annotate",
            "start_profile", "stop_profile", "profile", "profiling_active",
            "current_capture_dir", "last_capture_dir",
            "AverageMeter", "save_checkpoint", "restore_checkpoint",
-           "latest_step", "available_steps"]
+           "latest_step", "available_steps", "configure_compile_cache"]

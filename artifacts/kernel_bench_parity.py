@@ -10,7 +10,7 @@ kernel (including parity-only ones like the standalone syncbn apply
 that production dispatch deliberately leaves to XLA fusion) — and
 compares the dumped outputs.  The steady_ms columns therefore time the
 forced-kernel path, not necessarily what the bench executes.
-Subprocess isolation keeps one wedged/OOM family from killing the
+Subprocess isolation keeps one hung/OOM family from killing the
 sweep, and guarantees the dispatch env is read fresh (it is consulted
 at trace time, so in-process toggling could silently reuse a cached
 compilation).

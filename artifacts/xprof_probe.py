@@ -7,7 +7,7 @@ Runs the same ResNet-50 amp-O2 DDP step bench.py's headline measures,
 warms the compile cache, then traces `ITERS` steps through
 apex_tpu.utils.profiler (range_push/pop annotate the phases) into
 artifacts/xprof_trace_<ts>/.  The trace is the artifact; the companion
-top-3 time-sink paragraph goes in PERF_NOTES_r5.md once step_probe's
+top-3 time-sink paragraph goes in PERF.md once step_probe's
 decomposition has run on the same silicon.
 
 Run:  python artifacts/xprof_probe.py  [batch]

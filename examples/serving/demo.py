@@ -13,9 +13,13 @@ and wall time:
   seq2seq  — encoder-decoder (T5) continuous batching
 
 Weights are random (content-free); the point is the mechanics and the
-relative costs.  Usage:
+relative costs.
 
+Rehearse on the CPU:
+  JAX_PLATFORMS=cpu \
   python examples/serving/demo.py --batch 4 --prompt 16 --new 32
+On a TPU host (one process per chip; `python chip_smoke.py` first):
+  python examples/serving/demo.py --batch 8 --prompt 64 --new 64
 """
 
 import argparse
@@ -35,6 +39,7 @@ import jax.numpy as jnp
 
 from apex_tpu import models, quantization
 from apex_tpu.models import beam_search, generate_speculative
+from apex_tpu.utils import configure_compile_cache
 
 
 def build(n_layer, n_embd, seed, vocab, block):
@@ -68,6 +73,7 @@ def main():
     p.add_argument("--beams", type=int, default=4)
     p.add_argument("--gamma", type=int, default=4)
     args = p.parse_args()
+    configure_compile_cache()
     block = args.block or (args.prompt + args.new)
 
     target, tp = build(args.layers, args.width, 0, args.vocab, block)

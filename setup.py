@@ -39,6 +39,8 @@ setup(
                 "toolkit (Apex-equivalent on JAX/XLA/Pallas)",
     packages=find_packages(include=["apex_tpu", "apex_tpu.*"]),
     python_requires=">=3.10",
-    install_requires=["jax", "numpy"],
+    # written for and tested on jax/jaxlib 0.9.0 (libtpu 0.0.34 on TPU);
+    # no shims for other versions are carried
+    install_requires=["jax>=0.9.0,<0.10", "numpy"],
     cmdclass={"build_native": BuildNative},
 )

@@ -2,8 +2,8 @@
 """CI gate: run the serving + fleet suites TWICE against ONE
 persistent compile-cache dir — and MEASURE that run 2 reloaded.
 
-Why twice: the PR 2 donation gotcha.  On jax 0.4.37's XLA:CPU,
-donating the wrong argnum class (the per-slot length vectors,
+Why twice: the PR 2 donation gotcha.  On XLA:CPU (seen under jax
+0.4.37; not re-derived since), donating the wrong argnum class (the per-slot length vectors,
 ``serving.DONATION_BLOCKLIST``) produces executables that work when
 freshly compiled but decode garbage when RELOADED from the persistent
 compilation cache — so a single green run proves nothing about the
@@ -29,9 +29,13 @@ as unavailable and only the behavioral both-runs-green gate applies.
 
 Usage:
 
-    python tests/ci/double_run.py             # temp cache dir
+    python tests/ci/double_run.py             # fixed dir, emptied first
     python tests/ci/double_run.py /some/dir   # persistent across CI runs
-    python tests/ci/double_run.py --keep      # leave the temp dir behind
+
+The default directory is ``<checkout>/.jax_compile_cache/double_run`` —
+fixed, because the children find the cache through the standard
+``JAX_COMPILATION_CACHE_DIR`` and a moving path never hits — and it is
+emptied before run 1 so that run really is cold.
 
 Extra pytest args go after ``--``:
 
@@ -46,7 +50,6 @@ import os
 import shutil
 import subprocess
 import sys
-import tempfile
 
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(
     os.path.abspath(__file__)), os.pardir, os.pardir))
@@ -169,17 +172,16 @@ def main(argv):
     if "--" in args:
         split = args.index("--")
         args, extra = args[:split], args[split + 1:]
-    keep = "--keep" in args
-    args = [a for a in args if a != "--keep"]
     if args:
-        cache_dir, made_tmp = os.path.abspath(args[0]), False
-        os.makedirs(cache_dir, exist_ok=True)
+        cache_dir = os.path.abspath(args[0])
     else:
-        cache_dir = tempfile.mkdtemp(prefix="apex_tpu_double_run_")
-        made_tmp = True
+        cache_dir = os.path.join(_ROOT, ".jax_compile_cache",
+                                 "double_run")
+        shutil.rmtree(cache_dir, ignore_errors=True)
+    os.makedirs(cache_dir, exist_ok=True)
 
     env = dict(os.environ)
-    env["APEX_TPU_COMPILE_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env.pop("APEX_TPU_NO_COMPILE_CACHE", None)
     # every compile cacheable: the run-2 HIT assertion must not be
     # spoiled by toy compiles under the default 0.5s write threshold
@@ -188,40 +190,36 @@ def main(argv):
              for run in (1, 2)}
 
     status = 0
-    try:
-        for run in (1, 2):
-            label = ("cold (populates the cache)" if run == 1
-                     else "warm (AOT-reloaded executables)")
-            print(f"double_run: run {run}/2 — {label}; cache dir "
-                  f"{cache_dir}", flush=True)
-            env["APEX_TPU_COMPILATION_LEDGER_DUMP"] = dumps[run]
-            proc = subprocess.run(
-                [sys.executable, "-m", "pytest", *SUITES, "-q",
-                 *(extra or ["-x"])],
-                cwd=_ROOT, env=env)
-            if proc.returncode != 0:
-                print(f"double_run: run {run}/2 FAILED "
-                      f"(exit {proc.returncode})"
-                      + ("" if run == 1 else
-                         " — executables reloaded from the persistent "
-                         "compile cache misbehaved; suspect a donation "
-                         "change (see serving.DONATION_BLOCKLIST)"),
-                      file=sys.stderr)
-                status = proc.returncode
-                break
+    for run in (1, 2):
+        label = ("cold (populates the cache)" if run == 1
+                 else "warm (AOT-reloaded executables)")
+        print(f"double_run: run {run}/2 — {label}; cache dir "
+              f"{cache_dir}", flush=True)
+        env["APEX_TPU_COMPILATION_LEDGER_DUMP"] = dumps[run]
+        proc = subprocess.run(
+            [sys.executable, "-m", "pytest", *SUITES, "-q",
+             *(extra or ["-x"])],
+            cwd=_ROOT, env=env)
+        if proc.returncode != 0:
+            print(f"double_run: run {run}/2 FAILED "
+                  f"(exit {proc.returncode})"
+                  + ("" if run == 1 else
+                     " — executables reloaded from the persistent "
+                     "compile cache misbehaved; suspect a donation "
+                     "change (see serving.DONATION_BLOCKLIST)"),
+                  file=sys.stderr)
+            status = proc.returncode
+            break
+    else:
+        errs = check_cache_hits(dumps[1], dumps[2])
+        for e in errs:
+            print(f"double_run: {e}", file=sys.stderr)
+        if errs:
+            status = 1
         else:
-            errs = check_cache_hits(dumps[1], dumps[2])
-            for e in errs:
-                print(f"double_run: {e}", file=sys.stderr)
-            if errs:
-                status = 1
-            else:
-                print("double_run: both runs green — donated "
-                      "executables survive the AOT cache round trip, "
-                      "and run 2 measurably RELOADED them")
-    finally:
-        if made_tmp and not keep:
-            shutil.rmtree(cache_dir, ignore_errors=True)
+            print("double_run: both runs green — donated "
+                  "executables survive the AOT cache round trip, "
+                  "and run 2 measurably RELOADED them")
     return status
 
 

@@ -1,10 +1,6 @@
-"""bench.py stale-record mechanism (VERDICT r3 item 6): a TPU run
-persists its lines; a wedged run replays them with ``stale: true`` and
-provenance, headline last, so the round artifact degrades to "last known
-hardware number" instead of a CPU smoke that reads as a regression."""
+"""bench.py's importable pieces: the --comm record contract and the ZeRO
+legs' device-count gate."""
 
-import json
-import re
 import os
 import sys
 
@@ -12,88 +8,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import bench
-
-
-def _lines():
-    return [
-        {"metric": "resnet50_amp_o2_ddp_train_throughput", "value": 1830.0,
-         "unit": "images/sec/chip", "vs_baseline": 11.712,
-         "backend": "tpu", "ndev": 1, "arch": "TPU v5 lite"},
-        {"metric": "ddp_allreduce_bandwidth", "value": 12.0,
-         "unit": "GB/s/chip", "vs_baseline": None, "backend": "tpu",
-         "ndev": 1, "arch": "TPU v5 lite", "note": "chunked-psum path"},
-    ]
-
-
-def test_save_load_roundtrip(tmp_path):
-    p = str(tmp_path / "rec.json")
-    bench.save_tpu_record(_lines(), path=p, now="2026-07-30T04:55:00Z")
-    rec = bench.load_tpu_record(path=p)
-    assert rec["recorded_at"] == "2026-07-30T04:55:00Z"
-    assert rec["lines"] == [
-        {**ln, "recorded_at": "2026-07-30T04:55:00Z"} for ln in _lines()]
-
-
-def test_partial_run_merges_without_clobbering_headline(tmp_path):
-    """A later partial run (headline config hung) must not evict the
-    previous headline from the record — else a wedge replay ends on the
-    wrong metric."""
-    p = str(tmp_path / "rec.json")
-    bench.save_tpu_record(_lines(), path=p, now="2026-07-30T04:55:00Z")
-    bench.save_tpu_record(
-        [{"metric": "ddp_allreduce_bandwidth", "value": 14.0,
-          "unit": "GB/s/chip", "vs_baseline": None, "backend": "tpu",
-          "ndev": 1, "arch": "TPU v5 lite"}],
-        path=p, now="2026-07-31T08:00:00Z")
-    rec = bench.load_tpu_record(path=p)
-    by_metric = {ln["metric"]: ln for ln in rec["lines"]}
-    # headline carried over with its ORIGINAL timestamp
-    head = by_metric[bench.HEADLINE_METRIC]
-    assert head["value"] == 1830.0
-    assert head["recorded_at"] == "2026-07-30T04:55:00Z"
-    # updated metric replaced, stamped with the new time
-    assert by_metric["ddp_allreduce_bandwidth"]["value"] == 14.0
-    assert (by_metric["ddp_allreduce_bandwidth"]["recorded_at"]
-            == "2026-07-31T08:00:00Z")
-    # replay still ends on the headline, with per-line provenance
-    stale = bench.stale_lines(rec)
-    assert stale[-1]["metric"] == bench.HEADLINE_METRIC
-    assert stale[-1]["stale_recorded_at"] == "2026-07-30T04:55:00Z"
-    assert stale[0]["stale_recorded_at"] == "2026-07-31T08:00:00Z"
-
-
-def test_save_empty_is_noop(tmp_path):
-    p = str(tmp_path / "rec.json")
-    bench.save_tpu_record([], path=p)
-    assert not os.path.exists(p)
-    assert bench.load_tpu_record(path=p) is None
-
-
-def test_load_garbage_returns_none(tmp_path):
-    p = str(tmp_path / "rec.json")
-    with open(p, "w") as f:
-        f.write("{not json")
-    assert bench.load_tpu_record(path=p) is None
-
-
-def test_stale_lines_annotate_and_order_headline_last(tmp_path):
-    p = str(tmp_path / "rec.json")
-    bench.save_tpu_record(_lines(), path=p, now="2026-07-30T04:55:00Z")
-    out = bench.stale_lines(bench.load_tpu_record(path=p))
-    assert [ln["metric"] for ln in out] == [
-        "ddp_allreduce_bandwidth", bench.HEADLINE_METRIC]
-    for ln in out:
-        assert ln["stale"] is True
-        assert ln["stale_recorded_at"] == "2026-07-30T04:55:00Z"
-        assert ln["note"].startswith(
-            "STALE REPLAY — NOT A FRESH MEASUREMENT")
-        assert re.search(r"captured \d+d ago", ln["note"])
-        assert json.loads(json.dumps(ln)) == ln    # JSON-serializable
-    # original note preserved after the stale prefix
-    assert "chunked-psum path" in out[0]["note"]
-    # values untouched — this is a replay, not a new measurement
-    assert out[1]["value"] == 1830.0
-    assert out[1]["vs_baseline"] == 11.712
 
 
 def test_comm_bench_record_schema():
@@ -118,7 +32,7 @@ def test_comm_bench_record_schema():
                          "dcn_wire_bytes")}
     assert any("comm_topology" in e
                for e in exporters.validate_bench_record(bare))
-    # ...but a stale replay of a pre-topology record is exempt
+    # ...but a record marked stale (pre-topology) is exempt
     assert exporters.validate_bench_record(dict(bare, stale=True)) == []
     # bad values flag field-by-field
     assert any("comm_topology" in e for e in
@@ -132,16 +46,6 @@ def test_comm_bench_record_schema():
                    dict(good, compress="yes")))
     assert any("ici_size" in e for e in
                exporters.validate_bench_record(dict(good, ici_size=0)))
-
-
-def test_committed_record_is_valid():
-    """The repo ships a seeded record (r3's manual pre-wedge measurement)
-    so even a whole round of wedge leaves a hardware line."""
-    rec = bench.load_tpu_record()
-    assert rec is not None
-    stale = bench.stale_lines(rec)
-    assert stale[-1]["metric"] == bench.HEADLINE_METRIC
-    assert stale[-1]["backend"] == "tpu"
 
 
 def test_zero_leg_device_gate_is_bare_runtime_error():

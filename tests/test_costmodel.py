@@ -162,10 +162,13 @@ def test_fp32_matmul_fraction():
 
 
 def test_peak_flops_table_and_mfu():
-    """Documented peak table: the v5-lite bf16 entry is the 197
-    TFLOP/s the ROOFLINE_r5 headline was derived against; unknown
-    hardware yields mfu None (absent beats fabricated)."""
+    """Documented peak table: the v5-lite bf16 entry is the published
+    197 TFLOP/s, keyed on the exact ``device_kind`` the chip reports
+    (a substring is not a device); unknown hardware yields mfu None
+    (absent beats fabricated)."""
     assert costmodel.peak_flops("TPU v5 lite", "bfloat16") == 197e12
+    assert costmodel.peak_flops("TPU", "bfloat16") is None
+    assert costmodel.peak_flops("", "bfloat16") is None
     assert costmodel.peak_flops("cpu", "float32") == 100e9
     assert costmodel.peak_flops("warp drive", "bfloat16") is None
 
@@ -179,7 +182,7 @@ def test_peak_flops_table_and_mfu():
 
 
 def test_roofline_r5_flops_accounting_corrected():
-    """The promoted ROOFLINE_r5 math, now machine-checked — and
+    """The round-5 hand roofline math, now machine-checked — and
     CORRECTED: the hand-rolled roofline priced a resnet50 224^2
     forward at "4.1 GFLOP/img", which is the published ~4.1 GMACs
     quoted in the 2-flops-per-MAC convention the peak table uses, so

@@ -120,7 +120,7 @@ def test_growth_at_exactly_scale_window():
 def test_cap_behavior_at_max_loss_scale():
     """At the cap the grow branch still fires (streak keeps
     resetting), the scale stays clamped, and an overflow halves FROM
-    the cap — no wedge state."""
+    the cap — it never sticks."""
     W = 2
     cap = 2.0 ** 17
     s = LossScaler("dynamic", scale_window=W, max_loss_scale=cap)
