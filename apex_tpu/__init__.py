@@ -28,6 +28,9 @@ automatically off-TPU, mirroring Apex's graceful-degradation invariant
 (reference README.md:90-95).
 """
 
+import time as _time
+_import_began = _time.perf_counter()
+
 from . import nn
 from . import amp
 from . import multi_tensor_apply
@@ -48,3 +51,7 @@ from . import fleet
 from . import analysis
 
 __version__ = "0.1.0"
+
+# what importing the package cost, as a span on the process recorder
+observability.get_recorder().add_span("apex_tpu.import", _import_began,
+                                      _time.perf_counter())

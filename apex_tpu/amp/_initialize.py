@@ -93,21 +93,25 @@ class AmpModel:
               mutable: bool = True, **kwargs):
         props = self.properties
         ct = None if self.disabled else props.options.get("cast_model_type")
+        # the casts at the model's boundary belong to the model's phase
+        # (nn.apply opens the same root scope around the module itself)
         if ct is not None and jnp.dtype(ct) != jnp.dtype(jnp.float32):
-            args = _cast_floats(args, ct)
-            kwargs = _cast_floats(kwargs, ct)
+            with jax.named_scope("model"):
+                args = _cast_floats(args, ct)
+                kwargs = _cast_floats(kwargs, ct)
         from ..nn import module as _nn_module
         with _policy.use_policy(self._make_policy()):
             out, new_state = _nn_module.apply(
                 self.module, params, *args, state=state, train=train,
                 rng=rng, mutable=mutable, **kwargs)
         co = None if self.disabled else props.options.get("cast_model_outputs")
-        if co is not None:
-            out = _cast_floats(out, co)
-        elif ct is not None and jnp.dtype(ct) != jnp.dtype(jnp.float32):
-            # O2/O3 cast model outputs back to fp32 (reference
-            # _initialize.py:197-208) so losses run in fp32.
-            out = _cast_floats(out, jnp.float32)
+        with jax.named_scope("model"):
+            if co is not None:
+                out = _cast_floats(out, co)
+            elif ct is not None and jnp.dtype(ct) != jnp.dtype(jnp.float32):
+                # O2/O3 cast model outputs back to fp32 (reference
+                # _initialize.py:197-208) so losses run in fp32.
+                out = _cast_floats(out, jnp.float32)
         return out, new_state
 
     __call__ = apply

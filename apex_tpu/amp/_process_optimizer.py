@@ -662,7 +662,8 @@ class AmpOptimizer(Optimizer):
         elif flat:
             # fused-buffer hot path: one concat, one fused unscale, one
             # optimizer kernel, static slices back out
-            scaled_grads = opt_state.masters.layout.pack(scaled_grads)
+            with jax.named_scope("amp.pack"):
+                scaled_grads = opt_state.masters.layout.pack(scaled_grads)
         if zero:
             layout = opt_state.masters.layout
             dp = jax.lax.axis_size(zaxis)
@@ -704,7 +705,8 @@ class AmpOptimizer(Optimizer):
                 scaled_grads = jax.lax.psum_scatter(
                     scaled_grads, zaxis, scatter_dimension=0, tiled=True)
             scaled_grads = scaled_grads / dp
-        grads32, found_inf = self.scaler.unscale(scaled_grads, sstate)
+        with jax.named_scope("amp.unscale"):
+            grads32, found_inf = self.scaler.unscale(scaled_grads, sstate)
         if found_inf_extra is not None:
             found_inf = jnp.maximum(found_inf, found_inf_extra)
         if zero:
@@ -714,7 +716,8 @@ class AmpOptimizer(Optimizer):
         for ax in (found_inf_axes or ()):
             if _axis_in_scope(ax):
                 found_inf = jax.lax.pmax(found_inf, ax)
-        new_sstate = self.scaler.update(sstate, found_inf)
+        with jax.named_scope("amp.scaler_update"):
+            new_sstate = self.scaler.update(sstate, found_inf)
         scalers = tuple(new_sstate if i == loss_id else s
                         for i, s in enumerate(opt_state.scalers))
 
@@ -751,16 +754,18 @@ class AmpOptimizer(Optimizer):
                     half, zaxis, axis=0, tiled=True,
                     axis_index_groups=gather_groups)[:layout.total]
                     if half is not None else None)
-                new_p = layout.rebuild(full32, full_half,
-                                       jax.tree_util.tree_leaves(p))
+                with jax.named_scope("amp.rebuild"):
+                    new_p = layout.rebuild(full32, full_half,
+                                           jax.tree_util.tree_leaves(p))
                 return new_p, FlatMasters(new_buf, layout), new_inner
         elif flat:
             def do_update(operand):
                 p, masters, inner = operand
                 new_buf, new_inner, half = self._flat_inner_step(
                     masters, inner, grads32)
-                new_p = masters.layout.rebuild(
-                    new_buf, half, jax.tree_util.tree_leaves(p))
+                with jax.named_scope("amp.rebuild"):
+                    new_p = masters.layout.rebuild(
+                        new_buf, half, jax.tree_util.tree_leaves(p))
                 return new_p, FlatMasters(new_buf, masters.layout), new_inner
         elif opt_state.masters is not None:
             def do_update(operand):
@@ -769,7 +774,8 @@ class AmpOptimizer(Optimizer):
                     grads32, inner, masters)
                 # master -> model copy (the reference's
                 # _master_params_to_model_params, _process_optimizer.py:242-253)
-                new_p = _cast_like(new_masters, p)
+                with jax.named_scope("amp.rebuild"):
+                    new_p = _cast_like(new_masters, p)
                 return new_p, new_masters, new_inner
         else:
             def do_update(operand):
@@ -781,9 +787,10 @@ class AmpOptimizer(Optimizer):
         def skip_update(operand):
             return operand
 
-        new_params, new_masters, new_inner = jax.lax.cond(
-            found_inf > 0, skip_update, do_update,
-            (params, opt_state.masters, opt_state.inner))
+        with jax.named_scope("amp.update"):
+            new_params, new_masters, new_inner = jax.lax.cond(
+                found_inf > 0, skip_update, do_update,
+                (params, opt_state.masters, opt_state.inner))
 
         from ..optimizers.base import global_grad_norm
         # grad-norm gauge (observability): the unscaled fp32 grads are
@@ -792,18 +799,19 @@ class AmpOptimizer(Optimizer):
         # it DCE'd — no cost unless consumed.  Under ZeRO each device
         # holds a disjoint grad window, so the squared sums psum to the
         # global norm (the pad elements are zero).
-        if zero and zstage >= 2:
-            # windows are disjoint within the slice but REPLICATED
-            # across slices (post-DCN grads are identical): a full-axis
-            # psum would overcount by dcn_size
-            grad_norm = jnp.sqrt(jax.lax.psum(
-                jnp.sum(jnp.square(grads32)), zaxis,
-                axis_index_groups=zero_groups[0]))
-        elif zero:
-            grad_norm = jnp.sqrt(jax.lax.psum(
-                jnp.sum(jnp.square(grads32)), zaxis))
-        else:
-            grad_norm = global_grad_norm(grads32)
+        with jax.named_scope("amp.grad_norm"):
+            if zero and zstage >= 2:
+                # windows are disjoint within the slice but REPLICATED
+                # across slices (post-DCN grads are identical): a
+                # full-axis psum would overcount by dcn_size
+                grad_norm = jnp.sqrt(jax.lax.psum(
+                    jnp.sum(jnp.square(grads32)), zaxis,
+                    axis_index_groups=zero_groups[0]))
+            elif zero:
+                grad_norm = jnp.sqrt(jax.lax.psum(
+                    jnp.sum(jnp.square(grads32)), zaxis))
+            else:
+                grad_norm = global_grad_norm(grads32)
         info = {"found_inf": found_inf,
                 "loss_scale": new_sstate.loss_scale,
                 "steps_skipped": new_sstate.steps_skipped,

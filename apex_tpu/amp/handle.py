@@ -47,17 +47,19 @@ def scaled_grad(loss_fn: Callable, params: Any, opt_state: AmpOptState,
 
     def scaled_fn(p):
         res = loss_fn(p, *args, **kwargs)
-        if has_aux:
-            loss, aux = res
-            return loss.astype(jnp.float32) * scale, aux
-        return res.astype(jnp.float32) * scale
+        loss, aux = res if has_aux else (res, None)
+        with jax.named_scope("amp.scale_loss"):
+            scaled = loss.astype(jnp.float32) * scale
+        return (scaled, aux) if has_aux else scaled
 
     if has_aux:
         (scaled_loss, aux), grads = jax.value_and_grad(
             scaled_fn, has_aux=True)(params)
-        return scaled_loss / scale, aux, grads
-    scaled_loss, grads = jax.value_and_grad(scaled_fn)(params)
-    return scaled_loss / scale, grads
+    else:
+        scaled_loss, grads = jax.value_and_grad(scaled_fn)(params)
+    with jax.named_scope("amp.scale_loss"):
+        loss = scaled_loss / scale
+    return (loss, aux, grads) if has_aux else (loss, grads)
 
 
 def scaled_grad_accum(loss_fn: Callable, params: Any,
@@ -81,9 +83,13 @@ def scaled_grad_accum(loss_fn: Callable, params: Any,
     scale = opt_state.scalers[loss_id].loss_scale
     K = jax.tree_util.tree_leaves(batches)[0].shape[0]
 
+    def scaled_fn(pp, mb):
+        loss = loss_fn(pp, mb)
+        with jax.named_scope("amp.scale_loss"):
+            return loss.astype(jnp.float32) * scale
+
     def one(p, mb):
-        return jax.value_and_grad(
-            lambda pp: loss_fn(pp, mb).astype(jnp.float32) * scale)(p)
+        return jax.value_and_grad(scaled_fn)(p, mb)
 
     def body(carry, mb):
         loss_sum, acc = carry
@@ -100,12 +106,14 @@ def scaled_grad_accum(loss_fn: Callable, params: Any,
         lambda l: jnp.zeros(l.shape, jnp.float32), params)
     (loss_sum, grads), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), zeros), batches)
-    if average:
-        grads = jax.tree_util.tree_map(lambda g: g / K, grads)
-        return loss_sum / scale / K, grads
-    # sum convention: loss and grads agree (the caller's objective is
-    # the SUM of micro-batch losses)
-    return loss_sum / scale, grads
+    with jax.named_scope("amp.scale_loss"):
+        loss = loss_sum / scale
+        # sum convention (average=False): loss and grads agree, the
+        # caller's objective is the SUM of micro-batch losses
+        if average:
+            loss = loss / K
+            grads = jax.tree_util.tree_map(lambda g: g / K, grads)
+    return loss, grads
 
 
 class _ScaledLoss:

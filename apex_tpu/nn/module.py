@@ -172,7 +172,10 @@ class Module:
         raise NotImplementedError(type(self).__name__)
 
     def __call__(self, params: Dict[str, Any], *args, **kwargs):
-        return self.forward(params, *args, **kwargs)
+        # the module path names the HLO (bert/encoder/3/attention/...),
+        # under the root scope ``model`` that :func:`apply` opens
+        with jax.named_scope(self._name or type(self).__name__):
+            return self.forward(params, *args, **kwargs)
 
     def apply(self, params: Dict[str, Any], *args,
               state: Optional[Dict[str, Any]] = None, train: bool = False,
@@ -256,7 +259,8 @@ def apply(module: Module, params: Dict[str, Any], *args,
     ctx = ApplyContext(state, train, rng, mutable)
     _CTX.stack.append(ctx)
     try:
-        out = module(params, *args, **kwargs)
+        with jax.named_scope("model"):
+            out = module(params, *args, **kwargs)
     finally:
         _CTX.stack.pop()
     return out, ctx.merged_state()

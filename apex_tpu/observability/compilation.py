@@ -32,6 +32,13 @@ ledger dispatch in flight on that thread (installed lazily at the first
 :func:`instrumented_jit`; absent monitoring support the cache column
 reads ``uncached``).
 
+The same listeners keep, per tracing dispatch, the stages jax itself
+times (:data:`STAGE_FIELDS`: tracing, jaxpr -> MLIR, the persistent
+cache's retrieval, the backend compile), and a tracing dispatch leaves
+what :meth:`CompilationLedger.compiled_text` needs to hand out the
+entry's optimized HLO text later (``observability.phases`` reads the
+phase of every instruction from it).  A cached dispatch leaves nothing.
+
 Causes (:data:`RETRACE_CAUSES`):
 
 - ``new_entry`` — the entry's first trace ever (the expected warmup
@@ -80,11 +87,12 @@ import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["RETRACE_CAUSES", "SIGNATURE_CHANGE_CAUSES",
-           "BENCH_COMPILE_FIELDS", "CompilationLedger",
+           "BENCH_COMPILE_FIELDS", "STAGE_FIELDS", "CompilationLedger",
            "abstract_signature", "diff_signatures", "format_signature",
            "signature_fingerprint", "instrumented_jit",
            "get_ledger", "set_ledger"]
@@ -134,7 +142,8 @@ class _Dispatch:
     the monitoring listeners attribute to this thread."""
 
     __slots__ = ("ledger", "entry", "events", "cache_hits",
-                 "cache_misses", "backend_compile_s")
+                 "cache_misses", "backend_compile_s", "lower_s",
+                 "cache_load_s", "trace_spans")
 
     def __init__(self, ledger: "CompilationLedger", entry: str):
         self.ledger = ledger
@@ -143,6 +152,34 @@ class _Dispatch:
         self.cache_hits = 0
         self.cache_misses = 0
         self.backend_compile_s = 0.0
+        self.lower_s = 0.0
+        self.cache_load_s = 0.0
+        # jax times every nested jit's trace inside the outer one's:
+        # the spans are kept and their union is the tracing time
+        self.trace_spans: List[Tuple[float, float]] = []
+
+    @property
+    def compile_s(self) -> float:
+        """Seconds in the backend's compiler proper: jax's event spans
+        compile-or-load, so a persistent-cache hit's retrieval is taken
+        out of it and the stage fields stay disjoint."""
+        return max(self.backend_compile_s - self.cache_load_s, 0.0)
+
+    @property
+    def compiled_here(self) -> bool:
+        """The backend compiled in this dispatch and nothing came out of
+        the persistent cache: the executable is of this program's own
+        module (false also where the listeners saw nothing)."""
+        return self.cache_hits == 0 and self.backend_compile_s > 0.0
+
+    @property
+    def trace_s(self) -> float:
+        total, end = 0.0, float("-inf")
+        for s, e in sorted(self.trace_spans):
+            if e > end:
+                total += e - max(s, end)
+                end = e
+        return total
 
     @property
     def cache_label(self) -> str:
@@ -159,7 +196,15 @@ class _Dispatch:
 
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+# spans compile-or-load: on a persistent-cache hit it holds the
+# retrieval (_CACHE_LOAD_EVENT) and no compile (_Dispatch.compile_s)
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# the per-stage seconds an entry's record shows beside compile_wall_s,
+# disjoint and in the order they happen inside a tracing dispatch
+STAGE_FIELDS = ("trace_s", "lower_s", "cache_load_s", "backend_compile_s")
 
 _monitoring_installed = False
 _monitoring_lock = threading.Lock()
@@ -181,6 +226,17 @@ def _on_monitoring_duration(event: str, duration: float, **kwargs):
         return
     if event == _BACKEND_COMPILE_EVENT:
         rec.backend_compile_s += float(duration)
+    elif event == _LOWER_EVENT:
+        rec.lower_s += float(duration)
+    elif event == _CACHE_LOAD_EVENT:
+        rec.cache_load_s += float(duration)
+
+
+def _on_monitoring_time_span(event: str, start: float, end: float,
+                             **kwargs):
+    rec = current_dispatch()
+    if rec is not None and event == _TRACE_EVENT:
+        rec.trace_spans.append((float(start), float(end)))
 
 
 def _install_monitoring():
@@ -197,6 +253,8 @@ def _install_monitoring():
             _mon.register_event_listener(_on_monitoring_event)
             _mon.register_event_duration_secs_listener(
                 _on_monitoring_duration)
+            _mon.register_event_time_span_listener(
+                _on_monitoring_time_span)
         except Exception:       # noqa: BLE001 — API drift: the ledger
             # still counts traces; the cache column reads "uncached"
             pass
@@ -358,6 +416,11 @@ class CompilationLedger:
         self._max_events = int(max_events_per_entry)
         self._total_traces = 0
         self._total_wall_s = 0.0
+        # entry -> (weakref to the instrumented callable, abstract
+        # args, kwargs, compiled here) of its last traced signature, and
+        # the optimized HLO text of it, read on demand (compiled_text)
+        self._lowerable: Dict[str, Tuple[Any, tuple, dict, bool]] = {}
+        self._compiled_text: Dict[str, str] = {}
 
     # -- default resolution (per use) ----------------------------------
     def _reg(self):
@@ -384,7 +447,8 @@ class CompilationLedger:
                 "last_signature": None, "last_closure": None,
                 "last_fingerprint": None,
                 "last_retrace": None,
-                "compile_wall_s": 0.0, "backend_compile_s": 0.0,
+                "compile_wall_s": 0.0, "trace_s": 0.0, "lower_s": 0.0,
+                "cache_load_s": 0.0, "backend_compile_s": 0.0,
                 "last_trace_t_s": None,
                 "events": deque(maxlen=self._max_events)}
         return st
@@ -474,11 +538,14 @@ class CompilationLedger:
             dispatch.events.append(ev)
         return ev
 
-    def _finalize_dispatch(self, rec: _Dispatch, wall_s: float):
+    def _finalize_dispatch(self, rec: _Dispatch, wall_s: float,
+                           lowerable: Optional[tuple] = None):
         """Close the books on one instrumented dispatch that traced:
         the wall duration (trace + lower + compile + first execution —
         the honest 'how long did the cold call cost' number), the
-        persistent-cache attribution, and the compile counters."""
+        stages jax itself timed inside it (:data:`STAGE_FIELDS`), the
+        persistent-cache attribution, the compile counters, and what
+        :meth:`compiled_text` needs to lower the signature again."""
         if not rec.events:
             return
         label = rec.cache_label
@@ -488,8 +555,13 @@ class CompilationLedger:
             st["cache"][label] = st["cache"].get(label, 0) + 1
             st["compile_wall_s"] = round(
                 st["compile_wall_s"] + wall_s, 6)
-            st["backend_compile_s"] = round(
-                st["backend_compile_s"] + rec.backend_compile_s, 6)
+            for field, spent in zip(STAGE_FIELDS, (
+                    rec.trace_s, rec.lower_s, rec.cache_load_s,
+                    rec.compile_s)):
+                st[field] = round(st[field] + spent, 6)
+            if lowerable is not None:
+                self._lowerable[rec.entry] = lowerable
+                self._compiled_text.pop(rec.entry, None)
             for ev in rec.events:
                 ev["wall_s"] = round(wall_s, 6)
                 ev["cache"] = label
@@ -509,6 +581,32 @@ class CompilationLedger:
     def jit(self, fun, entry: str, **kwargs):
         """:func:`instrumented_jit` bound to THIS ledger."""
         return instrumented_jit(fun, entry, ledger=self, **kwargs)
+
+    def compiled_text(self, entry: str) -> Optional[str]:
+        """Optimized HLO text of ``entry``'s last traced signature (what
+        :func:`~apex_tpu.observability.phases.instruction_phases` reads),
+        computed on first demand and kept.  Where the tracing dispatch
+        compiled the program itself it is the text of that executable,
+        from jax's in-memory caches; where it loaded the executable from
+        the persistent cache, the program is compiled once more past the
+        cache (:func:`_own_text` says why).  None when the entry never
+        traced through :func:`instrumented_jit` or its callable is
+        gone."""
+        with self._lock:
+            text = self._compiled_text.get(entry)
+        if text is not None:
+            return text
+        with _text_lock:            # one compile at a time, one an entry
+            with self._lock:
+                text = self._compiled_text.get(entry)
+                lowerable = self._lowerable.get(entry)
+            if text is None and lowerable is not None:
+                text = _own_text(*lowerable)
+                with self._lock:
+                    if (text is not None
+                            and self._lowerable.get(entry) is lowerable):
+                        self._compiled_text[entry] = text
+        return text
 
     # -- contract / snapshot surface -------------------------------------
     def total_traces(self) -> int:
@@ -586,6 +684,77 @@ class CompilationLedger:
 
 # -- instrumentation --------------------------------------------------------
 
+def _lowerable(fn, args, kwargs, static_argnums, static_argnames,
+               compiled_here):
+    """What ``compiled_text`` needs to lower a traced call again: the
+    callable, weakly held, its arguments with every array leaf (jax or
+    numpy) replaced by its shape, dtype and sharding (all still readable
+    on a donated array), and whether the dispatch compiled the program
+    itself; static arguments and python scalars stay as they are.  None
+    for a call made under an outer trace: its arguments are tracers, and
+    the outer program is what gets compiled."""
+    import jax
+    import numpy as np
+
+    if any(isinstance(x, jax.core.Tracer)
+           for x in jax.tree_util.tree_leaves((args, kwargs))):
+        return None
+
+    def leaf(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=x.sharding,
+                                        weak_type=x.weak_type)
+        if isinstance(x, np.ndarray):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    abstract = lambda tree: jax.tree_util.tree_map(leaf, tree)
+    return (weakref.ref(fn),
+            tuple(a if i in static_argnums else abstract(a)
+                  for i, a in enumerate(args)),
+            {k: v if k in static_argnames else abstract(v)
+             for k, v in kwargs.items()},
+            compiled_here)
+
+
+# compiled_text compiles one entry at a time, in whatever ledger
+_text_lock = threading.Lock()
+
+
+def _own_text(ref, args, kwargs, compiled_here) -> Optional[str]:
+    """Optimized HLO text of the program ``_lowerable`` described, with
+    THIS program's ``op_name``s.
+
+    The persistent cache's key leaves metadata out, so an executable a
+    dispatch loaded from it may have been compiled from another
+    checkout's module and carry ITS ``op_name``s (none of this program's
+    scopes); instruction names are the same either way.  Whether a
+    loaded executable is foreign cannot be told from its text: the
+    compiler drops whole scopes (``amp.grad_norm`` in the BERT step), so
+    a scope that is missing proves nothing.  So only a dispatch that
+    compiled here vouches for its executable, and jax's in-memory caches
+    then hand back that very module and executable.  Otherwise this
+    program's own module is compiled past the cache: a key that holds the
+    metadata finds nothing to read, and nothing is written (a second copy
+    of a large step would push the entries the next start needs out of a
+    size-capped cache).  Either way a process pays one backend compile
+    of the step, in its dispatch or here, and not both."""
+    fn = ref()
+    if fn is None:
+        return None
+    from jax._src import config as jax_config     # thread-local settings
+    _inflight.quiet = True       # this lowering is not a trace to count
+    try:
+        if compiled_here:
+            return fn.lower(*args, **kwargs).compile().as_text()
+        with jax_config.compilation_cache_include_metadata_in_key(True), \
+                jax_config.persistent_cache_min_compile_time_secs(1e30):
+            return fn.lower_afresh(*args, **kwargs).compile().as_text()
+    finally:
+        _inflight.quiet = False
+
+
 def instrumented_jit(fun, entry: str, *, ledger=None,
                      arg_names: Optional[Sequence[str]] = None,
                      static_argnums: Sequence[int] = (),
@@ -625,6 +794,8 @@ def instrumented_jit(fun, entry: str, *, ledger=None,
         return led if led is not None else get_ledger()
 
     def _traced(*args, **kwargs):
+        if getattr(_inflight, "quiet", False):      # compiled_text
+            return fun(*args, **kwargs)
         rec = current_dispatch()
         led = rec.ledger if rec is not None else _resolve(ledger)
         sig = abstract_signature(args, kwargs, static_argnums=sargs,
@@ -658,9 +829,24 @@ def instrumented_jit(fun, entry: str, *, ledger=None,
                 st.remove(rec)
             except ValueError:
                 pass
-            led._finalize_dispatch(rec, dt)
+            if rec.events:      # this dispatch traced: never per dispatch
+                led._finalize_dispatch(rec, dt, _lowerable(
+                    wrapped, args, kwargs, sargs, snames,
+                    rec.compiled_here))
+
+    def lower_afresh(*args, **kwargs):
+        """Trace and lower again through a new jit of a new function
+        object: past every in-memory cache jax keeps per function."""
+        def again(*a, **k):
+            return _traced(*a, **k)
+        again.__name__, again.__qualname__ = (_traced.__name__,
+                                              _traced.__qualname__)
+        return jax.jit(again, static_argnums=sargs or None,
+                       static_argnames=snames or None,
+                       **jit_kwargs).lower(*args, **kwargs)
 
     wrapped.lower = jitted.lower
+    wrapped.lower_afresh = lower_afresh
     wrapped.jitted = jitted
     wrapped.entry = entry
     wrapped.closure_id = cid

@@ -118,8 +118,36 @@ class SpanRecorder:
         self._pid = os.getpid()
         self._next_span = 0
 
+    @property
+    def origin(self) -> float:
+        """The clock reading every ``ts`` counts from (``perf_counter``
+        unless another clock was given): ``origin + ts / 1e6`` places a
+        span beside anything else timed on that clock."""
+        return self._t0
+
     def _now_us(self) -> float:
         return (self._clock() - self._t0) * 1e6
+
+    def add_span(self, name: str, begin: float, end: float, **attrs) -> int:
+        """Record a complete span from two readings of this recorder's
+        clock that the caller took itself (a span that began before the
+        recorder could be reached: the package's own import)."""
+        span_id = self._alloc_span()
+        self._complete(name, (begin - self._t0) * 1e6,
+                       (end - self._t0) * 1e6, threading.get_ident(),
+                       None, span_id, None, attrs)
+        return span_id
+
+    def _complete(self, name, begin_us, end_us, tid, trace_id, span_id,
+                  parent_id, attrs):
+        ev = {"name": name, "ph": "X", "ts": begin_us,
+              "dur": max(end_us - begin_us, 0.0),
+              "pid": self._pid, "tid": tid}
+        self._stamp(ev, trace_id, span_id, parent_id)
+        if attrs:
+            ev["args"] = dict(attrs)
+        with self._lock:
+            self._events.append(ev)
 
     def _alloc_span(self) -> int:
         """Next span id, allocated under the lock at span ENTRY, so ids
@@ -178,15 +206,8 @@ class SpanRecorder:
         finally:
             if token is not None:
                 _CURRENT.reset(token)
-            end = self._now_us()
-            ev = {"name": name, "ph": "X", "ts": begin,
-                  "dur": max(end - begin, 0.0),
-                  "pid": self._pid, "tid": tid}
-            self._stamp(ev, trace_id, span_id, parent_id)
-            if attrs:
-                ev["args"] = dict(attrs)
-            with self._lock:
-                self._events.append(ev)
+            self._complete(name, begin, self._now_us(), tid, trace_id,
+                           span_id, parent_id, attrs)
 
     def event(self, name: str, trace_id: Optional[str] = None,
               parent_id: Optional[int] = None, **attrs) -> int:

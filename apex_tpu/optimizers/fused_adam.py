@@ -99,6 +99,7 @@ class FusedAdam(Optimizer):
         return self.step(params, state, grads)[:2]
 
     # -- reference-shaped step --------------------------------------------
+    @jax.named_scope("optim.adam")
     def step(self, params: Any, state: AdamState, grads: Any,
              scale: float = 1.0, grad_norm: Optional[jax.Array] = None,
              output_params_dtype=None):

@@ -67,6 +67,7 @@ class FusedLAMB(Optimizer):
     def update(self, grads: Any, state: LambState, params: Any):
         return self.step(params, state, grads)
 
+    @jax.named_scope("optim.lamb")
     def step(self, params: Any, state: LambState, grads: Any,
              grad_norm: Optional[jax.Array] = None):
         """One LAMB step over the chunk-padded fused buffer.

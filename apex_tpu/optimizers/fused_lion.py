@@ -59,6 +59,7 @@ class FusedLion(Optimizer):
     def update(self, grads: Any, state: LionState, params: Any):
         return self.step(params, state, grads)[:2]
 
+    @jax.named_scope("optim.lion")
     def step(self, params: Any, state: LionState, grads: Any,
              scale: float = 1.0, grad_norm: Optional[jax.Array] = None,
              output_params_dtype=None):

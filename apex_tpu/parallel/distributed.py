@@ -405,8 +405,11 @@ def allreduce_grads_tree(grads: Any, axis_name: str = "data",
             buckets = [idxs]
 
         for bucket in buckets:
-            flat = jnp.concatenate([leaves[i].reshape(-1) for i in bucket])
-            comm = flat.astype(jnp.float32) if allreduce_always_fp32 else flat
+            with jax.named_scope("ddp.pack"):
+                flat = jnp.concatenate(
+                    [leaves[i].reshape(-1) for i in bucket])
+                comm = (flat.astype(jnp.float32) if allreduce_always_fp32
+                        else flat)
             nstat = None
             if numerics_out is not None:
                 # bucket health on the pre-divide comm buffer: what
@@ -426,31 +429,34 @@ def allreduce_grads_tree(grads: Any, axis_name: str = "data",
             pre, post = predivide_factors(world,
                                           gradient_predivide_factor)
             if pre != 1.0:
-                comm = comm / jnp.asarray(pre, comm.dtype)
+                with jax.named_scope("ddp.pack"):
+                    comm = comm / jnp.asarray(pre, comm.dtype)
 
             n = comm.shape[0]
             acct = _bucket_wire_accounting(
                 n, comm.dtype, topo, ici, compress, message_size,
                 delay_allreduce, bool(trigger_paths))
-            if topo == "hierarchical":
-                reduced, comp_err = _hierarchical_reduce(
-                    comm, axis_name, ici_groups, dcn_groups, compress,
-                    want_error=numerics_out is not None)
-                if nstat is not None and comp_err is not None:
-                    nstat["compression_sq_error"] = comp_err
-            elif acct["chunks"] == 1:
-                reduced = lax.psum(comm, axis_name,
-                                   axis_index_groups=axis_index_groups)
-            else:
-                # chunked psum: XLA schedules the pieces independently —
-                # the compiler-native form of the reference's bucket overlap
-                nchunks = acct["chunks"]
-                pad = nchunks * message_size - n
-                padded = jnp.pad(comm, (0, pad))
-                chunks = padded.reshape(nchunks, message_size)
-                reduced = lax.psum(chunks, axis_name,
-                                   axis_index_groups=axis_index_groups)
-                reduced = reduced.reshape(-1)[:n]
+            with jax.named_scope("ddp.reduce"):
+                if topo == "hierarchical":
+                    reduced, comp_err = _hierarchical_reduce(
+                        comm, axis_name, ici_groups, dcn_groups, compress,
+                        want_error=numerics_out is not None)
+                    if nstat is not None and comp_err is not None:
+                        nstat["compression_sq_error"] = comp_err
+                elif acct["chunks"] == 1:
+                    reduced = lax.psum(comm, axis_name,
+                                       axis_index_groups=axis_index_groups)
+                else:
+                    # chunked psum: XLA schedules the pieces
+                    # independently — the compiler-native form of the
+                    # reference's bucket overlap
+                    nchunks = acct["chunks"]
+                    pad = nchunks * message_size - n
+                    padded = jnp.pad(comm, (0, pad))
+                    chunks = padded.reshape(nchunks, message_size)
+                    reduced = lax.psum(chunks, axis_name,
+                                       axis_index_groups=axis_index_groups)
+                    reduced = reduced.reshape(-1)[:n]
 
             if comm_stats is not None:
                 comm_stats.append({
@@ -461,16 +467,18 @@ def allreduce_grads_tree(grads: Any, axis_name: str = "data",
             if nstat is not None:
                 numerics_out.append(nstat)
 
-            if gradient_average:
-                reduced = reduced / post.astype(reduced.dtype)
-            reduced = reduced.astype(dt)
-            if retain_buffers is not None:
-                retain_buffers.append(reduced)
-            off = 0
-            for i in bucket:
-                sz = leaves[i].size
-                new_leaves[i] = reduced[off:off + sz].reshape(leaves[i].shape)
-                off += sz
+            with jax.named_scope("ddp.unpack"):
+                if gradient_average:
+                    reduced = reduced / post.astype(reduced.dtype)
+                reduced = reduced.astype(dt)
+                if retain_buffers is not None:
+                    retain_buffers.append(reduced)
+                off = 0
+                for i in bucket:
+                    sz = leaves[i].size
+                    new_leaves[i] = reduced[off:off + sz].reshape(
+                        leaves[i].shape)
+                    off += sz
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 
@@ -1018,13 +1026,17 @@ def flat_dist_call(tree: Any, axis_name: str = "data", op: str = "psum",
         groups.setdefault(jnp.dtype(g.dtype), []).append(i)
     out: List[Any] = [None] * len(leaves)
     for dt, idxs in groups.items():
-        flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
-        red = reducer(flat, axis_name, axis_index_groups=axis_index_groups)
-        off = 0
-        for i in idxs:
-            sz = leaves[i].size
-            out[i] = red[off:off + sz].reshape(leaves[i].shape)
-            off += sz
+        with jax.named_scope("ddp.pack"):
+            flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
+        with jax.named_scope("ddp.reduce"):
+            red = reducer(flat, axis_name,
+                          axis_index_groups=axis_index_groups)
+        with jax.named_scope("ddp.unpack"):
+            off = 0
+            for i in idxs:
+                sz = leaves[i].size
+                out[i] = red[off:off + sz].reshape(leaves[i].shape)
+                off += sz
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -1404,20 +1416,25 @@ class DistributedDataParallel:
                     f"fused shard update runs on ONE flat buffer per "
                     f"stage — cast the stage params to a single dtype")
             (dt,) = dts
-            flat = (leaves[0].reshape(-1) if len(leaves) == 1 else
-                    jnp.concatenate([l.reshape(-1) for l in leaves]))
-            comm = (flat.astype(jnp.float32)
-                    if self.allreduce_always_fp32 else flat)
+            with jax.named_scope("ddp.pack"):
+                flat = (leaves[0].reshape(-1) if len(leaves) == 1 else
+                        jnp.concatenate([l.reshape(-1) for l in leaves]))
+                comm = (flat.astype(jnp.float32)
+                        if self.allreduce_always_fp32 else flat)
             pre, post = predivide_factors(
                 world_scalar, self.gradient_predivide_factor)
             if pre != 1.0:
-                comm = comm / jnp.asarray(pre, comm.dtype)
+                with jax.named_scope("ddp.pack"):
+                    comm = comm / jnp.asarray(pre, comm.dtype)
             n = comm.shape[0]
-            g_shard, _ = _hier_scatter_reduce(
-                comm, self.axis_name, ici_groups, dcn_groups, compress)
-            if self.gradient_average:
-                g_shard = g_shard / post.astype(g_shard.dtype)
-            g_shard = g_shard.astype(dt)
+            with jax.named_scope("ddp.reduce"):
+                g_shard, _ = _hier_scatter_reduce(
+                    comm, self.axis_name, ici_groups, dcn_groups,
+                    compress)
+            with jax.named_scope("ddp.unpack"):
+                if self.gradient_average:
+                    g_shard = g_shard / post.astype(g_shard.dtype)
+                g_shard = g_shard.astype(dt)
             m = g_shard.shape[0]
             # the local window of the CURRENT params at the shard's
             # offset — a static-offset slice, no communication
@@ -1429,13 +1446,15 @@ class DistributedDataParallel:
             idx = lax.axis_index(self.axis_name) % ici
             p_shard = lax.dynamic_slice_in_dim(flat_par, idx * m, m)
             new_shard = update_shard(stage, p_shard, g_shard)
-            full = _hier_gather(new_shard, self.axis_name, ici_groups,
-                                n)
+            with jax.named_scope("ddp.reduce"):
+                full = _hier_gather(new_shard, self.axis_name,
+                                    ici_groups, n)
             out, off = [], 0
-            for l in leaves:
-                sz = int(l.size)
-                out.append(full[off:off + sz].reshape(l.shape))
-                off += sz
+            with jax.named_scope("ddp.unpack"):
+                for l in leaves:
+                    sz = int(l.size)
+                    out.append(full[off:off + sz].reshape(l.shape))
+                    off += sz
             acct = _bucket_wire_accounting(
                 n, comm.dtype, "hierarchical", ici, compress,
                 self.message_size, False, False)

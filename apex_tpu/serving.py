@@ -1468,6 +1468,7 @@ class PagedEngine(_SlotScheduler):
         K = self.window
         n_slots = slots
 
+        @jax.named_scope("paged.gather")
         def _gather_dense(pool, tables):
             """pool leaves -> per-slot dense (slots, H, MB*bs, D)
             views through the block tables (stale/padded table entries
@@ -1479,6 +1480,7 @@ class PagedEngine(_SlotScheduler):
                                  leaf.shape[3])
             return jax.tree_util.tree_map(g, pool)
 
+        @jax.named_scope("paged.scatter")
         def _scatter_cols(pool, dense, tables, q, gate):
             """Write the freshly computed columns ``q`` (slots, L) of
             the dense views back into their physical blocks.  Gated:
@@ -1561,8 +1563,9 @@ class PagedEngine(_SlotScheduler):
                     toks = jnp.take_along_axis(
                         ids, jnp.clip(qs, 0, buf_len - 1), axis=1)
                     dense = _gather_dense(pool, tables)
-                    _, dense = model.decode_chunk(params, toks, pos0,
-                                                  dense)
+                    with jax.named_scope("paged.attend"):
+                        _, dense = model.decode_chunk(params, toks,
+                                                      pos0, dense)
                     gate = (needs_pf[:, None]
                             & (qs < (cur_len - 1)[:, None]))
                     pool2 = _scatter_cols(pool, dense, tables, qs,
@@ -1587,8 +1590,9 @@ class PagedEngine(_SlotScheduler):
                     ids, jnp.clip(pos, 0, buf_len - 1)[:, None],
                     axis=1)
                 dense = _gather_dense(pool, tables)
-                h, dense = model.decode_chunk(params, tok_in, pos,
-                                              dense)
+                with jax.named_scope("paged.attend"):
+                    h, dense = model.decode_chunk(params, tok_in, pos,
+                                                  dense)
                 pool = _scatter_cols(pool, dense, tables, pos[:, None],
                                      dec_ok[:, None])
                 logits = _head_logits(model, params, h)[:, 0]

@@ -60,7 +60,10 @@ def build(args):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from apex_tpu import amp, models, optimizers, parallel
+    from apex_tpu.observability import get_recorder
     from apex_tpu.observability.compilation import instrumented_jit
+
+    span = get_recorder().span      # set-up by phase: build.*
 
     if args.config == "tiny":
         cfg = models.BertConfig(vocab_size=1024, hidden_size=64,
@@ -78,11 +81,12 @@ def build(args):
     else:
         optimizer = optimizers.FusedAdam(lr=lr, weight_decay=0.01)
 
-    model, optimizer = amp.initialize(
-        models.BertForPretraining(cfg), optimizer,
-        opt_level=args.opt_level, loss_scale=args.loss_scale,
-        half_dtype=args.half_dtype)
-    ddp = parallel.DistributedDataParallel(model)
+    with span("build.amp_initialize"):
+        model, optimizer = amp.initialize(
+            models.BertForPretraining(cfg), optimizer,
+            opt_level=args.opt_level, loss_scale=args.loss_scale,
+            half_dtype=args.half_dtype)
+        ddp = parallel.DistributedDataParallel(model)
 
     ndev = len(jax.devices())
     global_batch = args.batch_size * ndev
@@ -96,9 +100,12 @@ def build(args):
     def put_batch(batch):
         return jax.device_put(batch, batch_sharding)
 
-    params, _ = model.init(jax.random.PRNGKey(args.seed))
-    params = jax.device_put(params, replicated)
-    opt_state = jax.device_put(optimizer.init(params), replicated)
+    with span("build.model_init"):
+        params, _ = model.init(jax.random.PRNGKey(args.seed))
+    with span("build.place_params"):
+        params = jax.device_put(params, replicated)
+    with span("build.optimizer_init"):
+        opt_state = jax.device_put(optimizer.init(params), replicated)
 
     rng = np.random.RandomState(args.seed)
     T = args.seq_len
@@ -119,15 +126,18 @@ def build(args):
         def loss_fn(p):
             # through model.apply so the amp cast policy is in scope
             (mlm_logits, nsp_logits), _ = model.apply(p, ids)
-            logp = jax.nn.log_softmax(mlm_logits.astype(jnp.float32), -1)
-            valid = mlm_labels != -100
-            lbl = jnp.where(valid, mlm_labels, 0)
-            nll = -jnp.take_along_axis(logp, lbl[..., None], -1)[..., 0]
-            mlm = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
-            nsp_logp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), -1)
-            nsp = -jnp.mean(jnp.take_along_axis(
-                nsp_logp, nsp_labels[:, None], -1))
-            return mlm + nsp
+            with jax.named_scope("loss"):
+                logp = jax.nn.log_softmax(
+                    mlm_logits.astype(jnp.float32), -1)
+                valid = mlm_labels != -100
+                lbl = jnp.where(valid, mlm_labels, 0)
+                nll = -jnp.take_along_axis(logp, lbl[..., None], -1)[..., 0]
+                mlm = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
+                nsp_logp = jax.nn.log_softmax(
+                    nsp_logits.astype(jnp.float32), -1)
+                nsp = -jnp.mean(jnp.take_along_axis(
+                    nsp_logp, nsp_labels[:, None], -1))
+                return mlm + nsp
 
         loss, grads = amp.scaled_grad(loss_fn, params, opt_state)
         grads = ddp.allreduce_grads(grads)
@@ -139,12 +149,13 @@ def build(args):
     # the compilation ledger watches the step (a retrace mid-run is a
     # bug, and the ledger names the argument that caused it); the old
     # state's buffers are donated to the new one
-    train_step = instrumented_jit(jax.shard_map(
-        step, mesh=mesh,
-        in_specs=(P(), (P("data"), P("data"), P("data"))),
-        out_specs=(P(), P()), check_vma=False),
-        "bert.train_step", arg_names=("state", "batch"),
-        donate_argnums=(0,))
+    with span("build.step_wrap"):
+        train_step = instrumented_jit(jax.shard_map(
+            step, mesh=mesh,
+            in_specs=(P(), (P("data"), P("data"), P("data"))),
+            out_specs=(P(), P()), check_vma=False),
+            "bert.train_step", arg_names=("state", "batch"),
+            donate_argnums=(0,))
 
     return types.SimpleNamespace(
         model=model, optimizer=optimizer, ddp=ddp, mesh=mesh,

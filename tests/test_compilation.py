@@ -6,9 +6,13 @@ outcomes on real jits, and the jit wrapper keeps the `.lower()` /
 `make_jaxpr` surfaces the analysis entry points depend on."""
 
 import json
+import os
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from apex_tpu.observability import compilation as C
@@ -348,3 +352,147 @@ def test_sequential_engines_do_not_storm_the_supervisor():
         assert sup._counts["recompilation_storm"] == 0
     finally:
         flightrec.set_ring(prev)
+
+
+# -- PR 24: stage times of a tracing dispatch, and the entry's own text -----
+
+@pytest.fixture
+def fresh_cache(tmp_path):
+    """A persistent compilation cache of this test's own, every compile cached."""
+    from jax.experimental.compilation_cache import compilation_cache
+    options = {"jax_compilation_cache_dir": str(tmp_path / "cache"),
+               "jax_persistent_cache_min_compile_time_secs": 0.0,
+               "jax_persistent_cache_min_entry_size_bytes": -1}
+    prev = {k: getattr(jax.config, k) for k in options}
+    for k, v in options.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()          # this directory, whatever was open
+    yield tmp_path / "cache"
+    for k, v in prev.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("dispatch", ["cold", "cached"])
+def test_stage_times_of_a_cold_and_a_cached_dispatch(fresh_cache, dispatch):
+    led = C.CompilationLedger()
+
+    def body(x):
+        return jnp.tanh(x @ x).sum()
+    x = jnp.ones((32, 32))
+    C.instrumented_jit(body, "t.stages", ledger=led)(x)
+    record = led.snapshot()["entries"]["t.stages"]
+    if dispatch == "cached":
+        if record["cache"]["uncached"]:
+            pytest.skip("jax.monitoring cache events unavailable")
+        cold = dict(record)
+        C.instrumented_jit(body, "t.stages", ledger=led)(x)      # a fresh closure: reload
+        record = led.snapshot()["entries"]["t.stages"]
+        assert record["cache"]["hit"] == 1
+        assert record["cache_load_s"] > 0.0
+        # the load is not counted again as compiling
+        added = record["backend_compile_s"] - cold["backend_compile_s"]
+        assert added <= 0.25 * cold["backend_compile_s"] + 0.01
+    else:
+        assert record["cache_load_s"] == 0.0 and record["backend_compile_s"] > 0.0
+    assert record["trace_s"] > 0.0 and record["lower_s"] > 0.0
+    assert set(C.STAGE_FIELDS) <= set(record)
+    assert sum(record[f] for f in C.STAGE_FIELDS) <= record["compile_wall_s"] * 1.05
+
+
+def _scoped(led, scope):
+    def step(x):
+        with jax.named_scope(scope):
+            return jnp.tanh(x @ x).sum()
+    return C.instrumented_jit(step, "t." + scope, ledger=led)
+
+
+def test_compiled_text_is_this_programs_own_whatever_the_cache_held(fresh_cache):
+    """The persistent cache's key leaves metadata out: a dispatch may load an
+    executable compiled from the same operations under other scopes."""
+    led = C.CompilationLedger()
+
+    x = jnp.ones((48, 48))
+    older, newer = _scoped(led, "older_scope"), _scoped(led, "amp.update")
+    older(x)
+    newer(x)
+    if led.snapshot()["entries"]["t.amp.update"]["cache"]["hit"] != 1:
+        pytest.skip("the second program did not load the first one's executable")
+    entries = lambda: sorted(f for f in os.listdir(fresh_cache) if not f.endswith("-atime"))
+    held = entries()
+    text = led.compiled_text("t.amp.update")
+    assert "/amp.update/" in text and "older_scope" not in text
+    # nothing was written to the cache, and the options are as they were
+    assert entries() == held
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_compiled_text_of_a_dispatch_that_compiled_is_that_executables(fresh_cache):
+    """A dispatch that missed the cache compiled this program's own module:
+    its text is read back from jax's in-memory caches, with no second compile."""
+    led = C.CompilationLedger()
+    program = _scoped(led, "amp.pack")
+    program(np.ones((40, 40), np.float32))       # a numpy argument is abstracted too
+    ref, args, kwargs, compiled_here = led._lowerable["t.amp.pack"]
+    assert compiled_here and isinstance(args[0], jax.ShapeDtypeStruct)
+
+    def no_second_compile(*a, **k):
+        raise AssertionError("compiled past the cache")
+    program.lower_afresh = no_second_compile
+    before = led.total_traces()
+    assert "/amp.pack/" in led.compiled_text("t.amp.pack")
+    assert led.total_traces() == before
+
+
+def test_compiled_text_from_two_threads_leaves_the_settings_alone(fresh_cache):
+    """Two callers at once, both past the cache: the settings are this
+    thread's only, so no other thread's compile sees them and none is left
+    behind; the compiles take their turns."""
+    from jax._src import config as jax_config
+    led = C.CompilationLedger()
+    x = jnp.ones((56, 56))
+    programs = {scope: _scoped(led, scope)     # the ledger holds them weakly
+                for scope in ("older_a", "amp.update", "older_b", "amp.unscale")}
+    for program in programs.values():
+        program(x)
+    entries = led.snapshot()["entries"]
+    if not all(entries["t." + s]["cache"]["hit"] == 1 for s in ("amp.update", "amp.unscale")):
+        pytest.skip("the second programs did not load the first ones' executables")
+    inside, seen = [0], []
+    for scope in ("amp.update", "amp.unscale"):
+        fn = programs[scope]
+
+        def watched(*a, _afresh=fn.lower_afresh, **k):
+            inside[0] += 1
+            seen.append((inside[0],
+                         jax_config.compilation_cache_include_metadata_in_key.value))
+            try:
+                time.sleep(0.05)
+                return _afresh(*a, **k)
+            finally:
+                inside[0] -= 1
+        fn.lower_afresh = watched
+    texts = {}
+    threads = [threading.Thread(
+        target=lambda s=s: texts.__setitem__(s, led.compiled_text("t." + s)))
+        for s in ("amp.update", "amp.unscale")]
+    for t in threads:
+        t.start()
+    while any(t.is_alive() for t in threads):
+        # what this thread would compile with, all the while
+        assert not jax_config.compilation_cache_include_metadata_in_key.value
+        time.sleep(0.005)
+    for t in threads:
+        t.join()
+    assert seen == [(1, True), (1, True)]
+    assert "/amp.update/" in texts["amp.update"] and "older" not in texts["amp.update"]
+    assert "/amp.unscale/" in texts["amp.unscale"] and "older" not in texts["amp.unscale"]
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_nested_jit_traces_are_counted_once():
+    rec = C._Dispatch(C.CompilationLedger(), "t")
+    rec.trace_spans += [(1.0, 2.0), (1.2, 1.4), (0.0, 3.0), (5.0, 6.0)]
+    assert rec.trace_s == pytest.approx(4.0)
