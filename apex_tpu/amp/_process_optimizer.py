@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .scaler import LossScaler, ScalerState
+from ..ops.pallas_common import aligned_len
 from ..optimizers.base import Optimizer
 
 
@@ -352,7 +353,19 @@ class _FlatLayout:
     construction (apex/optimizers/fp16_optimizer.py:57-70); round-1 apex_tpu
     instead re-packed the whole tree every step
     (round-2 VERDICT weak-item 2) — this layout makes pack/unpack a single
-    concat / static-slice set that XLA folds into neighbouring ops."""
+    concat / static-slice set that XLA folds into neighbouring ops.
+
+    Two lengths.  ``total`` is the LOGICAL element count, the sum of the
+    float leaves' sizes: offsets, ``rebuild``, ``unpack_masters``, the
+    ZeRO shard arithmetic and the comm plans all count in it.
+    ``storage`` is the length the un-sharded path keeps its persistent
+    buffers at (masters, the packed gradient, the inner optimizer's
+    moments): ``total`` rounded up to the kernels' block
+    (``ops.pallas_common.aligned_len``), so the Adam and unscale kernels
+    view them without a pad or a slice and update them in place.  The
+    tail past ``total`` is zero and stays zero under every elementwise
+    inner optimizer (g = m = v = p = 0 updates to 0).  ZeRO shards keep
+    their own length, ``ceil(total / population)``, with no tail."""
 
     def __init__(self, params):
         leaves, self.treedef = jax.tree_util.tree_flatten(params)
@@ -404,11 +417,22 @@ class _FlatLayout:
     def __hash__(self):
         return hash(self._key())
 
+    @property
+    def storage(self) -> int:
+        """Length of the un-sharded path's persistent flat buffers."""
+        return aligned_len(self.total)
+
     def pack(self, tree) -> jax.Array:
-        """Float leaves → one flat fp32 buffer (single concat)."""
+        """Float leaves → one flat fp32 buffer (single concat): of
+        ``storage`` elements, the zero tail one more operand of the
+        concat, on the un-sharded path; of ``total`` elements under
+        ZeRO, whose callers pad to their shard population."""
         leaves = jax.tree_util.tree_leaves(tree)
         parts = [l.reshape(-1).astype(jnp.float32)
                  for l, f in zip(leaves, self.is_float) if f]
+        tail = self.storage - self.total if self.zero_axis is None else 0
+        if parts and tail:
+            parts.append(jnp.zeros((tail,), jnp.float32))
         if not parts:
             return jnp.zeros((0,), jnp.float32)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)

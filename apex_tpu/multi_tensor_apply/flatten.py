@@ -124,8 +124,10 @@ class ChunkedFlatLayout:
     equivalent of the reference's single multi_tensor_l2norm kernel with a
     per-tensor output buffer (csrc/multi_tensor_l2norm_kernel.cu:117-180),
     replacing round-1's per-leaf Python loop (~2 reductions per leaf on a
-    400-leaf tree).  Distinct from amp's dense ``_FlatLayout`` (no padding,
-    fused half-copy rebuild): here padding buys alignment for segment math.
+    400-leaf tree).  Distinct from amp's dense ``_FlatLayout`` (leaves
+    packed back to back, one zero tail after the last so that the whole
+    buffer is a block-aligned length; fused half-copy rebuild): here
+    per-leaf padding buys alignment for segment math.
 
     The layout is static (computed once, hashable) so it can ride pytree
     aux_data; padded slots hold zeros and are invariant under elementwise

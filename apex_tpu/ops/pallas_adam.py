@@ -7,7 +7,12 @@ the same pass.  Bias correction is folded into ``step_size`` host-side
 (:83-91), matching the reference.
 
 Inputs are fp32 flat buffers viewed as (rows, 128); p/m/v are updated via
-``input_output_aliases`` so the kernel is in-place on device memory.
+``input_output_aliases``.  That is in place on the CALLER's buffers only
+when their length is a whole number of blocks (``pallas_common.aligned_len``;
+amp's flat state is kept at such a length): ``to_2d`` is then a reshape and
+the alias reaches the optimizer state itself.  At any other length the
+kernel updates padded copies in place and ``from_2d`` slices the results
+back out — four pads and three or four slices of the whole buffer a step.
 """
 
 from __future__ import annotations

@@ -6,6 +6,17 @@ structs, chunking and relaunching as the struct fills.  TPU has no such
 constraint: the tensor list is concatenated into one flat buffer on device
 (a fusion XLA performs as pure data movement) and each kernel tiles over a
 2-D (rows, 128) view of it — lanes fixed at 128, row blocks sized for VMEM.
+
+The view is free only when the buffer's length is a whole number of
+blocks: ``to_2d`` is then a reshape (a bitcast on the chip) and
+``from_2d`` slices nothing, so a kernel's ``input_output_aliases`` reach
+the caller's own buffer.  Any other length costs one ``pad`` per operand
+on the way in and one ``slice`` per result on the way out, each a full
+copy of the buffer.  ``aligned_len`` is the rule for a caller that keeps
+a buffer across steps and can choose its length (amp's flat masters,
+gradients and moments do); the two counters ``flat_pad_copies_total`` /
+``flat_pad_copy_elements_total`` in the observability registry count, per
+traced program, the copies that were made anyway.
 """
 
 from __future__ import annotations
@@ -55,19 +66,58 @@ def pick_block_rows(n: int) -> int:
     return -(-rows // MIN_SUBLANES) * MIN_SUBLANES
 
 
+def aligned_len(n: int) -> int:
+    """Smallest length >= ``n`` that ``to_2d(buf, pick_block_rows(n))``
+    views without padding: a whole number of ``pick_block_rows(n) *
+    LANES``-element blocks (65 536 elements from one full block up, so
+    at most 256 KiB of fp32 more than ``n``).  An empty buffer stays
+    empty."""
+    n = int(n)
+    if n <= 0:
+        return 0
+    block = pick_block_rows(n) * LANES
+    return -(-n // block) * block
+
+
+def _count_copy(elements: int) -> None:
+    """One operand padded or one result sliced, at TRACE time (the
+    registry's DDP counters work the same way: totals count traced
+    programs, not executed steps)."""
+    from ..observability.metrics import get_registry
+    reg = get_registry()
+    reg.counter(
+        "flat_pad_copies_total",
+        help="flat-buffer operands padded by to_2d and results sliced "
+             "by from_2d, per traced program; 0 when every buffer has "
+             "a block-aligned length").inc()
+    reg.counter(
+        "flat_pad_copy_elements_total",
+        help="elements those pads and slices copy per traced program"
+    ).inc(elements)
+
+
 def to_2d(flat: jax.Array, block_rows: int = BLOCK_ROWS
           ) -> Tuple[jax.Array, int]:
-    """Pad a 1-D buffer to a (rows, LANES) view, rows a multiple of
-    ``block_rows`` so every grid block is full.  Returns
-    (arr2d, orig_len)."""
+    """View a 1-D buffer as (rows, LANES), rows a multiple of
+    ``block_rows`` so every grid block is full: a reshape when the
+    length already is one (see ``aligned_len``), else a zero pad first
+    (a copy of the buffer, counted).  Returns (arr2d, orig_len)."""
     n = flat.shape[0]
     rows = max(1, -(-n // LANES))
     rows = -(-rows // block_rows) * block_rows
     padded = rows * LANES
     if padded != n:
+        _count_copy(padded)
         flat = jnp.pad(flat, (0, padded - n))
     return flat.reshape(rows, LANES), n
 
 
 def from_2d(arr2d: jax.Array, n: int) -> jax.Array:
-    return arr2d.reshape(-1)[:n]
+    """Inverse of ``to_2d``: the first ``n`` elements as a 1-D buffer —
+    a reshape when the view held nothing else, else a slice (a copy,
+    counted)."""
+    flat = arr2d.reshape(-1)
+    if flat.shape[0] == n:
+        return flat
+    _count_copy(n)
+    return flat[:n]

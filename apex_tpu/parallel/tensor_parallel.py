@@ -570,7 +570,19 @@ def sharded_optimizer_specs(optimizer, params: Any, param_specs: Any,
     glob = jax.eval_shape(optimizer.init, params)
     loc = jax.eval_shape(optimizer.init, local_params)
 
+    # amp's flat buffers are stored at a block-aligned length
+    # (_FlatLayout.storage), so a small model's local and global
+    # buffers can have one shape while holding different elements:
+    # whether they are sharded is read from the logical counts
+    from ..amp._process_optimizer import FlatMasters
+    flat_len = None
+    if (isinstance(getattr(loc, "masters", None), FlatMasters)
+            and glob.masters.layout.total != loc.masters.layout.total):
+        flat_len = loc.masters.layout.storage
+
     def leaf_spec(g, l):
+        if l.ndim == 1 and l.shape[0] == flat_len:
+            return P(axis_name)
         if tuple(g.shape) == tuple(l.shape):
             return P()
         if l.ndim == 1:

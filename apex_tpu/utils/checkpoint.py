@@ -275,6 +275,20 @@ def latest_durable_step(ckpt_dir: str) -> Optional[int]:
     return None
 
 
+def _flat_tails(template: Any) -> dict:
+    """``{logical length: storage length}`` of the amp flat layouts in
+    ``template`` whose buffers carry a zero tail.  A snapshot written
+    before the tail existed holds masters and moments at the logical
+    length; restoring appends the zeros they would have had."""
+    from ..amp._process_optimizer import FlatMasters
+    nodes = jax.tree_util.tree_leaves(
+        template, is_leaf=lambda n: isinstance(n, FlatMasters))
+    return {n.layout.total: n.buf.shape[0] for n in nodes
+            if isinstance(n, FlatMasters)
+            and n.layout.zero_axis is None
+            and n.buf.shape[0] != n.layout.total}
+
+
 def restore_checkpoint(ckpt_dir: str, template: Any,
                        step: Optional[int] = None) -> Any:
     """Return ``template`` with every leaf replaced by the stored value
@@ -293,6 +307,7 @@ def restore_checkpoint(ckpt_dir: str, template: Any,
     stored = _load_verified(path)
     stored.pop(_DATA_STATE_KEY, None)   # read via load_data_state
     flat, treedef = jax.tree_util.tree_flatten_with_path(template)
+    tails = _flat_tails(template)
     out = []
     for kp, leaf in flat:
         key = jax.tree_util.keystr(kp)
@@ -301,6 +316,9 @@ def restore_checkpoint(ckpt_dir: str, template: Any,
                 f"checkpoint {path} has no entry for {key!r} — template "
                 "structure does not match the saved state")
         arr = stored[key]
+        if (arr.ndim == 1 and getattr(leaf, "ndim", None) == 1
+                and tails.get(arr.shape[0]) == leaf.shape[0]):
+            arr = np.pad(arr, (0, leaf.shape[0] - arr.shape[0]))
         if hasattr(leaf, "shape") and tuple(arr.shape) != tuple(leaf.shape):
             raise ValueError(
                 f"shape mismatch for {key!r}: checkpoint {arr.shape} vs "

@@ -255,7 +255,7 @@ def test_zero1_shards_redistribute_onto_survivors(tmp_path):
         verbosity=0, hard_override=True)
     params, _ = model.init(jax.random.PRNGKey(0))
     ospecs = amp.zero_optimizer_specs(optimizer, params, "data")
-    total = optimizer.init(params).masters.buf.size
+    total = optimizer.init(params).masters.layout.total
     batches = _batches(10)
 
     def build_step(world):
@@ -372,7 +372,7 @@ def test_zero2_shards_redistribute_onto_survivors_hierarchical(tmp_path):
         net, optimizers.FusedAdam(lr=1e-2), opt_level="O2",
         verbosity=0, hard_override=True)
     params, _ = model.init(jax.random.PRNGKey(0))
-    total = optimizer.init(params).masters.buf.size
+    total = optimizer.init(params).masters.layout.total
     batches = _batches(10)
 
     def ici_of(world):
@@ -474,7 +474,7 @@ def test_zero3_torn_snapshot_falls_back_and_reshards(tmp_path):
         net, optimizers.FusedAdam(lr=1e-2), opt_level="O2",
         verbosity=0, hard_override=True)
     params, _ = model.init(jax.random.PRNGKey(0))
-    total = optimizer.init(params).masters.buf.size
+    total = optimizer.init(params).masters.layout.total
     batches = _batches(10)
 
     def ici_of(world):

@@ -490,6 +490,36 @@ def test_tp_overflow_skip_is_global_across_shards():
             assert np.abs(w1[4:] - w0[4:]).max() > 0
 
 
+def test_tp_specs_shard_flat_buffers_whose_lengths_coincide():
+    """amp stores its flat buffers at a block-aligned length, so a small
+    model's LOCAL buffer (32 logical elements) and its GLOBAL one (128)
+    both have 1024: the spec must come from the logical counts, or every
+    shard would be handed shard 0's masters."""
+    from apex_tpu import amp, optimizers
+
+    mesh = tp_mesh(4)
+    col = tp.ColumnParallelLinear(8, 16, bias=False)
+    model, optimizer = amp.initialize(col, optimizers.FusedAdam(lr=0.1),
+                                      opt_level="O2", verbosity=0,
+                                      hard_override=True)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    specs = tp.partition_specs(model, params)
+    ospecs = tp.sharded_optimizer_specs(optimizer, params, specs, mesh)
+    assert ospecs.masters.layout.total == 32
+    assert ospecs.masters.layout.storage \
+        == optimizer.init(params).masters.layout.storage == 1024
+    assert ospecs.masters.buf == P("model")
+    assert ospecs.inner.m == ospecs.inner.v == P("model")
+    opt_state = jax.jit(jax.shard_map(
+        optimizer.init, mesh=mesh, in_specs=(specs,), out_specs=ospecs,
+        check_vma=False))(params)
+    buf = np.asarray(opt_state.masters.buf).reshape(4, 1024)
+    np.testing.assert_array_equal(
+        buf[:, :32].reshape(16, 8),
+        np.asarray(params["weight"], np.float32))
+    assert not buf[:, 32:].any()
+
+
 def test_checkpoint_roundtrip_with_tp_sharded_state(tmp_path):
     """Save/restore of TP-sharded train state (params + per-shard amp
     optimizer state): the gathered checkpoint restores to an identical

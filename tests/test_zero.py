@@ -68,7 +68,7 @@ def test_zero1_matches_ddp_trajectory():
     # the flat state really is sharded: the global array is the
     # device-concat (= padded full buffer), but each DEVICE holds only
     # a 1/dp slice of it
-    full_elems = optimizer.init(params).masters.buf.size
+    full_elems = optimizer.init(params).masters.layout.total
     gshape = opt_z.masters.buf.shape[0]
     dp = mesh.devices.size
     assert full_elems <= gshape < full_elems + dp     # padded concat
@@ -114,8 +114,8 @@ def test_zero1_matches_ddp_trajectory():
         zero_masters, mesh=mesh,
         in_specs=(P(), ospecs, P(), P("data"), P("data")),
         out_specs=P(), check_vma=False))(params, opt_z, bn_state, x, y)
-    np.testing.assert_allclose(np.asarray(mz)[:mref.size],
-                               np.asarray(mref), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(mz)[:full_elems],
+                               np.asarray(mref)[:full_elems], atol=1e-6)
 
     # multi-step: the trajectories track (Adam amplifies the psum-vs-
     # psum_scatter reduction-order round-off, so bitwise equality is
@@ -353,7 +353,7 @@ def _zero1_reference_masters(model, optimizer, params, bn_state, mesh,
         masters, mesh=mesh,
         in_specs=(P(), ospecs, P(), P("data"), P("data")),
         out_specs=P(), check_vma=False))(params, opt_z, bn_state, x, y)
-    total = optimizer.init(params).masters.buf.size
+    total = optimizer.init(params).masters.layout.total
     return np.asarray(m1)[:total], total
 
 
