@@ -32,7 +32,9 @@ __all__ = ["AmpModel", "AmpOptimizer", "_initialize", "cast_param_tree"]
 def cast_param_tree(module, params: dict, dtype,
                     keep_batchnorm_fp32: Optional[bool]) -> dict:
     """Cast a params tree to ``dtype``, skipping fp32-pinned modules
-    (BatchNorm/LayerNorm) when keep_batchnorm_fp32 is truthy."""
+    (BatchNorm/LayerNorm: ``fp32_params``) and a module's own fp32-pinned
+    leaves (an expert layer's router: ``fp32_param_names``) when
+    keep_batchnorm_fp32 is truthy."""
     keep = bool(keep_batchnorm_fp32)
 
     def walk(mod, p: Any) -> Any:
@@ -45,7 +47,12 @@ def cast_param_tree(module, params: dict, dtype,
         out = {}
         for k, v in p.items():
             child = mod._children.get(k)
-            out[k] = walk(child, v) if child is not None else walk(mod, v)
+            if child is not None:
+                out[k] = walk(child, v)
+            elif keep and k in getattr(mod, "fp32_param_names", ()):
+                out[k] = v
+            else:
+                out[k] = walk(mod, v)
         return out
 
     return walk(module, params)

@@ -9,6 +9,7 @@ from .dcgan import Generator, Discriminator, dcgan
 from .gpt import GPTConfig, GPT, gpt2_small, gpt2_medium
 from .llama import LlamaConfig, Llama, RMSNorm, llama_params_to_tp
 from .mixtral import MixtralConfig, Mixtral
+from .laguna import LagunaConfig, Laguna
 from .speculative import generate_speculative
 from .beam import beam_search
 from .t5 import T5Config, T5

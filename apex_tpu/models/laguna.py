@@ -1,0 +1,297 @@
+"""A decoder whose layers differ: per layer an attention kind (full or
+sliding-window), a query-head count, a RoPE kind and an MLP kind (dense
+SwiGLU or routed experts with a shared expert).
+
+The shape is the published ``laguna`` config's (``layer_types``,
+``num_attention_heads_per_layer``, ``mlp_layer_types``, one
+``rope_parameters`` group per attention kind, ``sliding_window``).  Pre-norm
+residual blocks on the Llama parts (models/llama.py: RMSNorm, SwiGLU, the
+fused chunked head), with
+
+- attention: ``H_l`` query heads over ``num_key_value_heads`` K/V heads,
+  causal, a band of ``sliding_window`` keys in a sliding layer (handed to
+  ``dot_product_attention(window=)``, so the flash kernels apply it), RoPE
+  per kind (``default``: theta, the whole or a leading part of the head;
+  ``yarn``: blended frequencies and a scale on cos/sin, as ``transformers``
+  computes them), and — ``gating`` — a sigmoid gate per head on the
+  attention output, ``o_h <- sigmoid(x w_h) * o_h``;
+- sparse MLP: ``parallel.ExpertParallelMLP`` with a sigmoid router over the
+  published ``router_experts``, the ``num_experts_per_tok`` largest
+  renormalized and scaled by ``moe_routed_scaling_factor``, the experts
+  this chip holds (``experts_held``) and a shared expert.
+
+Training and full-sequence forward only: a cache for decoding would have to
+hold window and global layers side by side (ROADMAP, Reach).
+"""
+
+from __future__ import annotations
+
+import inspect
+import math
+from typing import Sequence, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..nn import functional as F
+from ..parallel.expert_parallel import ExpertParallelMLP
+from ..transformer.attention import dot_product_attention
+from ._remat import _MODES, wrap_block
+from .llama import LlamaMLP, RMSNorm, _rotate_half
+
+__all__ = ["LagunaConfig", "Laguna", "rope_inv_freq"]
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+
+
+class LagunaConfig:
+    """Sizes per layer.  ``num_experts`` experts are HELD here, from
+    ``experts_held_start``, of the ``router_experts`` the router scores
+    (default: all of them are held)."""
+
+    def __init__(self, vocab_size, hidden_size, intermediate_size,
+                 layer_types: Sequence[str],
+                 num_attention_heads_per_layer: Sequence[int],
+                 mlp_layer_types: Sequence[str],
+                 num_key_value_heads, head_dim, rope_parameters: dict,
+                 sliding_window, num_experts, num_experts_per_tok,
+                 moe_intermediate_size, shared_expert_intermediate_size,
+                 moe_routed_scaling_factor=1.0, router_experts=None,
+                 experts_held_start=0, moe_row_buffer_factor=None,
+                 gating=True, rms_norm_eps=1e-6,
+                 max_position_embeddings=8192, remat=None, head_chunk=8192):
+        n = len(layer_types)
+        if not (len(num_attention_heads_per_layer) == n
+                and len(mlp_layer_types) == n):
+            raise ValueError("layer_types, num_attention_heads_per_layer "
+                             "and mlp_layer_types must have one entry a "
+                             "layer")
+        for kind in layer_types:
+            if kind not in (FULL, SLIDING):
+                raise ValueError(f"unknown layer type {kind!r}")
+            if kind not in rope_parameters:
+                raise ValueError(f"no rope_parameters for {kind!r}")
+        for kind in mlp_layer_types:
+            if kind not in ("dense", "sparse"):
+                raise ValueError(f"unknown mlp layer type {kind!r}")
+        for h in num_attention_heads_per_layer:
+            if h % num_key_value_heads:
+                raise ValueError(f"{h} query heads do not divide over "
+                                 f"{num_key_value_heads} K/V heads")
+        if remat not in _MODES:
+            raise ValueError(f"remat={remat!r} not in {_MODES}")
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.intermediate_size = intermediate_size
+        self.num_hidden_layers = n
+        self.layer_types = tuple(layer_types)
+        self.num_attention_heads_per_layer = tuple(
+            num_attention_heads_per_layer)
+        self.mlp_layer_types = tuple(mlp_layer_types)
+        self.num_key_value_heads = num_key_value_heads
+        self.head_dim = head_dim
+        self.rope_parameters = rope_parameters
+        self.sliding_window = sliding_window
+        self.num_experts = num_experts
+        self.router_experts = router_experts or num_experts
+        self.experts_held_start = experts_held_start
+        self.num_experts_per_tok = num_experts_per_tok
+        self.moe_intermediate_size = moe_intermediate_size
+        self.shared_expert_intermediate_size = shared_expert_intermediate_size
+        self.moe_routed_scaling_factor = moe_routed_scaling_factor
+        self.moe_row_buffer_factor = moe_row_buffer_factor
+        self.gating = gating
+        self.rms_norm_eps = rms_norm_eps
+        self.max_position_embeddings = max_position_embeddings
+        self.remat = remat
+        self.head_chunk = head_chunk
+        # what LlamaMLP reads of a config
+        self.tp_axis = None
+        self.mlp_act = "silu"
+
+    @classmethod
+    def from_dict(cls, d: dict, **over) -> "LagunaConfig":
+        """From the keys of a published config file (others are ignored).
+        Where the file is a chip's share, ``num_experts`` counts the experts
+        held and ``num_experts_published`` the router's width."""
+        names = inspect.signature(cls.__init__).parameters
+        kw = {k: d[k] for k in names if k in d}
+        if "num_experts_published" in d:
+            kw["router_experts"] = d["num_experts_published"]
+        kw.update(over)
+        return cls(**kw)
+
+
+def rope_inv_freq(params: dict, head_dim: int) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies of the rotated pairs, scale on cos and sin) of
+    one ``rope_parameters`` group, as ``transformers`` computes them
+    (``ROPE_INIT_FUNCTIONS``: ``default`` and ``yarn``)."""
+    dim = int(head_dim * params.get("partial_rotary_factor", 1.0))
+    base = float(params["rope_theta"])
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    kind = params.get("rope_type", "default")
+    if kind == "default":
+        return (1.0 / pos_freqs).astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"unknown rope_type {kind!r}")
+    factor = float(params["factor"])
+    orig = params["original_max_position_embeddings"]
+    scale = params.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+
+    def correction_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(params.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(params.get("beta_slow", 1))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolation = 1.0 - ramp          # 1: keep the frequency as it is
+    inv = ((1.0 / (factor * pos_freqs)) * (1.0 - extrapolation)
+           + (1.0 / pos_freqs) * extrapolation)
+    return inv.astype(np.float32), float(scale)
+
+
+class LagunaAttention(nn.Module):
+    def __init__(self, cfg: LagunaConfig, layer: int):
+        super().__init__()
+        self.H = cfg.num_attention_heads_per_layer[layer]
+        self.Hkv = cfg.num_key_value_heads
+        self.D = cfg.head_dim
+        kind = cfg.layer_types[layer]
+        self.window = cfg.sliding_window if kind == SLIDING else None
+        self.inv_freq, self.rope_scale = rope_inv_freq(
+            cfg.rope_parameters[kind], self.D)
+        E = cfg.hidden_size
+        self.q_proj = nn.Linear(E, self.H * self.D, bias=False)
+        self.k_proj = nn.Linear(E, self.Hkv * self.D, bias=False)
+        self.v_proj = nn.Linear(E, self.Hkv * self.D, bias=False)
+        self.o_proj = nn.Linear(self.H * self.D, E, bias=False)
+        if cfg.gating:
+            self.g_proj = nn.Linear(E, self.H, bias=False)
+        self.gating = cfg.gating
+
+    def _rope(self, x, T):
+        """x: (B, heads, T, D); the first ``2 * len(inv_freq)`` dims of each
+        head rotate (rotate-half pairing), the rest pass through."""
+        rd = 2 * self.inv_freq.shape[0]
+        ang = jnp.arange(T, dtype=jnp.float32)[:, None] * self.inv_freq
+        emb = jnp.concatenate([ang, ang], axis=-1)
+        cos, sin = (jnp.cos(emb) * self.rope_scale,
+                    jnp.sin(emb) * self.rope_scale)
+        xr = x[..., :rd].astype(jnp.float32)
+        out = (xr * cos + _rotate_half(xr) * sin).astype(x.dtype)
+        return out if rd == self.D else jnp.concatenate(
+            [out, x[..., rd:]], axis=-1)
+
+    def forward(self, p, x):
+        B, T, _ = x.shape
+        heads = lambda y, n: jnp.moveaxis(y.reshape(B, T, n, self.D), 2, 1)
+        q = self._rope(heads(self.q_proj(p["q_proj"], x), self.H), T)
+        k = self._rope(heads(self.k_proj(p["k_proj"], x), self.Hkv), T)
+        v = heads(self.v_proj(p["v_proj"], x), self.Hkv)
+        rep = self.H // self.Hkv
+        if rep > 1:                 # query head h reads K/V head h // rep
+            k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+        ctx = dot_product_attention(q, k, v, causal=True,
+                                    window=self.window)
+        ctx = jnp.moveaxis(ctx, 1, 2)                       # (B, T, H, D)
+        if self.gating:
+            gate = jax.nn.sigmoid(
+                self.g_proj(p["g_proj"], x).astype(jnp.float32))
+            ctx = ctx * gate[..., None].astype(ctx.dtype)
+        return self.o_proj(p["o_proj"], ctx.reshape(B, T, self.H * self.D))
+
+
+class LagunaBlock(nn.Module):
+    def __init__(self, cfg: LagunaConfig, layer: int):
+        super().__init__()
+        self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        self.self_attn = LagunaAttention(cfg, layer)
+        self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
+                                                cfg.rms_norm_eps)
+        self.sparse = cfg.mlp_layer_types[layer] == "sparse"
+        if self.sparse:
+            self.mlp = ExpertParallelMLP(
+                cfg.hidden_size, cfg.moe_intermediate_size,
+                cfg.router_experts, capacity_factor=None,
+                top_k=cfg.num_experts_per_tok, expert_type="swiglu",
+                router_type="sigmoid",
+                routed_scaling=cfg.moe_routed_scaling_factor,
+                experts_held=(cfg.experts_held_start, cfg.num_experts),
+                shared_hidden=cfg.shared_expert_intermediate_size,
+                row_buffer_factor=cfg.moe_row_buffer_factor)
+        else:
+            self.mlp = LlamaMLP(cfg)
+
+    def forward(self, p, x):
+        """-> (x, the expert layer's counters or None)."""
+        x = x + self.self_attn(p["self_attn"], self.input_layernorm(
+            p["input_layernorm"], x))
+        h = self.post_attention_layernorm(p["post_attention_layernorm"], x)
+        if self.sparse:
+            y, stats = self.mlp(p["mlp"], h, return_stats=True)
+            return x + y, stats
+        return x + self.mlp(p["mlp"], h), None
+
+
+class Laguna(nn.Module):
+    def __init__(self, cfg: LagunaConfig):
+        super().__init__()
+        self.cfg = cfg
+        self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.hidden_size,
+                                         init_std=0.02)
+        self.layers = nn.ModuleList(
+            [LagunaBlock(cfg, i) for i in range(cfg.num_hidden_layers)])
+        self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
+                                 bias=False)
+
+    def _backbone(self, p, input_ids):
+        """-> (final hidden states, the step's MoE counters or {})."""
+        T = input_ids.shape[1]
+        if T > self.cfg.max_position_embeddings:
+            raise ValueError(f"sequence length {T} exceeds "
+                             f"max_position_embeddings "
+                             f"{self.cfg.max_position_embeddings}")
+        x = self.embed_tokens(p["embed_tokens"], input_ids)
+        stats = []
+        for i, block in enumerate(self.layers):
+            def layer(pp, xx, block=block):
+                with jax.named_scope("layers"):
+                    return block(pp, xx)
+            x, s = wrap_block(layer, self.cfg.remat)(p["layers"][str(i)], x)
+            if s is not None:
+                stats.append(s)
+        return (self.norm(p["norm"], x),
+                ExpertParallelMLP.reduce_stats(stats) if stats else {})
+
+    def forward(self, p, input_ids):
+        x, _ = self._backbone(p, input_ids)
+        return F.matmul(x, p["lm_head"]["weight"].T.astype(x.dtype))
+
+    def loss(self, p, input_ids, return_stats: bool = False):
+        """Mean next-token cross-entropy over every position but each row's
+        last, through the fused chunked head (scope ``loss``); with
+        ``return_stats`` also the step's MoE counters."""
+        B, T = input_ids.shape
+        with jax.named_scope("model"):      # the root scope nn.apply opens
+            x, stats = self._backbone(p, input_ids)
+        with jax.named_scope("loss"):
+            from ..nn.fused_xent import linear_cross_entropy
+            labels = jnp.concatenate(
+                [input_ids[:, 1:], jnp.zeros((B, 1), input_ids.dtype)], 1)
+            nll = linear_cross_entropy(
+                x.reshape(B * T, -1), p["lm_head"]["weight"],
+                labels.reshape(-1), int(self.cfg.head_chunk)).reshape(B, T)
+            valid = jnp.arange(T) < T - 1
+            loss = jnp.sum(nll * valid) / (B * (T - 1))
+        return (loss, stats) if return_stats else loss

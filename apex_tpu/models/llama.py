@@ -87,10 +87,10 @@ class LlamaConfig:
             raise ValueError(f"remat={remat!r} not in {_MODES}")
         self.remat = remat
         # Mistral-style sliding-window attention: key j visible to
-        # query i iff i - W < j <= i.  Training takes the dense
-        # (banded-mask) path — the flash kernel streams key-padding
-        # masks, not bands; decode applies the window in its cache
-        # read.  The KV cache stays full-length (HF's rolling buffer
+        # query i iff i - W < j <= i.  Training and prefill hand the
+        # window to dot_product_attention (the flash kernels apply the
+        # band and skip the blocks outside it); decode applies it in its
+        # cache read.  The KV cache stays full-length (HF's rolling buffer
         # is a memory optimization, not a semantics change).
         if sliding_window is not None:
             if sliding_window < 1:
@@ -260,21 +260,12 @@ class LlamaAttention(nn.Module):
             from ..transformer.ring_attention import ring_attention
             ctx = ring_attention(q, k, v, axis_name=self.sp, causal=True)
         else:
-            mask = self._with_band(mask, T)
             ctx = dot_product_attention(q, k, v, mask, causal=True,
-                                        dropout_rate=0.0)
+                                        dropout_rate=0.0,
+                                        window=self.window)
         ctx = jnp.moveaxis(ctx, 1, 2).reshape(
             B, T, self.H * self.D)
         return self.o_proj(p["o_proj"], ctx)
-
-    def _with_band(self, mask, T):
-        """AND the sliding-window band (key j visible to query i iff
-        j > i - W; the causal half lives in causal=True) into ``mask``."""
-        if self.window is None:
-            return mask
-        band = (jnp.arange(T)[None, :]
-                > jnp.arange(T)[:, None] - self.window)[None, None]
-        return band if mask is None else (mask & band)
 
     def prefill(self, p, x):
         """Full-sequence attention that also returns the COMPACT
@@ -290,8 +281,8 @@ class LlamaAttention(nn.Module):
             rep = self.H // self.Hkv
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
-        ctx = dot_product_attention(q, k, v, self._with_band(None, T),
-                                    causal=True, dropout_rate=0.0)
+        ctx = dot_product_attention(q, k, v, causal=True,
+                                    dropout_rate=0.0, window=self.window)
         ctx = jnp.moveaxis(ctx, 1, 2).reshape(
             B, T, self.H * self.D)
         return self.o_proj(p["o_proj"], ctx), kc, vc

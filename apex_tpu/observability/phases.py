@@ -45,6 +45,11 @@ PHASES = (
     "paged.gather",        # PagedEngine tick: _gather_dense
     "paged.scatter",       # PagedEngine tick: _scatter_cols
     "paged.attend",        # PagedEngine tick: model.decode_chunk
+    # parallel/expert_parallel.py, the sorted dispatch (inside ``model``)
+    "moe.route",           # router matmul, scores, top-k, gate weights
+    "moe.dispatch",        # sort of the assignments, group sizes, row gather
+    "moe.experts",         # grouped products and the shared expert
+    "moe.combine",         # weighted scatter-add back to the tokens
 )
 MODEL = "model"
 
@@ -53,7 +58,8 @@ _VOCAB = frozenset(PHASES)
 _JAX_STRUCTURE = frozenset((
     "cond", "while", "scan", "checkpoint", "remat", "pallas_call",
     "shard_map", "closed_call", "core_call", "custom_vjp_call",
-    "custom_jvp_call", "custom_vjp_call_jaxpr", "custom_lin"))
+    "custom_jvp_call", "custom_vjp_call_jaxpr", "custom_lin",
+    "rematted_computation"))
 _PLAIN = re.compile(r"[A-Za-z0-9_]+$")
 _WRAPPED = re.compile(r"(?:[\w.\-]+\()*([^()]*)\)*$")
 
@@ -64,6 +70,8 @@ _CALLEE = re.compile(r"(?:to_apply|body|condition|true_computation|"
                      r"false_computation)=%?([^\s,}]+)")
 _FUSED = re.compile(r"calls=%?([^\s,}]+)")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_CUSTOM_CALL = re.compile(r"\scustom-call\(([^)]*)\)")
+_OPERAND = re.compile(r"%([^\s,()]+)")
 
 Phase = Tuple[Tuple[str, ...], bool]
 
@@ -74,22 +82,32 @@ def phase_of_op_name(op_name: str) -> Phase:
     ``model`` kept whole as one element (``("model",
     "BertForPretraining/bert/3/attention/qkv")``); backward when a scope
     sits inside ``transpose(``.  ``((), False)`` when no scope is found."""
-    parts = op_name.split("/")
+    # the compiler joins the names of instructions it merged with ";"
+    parts = op_name.split(";")[0].split("/")
     path, last, i = [], -1, 0
     while i < len(parts):
         base = _WRAPPED.match(parts[i]).group(1)
         if base in _VOCAB:
-            path.append(base)
+            if not (path and path[-1] == base):
+                path.append(base)
             last = i
             if base == MODEL:
                 modules = []
                 # the last component is the primitive, never a module
-                while (i + 1 < len(parts) - 1 and _PLAIN.match(parts[i + 1])
-                       and parts[i + 1] not in _VOCAB
-                       and parts[i + 1] not in _JAX_STRUCTURE
-                       and not parts[i + 1].startswith("branch_")):
+                while i + 1 < len(parts) - 1:
+                    nxt = parts[i + 1]
+                    if not modules and (nxt in _JAX_STRUCTURE or _WRAPPED
+                                        .match(nxt).group(1) == MODEL):
+                        # a rematerialized block's backward: transpose(
+                        # jvp(model))/jvp(model)/checkpoint/<modules>
+                        i += 1
+                        continue
+                    if not (_PLAIN.match(nxt) and nxt not in _VOCAB
+                            and nxt not in _JAX_STRUCTURE
+                            and not nxt.startswith("branch_")):
+                        break
                     i += 1
-                    modules.append(parts[i])
+                    modules.append(nxt)
                 if modules:
                     path.append("/".join(modules))
         i += 1
@@ -105,12 +123,17 @@ def instruction_phases(hlo_text: str) -> Dict[str, Phase]:
     that has an ``op_name``), anything else that of the innermost
     ``while`` / ``conditional`` / ``call`` / fusion instruction whose
     computation holds it; failing both its path is ``()``, which readers
-    report as ``unscoped``."""
+    report as ``unscoped``.  A ``custom-call`` the compiler named itself
+    (``op_name="ragged-dot-none"``: its own grouped-product kernel for
+    ``lax.ragged_dot``, and the kernel that prepares its tiles) takes the
+    phase of the first of its operands that has one, or else of the first
+    instruction that reads it."""
     own: Dict[str, Optional[Phase]] = {}
     computation_of: Dict[str, str] = {}
     caller_of: Dict[str, str] = {}          # computation -> calling instruction
     fused_of: Dict[str, str] = {}           # fusion instruction -> its computation
     members: Dict[str, list] = {}           # computation -> its instructions
+    named_by_compiler: Dict[str, list] = {}  # custom-call -> its operands, then its readers
     current = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -124,6 +147,12 @@ def instruction_phases(hlo_text: str) -> Dict[str, Phase]:
         own[name] = phase_of_op_name(op.group(1)) if op else None
         computation_of[name] = current
         members.setdefault(current, []).append(name)
+        call = _CUSTOM_CALL.search(line)
+        if call and own[name] == ((), False):
+            named_by_compiler[name] = _OPERAND.findall(call.group(1))
+        for read in _OPERAND.findall(line[m.end():]):
+            if read in named_by_compiler and read != name:
+                named_by_compiler[read].append(name)
         callees = _CALLEE.findall(line)
         fused = _FUSED.search(line)
         if fused:
@@ -140,6 +169,10 @@ def instruction_phases(hlo_text: str) -> Dict[str, Phase]:
         if name in out:
             return out[name]
         phase, seen, at = own[name], {name}, name
+        if name in named_by_compiler:
+            out[name] = phase               # (a cycle through such calls ends here)
+            phase = next((p for p in map(resolve, (o for o in named_by_compiler[name]
+                                                   if o in own)) if p[0]), phase)
         if phase is None:
             phase = next((own[i] for i in reversed(members.get(fused_of.get(name), ()))
                           if own[i] is not None), None)

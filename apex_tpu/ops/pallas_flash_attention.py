@@ -17,7 +17,11 @@ forward emits the per-row logsumexp; the backward recomputes
 probabilities from it in two streamed passes: a dQ pass (K/V streamed)
 and a dK/dV pass (Q/dO streamed), each a handful of MXU contractions per
 block pair.  Causal q/k block pairs above the diagonal are skipped via
-``pl.when``.
+``pl.when``.  With a sliding ``window`` (key j visible to query i iff
+i - W < j <= i) the streamed axis of each grid covers only the
+``_band_blocks`` blocks a row of blocks can see: a block pair wholly
+outside the band is no grid step at all, so it is neither computed nor
+fetched, and the cost of a windowed layer is linear in T.
 
 Per-row statistics (lse, delta and the m/l scratch) are stored
 lane-broadcast as (rows, 128) tiles — Mosaic requires the last two block
@@ -104,6 +108,13 @@ def _block_for(T: int) -> int:
     return LANES
 
 
+def _band_blocks(window: int, blk: int, n: int) -> int:
+    """Blocks of ``blk`` keys that the queries of one block can see through
+    a causal window of ``window`` keys (at most all ``n``): the diagonal
+    block and those the lowest query row reaches back into."""
+    return min(n, -(-(window - 1) // blk) + 1)
+
+
 def fits_vmem(T: int, D: int, dropout: bool = False,
               segments: bool = False) -> bool:
     """VMEM needed per grid step — independent of T now that K/V stream
@@ -148,7 +159,7 @@ def _lanes(vec, Tp):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, has_mask, has_segments,
-                dropout_rate, T_real, blk, nk):
+                dropout_rate, T_real, blk, nk, window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     del refs[:3]
@@ -162,17 +173,24 @@ def _fwd_kernel(*refs, scale, causal, has_mask, has_segments,
     o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    # causal: the (i, j) block pair is dead when its lowest q row sits
-    # above its lowest k column (j*blk > i*blk + blk - 1 ⇔ j > i)
-    run = (j <= i) if causal else (j >= 0)
+    if window is None:
+        j = step
+        # causal: the (i, j) block pair is dead when its lowest q row sits
+        # above its lowest k column (j*blk > i*blk + blk - 1 ⇔ j > i)
+        run = (j <= i) if causal else (j >= 0)
+    else:
+        # the nk steps end at the diagonal block; those that would start
+        # before block 0 are dead (their fetch is clamped onto block 0)
+        j = i - (nk - 1) + step
+        run = j >= 0
 
     @pl.when(run)
     def _compute():
@@ -185,6 +203,8 @@ def _fwd_kernel(*refs, scale, causal, has_mask, has_segments,
         qpos = i * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         if causal:
             valid = jnp.logical_and(valid, qpos >= kpos)
+        if window is not None:
+            valid = jnp.logical_and(valid, kpos > qpos - window)
         if has_mask:
             # (1, blk) key-validity row, sublane-broadcast tile layout:
             # k positions on the lane axis, matching s's column axis
@@ -219,7 +239,7 @@ def _fwd_kernel(*refs, scale, causal, has_mask, has_segments,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == nk - 1)
+    @pl.when(step == nk - 1)
     def _done():
         l = l_ref[...][:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -229,8 +249,9 @@ def _fwd_kernel(*refs, scale, causal, has_mask, has_segments,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
-                                             "dropout_rate"))
-def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate):
+                                             "dropout_rate", "window"))
+def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate,
+         window=None):
     """kvm: (B, 8, Tp) fp32 key-validity (sublane-broadcast) or None.
     qseg/kseg: (B, Tp, LANES) lane- / (B, 8, Tp) sublane-broadcast int32
     segment ids or None.  seed: (1, 2) int32 dropout seed or None."""
@@ -240,9 +261,14 @@ def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate):
     Dp = -(-D // LANES) * LANES
     qp, kp, vp = (_pad_to(x, Tp, Dp) for x in (q, k, v))
     nq, nk = Tp // blk, Tp // blk
+    if window is None:
+        kb = lambda i, j: j              # the k block of grid step (i, j)
+    else:
+        nk = _band_blocks(window, blk, nk)
+        kb = lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
     grid = (BH, nq, nk)
     row = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, i, 0))
-    col = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, j, 0))
+    col = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, kb(i, j), 0))
     stat = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b, i, 0))
     has_mask = kvm is not None
     has_segments = qseg is not None
@@ -250,14 +276,14 @@ def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate):
     operands = [qp, kp, vp]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 8, blk),
-                                     lambda b, i, j: (b // H, 0, j)))
+                                     lambda b, i, j: (b // H, 0, kb(i, j))))
         operands.append(kvm)
     if has_segments:
         in_specs.append(pl.BlockSpec((1, blk, LANES),
                                      lambda b, i, j: (b // H, i, 0)))
         operands.append(qseg)
         in_specs.append(pl.BlockSpec((1, 8, blk),
-                                     lambda b, i, j: (b // H, 0, j)))
+                                     lambda b, i, j: (b // H, 0, kb(i, j))))
         operands.append(kseg)
     if dropout_rate:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -266,7 +292,7 @@ def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate):
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           has_mask=has_mask, has_segments=has_segments,
                           dropout_rate=dropout_rate,
-                          T_real=T, blk=blk, nk=nk),
+                          T_real=T, blk=blk, nk=nk, window=window),
         grid=grid,
         in_specs=in_specs,
         out_specs=[row, stat],
@@ -288,7 +314,7 @@ def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(*refs, scale, causal, has_mask, has_segments, dropout_rate,
-               T_real, blk, nk):
+               T_real, blk, nk, window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     del refs[:6]
@@ -302,13 +328,18 @@ def _dq_kernel(*refs, scale, causal, has_mask, has_segments, dropout_rate,
     dq_ref, dq_acc = refs
     b = pl.program_id(0)
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    run = (j <= i) if causal else (j >= 0)
+    if window is None:
+        j = step
+        run = (j <= i) if causal else (j >= 0)
+    else:                       # as in _fwd_kernel
+        j = i - (nk - 1) + step
+        run = j >= 0
 
     @pl.when(run)
     def _compute():
@@ -324,6 +355,8 @@ def _dq_kernel(*refs, scale, causal, has_mask, has_segments, dropout_rate,
         qpos = i * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         if causal:
             valid = jnp.logical_and(valid, qpos >= kpos)
+        if window is not None:
+            valid = jnp.logical_and(valid, kpos > qpos - window)
         if has_mask:
             valid = jnp.logical_and(valid, kvm_ref[0][:1, :] > 0.5)
         if has_segments:
@@ -340,13 +373,13 @@ def _dq_kernel(*refs, scale, causal, has_mask, has_segments, dropout_rate,
         ds = (p * (dp - delta)).astype(k.dtype)
         dq_acc[...] += _dot(ds, k, ((1,), (0,))) * scale
 
-    @pl.when(j == nk - 1)
+    @pl.when(step == nk - 1)
     def _done():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, causal, has_mask, has_segments,
-                dropout_rate, T_real, blk, nq):
+                dropout_rate, T_real, blk, nq, window=None, nq_all=None):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     del refs[:6]
@@ -360,15 +393,22 @@ def _dkv_kernel(*refs, scale, causal, has_mask, has_segments,
     dk_ref, dv_ref, dk_acc, dv_acc = refs
     b = pl.program_id(0)
     i = pl.program_id(1)          # k block
-    j = pl.program_id(2)          # q block (streamed)
+    step = pl.program_id(2)       # q block (streamed)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    # causal: q block j only sees k block i when j*blk + blk - 1 >= i*blk
-    run = (j >= i) if causal else (j >= 0)
+    if window is None:
+        j = step
+        # causal: q block j only sees k block i when j*blk + blk - 1 >= i*blk
+        run = (j >= i) if causal else (j >= 0)
+    else:
+        # the nq steps start at the diagonal block; those past the last q
+        # block are dead (their fetch is clamped onto the last block)
+        j = i + step
+        run = j < nq_all
 
     @pl.when(run)
     def _compute():
@@ -384,6 +424,8 @@ def _dkv_kernel(*refs, scale, causal, has_mask, has_segments,
         qpos = j * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         if causal:
             valid = jnp.logical_and(valid, qpos >= kpos)
+        if window is not None:
+            valid = jnp.logical_and(valid, kpos > qpos - window)
         if has_mask:
             valid = jnp.logical_and(valid, kvm_ref[0][:1, :] > 0.5)
         if has_segments:
@@ -406,16 +448,16 @@ def _dkv_kernel(*refs, scale, causal, has_mask, has_segments,
         ds = (p * (dp - delta)).astype(q.dtype)
         dk_acc[...] += _dot(ds, q, ((0,), (0,))) * scale
 
-    @pl.when(j == nq - 1)
+    @pl.when(step == nq - 1)
     def _done():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
-                                             "dropout_rate"))
+                                             "dropout_rate", "window"))
 def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
-         dropout_rate):
+         dropout_rate, window=None):
     BH, T, D = q.shape
     blk = _block_for(T)
     Tp = -(-T // blk) * blk
@@ -425,24 +467,44 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     deltap = _lanes(delta, Tp)
     lsep = _lanes(lse, Tp)
-    nq = nk = Tp // blk
+    nq = nk = n = Tp // blk
     has_mask = kvm is not None
     has_segments = qseg is not None
     sem = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     rowi = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, i, 0))
-    colj = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, j, 0))
     stati = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b, i, 0))
-    statj = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b, j, 0))
-    # key-validity / k-segment tiles for the k block: streamed along the
-    # j axis in the dq pass, along the i (k-block) axis in the dk/dv
-    # pass; q-segment ids ride the lane-broadcast (stat) layout
-    kvmj = pl.BlockSpec((1, 8, blk), lambda b, i, j: (b // H, 0, j))
     kvmi = pl.BlockSpec((1, 8, blk), lambda b, i, j: (b // H, 0, i))
     qsegi = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b // H, i, 0))
-    qsegj = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b // H, j, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    # what streams along a grid's last axis, ``sj(i, j)`` being the block of
+    # step j in row i: operand blocks, row statistics and q-segment ids (the
+    # lane-broadcast stat layout), key-validity / k-segment tiles
+    def operand(sj):
+        return pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, sj(i, j), 0))
+
+    def stat(sj, per_head=True):
+        return pl.BlockSpec(
+            (1, blk, LANES),
+            lambda b, i, j: (b if per_head else b // H, sj(i, j), 0))
+
+    def key_tile(sj):
+        return pl.BlockSpec((1, 8, blk),
+                            lambda b, i, j: (b // H, 0, sj(i, j)))
+
+    if window is None:
+        kstep = qstep = lambda i, j: j
+    else:
+        # dq: the k blocks up to the diagonal; dk/dv: the q blocks from it
+        nk = nq = _band_blocks(window, blk, n)
+        kstep = lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
+        qstep = lambda i, j: jnp.minimum(i + j, n - 1)
+    # the dq pass streams K/V (and their tiles) along j; the dk/dv pass has
+    # them on its i axis and streams Q, dO, their statistics and q ids
+    colj, kvmj = operand(kstep), key_tile(kstep)
+    colq, statj, qsegj = operand(qstep), stat(qstep), stat(qstep, False)
 
     dq_specs = [rowi, colj, colj, rowi, stati, stati]
     dq_ops = [qp, kp, vp, dop, lsep, deltap]
@@ -459,8 +521,8 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           has_mask=has_mask, has_segments=has_segments,
                           dropout_rate=dropout_rate,
-                          T_real=T, blk=blk, nk=nk),
-        grid=(BH, nq, nk),
+                          T_real=T, blk=blk, nk=nk, window=window),
+        grid=(BH, n, nk),
         in_specs=dq_specs,
         out_specs=rowi,
         out_shape=jax.ShapeDtypeStruct((BH, Tp, Dp), q.dtype),
@@ -470,7 +532,7 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
         name="flash_dq",
     )(*dq_ops)
 
-    dkv_specs = [colj, rowi, rowi, colj, statj, statj]
+    dkv_specs = [colq, rowi, rowi, colq, statj, statj]
     dkv_ops = [qp, kp, vp, dop, lsep, deltap]
     if has_mask:
         dkv_specs.append(kvmi)
@@ -487,8 +549,9 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           has_mask=has_mask, has_segments=has_segments,
                           dropout_rate=dropout_rate,
-                          T_real=T, blk=blk, nq=nq),
-        grid=(BH, nk, nq),
+                          T_real=T, blk=blk, nq=nq, window=window,
+                          nq_all=n),
+        grid=(BH, n, nq),
         in_specs=dkv_specs,
         out_specs=[rowi, rowi],
         out_shape=[jax.ShapeDtypeStruct((BH, Tp, Dp), k.dtype),
@@ -506,25 +569,25 @@ def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
 # public op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
 def _flash(q3, k3, v3, kvm, qseg, kseg, seed, scale: float, causal: bool,
-           H: int, dropout_rate: float):
+           H: int, dropout_rate: float, window: Optional[int]):
     o, _ = _fwd(q3, k3, v3, kvm, qseg, kseg, seed, scale, causal, H,
-                dropout_rate)
+                dropout_rate, window)
     return o
 
 
 def _flash_fwd(q3, k3, v3, kvm, qseg, kseg, seed, scale, causal, H,
-               dropout_rate):
+               dropout_rate, window):
     o, lse = _fwd(q3, k3, v3, kvm, qseg, kseg, seed, scale, causal, H,
-                  dropout_rate)
+                  dropout_rate, window)
     return o, (q3, k3, v3, o, lse, kvm, qseg, kseg, seed)
 
 
-def _flash_bwd(scale, causal, H, dropout_rate, res, do):
+def _flash_bwd(scale, causal, H, dropout_rate, window, res, do):
     q3, k3, v3, o, lse, kvm, qseg, kseg, seed = res
     dq, dk, dv = _bwd(q3, k3, v3, o, lse, do, kvm, qseg, kseg, seed,
-                      scale, causal, H, dropout_rate)
+                      scale, causal, H, dropout_rate, window)
     dkvm = None if kvm is None else jnp.zeros_like(kvm)
     # int primals -> float0 cotangents
     f0 = lambda a: (None if a is None
@@ -542,7 +605,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     kv_mask: Optional[jax.Array] = None,
                     dropout_rate: float = 0.0,
                     dropout_seed: Optional[jax.Array] = None,
-                    segment_ids: Optional[jax.Array] = None) -> jax.Array:
+                    segment_ids: Optional[jax.Array] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """softmax(q k^T * scale [+ causal mask]) v without materializing the
     score matrix in HBM.  q, k, v: (B, H, T, D) self-attention operands
     (equal sequence lengths).  K/V are streamed through VMEM in blocks,
@@ -567,7 +631,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     position pairs attend only within equal ids (q-ids stream as
     lane-broadcast tiles, k-ids as sublane tiles).  Composes with
     ``causal``/``kv_mask``/dropout.  Rows whose segment has no other
-    member still see themselves (the diagonal id always matches)."""
+    member still see themselves (the diagonal id always matches).
+
+    ``window``: a static sliding window on top of ``causal=True`` — key j
+    is visible to query i iff ``i - window < j <= i``.  Each of the three
+    kernels then visits only the block pairs the band touches; with
+    ``window=None`` they are the programs they are without it."""
     if q.ndim != 4:
         raise ValueError(f"expected (B, H, T, D), got {q.shape}")
     if q.shape != k.shape or k.shape != v.shape:
@@ -578,6 +647,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          f"{dropout_rate}")
     if dropout_rate and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError("window needs causal=True and window >= 1, "
+                             f"got causal={causal}, window={window}")
     B, H, T, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -615,5 +689,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kseg = jax.lax.broadcast_in_dim(idk, (B, 8, Tp), (0, 2))
     fold = lambda x: x.reshape(B * H, T, D)
     out = _flash(fold(q), fold(k), fold(v), kvm, qseg, kseg, seed,
-                 float(scale), bool(causal), H, dropout_rate)
+                 float(scale), bool(causal), H, dropout_rate, window)
     return out.reshape(B, H, T, D)

@@ -1,36 +1,55 @@
-"""Expert parallelism: Switch-style Mixture-of-Experts over a mesh axis.
+"""Expert parallelism: Mixture-of-Experts over a mesh axis.
 
 The reference predates MoE entirely; this completes apex_tpu's
-parallelism surface (dp/tp/pp/sp/ep).  The design is the GShard/Switch
-SPMD pattern in shard_map form:
+parallelism surface (dp/tp/pp/sp/ep).  One layer, two dispatches:
 
-- the ``expert`` mesh axis shards BOTH the tokens (data-style) and the
-  expert homes: device d holds tokens-shard d and experts
-  ``[d*E/ep, (d+1)*E/ep)``;
-- each device routes its local tokens (replicated router weights),
-  builds a capacity-bounded dispatch tensor, and one ``all_to_all``
-  ships every token to the device owning its expert; the expert MLPs
-  run as one vmapped batch; the reverse ``all_to_all`` brings results
-  home, where the gate-weighted combine reads them back;
-- tokens over an expert's capacity are DROPPED (contribute zero), the
-  standard Switch behavior — size everything with ``capacity_factor``.
+**Local (no ``expert`` axis in scope): sorted and grouped.**  The router
+scores every token over all ``n_experts``; the ``T*k`` assignments are
+sorted by expert (stable, choice-major: every token's first choice queues
+before any token's second); the rows of the experts this layer *holds*
+(``experts_held`` = a start and a count, default all of them) are
+gathered into one row buffer of a static size; the experts run as grouped
+matrix products over the contiguous row groups (``lax.ragged_dot``); and
+a scatter-add with the gate weights brings the rows home.  Nothing of a
+``tokens x experts x slots`` shape exists.  Assignments to experts held
+elsewhere add nothing here — that is the layer of ONE chip of an
+expert-parallel group, without its exchange.  With ``capacity_factor=None``
+nothing is dropped; a capacity (``ceil(cf * T * k / E)`` rows an expert)
+drops what queues behind it, as Switch does.  The row buffer is
+``T * min(k, held)`` rows (it cannot overflow) unless
+``row_buffer_factor`` sizes it as that multiple of the expected rows,
+``T * k * held / E``; what overflowed it is counted with the dropped.
 
-Communication per layer: two all_to_alls (forward) — their transposes
-are all_to_alls again, so backward needs no f/g correction the way
-psum-based TP does.
+**Across an ``expert`` mesh axis: GShard/Switch in shard_map form.**  The
+axis shards BOTH the tokens (data-style) and the expert homes: device d
+holds tokens-shard d and experts ``[d*E/ep, (d+1)*E/ep)``; each device
+routes its local tokens (replicated router weights), builds a
+capacity-bounded dispatch tensor, and one ``all_to_all`` ships every token
+to the device owning its expert; the expert MLPs run as one vmapped batch;
+the reverse ``all_to_all`` brings results home, where the gate-weighted
+combine reads them back.  This path keeps the ``(T, E, C)`` masks and
+needs a capacity.  Two all_to_alls forward — their transposes are
+all_to_alls again, so backward needs no f/g correction the way psum-based
+TP does.
 
-Router: top-1 (Switch) by default; ``top_k=2`` with
+Router: ``"softmax"`` — top-1 (Switch) by default; ``top_k=2`` with
 ``expert_type="swiglu"`` gives the Mixtral shape (renormalized gate
-weights, SwiGLU experts).  The auxiliary load-balancing loss
-(Switch eq. 4: E * sum_e f_e * P_e, fraction counted over all k
-assignments) is returned by ``forward`` when ``return_aux_loss`` — add
-``aux_weight * aux`` to the task loss.
+weights, SwiGLU experts) — or ``"sigmoid"``: independent scores, the k
+largest renormalized to sum 1 and scaled by ``routed_scaling``.  The
+auxiliary load-balancing loss (Switch eq. 4: E * sum_e f_e * P_e, fraction
+counted over all k assignments) is returned by ``forward`` when
+``return_aux_loss`` — add ``aux_weight * aux`` to the task loss.
+``shared_hidden`` adds a SwiGLU expert that every token passes through.
+
+The work is written under the scopes ``moe.route`` / ``moe.dispatch`` /
+``moe.experts`` / ``moe.combine`` (observability/phases.py), and
+``return_stats`` hands back the counters :data:`MOE_COUNTERS`.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,40 +60,66 @@ from ..nn.module import Module
 from ..nn import functional as F
 from .sync_batchnorm import _axis_in_scope
 
-__all__ = ["ExpertParallelMLP", "allreduce_replicated_grads"]
+__all__ = ["ExpertParallelMLP", "allreduce_replicated_grads",
+           "MOE_COUNTERS", "record_moe_counters"]
 
 DEFAULT_AXIS = "expert"
+# per step and layer-reduced (ExpertParallelMLP.reduce_stats): assignments
+# that landed on an expert held here; the most rows any held expert got;
+# assignments lost to a capacity or to the row buffer (dropless: 0)
+MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max",
+                "moe_dropped_assignments")
+
+
+def _swiglu(x, w_gate, w_in, w_out):
+    return (F.silu(x @ w_gate.astype(x.dtype))
+            * (x @ w_in.astype(x.dtype))) @ w_out.astype(x.dtype)
 
 
 class ExpertParallelMLP(Module):
     """Top-k routed MoE MLP; experts sharded over ``axis_name``.
 
-    Params: ``router`` (d, E) replicated; ``w_in`` (E, d, hidden) and
-    ``w_out`` (E, hidden, d) sharded on the expert dim (see
-    ``param_specs``); gated experts add ``w_gate`` (E, d, hidden).
+    Params: ``router`` (d, E) replicated and kept fp32 under amp; ``w_in``
+    (n, d, hidden) and ``w_out`` (n, hidden, d) for the ``n`` experts held
+    (all E by default), sharded on the expert dim (see ``param_specs``);
+    gated experts add ``w_gate`` (n, d, hidden); ``shared_hidden`` adds
+    ``shared`` = {w_gate, w_in, w_out} without the expert dim.
     Call inside shard_map with tokens sharded over the same axis;
-    outside any mesh all experts run locally.
+    outside any mesh the experts held run locally (module docstring).
 
     ``top_k=1`` is Switch (gate = raw top-1 prob).  ``top_k>1`` is the
     GShard/Mixtral shape: each token goes to its k best experts, gate
-    weights renormalized to sum 1 over the chosen k; capacity slots are
-    assigned first-choice-first (every token's first choice queues
-    before any token's second), so under pressure second choices drop
-    first.  ``expert_type="swiglu"`` makes each expert the Llama MLP
+    weights renormalized to sum 1 over the chosen k; under a capacity,
+    slots are assigned first-choice-first (every token's first choice
+    queues before any token's second), so under pressure second choices
+    drop first.  ``expert_type="swiglu"`` makes each expert the Llama MLP
     ``(silu(x@w_gate) * (x@w_in)) @ w_out`` (Mixtral's expert).
     """
 
+    fp32_param_names = ("router",)
+
     def __init__(self, embed_dim: int, hidden_dim: int, n_experts: int,
-                 capacity_factor: float = 1.25,
+                 capacity_factor: Optional[float] = 1.25,
                  activation: str = "gelu",
                  axis_name: str = DEFAULT_AXIS,
                  top_k: int = 1,
-                 expert_type: str = "mlp"):
+                 expert_type: str = "mlp",
+                 router_type: str = "softmax",
+                 routed_scaling: float = 1.0,
+                 experts_held: Optional[Tuple[int, int]] = None,
+                 shared_hidden: Optional[int] = None,
+                 row_buffer_factor: Optional[float] = None):
         super().__init__()
         if not 1 <= top_k <= n_experts:
             raise ValueError(f"top_k={top_k} not in [1, {n_experts}]")
         if expert_type not in ("mlp", "swiglu"):
             raise ValueError(f"unknown expert_type {expert_type!r}")
+        if router_type not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_type {router_type!r}")
+        start, count = experts_held or (0, n_experts)
+        if not (0 <= start and count >= 1 and start + count <= n_experts):
+            raise ValueError(f"experts_held={experts_held} not inside "
+                             f"[0, {n_experts})")
         self.embed_dim = embed_dim
         self.hidden_dim = hidden_dim
         self.n_experts = n_experts
@@ -83,20 +128,34 @@ class ExpertParallelMLP(Module):
         self.axis_name = axis_name
         self.top_k = top_k
         self.expert_type = expert_type
+        self.router_type = router_type
+        self.routed_scaling = routed_scaling
+        self.held_start, self.n_held = start, count
+        self.shared_hidden = shared_hidden
+        self.row_buffer_factor = row_buffer_factor
 
     def create_params(self, key):
-        k1, k2, k3, k4 = jax.random.split(key, 4)
-        d, h, E = self.embed_dim, self.hidden_dim, self.n_experts
+        k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+        d, h, E, n = (self.embed_dim, self.hidden_dim, self.n_experts,
+                      self.n_held)
         s_in = (2.0 / d) ** 0.5
         s_out = (2.0 / h) ** 0.5
         p = {
             "router": jax.random.normal(k1, (d, E), jnp.float32) * 0.02,
-            "w_in": jax.random.normal(k2, (E, d, h), jnp.float32) * s_in,
-            "w_out": jax.random.normal(k3, (E, h, d), jnp.float32) * s_out,
+            "w_in": jax.random.normal(k2, (n, d, h), jnp.float32) * s_in,
+            "w_out": jax.random.normal(k3, (n, h, d), jnp.float32) * s_out,
         }
         if self.expert_type == "swiglu":
-            p["w_gate"] = (jax.random.normal(k4, (E, d, h), jnp.float32)
+            p["w_gate"] = (jax.random.normal(k4, (n, d, h), jnp.float32)
                            * s_in)
+        if self.shared_hidden:
+            hs = self.shared_hidden
+            ks = jax.random.split(k5, 3)
+            p["shared"] = {
+                "w_gate": jax.random.normal(ks[0], (d, hs)) * s_in,
+                "w_in": jax.random.normal(ks[1], (d, hs)) * s_in,
+                "w_out": jax.random.normal(ks[2], (hs, d))
+                * (2.0 / hs) ** 0.5}
         return p
 
     def param_specs(self) -> Dict[str, P]:
@@ -105,21 +164,49 @@ class ExpertParallelMLP(Module):
              "w_out": P(self.axis_name, None, None)}
         if self.expert_type == "swiglu":
             s["w_gate"] = P(self.axis_name, None, None)
+        if self.shared_hidden:
+            s["shared"] = {"w_gate": P(), "w_in": P(), "w_out": P()}
         return s
 
+    def capacity(self, n_tokens: int) -> Optional[int]:
+        """Rows an expert takes of ``n_tokens`` tokens' ``top_k``
+        assignments each; None when nothing is dropped."""
+        if self.capacity_factor is None:
+            return None
+        return max(1, math.ceil(self.capacity_factor * n_tokens
+                                * self.top_k / self.n_experts))
+
     # -- routing ----------------------------------------------------------
-    def _dispatch(self, x2d: jax.Array, router: jax.Array, capacity: int
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """(dispatch (T,E,C) one-hot, combine (T,E,C) gate-weighted,
-        aux load-balance loss) for the local token block."""
-        T = x2d.shape[0]
+    def _route(self, x2d: jax.Array, router: jax.Array, want_aux: bool):
+        """(gates (T, k) fp32, experts (T, k) int32, aux loss) — scores in
+        fp32 over all ``n_experts``, whatever this layer holds."""
         E, k = self.n_experts, self.top_k
-        logits = x2d.astype(jnp.float32) @ router
-        probs = jax.nn.softmax(logits, axis=-1)
+        logits = x2d.astype(jnp.float32) @ router.astype(jnp.float32)
+        if self.router_type == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
         gates, experts = lax.top_k(probs, k)                   # (T,k)
-        if k > 1:
+        if k > 1 or self.router_type == "sigmoid":
             # Mixtral: gate weights renormalized over the chosen k
             gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates * self.routed_scaling
+        aux = 0.0
+        if want_aux:
+            # Switch aux loss (eq. 4), fraction over all k assignments:
+            # f_e x mean prob P_e, scaled E; reduces to Switch at k=1
+            onehot = jax.nn.one_hot(experts, E, dtype=jnp.float32)
+            f_e = jnp.mean(jnp.sum(onehot, axis=1), axis=0) / k
+            aux = E * jnp.sum(f_e * jnp.mean(probs, axis=0))
+        return gates, experts, aux
+
+    def _dispatch(self, x2d: jax.Array, router: jax.Array, capacity: int
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """The all_to_all path's (dispatch (T,E,C) one-hot, combine (T,E,C)
+        gate-weighted, aux load-balance loss) for the local token block."""
+        T = x2d.shape[0]
+        E, k = self.n_experts, self.top_k
+        gates, experts, aux = self._route(x2d, router, True)
         onehot = jax.nn.one_hot(experts, E, dtype=jnp.float32)  # (T,k,E)
         # queue positions, choice-major: every token's 1st choice is
         # enqueued before any token's 2nd, so overflow drops 2nd picks
@@ -135,32 +222,95 @@ class ExpertParallelMLP(Module):
         # slots are disjoint across choices, so the union is a sum
         dispatch = jnp.sum(per_choice, axis=0)                 # (T,E,C)
         combine = jnp.einsum("ktec,tk->tec", per_choice, gates)
-        # Switch aux loss (eq. 4), fraction over all k assignments:
-        # f_e x mean prob P_e, scaled E; reduces to Switch at k=1
-        f_e = jnp.mean(jnp.sum(onehot, axis=1), axis=0) / k
-        p_e = jnp.mean(probs, axis=0)
-        aux = E * jnp.sum(f_e * p_e)
         return dispatch, combine, aux
 
     def _expert_mlp(self, params, xe):
         """xe: (E_local, S, d) -> (E_local, S, d), vmapped over experts."""
-        act = getattr(F, self.activation)
-
         if self.expert_type == "swiglu":
-            def one(w_gate, w_in, w_out, t):
-                return (F.silu(t @ w_gate.astype(t.dtype))
-                        * (t @ w_in.astype(t.dtype))
-                        ) @ w_out.astype(t.dtype)
-
-            return jax.vmap(one)(params["w_gate"], params["w_in"],
-                                 params["w_out"], xe)
+            return jax.vmap(lambda g, i, o, t: _swiglu(t, g, i, o))(
+                params["w_gate"], params["w_in"], params["w_out"], xe)
+        act = getattr(F, self.activation)
 
         def one(w_in, w_out, t):
             return act(t @ w_in.astype(t.dtype)) @ w_out.astype(t.dtype)
 
         return jax.vmap(one)(params["w_in"], params["w_out"], xe)
 
-    def forward(self, params, x, return_aux_loss: bool = False):
+    def _grouped_mlp(self, params, xs, group_sizes, live):
+        """xs: (R, d) rows sorted by expert, ``group_sizes`` (n,) rows each
+        expert held takes from the front, ``live`` (R, 1) the rows inside a
+        group -> (R, d).  Rows outside every group are forced to zero on
+        both sides of each product: a grouped product need not write (nor,
+        transposed, read) them."""
+        def gdot(t, w):
+            y = lax.ragged_dot(jnp.where(live, t, 0).astype(w.dtype), w,
+                               group_sizes,
+                               preferred_element_type=jnp.float32)
+            return jnp.where(live, y, 0).astype(t.dtype)
+
+        if self.expert_type == "swiglu":
+            h = F.silu(gdot(xs, params["w_gate"])) * gdot(xs, params["w_in"])
+        else:
+            h = getattr(F, self.activation)(gdot(xs, params["w_in"]))
+        return gdot(h, params["w_out"])
+
+    def _sorted_forward(self, params, x2d, want_aux):
+        """The local path: (y (T, d), aux, counters)."""
+        T, d = x2d.shape
+        k, n = self.top_k, self.n_held
+        with jax.named_scope("moe.route"):
+            gates, experts, aux = self._route(x2d, params["router"],
+                                              want_aux)
+        with jax.named_scope("moe.dispatch"):
+            # choice-major queue: assignment a = choice * T + token
+            local = experts.T.reshape(-1) - self.held_start
+            key = jnp.where((local >= 0) & (local < n), local, n)
+            sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
+                            dtype=jnp.int32)                   # (n,)
+            held = jnp.sum(sizes)
+            rows = T * min(k, n)
+            if self.row_buffer_factor is not None:
+                want = math.ceil(self.row_buffer_factor * T * k * n
+                                 / self.n_experts)
+                rows = min(rows, -(-want // 8) * 8)
+            order = jnp.argsort(key, stable=True)[:rows]
+            key_s = key[order]
+            starts = jnp.cumsum(sizes) - sizes
+            place = jnp.arange(rows) - starts[jnp.minimum(key_s, n - 1)]
+            cap = self.capacity(T)
+            live = key_s < n
+            kept = live if cap is None else live & (place < cap)
+            # a group ends where the buffer does; rows over the capacity
+            # stay in their group and weigh nothing
+            sizes_in = jnp.clip(rows - starts, 0, sizes)
+            weight = jnp.where(kept, gates.T.reshape(-1)[order], 0.0)
+            token = order % T
+            xs = x2d[token]
+            stats = {"moe_assignments_held": held,
+                     "moe_expert_load_max": jnp.max(sizes),
+                     "moe_dropped_assignments": held - jnp.sum(kept)}
+        with jax.named_scope("moe.experts"):
+            ys = self._grouped_mlp(params, xs, sizes_in, live[:, None])
+            shared = (_swiglu(x2d, **params["shared"])
+                      if self.shared_hidden else None)
+        with jax.named_scope("moe.combine"):
+            y = jnp.zeros((T, d), jnp.float32).at[token].add(
+                ys.astype(jnp.float32) * weight[:, None])
+            if shared is not None:
+                y = y + shared.astype(jnp.float32)
+        return y.astype(x2d.dtype), aux, stats
+
+    @staticmethod
+    def reduce_stats(stats):
+        """One step's counters from its layers' (``MOE_COUNTERS``)."""
+        pick = lambda name: jnp.stack([s[name] for s in stats])
+        return {"moe_assignments_held": jnp.sum(pick("moe_assignments_held")),
+                "moe_expert_load_max": jnp.max(pick("moe_expert_load_max")),
+                "moe_dropped_assignments":
+                    jnp.sum(pick("moe_dropped_assignments"))}
+
+    def forward(self, params, x, return_aux_loss: bool = False,
+                return_stats: bool = False):
         *lead, d = x.shape
         x2d = x.reshape(-1, d)
         T = x2d.shape[0]
@@ -170,31 +320,59 @@ class ExpertParallelMLP(Module):
         if E % ep:
             raise ValueError(f"n_experts={E} not divisible by expert-"
                              f"parallel size {ep}")
-        capacity = max(1, math.ceil(self.capacity_factor * T / E))
+        if ep == 1:
+            y2d, aux, stats = self._sorted_forward(params, x2d,
+                                                   return_aux_loss)
+        else:
+            y2d, aux = self._all_to_all_forward(params, x2d, ep)
+            stats = None
+        out = (y2d.reshape(*lead, d),)
+        if return_aux_loss:
+            out += (aux,)
+        if return_stats:
+            out += (stats,)
+        return out if len(out) > 1 else out[0]
+
+    def _all_to_all_forward(self, params, x2d, ep):
+        T, d = x2d.shape
+        E, e_loc = self.n_experts, self.n_experts // ep
+        if (self.capacity_factor is None or self.n_held != E
+                or self.shared_hidden):
+            raise NotImplementedError(
+                "the all_to_all dispatch needs a capacity_factor and has "
+                "no experts_held / shared expert; the sorted dispatch is "
+                "not exchanged across an expert axis yet")
+        capacity = self.capacity(T)
         dispatch, combine, aux = self._dispatch(x2d, params["router"],
                                                 capacity)
         # (T,E,C) x (T,d) -> (E,C,d): the local contribution per expert
         sent = jnp.einsum("tec,td->ecd", dispatch.astype(x2d.dtype), x2d)
-        if ep > 1:
-            e_loc = E // ep
-            # (E,C,d) -> (ep, e_loc, C, d) -all_to_all-> every device
-            # ends up with ITS experts' queues from all source devices
-            sent = sent.reshape(ep, e_loc, capacity, d)
-            recv = lax.all_to_all(sent, self.axis_name, split_axis=0,
-                                  concat_axis=0, tiled=False)
-            # (ep_src, e_loc, C, d) -> (e_loc, ep_src*C, d)
-            xe = jnp.moveaxis(recv, 0, 1).reshape(e_loc, ep * capacity, d)
-            ye = self._expert_mlp(params, xe)
-            back = jnp.moveaxis(
-                ye.reshape(e_loc, ep, capacity, d), 1, 0)
-            got = lax.all_to_all(back, self.axis_name, split_axis=0,
-                                 concat_axis=0, tiled=False)
-            got = got.reshape(E, capacity, d)
-        else:
-            got = self._expert_mlp(params, sent)
-        y2d = jnp.einsum("tec,ecd->td", combine.astype(got.dtype), got)
-        y = y2d.reshape(*lead, d)
-        return (y, aux) if return_aux_loss else y
+        # (E,C,d) -> (ep, e_loc, C, d) -all_to_all-> every device
+        # ends up with ITS experts' queues from all source devices
+        sent = sent.reshape(ep, e_loc, capacity, d)
+        recv = lax.all_to_all(sent, self.axis_name, split_axis=0,
+                              concat_axis=0, tiled=False)
+        # (ep_src, e_loc, C, d) -> (e_loc, ep_src*C, d)
+        xe = jnp.moveaxis(recv, 0, 1).reshape(e_loc, ep * capacity, d)
+        ye = self._expert_mlp(params, xe)
+        back = jnp.moveaxis(ye.reshape(e_loc, ep, capacity, d), 1, 0)
+        got = lax.all_to_all(back, self.axis_name, split_axis=0,
+                             concat_axis=0, tiled=False)
+        got = got.reshape(E, capacity, d)
+        return (jnp.einsum("tec,ecd->td", combine.astype(got.dtype), got),
+                aux)
+
+
+def record_moe_counters(metrics, registry=None) -> None:
+    """Host side: keep a step's :data:`MOE_COUNTERS` (those its metrics
+    hold) as gauges of the observability registry."""
+    from ..observability.metrics import get_registry
+    reg = registry or get_registry()
+    for name in MOE_COUNTERS:
+        if name in metrics:
+            reg.gauge(name, help="expert layers of the last step, see "
+                      "parallel/expert_parallel.py").set(
+                          float(metrics[name]))
 
 
 def allreduce_replicated_grads(grads, specs, axis_name: str):

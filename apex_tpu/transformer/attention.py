@@ -55,7 +55,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           dropout_rate: float = 0.0,
                           causal: bool = False,
                           dropout_rng: Optional[jax.Array] = None,
-                          segment_ids: Optional[jax.Array] = None
+                          segment_ids: Optional[jax.Array] = None,
+                          window: Optional[int] = None
                           ) -> jax.Array:
     """q,k,v: (..., T, H) — softmax(qk^T/sqrt(H)) v with fp32 softmax.
 
@@ -77,6 +78,9 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel draw different masks from the rng, so expect statistical, not
     bitwise, agreement between backends).  Only arbitrary per-pair mask
     shapes take the dense path.
+    ``window`` (static, with ``causal=True``): key j is visible to query i
+    iff ``i - window < j <= i``.  The flash kernels apply the band and
+    skip the block pairs outside it; the dense path builds the band mask.
 
     Caveat on fully-masked rows: flash emits zeros for a query whose
     keys are all masked, while the dense softmax degrades to a uniform
@@ -94,6 +98,9 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if segment_ids.shape != expect:
             raise ValueError(f"segment_ids must be (B, T) = {expect}, "
                              f"got {segment_ids.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("window needs causal=True and window >= 1, got "
+                         f"causal={causal}, window={window}")
     ctx = current_context()
     train_dropout = (dropout_rate > 0.0
                      and (dropout_rng is not None
@@ -132,7 +139,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 return pfa.flash_attention(
                     q, k, v, causal=causal, scale=scale, kv_mask=kv_mask,
                     dropout_rate=(dropout_rate if train_dropout else 0.0),
-                    dropout_seed=seed, segment_ids=segment_ids)
+                    dropout_seed=seed, segment_ids=segment_ids,
+                    window=window)
     _note_path("dense")
     if causal:
         Tq, Tk = q.shape[-2], k.shape[-2]
@@ -142,6 +150,9 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # causal constraint — it must never replace it.
         qpos = Tk - Tq + jnp.arange(Tq)
         cmask = qpos[:, None] >= jnp.arange(Tk)[None, :]
+        if window is not None:
+            cmask = cmask & (jnp.arange(Tk)[None, :]
+                             > qpos[:, None] - window)
         mask = cmask if mask is None else jnp.logical_and(mask, cmask)
     scores = F.matmul(q, jnp.swapaxes(k, -1, -2)).astype(jnp.float32) * scale
     if mask is not None:

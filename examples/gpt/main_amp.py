@@ -11,12 +11,21 @@ Rehearse on the CPU mesh:
   python examples/gpt/main_amp.py --config tiny --iters 20 --generate 64
 On a TPU host (one process per chip set; `python chip_smoke.py` first):
   python examples/gpt/main_amp.py --config small -b 8
+The per-layer decoder with routed experts, from a published config file:
+  python examples/gpt/main_amp.py --arch laguna -b 2 --block-size 8192 \
+      --model-config benchmark/configs/laguna-xs2.json
+
+``build(args)`` returns the model, mesh, state and jitted train step that
+``main()`` loops over; the benchmark and the tests drive the same objects.
 """
 
 import argparse
+import inspect
+import json
 import os
 import sys
 import time
+import types
 
 import numpy as np
 
@@ -58,12 +67,19 @@ def _stdlib_corpus(mb: float) -> str:
     return text
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description="apex_tpu GPT training")
-    p.add_argument("--arch", default="gpt", choices=["gpt", "llama"],
+    p.add_argument("--arch", default="gpt",
+                   choices=["gpt", "llama", "laguna"],
                    help="decoder family: GPT-2 (LayerNorm + learned "
-                        "positions) or Llama (RMSNorm + RoPE + SwiGLU "
-                        "+ GQA)")
+                        "positions), Llama (RMSNorm + RoPE + SwiGLU "
+                        "+ GQA), or the per-layer decoder of "
+                        "models/laguna.py (window and full attention, "
+                        "routed experts) built from --model-config")
+    p.add_argument("--model-config", default=None, metavar="JSON",
+                   help="laguna: a config file with the published keys "
+                        "(benchmark/configs/laguna-xs2.json); the "
+                        "sequence length is --block-size")
     p.add_argument("--n-kv-head", type=int, default=None,
                    help="grouped-query attention KV heads (llama; "
                         "default MHA)")
@@ -71,10 +87,11 @@ def parse_args():
                    choices=["tiny", "small", "medium"])
     p.add_argument("-b", "--batch-size", type=int, default=8,
                    help="per-device batch size")
-    p.add_argument("--block-size", type=int, default=None,
+    p.add_argument("--block-size", "--seq-len", type=int, default=None,
                    help="sequence length (default: config's)")
     p.add_argument("--iters", type=int, default=100)
     p.add_argument("--lr", type=float, default=3e-4)
+    p.add_argument("--weight-decay", type=float, default=0.0)
     p.add_argument("--opt-level", default="O2")
     p.add_argument("--text", default=None,
                    help="path to a UTF-8 text corpus (char-level); "
@@ -103,29 +120,72 @@ def parse_args():
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--print-freq", type=int, default=10)
     p.add_argument("--seed", type=int, default=0)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
+def _corpus(args):
+    if args.stdlib_corpus:
+        return _stdlib_corpus(args.stdlib_corpus)
+    if args.text:
+        return open(args.text, encoding="utf-8").read()
+    return _BUILTIN_TEXT
 
+
+def _network(args, n_chars):
+    """(module, sequence length) of ``--arch``."""
+    from apex_tpu import models
+    if args.arch == "laguna":
+        if not args.model_config:
+            raise SystemExit("--arch laguna needs --model-config <file>")
+        with open(args.model_config) as f:
+            file_cfg = json.load(f)
+        T = args.block_size or file_cfg.get("seq_len", 512)
+        cfg = models.LagunaConfig.from_dict(
+            file_cfg, max_position_embeddings=max(
+                T, file_cfg.get("max_position_embeddings", T)))
+        if cfg.vocab_size < n_chars:
+            raise SystemExit(f"the corpus has {n_chars} characters, the "
+                             f"model's vocabulary {cfg.vocab_size}")
+        return models.Laguna(cfg), T
+    shapes = {"tiny": dict(n_layer=2, n_head=4, n_embd=64, block_size=64),
+              "small": dict(n_layer=12, n_head=12, n_embd=768,
+                            block_size=512),
+              "medium": dict(n_layer=24, n_head=16, n_embd=1024,
+                             block_size=512)}[args.config]
+    if args.block_size:
+        shapes["block_size"] = args.block_size
+    T = shapes["block_size"]
+    if args.arch == "llama":
+        return models.Llama(models.LlamaConfig(
+            vocab_size=max(n_chars, 2),
+            hidden_size=shapes["n_embd"],
+            intermediate_size=4 * shapes["n_embd"],
+            num_hidden_layers=shapes["n_layer"],
+            num_attention_heads=shapes["n_head"],
+            num_key_value_heads=args.n_kv_head,
+            max_position_embeddings=T, tie_word_embeddings=True)), T
+    return models.GPT(models.GPTConfig(
+        vocab_size=max(n_chars, 2), dropout=0.0,
+        n_kv_head=args.n_kv_head, **shapes)), T
+
+
+def build(args):
+    """Everything up to (not including) the first step, as
+    examples/bert/main_amp.py's ``build``: corpus, amp-initialized model +
+    optimizer, DDP wrapper, mesh, placed state and the jitted,
+    state-donating train step ``(state, (ids,)) -> (state, metrics)``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from apex_tpu import amp, models, optimizers, parallel
-    from apex_tpu.utils import AverageMeter, configure_compile_cache
-    from apex_tpu.nn import functional as F  # noqa: F401 (parity import)
+    from apex_tpu import amp, optimizers, parallel
+    from apex_tpu.observability import get_recorder
+    from apex_tpu.observability.compilation import instrumented_jit
 
-    configure_compile_cache()
+    span = get_recorder().span      # set-up by phase: build.*
     ndev = len(jax.devices())
-    if args.stdlib_corpus:
-        text = _stdlib_corpus(args.stdlib_corpus)
-    elif args.text:
-        text = open(args.text, encoding="utf-8").read()
-    else:
-        text = _BUILTIN_TEXT
+    text = _corpus(args)
     vocab = sorted(set(text))
     stoi = {c: i for i, c in enumerate(vocab)}
     data = np.asarray([stoi[c] for c in text], np.int32)
@@ -136,14 +196,7 @@ def main():
           f"vocab {len(vocab)}; {ndev} device(s) on "
           f"{jax.default_backend()}")
 
-    shapes = {"tiny": dict(n_layer=2, n_head=4, n_embd=64, block_size=64),
-              "small": dict(n_layer=12, n_head=12, n_embd=768,
-                            block_size=512),
-              "medium": dict(n_layer=24, n_head=16, n_embd=1024,
-                             block_size=512)}[args.config]
-    if args.block_size:
-        shapes["block_size"] = args.block_size
-    T = shapes["block_size"]
+    net, T = _network(args, len(vocab))
     if val_data is not None and len(val_data) <= T:
         # mirrors the imagenet example's refuse-undersized-val-split
         # startup guard: run_eval needs at least one full block
@@ -151,52 +204,64 @@ def main():
             f"--val-frac {args.val_frac} holds out only "
             f"{len(val_data)} chars but the block size is {T}; raise "
             f"--val-frac or use a bigger corpus")
-    if args.arch == "llama":
-        cfg = models.LlamaConfig(
-            vocab_size=max(len(vocab), 2),
-            hidden_size=shapes["n_embd"],
-            intermediate_size=4 * shapes["n_embd"],
-            num_hidden_layers=shapes["n_layer"],
-            num_attention_heads=shapes["n_head"],
-            num_key_value_heads=args.n_kv_head,
-            max_position_embeddings=T, tie_word_embeddings=True)
-        net = models.Llama(cfg)
-    else:
-        cfg = models.GPTConfig(vocab_size=max(len(vocab), 2),
-                               dropout=0.0, n_kv_head=args.n_kv_head,
-                               **shapes)
-        net = models.GPT(cfg)
 
-    model, optimizer = amp.initialize(
-        net, optimizers.FusedAdam(lr=args.lr),
-        opt_level=args.opt_level, verbosity=0)
-    ddp = parallel.DistributedDataParallel(model)
-    params, _ = model.init(jax.random.PRNGKey(args.seed))
-    opt_state = optimizer.init(params)
+    with span("build.amp_initialize"):
+        model, optimizer = amp.initialize(
+            net, optimizers.FusedAdam(lr=args.lr,
+                                      weight_decay=args.weight_decay),
+            opt_level=args.opt_level, verbosity=0)
+        ddp = parallel.DistributedDataParallel(model)
     mesh = Mesh(np.array(jax.devices()), ("data",))
+    replicated = NamedSharding(mesh, P())
+    batch_sharding = NamedSharding(mesh, P("data"))
+    with span("build.model_init"):
+        params, _ = model.init(jax.random.PRNGKey(args.seed))
+    with span("build.place_params"):
+        params = jax.device_put(params, replicated)
+    with span("build.optimizer_init"):
+        opt_state = jax.device_put(optimizer.init(params), replicated)
     B = args.batch_size * ndev
     rng = np.random.RandomState(args.seed)
 
-    def get_batch():
+    def get_batch(i=None):
         ix = rng.randint(0, len(data) - T, B)
-        return jnp.asarray(np.stack([data[i:i + T] for i in ix]))
+        return (np.stack([data[i:i + T] for i in ix]),)
+
+    def put_batch(batch):
+        return jax.device_put(batch, batch_sharding)
+
+    # a model whose loss can hand back its expert layers' counters
+    with_stats = "return_stats" in inspect.signature(
+        model.loss).parameters
 
     def step(state, batch):
         params, opt_state = state
         (ids,) = batch
 
         def loss_fn(p):
-            return model.loss(p, ids), ()
+            if with_stats:          # + the expert layers' counters
+                return model.loss(p, ids, return_stats=True)
+            return model.loss(p, ids), {}
 
-        loss, _, grads = amp.scaled_grad(loss_fn, params, opt_state,
-                                         has_aux=True)
+        loss, stats, grads = amp.scaled_grad(loss_fn, params, opt_state,
+                                             has_aux=True)
         grads = ddp.allreduce_grads(grads)
-        params, opt_state, _ = optimizer.step(params, opt_state, grads)
-        return (params, opt_state), lax.pmean(loss, "data")
+        params, opt_state, info = optimizer.step(params, opt_state, grads)
+        return (params, opt_state), {
+            "loss": lax.pmean(loss, "data"),
+            "loss_scale": info["loss_scale"],
+            "found_inf": info["found_inf"],
+            **{k: lax.pmax(v, "data") if k.endswith("_max")
+               else lax.psum(v, "data") for k, v in stats.items()}}
 
-    train = jax.jit(jax.shard_map(
-        step, mesh=mesh, in_specs=(P(), (P("data"),)),
-        out_specs=(P(), P()), check_vma=False))
+    # the compilation ledger watches the step; the old state's buffers
+    # are donated to the new one
+    with span("build.step_wrap"):
+        train_step = instrumented_jit(jax.shard_map(
+            step, mesh=mesh, in_specs=(P(), (P("data"),)),
+            out_specs=(P(), P()), check_vma=False),
+            "lm.train_step", arg_names=("state", "batch"),
+            donate_argnums=(0,))
 
     eval_loss = jax.jit(jax.shard_map(
         lambda p, ids: lax.pmean(model.loss(p, ids), "data"),
@@ -218,29 +283,50 @@ def main():
             tot += float(eval_loss(p, ids))
         return tot / args.val_batches
 
-    state = (params, opt_state)
+    return types.SimpleNamespace(
+        model=model, optimizer=optimizer, ddp=ddp, mesh=mesh,
+        state=(params, opt_state), train_step=train_step,
+        get_batch=get_batch, put_batch=put_batch, global_batch=B,
+        ndev=ndev, seq_len=T, run_eval=run_eval,
+        has_val=val_data is not None, text=text, vocab=vocab, stoi=stoi)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.parallel.expert_parallel import record_moe_counters
+    from apex_tpu.utils import AverageMeter, configure_compile_cache
+
+    configure_compile_cache()
+    run = build(args)
+    train, get_batch, put_batch = run.train_step, run.get_batch, run.put_batch
+    B, ndev, T, state = run.global_batch, run.ndev, run.seq_len, run.state
     print("=> compiling train step...")
     t0 = time.time()
-    state, loss = train(state, (get_batch(),))
-    jax.block_until_ready(loss)
+    state, metrics = train(state, put_batch(get_batch()))
+    jax.block_until_ready(metrics)
     print(f"=> compiled in {time.time() - t0:.1f}s")
 
     bt, losses = AverageMeter(), AverageMeter()
     end = time.time()
     for i in range(args.iters):
-        state, loss = train(state, (get_batch(),))
-        jax.block_until_ready(loss)
+        state, metrics = train(state, put_batch(get_batch()))
+        jax.block_until_ready(metrics)
         bt.update(time.time() - end)
         end = time.time()
-        losses.update(float(loss))
+        losses.update(float(metrics["loss"]))
+        record_moe_counters(metrics)
         if i % args.print_freq == 0:
             print(f"iter [{i}/{args.iters}]  Time {bt.val:.3f} "
                   f"({bt.avg:.3f})  Speed {B / bt.val:.1f} seq/s  "
                   f"Loss {losses.val:.4f} ({losses.avg:.4f})")
-        if (val_data is not None and args.eval_freq
+        if (run.has_val and args.eval_freq
                 and i and i % args.eval_freq == 0):
             print(f"iter [{i}/{args.iters}]  val_loss "
-                  f"{run_eval(state[0]):.4f}")
+                  f"{run.run_eval(state[0]):.4f}")
     if bt.avg > 0:
         print(f"=> done. avg {B / bt.avg:.1f} seq/s "
               f"({B / bt.avg / ndev:.1f} seq/s/device)")
@@ -248,9 +334,9 @@ def main():
         print("=> done. (no timed iterations)")
 
     final_val = None
-    if val_data is not None:
-        final_val = run_eval(state[0])
-        uniform = float(np.log(max(len(vocab), 2)))
+    if run.has_val:
+        final_val = run.run_eval(state[0])
+        uniform = float(np.log(max(len(run.vocab), 2)))
         print(f"FINAL val_loss {final_val:.4f} nats/char "
               f"(uniform {uniform:.2f})")
     if args.target_val_loss is not None:
@@ -264,14 +350,17 @@ def main():
             raise SystemExit(1)
 
     if args.generate:
-        params = state[0]
-        prompt = text[:min(16, T // 2)]
+        if args.arch == "laguna":
+            raise SystemExit("--generate: models/laguna.py has no cached "
+                             "decoding (training and full forward only)")
+        params, stoi = state[0], run.stoi
+        prompt = run.text[:min(16, T // 2)]
         buf = np.zeros((1, T), np.int32)
         buf[0, :len(prompt)] = [stoi[c] for c in prompt]
         n = min(args.generate, T - len(prompt))
         gen_rng = (jax.random.PRNGKey(args.seed)
                    if args.temperature > 0 else None)
-        out, flen = jax.jit(lambda p, b: model.generate_cached(
+        out, flen = jax.jit(lambda p, b: run.model.generate_cached(
             p, b, len(prompt), n, temperature=args.temperature,
             rng=gen_rng))(params, jnp.asarray(buf))
         toks = np.asarray(out)[0][:int(flen[0])]

@@ -117,7 +117,10 @@ def _loop_moe(moe, params, x):
     x2 = np.asarray(x)
     T, d = x2.shape
     E, k = moe.n_experts, moe.top_k
-    C = max(1, math.ceil(moe.capacity_factor * T / E))
+    # an expert's capacity counts all k assignments of a token (the
+    # capacity repair of the sorted dispatch: cf * T * k / E rows)
+    C = moe.capacity(T)
+    assert C == max(1, math.ceil(moe.capacity_factor * T * k / E))
     logits = x2 @ np.asarray(params["router"])
     z = np.exp(logits - logits.max(1, keepdims=True))
     probs = z / z.sum(1, keepdims=True)
@@ -145,6 +148,36 @@ def _loop_moe(moe, params, x):
                     np.sqrt(2.0 / np.pi) * (h + 0.044715 * h ** 3)))
             y[t] += gates[t, c] * (h @ wo[e])
     return y
+
+
+def _dense_grad_f64(moe, params, x, n_shards):
+    """Gradient of sum(y**2) in float64 with no dispatch at all: each
+    token shard routed on its own (softmax, top-k renormalized,
+    choice-major queue under the capacity), every SwiGLU expert computed
+    for every token and weighted by the gate it kept."""
+    E, k = moe.n_experts, moe.top_k
+    with jax.enable_x64(True):
+        def loss(p):
+            total = 0.0
+            for xs in np.split(np.asarray(x, np.float64), n_shards):
+                xs = jnp.asarray(xs)
+                T = xs.shape[0]
+                gates, experts = lax.top_k(
+                    jax.nn.softmax(xs @ p["router"], axis=-1), k)
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+                oh = jax.nn.one_hot(experts.T.reshape(-1), E)  # (kT, E)
+                pos = jnp.sum(jnp.cumsum(oh, axis=0) * oh, axis=-1) - 1
+                kept = (pos < moe.capacity(T)).reshape(k, T).T
+                w = jnp.einsum("tk,tke->te", gates * kept,
+                               jax.nn.one_hot(experts, E))
+                h = (jax.nn.silu(jnp.einsum("td,edh->teh", xs, p["w_gate"]))
+                     * jnp.einsum("td,edh->teh", xs, p["w_in"]))
+                y = jnp.einsum("te,teh,ehd->td", w, h, p["w_out"])
+                total = total + jnp.sum(jnp.square(y))
+            return total
+
+        return jax.grad(loss)(jax.tree_util.tree_map(
+            lambda a: jnp.asarray(np.asarray(a), jnp.float64), params))
 
 
 @pytest.mark.parametrize("cap", [2.0, 0.5])
@@ -199,7 +232,12 @@ def test_moe_top2_gradients_match_per_shard_reference():
     def ref_loss(p):
         return jnp.sum(jnp.square(_ref_sharded(moe, p, x, 4)))
 
-    assert_trees_close(g_tp, jax.grad(ref_loss)(params), atol=3e-5)
+    # the exchanged masks and the local sorted dispatch sum in different
+    # orders (router gradients reach 60 here), so each is held to the
+    # float64 oracle and not to the other
+    g64 = _dense_grad_f64(moe, params, x, 4)
+    assert_trees_close(g_tp, g64, atol=3e-5)
+    assert_trees_close(jax.grad(ref_loss)(params), g64, atol=3e-5)
 
 
 def test_moe_top2_gates_renormalized():

@@ -114,6 +114,21 @@ def programs():
                                   prefill_chunk=8, window=2)
         eng.warmup()
         out["paged"] = (_scopes(C.get_ledger().compiled_text("engine._paged_step_k")), None)
+
+        # the per-layer decoder with routed experts, its blocks rematerialized
+        lag = models.Laguna(models.LagunaConfig(
+            vocab_size=64, hidden_size=16, intermediate_size=32,
+            layer_types=["full_attention", "sliding_attention"],
+            num_attention_heads_per_layer=[2, 4], mlp_layer_types=["dense", "sparse"],
+            num_key_value_heads=2, head_dim=8, sliding_window=4, num_experts=4,
+            num_experts_per_tok=2, moe_intermediate_size=8, shared_expert_intermediate_size=8,
+            rope_parameters={k: {"rope_theta": 10000.0}
+                             for k in ("full_attention", "sliding_attention")},
+            remat="dots", head_chunk=32))
+        lparams, _ = lag.init(jax.random.PRNGKey(2))
+        text = jax.jit(jax.grad(lambda p: lag.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
+            lparams).compile().as_text()
+        out["moe"] = (_scopes(text), phases.instruction_phases(text))
     finally:
         C.set_ledger(prev)
     return out
@@ -122,7 +137,9 @@ def programs():
 CASES = ([("mesh", s) for s in TRAIN_SCOPES + DDP_SCOPES]
          + [("one", s) for s in TRAIN_SCOPES]
          + [("lamb", "optim.lamb"), ("lion", "optim.lion"), ("lion", "amp.grad_norm"),
-            ("paged", "paged.gather"), ("paged", "paged.scatter"), ("paged", "paged.attend")])
+            ("paged", "paged.gather"), ("paged", "paged.scatter"), ("paged", "paged.attend"),
+            ("moe", "moe.route"), ("moe", "moe.dispatch"), ("moe", "moe.experts"),
+            ("moe", "moe.combine")])
 
 
 def test_every_scope_of_the_vocabulary_has_a_case():
@@ -338,3 +355,41 @@ def test_the_package_records_its_own_import():
     rec.add_span("x", rec.origin - 2.0, rec.origin - 0.5)
     (ev,) = rec.events()
     assert ev["ts"] == pytest.approx(-2e6) and ev["dur"] == pytest.approx(1.5e6)
+
+
+def test_a_rematerialized_blocks_backward_keeps_its_module_path(programs):
+    """``transpose(jvp(model))/jvp(model)/checkpoint/layers/1/mlp/moe.experts/..``:
+    the repeated root scope and jax's own ``checkpoint`` / ``rematted_computation``
+    are stepped over, so the backward of a rematerialized block is read by module
+    like its forward."""
+    found = set(programs["moe"][1].values())
+    for backward in (False, True):
+        assert (("model", "layers/1/mlp", "moe.experts"), backward) in found
+        assert any(path[:2] == ("model", "layers/1/self_attn/q_proj") and b == backward
+                   for path, b in found)
+    assert not any(path[:2] == ("model", "model") for path, _ in found)
+    name = ("jit(f)/transpose(jvp(model))/jvp(model)/checkpoint/rematted_computation/layers/0/"
+            "self_attn/mul;jit(f)/jvp(loss)/add")
+    assert phases.phase_of_op_name(name) == (("model", "layers/0/self_attn"), True)
+
+
+def test_a_custom_call_the_compiler_named_takes_its_operands_or_its_readers_phase():
+    """``lax.ragged_dot`` becomes the TPU compiler's own kernel with
+    ``op_name="ragged-dot-none"``: the scope it was written under is on its
+    operand's producer, or (a weight gradient read from a prefetch) on its reader."""
+    text = """HloModule m
+ENTRY %main (p: f32[8,8]) -> f32[8,8] {
+  %p = f32[8,8]{1,0} parameter(0)
+  %fusion.1 = f32[8,8]{1,0} fusion(%p), kind=kLoop, calls=%f1, metadata={op_name="jit(s)/jvp(model)/layers/1/mlp/moe.experts/select_n"}
+  %ragged-dot-none = f32[8,8]{1,0} custom-call(%p, %fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %ragged-dot-none.1 = f32[8,8]{1,0} custom-call(%p, %p), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %fusion.2 = f32[8,8]{1,0} fusion(%ragged-dot-none.1), kind=kLoop, calls=%f2, metadata={op_name="jit(s)/transpose(jvp(model))/layers/1/mlp/moe.experts/mul"}
+  %custom-call.9 = f32[8,8]{1,0} custom-call(%p), custom_call_target="Other", metadata={op_name="Other"}
+  ROOT %flash_fwd.3 = f32[8,8]{1,0} custom-call(%fusion.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(s)/jvp(model)/layers/1/self_attn/pallas_call"}
+}
+"""
+    got = phases.instruction_phases(text)
+    assert got["ragged-dot-none"] == (("model", "layers/1/mlp", "moe.experts"), False)
+    assert got["ragged-dot-none.1"] == (("model", "layers/1/mlp", "moe.experts"), True)
+    assert got["custom-call.9"] == ((), False)
+    assert got["flash_fwd.3"] == (("model", "layers/1/self_attn"), False)
