@@ -7,27 +7,41 @@ it, ``ulysses_attention``'s per-head local attention.  (Ring attention
 keeps its own jnp online-softmax accumulation: its inner blocks interleave
 with ppermutes and XLA fuses them against the collective.)
 
-Design (FlashAttention-style, true blocked form): the grid is
-(batch*heads, q_blocks, k_blocks) with the k axis innermost ("arbitrary"
-semantics, executed sequentially per core).  K and V are *streamed* one
-(BLK, D) block at a time — nothing scales with T in VMEM — while online
-softmax state (running max m, running sum l, unnormalized accumulator)
-lives in VMEM scratch that persists across the k-block sweep.  The
-forward emits the per-row logsumexp; the backward recomputes
-probabilities from it in two streamed passes: a dQ pass (K/V streamed)
-and a dK/dV pass (Q/dO streamed), each a handful of MXU contractions per
-block pair.  Causal q/k block pairs above the diagonal are skipped via
-``pl.when``.  With a sliding ``window`` (key j visible to query i iff
-i - W < j <= i) the streamed axis of each grid covers only the
-``_band_blocks`` blocks a row of blocks can see: a block pair wholly
-outside the band is no grid step at all, so it is neither computed nor
-fetched, and the cost of a windowed layer is linear in T.
+Design (FlashAttention-style, true blocked form): each of the three
+kernels runs a grid (batch*heads / hb, rows, steps) whose last axis is
+sequential ("arbitrary"); a step takes ``hb`` heads of one batch entry
+(``_heads_per_step``), which share its masks and its fixed cost.  A row
+accumulates into one output block in VMEM
+scratch while the blocks of the other side are *streamed* past it one
+(BLK, D) block a step — nothing scales with T in VMEM: K and V past a q
+block in the forward (running max m, running sum l, unnormalized
+accumulator) and in the dQ pass, Q and dO past a k block in the dK/dV
+pass.  The forward emits the per-row logsumexp; the backward recomputes
+probabilities from it, a handful of MXU contractions per block pair.
 
-Per-row statistics (lse, delta and the m/l scratch) are stored
-lane-broadcast as (rows, 128) tiles — Mosaic requires the last two block
-dims to be (8k, 128k)-aligned, so a (rows,) vector is carried as a full
-lane tile with every lane equal (same layout the upstream
-jax.experimental.pallas.ops.tpu.flash_attention uses).
+Which pairs a grid visits is ``_Sweep``'s: every pair without a mask on
+position; under ``causal`` the triangle, folded so that a long row and a
+short one share n + 1 steps and no step is dead; with a sliding ``window``
+(key j visible to query i iff i - W < j <= i) only the ``_band_blocks``
+blocks a row of blocks can see, so the cost of a windowed layer is linear
+in T.  A visited pair is *interior* when every one of its scores is
+visible (below the diagonal, inside the band, no padded key, no
+``kv_mask``, segments or dropout) and runs a body with no iota, compare
+or select; an *edge* pair runs the masked body.  The counters
+``flash_block_pairs_total{kind}`` and ``flash_pad_copies_total`` say at
+trace time what a call's grids visit and what it copies.
+
+Operands reach the kernels as they are when T is a whole number of blocks
+and D is under 128 or a multiple of it (a block's last dimension may equal
+the array's): no pad before, no slice after.  Per-row statistics cross HBM
+as (8, T) sublane-broadcast row tiles (lse, delta; the layout of the
+key-validity and segment-id tiles).  Where queries lie along a score
+tile's sublanes (forward, dQ) they are widened once a row into
+(rows, 128) tiles with every lane equal — the form of the m/l scratch,
+which meets the score tile as whole copies of its vregs and the
+accumulator as it is, never as a (rows, 1) column.  The dK/dV pass is
+computed transposed (scores k-major), so a query's statistics stay rows,
+and p and ds are born as the left operands of dV = P^T dO and dK = dS^T Q.
 
 Matmuls feed the MXU in the input dtype (bf16 stays bf16) with fp32
 accumulation via ``preferred_element_type``; softmax state is always
@@ -97,15 +111,47 @@ def _keep_unit(seed0, seed1, bh, qpos, kpos):
     return bits.astype(jnp.float32) * np.float32(1.0 / 2147483648.0)
 
 
-def _block_for(T: int) -> int:
+def _block_for(T: int, window: Optional[int] = None) -> int:
     """Largest block in {512, 256, 128} that divides the lane-padded
     length — bounds zero-padding at 127 rows (a fixed 512 block would pad
-    T=600 to 1024, wasting 41% of every MXU contraction)."""
+    T=600 to 1024, wasting 41% of every MXU contraction).  A row of blocks
+    under a causal ``window`` computes ``blk + window - 1`` keys for the
+    ``window`` a query sees, so a window under two 512-blocks takes 256-row
+    blocks: 768 keys a row for a window of 512, not 1024.  (On a v5e that
+    pays only because ``_heads_per_step`` then puts 8 heads into a grid
+    step, whose fixed cost a 256-block cannot carry alone; 128 loses to
+    both.  PERF.md section 6, PR 27.)"""
     Tp = -(-T // LANES) * LANES
+    cap = 256 if window is not None and window < 2 * _BLK else _BLK
     for blk in (_BLK, 256, LANES):
-        if Tp % blk == 0:
+        if blk <= cap and Tp % blk == 0:
             return min(blk, Tp)
     return LANES
+
+
+def _head_width(D: int) -> int:
+    """The head size the kernels take: ``D`` itself when it is under one
+    lane tile or a whole number of them (a block's last dimension may
+    equal the array's, so nothing is padded), else the next multiple."""
+    return D if D < LANES or D % LANES == 0 else -(-D // LANES) * LANES
+
+
+def _heads_per_step(H: int, Dp: int, itemsize: int, masked: bool,
+                    blk: int) -> int:
+    """Heads of one batch entry a grid step takes.  They share the step's
+    masks and its fixed cost, about 0.3 us on a v5e, a third of an
+    interior forward pair of 512 x 512: 4 heads take 13 % off the three
+    kernels at T = 8192, D = 128 (PERF.md section 6, PR 27).  Each head
+    adds its operand blocks, accumulators and score tiles to the step's
+    VMEM (compiled for a v5e at blk 512, D 128: 6 / 8 / 12 MiB for 1 / 2 /
+    4 heads in bf16, 14 with dropout; 10 / 14 / 24 in fp32, of the 16 MiB
+    a kernel may use), so: 4 for half-precision heads of one lane tile,
+    2 when mask, segment or dropout operands come along, twice that at
+    blocks of 256 or under, 1 otherwise."""
+    if Dp > LANES or itemsize > 2:
+        return 1
+    most = (2 if masked else 4) * (1 if blk > 256 else 2)
+    return next(hb for hb in (8, 4, 2, 1) if hb <= most and H % hb == 0)
 
 
 def _band_blocks(window: int, blk: int, n: int) -> int:
@@ -116,27 +162,36 @@ def _band_blocks(window: int, blk: int, n: int) -> int:
 
 
 def fits_vmem(T: int, D: int, dropout: bool = False,
-              segments: bool = False) -> bool:
-    """VMEM needed per grid step — independent of T now that K/V stream
-    through the grid.  Sized for the worst pass (backward dK/dV): six
-    double-buffered operand blocks (q, k, v, do in; dk, dv out), two fp32
-    accumulator scratches, the lane-broadcast stats tiles, and the
-    (blk, blk) score/prob/dp/ds intermediates.  Dropout holds two more
-    live (blk, blk) tiles in the dk/dv pass (the hash tile u and p_acc
-    alongside p/dp/ds); segments double-buffer the q-id (blk, LANES) and
-    k-id (8, blk) tiles plus the (blk, blk) equality mask."""
-    blk = _block_for(T)
+              segments: bool = False, window: Optional[int] = None) -> bool:
+    """VMEM needed per grid step and head — independent of T now that K/V
+    stream through the grid, at the block ``window`` selects (how many
+    heads share a step is ``_heads_per_step``'s, from compiled sizes).
+    Sized for the worst pass (backward dK/dV): six double-buffered operand
+    blocks (q, k, v, do in; dk, dv out; a head under 128 lanes still fills
+    a lane tile in VMEM), the double-buffered (8, blk) row tiles of lse
+    and delta, two fp32 accumulator scratches, and the (blk, blk)
+    score/prob/dp/ds intermediates.  Dropout holds two more live
+    (blk, blk) tiles in the dk/dv pass (the hash tile u and p_acc
+    alongside p/dp/ds); segments double-buffer the k-id (blk, LANES) and
+    q-id (8, blk) tiles plus the (blk, blk) equality mask."""
+    blk = _block_for(T, window)
     Dp = -(-D // LANES) * LANES
     operands = 6 * blk * Dp          # q, k, v, do, dk, dv blocks
-    stats = 2 * blk * LANES          # lse + delta tiles
+    stats = 2 * 8 * blk              # lse + delta row tiles
     resident = 2 * (operands + stats) * 4          # double-buffered
     scratch = 2 * blk * Dp * 4                     # dk/dv fp32 accumulators
     ntiles = 6 if dropout else 4     # s/p, dp, ds (+ u, p_acc)
     if segments:
         ntiles += 1                  # the id-equality mask
-        resident += 2 * (blk * LANES + 8 * blk) * 4    # qseg + kseg tiles
+        resident += 2 * (blk * LANES + 8 * blk) * 4    # kseg + qseg tiles
     score = ntiles * blk * blk * 4
     return resident + scratch + score <= _VMEM_BUDGET
+
+
+def _count(name: str, help: str, value: int = 1, **labels) -> None:
+    from ..observability.metrics import get_registry
+    c = get_registry().counter(name, help=help)
+    (c.labels(**labels) if labels else c).inc(value)
 
 
 def _pad_to(x, T, D):
@@ -147,453 +202,590 @@ def _pad_to(x, T, D):
     return jnp.pad(x, pad)
 
 
-def _lanes(vec, Tp):
-    """(BH, T) → (BH, Tp, LANES) lane-broadcast fp32."""
-    BH, T = vec.shape
-    v = jnp.pad(vec.astype(jnp.float32), ((0, 0), (0, Tp - T)))
-    return jax.lax.broadcast_in_dim(v, (BH, Tp, LANES), (0, 1))
+def _unpad(x, T, D):
+    return x if x.shape[-2:] == (T, D) else x[..., :T, :D]
+
+
+# ---------------------------------------------------------------------------
+# which block pairs a grid visits, and what each of them needs
+# ---------------------------------------------------------------------------
+
+class _Sweep:
+    """The (rows, steps) grid of one kernel over the block pairs it must
+    visit.  A row accumulates into one output block while ``steps``
+    blocks of the other side stream past: k blocks past a q block
+    (``streams="k"``: forward, dq) or q blocks past a k block
+    (``streams="q"``: dk/dv).
+
+    - no mask on position: ``n x n``, every pair.
+    - causal: the triangle folded so that no step is dead.  Triangle row
+      ``r`` has ``r + 1`` pairs; folded row ``g`` runs the long row
+      ``n - 1 - g`` and then the short row that fills it up to
+      ``n + 1`` steps (``n`` when ``n`` is odd, whose longest row stands
+      alone).  The output block changes once inside a folded row, which
+      Pallas writes back like any other change of block.
+    - causal with a window: a row visits the ``_band_blocks`` blocks the
+      band touches; those that would start before block 0 (or end after
+      the last) are dead steps whose fetch is clamped onto the block
+      already resident.
+
+    ``at(g, t)`` works on traced scalars (kernels, index maps), Python
+    ints (``analysis.pallas_lint``) and, with ``xp=np``, whole index
+    grids (the block-pair counter)."""
+
+    def __init__(self, n: int, blk: int, causal: bool,
+                 window: Optional[int], streams: str, ragged: bool,
+                 masked: bool):
+        """``ragged``: the last k block holds padded keys.  ``masked``: a
+        key-validity, segment-id or dropout operand comes along, so every
+        pair takes the masked body."""
+        self.n, self.blk, self.causal, self.window = n, blk, causal, window
+        self.streams, self.ragged, self.masked = streams, ragged, masked
+        if not causal:
+            self.rows, self.steps = n, n
+        elif window is None:
+            self.even = 1 - n % 2
+            self.rows, self.steps = (n + 1) // 2, n + self.even
+        else:
+            self.rows, self.steps = n, _band_blocks(window, blk, n)
+
+    def at(self, g, t, xp=jnp):
+        """(row block, streamed block, the block to fetch for it, live,
+        first step of the row, last step of the row)."""
+        n, k_streams = self.n, self.streams == "k"
+        if not self.causal:
+            return g, t, t, True, t == 0, t == n - 1
+        if self.window is None:
+            long = n - g
+            in_long = t < long
+            tri = xp.where(in_long, n - 1 - g, g - 1 + self.even)
+            pos = xp.where(in_long, t, t - long)
+            row = tri if k_streams else n - 1 - tri
+            col = pos if k_streams else row + pos
+            return row, col, col, True, pos == 0, pos == tri
+        last = self.steps - 1
+        if k_streams:
+            col = g - last + t
+            return g, col, xp.maximum(col, 0), col >= 0, t == 0, t == last
+        col = g + t
+        return g, col, xp.minimum(col, n - 1), col < n, t == 0, t == last
+
+    def qk(self, g, t, xp=jnp):
+        """``at`` with the pair named by side: (q block, k block, ...)."""
+        row, col, _, live, first, last = self.at(g, t, xp)
+        qi, kj = (row, col) if self.streams == "k" else (col, row)
+        return qi, kj, live, first, last
+
+    def interior(self, qi, kj):
+        """Whether every (query, key) of the pair is visible, so that its
+        scores need no mask: below the diagonal, inside the band, no
+        padded key, no operand that masks.  A Python bool where the call
+        decides it."""
+        if self.masked:
+            return False
+        if self.window is not None and self.window < 2 * self.blk:
+            return False        # a band under two blocks: no pair is whole
+        ok = True
+        if self.causal:
+            ok = kj < qi
+        if self.window is not None:
+            ok = ok & ((qi - kj + 1) * self.blk <= self.window)
+        if self.ragged:
+            ok = ok & (kj != self.n - 1)
+        return ok
+
+    def count(self, heads: int) -> None:
+        """Block pairs of one launch by kind, into the registry."""
+        g, t = np.meshgrid(np.arange(self.rows), np.arange(self.steps),
+                           indexing="ij")
+        qi, kj, live, _, _ = self.qk(g, t, np)
+        live = np.broadcast_to(live, g.shape)
+        inner = live & np.broadcast_to(
+            self.interior(qi, kj), g.shape)
+        for kind, pairs in (("interior", inner), ("edge", live & ~inner),
+                            ("dead", ~live)):
+            _count("flash_block_pairs_total",
+                   "block pairs the flash kernels' grids visit, per traced "
+                   "launch and head: interior (no mask needed), edge "
+                   "(masked in the kernel), dead (skipped; its fetch is "
+                   "clamped onto the resident block)",
+                   int(pairs.sum()) * heads, kind=kind)
+
+
+def _both(a, b):
+    """``a and b`` for Python bools and traced predicates alike."""
+    if a is True:
+        return b
+    if b is True:
+        return a
+    if a is False or b is False:
+        return False
+    return jnp.logical_and(a, b)
+
+
+def _run_pair(pair, live, interior):
+    """Run ``pair(edge)`` once for a live block pair: the mask-free body
+    for an interior pair, the masked one for an edge pair."""
+    if interior is not False:
+        pl.when(_both(live, interior))(lambda: pair(False))
+    if interior is not True:
+        not_interior = True if interior is False else jnp.logical_not(interior)
+        pl.when(_both(live, not_interior))(lambda: pair(True))
+
+
+def _tile_lanes(x, width: int):
+    """A (rows, LANES) tile whose lanes are all equal, as (rows, width):
+    whole copies of its vregs for a multiple of LANES, its leading lanes
+    for a narrower head."""
+    if width < LANES:
+        return x[:, :width]
+    reps = width // LANES
+    return x if reps == 1 else jnp.tile(x, (1, reps))
+
+
+def _to_columns(rows):
+    """(8, blk) row tile (sublanes equal) → (blk, LANES) with lanes equal."""
+    return jnp.broadcast_to(rows[:1, :], (LANES, rows.shape[1])).T
+
+
+def _to_rows(columns):
+    """(blk, LANES) with lanes equal → (8, blk) row tile."""
+    return columns.T[:8, :]
+
+
+def _visible(shape, qi, kj, blk, q_axis, *, causal, window, T_real, Tp,
+             kvm, qseg, kseg):
+    """Visibility of an edge pair's scores, ``None`` when nothing masks
+    them, with the absolute positions the dropout hash keys on.
+    ``q_axis``: the axis of ``shape`` the queries lie along; ``kvm`` and
+    ``kseg`` broadcast along it, ``qseg`` along the other."""
+    qpos = qi * blk + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = kj * blk + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    terms = []
+    if T_real != Tp:
+        terms.append(kpos < T_real)
+    if causal:
+        terms.append(qpos >= kpos)
+    if window is not None:
+        terms.append(kpos > qpos - window)
+    if kvm is not None:
+        terms.append(kvm > 0.5)
+    if qseg is not None:
+        # packed sequences: attend only within the same segment
+        terms.append(qseg == kseg)
+    valid = functools.reduce(jnp.logical_and, terms) if terms else None
+    return valid, qpos, kpos
+
+
+def _split_refs(refs, n_lead, has_mask, has_segments, dropout_rate):
+    refs = list(refs)
+    lead, refs = refs[:n_lead], refs[n_lead:]
+    kvm_ref = refs.pop(0) if has_mask else None
+    qseg_ref = refs.pop(0) if has_segments else None
+    kseg_ref = refs.pop(0) if has_segments else None
+    seed_ref = refs.pop(0) if dropout_rate else None
+    return lead, kvm_ref, qseg_ref, kseg_ref, seed_ref, refs
+
+
+def _row(ref):
+    """The (1, blk) row of a sublane-broadcast (8, blk) tile."""
+    return None if ref is None else ref[0][:1, :]
+
+
+def _column(ref):
+    """The (blk, 1) column of a lane-broadcast (blk, LANES) tile."""
+    return None if ref is None else ref[0][:, :1]
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, causal, has_mask, has_segments,
-                dropout_rate, T_real, blk, nk, window=None):
-    refs = list(refs)
-    q_ref, k_ref, v_ref = refs[:3]
-    del refs[:3]
-    kvm_ref = refs.pop(0) if has_mask else None
-    if has_segments:
-        qseg_ref = refs.pop(0)
-        kseg_ref = refs.pop(0)
-    else:
-        qseg_ref = kseg_ref = None
-    seed_ref = refs.pop(0) if dropout_rate else None
-    o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
+def _fwd_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
+                T_real, Tp):
+    ((q_ref, k_ref, v_ref), kvm_ref, qseg_ref, kseg_ref, seed_ref,
+     (o_ref, lse_ref, m_ref, l_ref, acc_ref)) = _split_refs(
+        refs, 3, has_mask, has_segments, dropout_rate)
+    hb, blk, D = q_ref.shape
     b = pl.program_id(0)
-    i = pl.program_id(1)
-    step = pl.program_id(2)
+    i, j, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    if window is None:
-        j = step
-        # causal: the (i, j) block pair is dead when its lowest q row sits
-        # above its lowest k column (j*blk > i*blk + blk - 1 ⇔ j > i)
-        run = (j <= i) if causal else (j >= 0)
-    else:
-        # the nk steps end at the diagonal block; those that would start
-        # before block 0 are dead (their fetch is clamped onto block 0)
-        j = i - (nk - 1) + step
-        run = j >= 0
+    def pair(edge):
+        valid = None
+        if edge:
+            # kvm / k ids: (1, blk) rows of sublane-broadcast tiles (k on
+            # the lane axis, as s's columns); q ids: a (blk, 1) column
+            valid, qpos, kpos = _visible(
+                (blk, blk), i, j, blk, 0, causal=sweep.causal,
+                window=sweep.window, T_real=T_real, Tp=Tp,
+                kvm=_row(kvm_ref), qseg=_column(qseg_ref),
+                kseg=_row(kseg_ref))
+        for h in range(hb):
+            # m, l and alpha are (blk, LANES) tiles with equal lanes from
+            # scratch to scratch: they meet the score tile as whole copies
+            # of their vregs and the accumulator as they are
+            v = v_ref[h]
+            s = _dot(q_ref[h], k_ref[h], ((1,), (1,))) * scale
+            if valid is not None:
+                s = jnp.where(valid, s, _NEG)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _tile_lanes(m_new, blk))
+            if valid is not None:
+                # explicit zeroing: when a row is fully masked m_new ==
+                # _NEG and exp(s - m_new) would be exp(0) = 1 on the
+                # masked entries
+                p = jnp.where(valid, p, 0.0)
+            # the softmax normalizer uses the UNdropped probabilities;
+            # only the value accumulation is dropped+rescaled
+            # (FlashAttention's dropout placement — the mask is
+            # regenerated bitwise in both backward passes from the same
+            # counter hash)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = m_new
+            if dropout_rate:
+                u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b * hb + h,
+                               qpos, kpos)
+                p = jnp.where(u >= dropout_rate, p, 0.0) * (
+                    1.0 / (1.0 - dropout_rate))
+            pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))
+            acc_ref[h] = acc_ref[h] * _tile_lanes(alpha, D) + pv
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = _dot(q, k, ((1,), (1,))) * scale
-        kpos = j * blk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos < T_real
-        qpos = i * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        if causal:
-            valid = jnp.logical_and(valid, qpos >= kpos)
-        if window is not None:
-            valid = jnp.logical_and(valid, kpos > qpos - window)
-        if has_mask:
-            # (1, blk) key-validity row, sublane-broadcast tile layout:
-            # k positions on the lane axis, matching s's column axis
-            valid = jnp.logical_and(valid, kvm_ref[0][:1, :] > 0.5)
-        if has_segments:
-            # packed sequences: attend only within the same segment —
-            # q ids ride the lane-broadcast (stat) layout as a (blk, 1)
-            # column, k ids the sublane layout as a (1, blk) row
-            valid = jnp.logical_and(
-                valid, qseg_ref[0][:, :1] == kseg_ref[0][:1, :])
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_ref[...][:, :1]                      # (blk, 1)
-        l_prev = l_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit zeroing: when a row is fully masked m_new == _NEG and
-        # exp(s - m_new) would be exp(0) = 1 on the masked entries
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        # the softmax normalizer uses the UNdropped probabilities; only
-        # the value accumulation is dropped+rescaled (FlashAttention's
-        # dropout placement — the mask is regenerated bitwise in both
-        # backward passes from the same counter hash)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_rate:
-            u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b, qpos, kpos)
-            p_acc = jnp.where(u >= dropout_rate, p, 0.0) * (
-                1.0 / (1.0 - dropout_rate))
-        else:
-            p_acc = p
-        pv = _dot(p_acc.astype(v.dtype), v, ((1,), (0,)))
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    _run_pair(pair, live, sweep.interior(i, j))
 
-    @pl.when(step == nk - 1)
+    @pl.when(last)
     def _done():
-        l = l_ref[...][:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(jnp.broadcast_to(l_safe,
-                                                           lse_ref.shape[1:]))
+        for h in range(hb):
+            l = l_ref[h]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[h] = (acc_ref[h] / _tile_lanes(l_safe, D)).astype(
+                o_ref.dtype)
+            lse_ref[h] = _to_rows(m_ref[h] + jnp.log(l_safe))
+
+
+def _specs(sweep, blk, D, H, hb):
+    """BlockSpecs of one kernel's grid, by what a block follows: the
+    grid's row or its streamed step; ``hb`` heads of one batch entry a
+    step, or that entry's own tile."""
+    row = lambda g, t: sweep.at(g, t)[0]
+    stream = lambda g, t: sweep.at(g, t)[2]
+    entry = H // hb                 # steps of the leading axis a batch entry
+
+    def operand(at):
+        return pl.BlockSpec((hb, blk, D), lambda b, g, t: (b, at(g, t), 0))
+
+    def row_tile(at, per_head=True):        # (., 8, Tp) sublane-broadcast
+        if per_head:
+            return pl.BlockSpec((hb, 8, blk),
+                                lambda b, g, t: (b, 0, at(g, t)))
+        return pl.BlockSpec((1, 8, blk),
+                            lambda b, g, t: (b // entry, 0, at(g, t)))
+
+    def column_tile(at):                    # (B, Tp, LANES) lane-broadcast
+        return pl.BlockSpec((1, blk, LANES),
+                            lambda b, g, t: (b // entry, at(g, t), 0))
+
+    return row, stream, operand, row_tile, column_tile
+
+
+def _optional(sweep, specs, kvm, idq, idk, seed):
+    """Specs and arrays of the operands that come only when asked for, in
+    the order ``_split_refs`` takes them: key validity, q ids, k ids (all
+    (B, Tp)), dropout seed.  What lies along a score tile's lanes goes as
+    sublane-broadcast (B, 8, Tp) rows, what lies along its sublanes as
+    lane-broadcast (B, Tp, LANES) columns: forward and dq stream the keys
+    on the lanes and hold a row's queries on the sublanes, dk/dv the other
+    way round."""
+    row, stream, _, row_tile, column_tile = specs
+    on_lanes = lambda x: (row_tile(stream, per_head=False), lax.broadcast_in_dim(
+        x, (x.shape[0], 8, x.shape[1]), (0, 2)))
+    on_sublanes = lambda x: (column_tile(row), lax.broadcast_in_dim(
+        x, (*x.shape, LANES), (0, 1)))
+    key, query = ((on_lanes, on_sublanes) if sweep.streams == "k"
+                  else (on_sublanes, on_lanes))
+    found = []
+    if kvm is not None:
+        found.append(key(kvm))
+    if idq is not None:
+        found += [query(idq), key(idk)]
+    if seed is not None:
+        found.append((pl.BlockSpec(memory_space=pltpu.SMEM), seed))
+    return [spec for spec, _ in found], [x for _, x in found]
+
+
+_SEM = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
                                              "dropout_rate", "window"))
-def _fwd(q, k, v, kvm, qseg, kseg, seed, scale, causal, H, dropout_rate,
+def _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H, dropout_rate,
          window=None):
-    """kvm: (B, 8, Tp) fp32 key-validity (sublane-broadcast) or None.
-    qseg/kseg: (B, Tp, LANES) lane- / (B, 8, Tp) sublane-broadcast int32
-    segment ids or None.  seed: (1, 2) int32 dropout seed or None."""
+    """kvm: (B, Tp) fp32 key validity or None.  idq/idk: (B, Tp) int32
+    segment ids as the query and the key side see them, or None.  seed:
+    (1, 2) int32 dropout seed or None.  Returns o (BH, T, D) and the
+    logsumexp as (BH, 8, Tp) sublane-broadcast row tiles."""
     BH, T, D = q.shape
-    blk = _block_for(T)
+    blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
-    Dp = -(-D // LANES) * LANES
+    Dp = _head_width(D)
     qp, kp, vp = (_pad_to(x, Tp, Dp) for x in (q, k, v))
-    nq, nk = Tp // blk, Tp // blk
-    if window is None:
-        kb = lambda i, j: j              # the k block of grid step (i, j)
-    else:
-        nk = _band_blocks(window, blk, nk)
-        kb = lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
-    grid = (BH, nq, nk)
-    row = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, i, 0))
-    col = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, kb(i, j), 0))
-    stat = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b, i, 0))
-    has_mask = kvm is not None
-    has_segments = qseg is not None
-    in_specs = [row, col, col]
-    operands = [qp, kp, vp]
-    if has_mask:
-        in_specs.append(pl.BlockSpec((1, 8, blk),
-                                     lambda b, i, j: (b // H, 0, kb(i, j))))
-        operands.append(kvm)
-    if has_segments:
-        in_specs.append(pl.BlockSpec((1, blk, LANES),
-                                     lambda b, i, j: (b // H, i, 0)))
-        operands.append(qseg)
-        in_specs.append(pl.BlockSpec((1, 8, blk),
-                                     lambda b, i, j: (b // H, 0, kb(i, j))))
-        operands.append(kseg)
-    if dropout_rate:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(seed)
+    masked = kvm is not None or idq is not None or seed is not None
+    hb = _heads_per_step(H, Dp, q.dtype.itemsize, masked, blk)
+    sweep = _Sweep(Tp // blk, blk, causal, window, "k", Tp != T, masked)
+    specs = row, stream, operand, row_tile, _ = _specs(sweep, blk, Dp, H, hb)
+    more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          has_mask=has_mask, has_segments=has_segments,
-                          dropout_rate=dropout_rate,
-                          T_real=T, blk=blk, nk=nk, window=window),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[row, stat],
+        functools.partial(_fwd_kernel, scale=scale, sweep=sweep,
+                          has_mask=kvm is not None,
+                          has_segments=idq is not None,
+                          dropout_rate=dropout_rate, T_real=T, Tp=Tp),
+        grid=(BH // hb, sweep.rows, sweep.steps),
+        in_specs=[operand(row), operand(stream), operand(stream),
+                  *more_specs],
+        out_specs=[operand(row), row_tile(row)],
         out_shape=[jax.ShapeDtypeStruct((BH, Tp, Dp), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Tp, LANES), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blk, LANES), jnp.float32),
-                        pltpu.VMEM((blk, LANES), jnp.float32),
-                        pltpu.VMEM((blk, Dp), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+                   jax.ShapeDtypeStruct((BH, 8, Tp), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, blk, LANES), jnp.float32),
+                        pltpu.VMEM((hb, blk, LANES), jnp.float32),
+                        pltpu.VMEM((hb, blk, Dp), jnp.float32)],
+        compiler_params=_SEM,
         interpret=interpret(),
         name="flash_fwd",
-    )(*operands)
-    return o[:, :T, :D], lse[:, :T, 0]
+    )(qp, kp, vp, *more)
+    return _unpad(o, T, D), lse
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(*refs, scale, causal, has_mask, has_segments, dropout_rate,
-               T_real, blk, nk, window=None):
-    refs = list(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    del refs[:6]
-    kvm_ref = refs.pop(0) if has_mask else None
-    if has_segments:
-        qseg_ref = refs.pop(0)
-        kseg_ref = refs.pop(0)
-    else:
-        qseg_ref = kseg_ref = None
-    seed_ref = refs.pop(0) if dropout_rate else None
-    dq_ref, dq_acc = refs
+def _dq_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
+               T_real, Tp):
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), kvm_ref, qseg_ref,
+     kseg_ref, seed_ref, (dq_ref, dq_acc, lse_w, delta_w)) = _split_refs(
+        refs, 6, has_mask, has_segments, dropout_rate)
+    hb, blk, _ = q_ref.shape
     b = pl.program_id(0)
-    i = pl.program_id(1)
-    step = pl.program_id(2)
+    i, j, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+        # the row's statistics arrive as (8, blk) row tiles and are
+        # widened once a row to the equal-lane columns the pairs use
+        for h in range(hb):
+            lse_w[h] = _to_columns(lse_ref[h])
+            delta_w[h] = _to_columns(delta_ref[h])
 
-    if window is None:
-        j = step
-        run = (j <= i) if causal else (j >= 0)
-    else:                       # as in _fwd_kernel
-        j = i - (nk - 1) + step
-        run = j >= 0
+    def pair(edge):
+        valid = None
+        if edge:
+            valid, qpos, kpos = _visible(
+                (blk, blk), i, j, blk, 0, causal=sweep.causal,
+                window=sweep.window, T_real=T_real, Tp=Tp,
+                kvm=_row(kvm_ref), qseg=_column(qseg_ref),
+                kseg=_row(kseg_ref))
+        for h in range(hb):
+            k = k_ref[h]
+            s = _dot(q_ref[h], k, ((1,), (1,))) * scale
+            p = jnp.exp(s - _tile_lanes(lse_w[h], blk))
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            dp = _dot(do_ref[h], v_ref[h], ((1,), (1,)))
+            if dropout_rate:
+                # dS = P ∘ (M ∘ (dO Vᵀ)/keep − delta): same counter hash
+                # as the forward, so the mask is bitwise-identical
+                u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b * hb + h,
+                               qpos, kpos)
+                dp = jnp.where(u >= dropout_rate, dp, 0.0) * (
+                    1.0 / (1.0 - dropout_rate))
+            ds = (p * (dp - _tile_lanes(delta_w[h], blk))).astype(k.dtype)
+            dq_acc[h] += _dot(ds, k, ((1,), (0,)))
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = _dot(q, k, ((1,), (1,))) * scale
-        kpos = j * blk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos < T_real
-        qpos = i * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        if causal:
-            valid = jnp.logical_and(valid, qpos >= kpos)
-        if window is not None:
-            valid = jnp.logical_and(valid, kpos > qpos - window)
-        if has_mask:
-            valid = jnp.logical_and(valid, kvm_ref[0][:1, :] > 0.5)
-        if has_segments:
-            valid = jnp.logical_and(
-                valid, qseg_ref[0][:, :1] == kseg_ref[0][:1, :])
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        dp = _dot(do, v, ((1,), (1,)))
-        if dropout_rate:
-            # dS = P ∘ (M ∘ (dO Vᵀ)/keep − delta): same counter hash as
-            # the forward, so the mask is bitwise-identical
-            u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b, qpos, kpos)
-            dp = jnp.where(u >= dropout_rate, dp, 0.0) * (
-                1.0 / (1.0 - dropout_rate))
-        ds = (p * (dp - delta)).astype(k.dtype)
-        dq_acc[...] += _dot(ds, k, ((1,), (0,))) * scale
+    _run_pair(pair, live, sweep.interior(i, j))
 
-    @pl.when(step == nk - 1)
+    @pl.when(last)
     def _done():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, has_mask, has_segments,
-                dropout_rate, T_real, blk, nq, window=None, nq_all=None):
-    refs = list(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    del refs[:6]
-    kvm_ref = refs.pop(0) if has_mask else None
-    if has_segments:
-        qseg_ref = refs.pop(0)
-        kseg_ref = refs.pop(0)
-    else:
-        qseg_ref = kseg_ref = None
-    seed_ref = refs.pop(0) if dropout_rate else None
-    dk_ref, dv_ref, dk_acc, dv_acc = refs
+def _dkv_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
+                T_real, Tp):
+    """In transposed form: the score tile is k-major, (k rows, q columns),
+    so p and ds are born as the left operands dV = Pᵀ dO and dK = dSᵀ Q
+    want, and a query's statistics are (1, blk) rows that broadcast along
+    sublanes."""
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), kvm_ref, qseg_ref,
+     kseg_ref, seed_ref, (dk_ref, dv_ref, dk_acc, dv_acc)) = _split_refs(
+        refs, 6, has_mask, has_segments, dropout_rate)
+    hb, blk, _ = q_ref.shape
     b = pl.program_id(0)
-    i = pl.program_id(1)          # k block
-    step = pl.program_id(2)       # q block (streamed)
+    j, i, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    if window is None:
-        j = step
-        # causal: q block j only sees k block i when j*blk + blk - 1 >= i*blk
-        run = (j >= i) if causal else (j >= 0)
-    else:
-        # the nq steps start at the diagonal block; those past the last q
-        # block are dead (their fetch is clamped onto the last block)
-        j = i + step
-        run = j < nq_all
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = _dot(q, k, ((1,), (1,))) * scale
-        kpos = i * blk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos < T_real
-        qpos = j * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        if causal:
-            valid = jnp.logical_and(valid, qpos >= kpos)
-        if window is not None:
-            valid = jnp.logical_and(valid, kpos > qpos - window)
-        if has_mask:
-            valid = jnp.logical_and(valid, kvm_ref[0][:1, :] > 0.5)
-        if has_segments:
-            valid = jnp.logical_and(
-                valid, qseg_ref[0][:, :1] == kseg_ref[0][:1, :])
-        # padded q rows contribute nothing: their do rows are zero
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)       # (bq, bk)
-        dp = _dot(do, v, ((1,), (1,)))
-        if dropout_rate:
+    def pair(edge):
+        valid = None
+        if edge:
             # absolute (qpos, kpos) arguments match the fwd/dq passes
-            # exactly, so the regenerated mask is bitwise-identical
-            u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b, qpos, kpos)
-            keep = u >= dropout_rate
-            inv_keep = 1.0 / (1.0 - dropout_rate)
-            p_acc = jnp.where(keep, p, 0.0) * inv_keep
-            dp = jnp.where(keep, dp, 0.0) * inv_keep
-        else:
+            # exactly, so the regenerated dropout mask is bitwise-identical
+            valid, qpos, kpos = _visible(
+                (blk, blk), j, i, blk, 1, causal=sweep.causal,
+                window=sweep.window, T_real=T_real, Tp=Tp,
+                kvm=_column(kvm_ref), qseg=_row(qseg_ref),
+                kseg=_column(kseg_ref))
+        for h in range(hb):
+            q = q_ref[h]
+            do = do_ref[h]
+            s = _dot(k_ref[h], q, ((1,), (1,))) * scale        # (bk, bq)
+            # padded q rows contribute nothing: their do rows are zero
+            p = jnp.exp(s - lse_ref[h][:1, :])
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            dp = _dot(v_ref[h], do, ((1,), (1,)))
             p_acc = p
-        dv_acc[...] += _dot(p_acc.astype(do.dtype), do, ((0,), (0,)))
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_acc[...] += _dot(ds, q, ((0,), (0,))) * scale
+            if dropout_rate:
+                u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b * hb + h,
+                               qpos, kpos)
+                keep = u >= dropout_rate
+                inv_keep = 1.0 / (1.0 - dropout_rate)
+                p_acc = jnp.where(keep, p, 0.0) * inv_keep
+                dp = jnp.where(keep, dp, 0.0) * inv_keep
+            dv_acc[h] += _dot(p_acc.astype(do.dtype), do, ((1,), (0,)))
+            ds = (p * (dp - delta_ref[h][:1, :])).astype(q.dtype)
+            dk_acc[h] += _dot(ds, q, ((1,), (0,)))
 
-    @pl.when(step == nq - 1)
+    _run_pair(pair, live, sweep.interior(j, i))
+
+    @pl.when(last)
     def _done():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
                                              "dropout_rate", "window"))
-def _bwd(q, k, v, o, lse, do, kvm, qseg, kseg, seed, scale, causal, H,
+def _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed, scale, causal, H,
          dropout_rate, window=None):
+    """lse: the forward's (BH, 8, Tp) row tiles; the rest as in _fwd."""
     BH, T, D = q.shape
-    blk = _block_for(T)
+    blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
-    Dp = -(-D // LANES) * LANES
-    qp, kp, vp = (_pad_to(x, Tp, Dp) for x in (q, k, v))
-    dop = _pad_to(do, Tp, Dp)
+    Dp = _head_width(D)
+    qp, kp, vp, dop = (_pad_to(x, Tp, Dp) for x in (q, k, v, do))
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-    deltap = _lanes(delta, Tp)
-    lsep = _lanes(lse, Tp)
-    nq = nk = n = Tp // blk
-    has_mask = kvm is not None
-    has_segments = qseg is not None
-    sem = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    if Tp != T:
+        delta = jnp.pad(delta, ((0, 0), (0, Tp - T)))
+    delta = lax.broadcast_in_dim(delta, (BH, 8, Tp), (0, 2))
+    masked = kvm is not None or idq is not None or seed is not None
+    hb = _heads_per_step(H, Dp, q.dtype.itemsize, masked, blk)
+    kinds = dict(scale=scale, has_mask=kvm is not None,
+                 has_segments=idq is not None, dropout_rate=dropout_rate,
+                 T_real=T, Tp=Tp)
+    acc = lambda width: pltpu.VMEM((hb, blk, width), jnp.float32)
 
-    rowi = pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, i, 0))
-    stati = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b, i, 0))
-    kvmi = pl.BlockSpec((1, 8, blk), lambda b, i, j: (b // H, 0, i))
-    qsegi = pl.BlockSpec((1, blk, LANES), lambda b, i, j: (b // H, i, 0))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-
-    # what streams along a grid's last axis, ``sj(i, j)`` being the block of
-    # step j in row i: operand blocks, row statistics and q-segment ids (the
-    # lane-broadcast stat layout), key-validity / k-segment tiles
-    def operand(sj):
-        return pl.BlockSpec((1, blk, Dp), lambda b, i, j: (b, sj(i, j), 0))
-
-    def stat(sj, per_head=True):
-        return pl.BlockSpec(
-            (1, blk, LANES),
-            lambda b, i, j: (b if per_head else b // H, sj(i, j), 0))
-
-    def key_tile(sj):
-        return pl.BlockSpec((1, 8, blk),
-                            lambda b, i, j: (b // H, 0, sj(i, j)))
-
-    if window is None:
-        kstep = qstep = lambda i, j: j
-    else:
-        # dq: the k blocks up to the diagonal; dk/dv: the q blocks from it
-        nk = nq = _band_blocks(window, blk, n)
-        kstep = lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
-        qstep = lambda i, j: jnp.minimum(i + j, n - 1)
-    # the dq pass streams K/V (and their tiles) along j; the dk/dv pass has
-    # them on its i axis and streams Q, dO, their statistics and q ids
-    colj, kvmj = operand(kstep), key_tile(kstep)
-    colq, statj, qsegj = operand(qstep), stat(qstep), stat(qstep, False)
-
-    dq_specs = [rowi, colj, colj, rowi, stati, stati]
-    dq_ops = [qp, kp, vp, dop, lsep, deltap]
-    if has_mask:
-        dq_specs.append(kvmj)
-        dq_ops.append(kvm)
-    if has_segments:
-        dq_specs += [qsegi, kvmj]        # k ids share the kvm layout
-        dq_ops += [qseg, kseg]
-    if dropout_rate:
-        dq_specs.append(smem)
-        dq_ops.append(seed)
+    # dq: a row is a q block (with do and its statistics); K, V and the
+    # key-side tiles stream
+    sweep = _Sweep(Tp // blk, blk, causal, window, "k", Tp != T, masked)
+    specs = row, stream, operand, row_tile, _ = _specs(sweep, blk, Dp, H, hb)
+    more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          has_mask=has_mask, has_segments=has_segments,
-                          dropout_rate=dropout_rate,
-                          T_real=T, blk=blk, nk=nk, window=window),
-        grid=(BH, n, nk),
-        in_specs=dq_specs,
-        out_specs=rowi,
+        functools.partial(_dq_kernel, sweep=sweep, **kinds),
+        grid=(BH // hb, sweep.rows, sweep.steps),
+        in_specs=[operand(row), operand(stream), operand(stream),
+                  operand(row), row_tile(row), row_tile(row), *more_specs],
+        out_specs=operand(row),
         out_shape=jax.ShapeDtypeStruct((BH, Tp, Dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk, Dp), jnp.float32)],
-        compiler_params=sem,
+        scratch_shapes=[acc(Dp), acc(LANES), acc(LANES)],
+        compiler_params=_SEM,
         interpret=interpret(),
         name="flash_dq",
-    )(*dq_ops)
+    )(qp, kp, vp, dop, lse, delta, *more)
 
-    dkv_specs = [colq, rowi, rowi, colq, statj, statj]
-    dkv_ops = [qp, kp, vp, dop, lsep, deltap]
-    if has_mask:
-        dkv_specs.append(kvmi)
-        dkv_ops.append(kvm)
-    if has_segments:
-        # dkv grid: i = k block, j = q block — q ids stream along j,
-        # k ids along i (sharing the kvm layouts)
-        dkv_specs += [qsegj, kvmi]
-        dkv_ops += [qseg, kseg]
-    if dropout_rate:
-        dkv_specs.append(smem)
-        dkv_ops.append(seed)
+    # dk/dv: a row is a k block (with v and the key-side tiles); Q, dO,
+    # their statistics and the q ids stream
+    sweep = _Sweep(Tp // blk, blk, causal, window, "q", Tp != T, masked)
+    specs = row, stream, operand, row_tile, _ = _specs(sweep, blk, Dp, H, hb)
+    more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          has_mask=has_mask, has_segments=has_segments,
-                          dropout_rate=dropout_rate,
-                          T_real=T, blk=blk, nq=nq, window=window,
-                          nq_all=n),
-        grid=(BH, n, nq),
-        in_specs=dkv_specs,
-        out_specs=[rowi, rowi],
+        functools.partial(_dkv_kernel, sweep=sweep, **kinds),
+        grid=(BH // hb, sweep.rows, sweep.steps),
+        in_specs=[operand(stream), operand(row), operand(row),
+                  operand(stream), row_tile(stream), row_tile(stream),
+                  *more_specs],
+        out_specs=[operand(row), operand(row)],
         out_shape=[jax.ShapeDtypeStruct((BH, Tp, Dp), k.dtype),
                    jax.ShapeDtypeStruct((BH, Tp, Dp), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, Dp), jnp.float32),
-                        pltpu.VMEM((blk, Dp), jnp.float32)],
-        compiler_params=sem,
+        scratch_shapes=[acc(Dp), acc(Dp)],
+        compiler_params=_SEM,
         interpret=interpret(),
         name="flash_dkv",
-    )(*dkv_ops)
-    return dq[:, :T, :D], dk[:, :T, :D], dv[:, :T, :D]
+    )(qp, kp, vp, dop, lse, delta, *more)
+    return _unpad(dq, T, D), _unpad(dk, T, D), _unpad(dv, T, D)
 
 
 # ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
 
+def _count_call(q3, causal, window, masked, launches):
+    """Trace-time counters of one flash call (docs/observability.md): the
+    block pairs each launch's grid visits by kind, and the operands padded
+    and results sliced around the kernels (forward: q, k, v in and o out;
+    backward: q, k, v, do in and dq, dk, dv out)."""
+    BH, T, D = q3.shape
+    blk = _block_for(T, window)
+    Tp = -(-T // blk) * blk
+    for streams in launches:
+        _Sweep(Tp // blk, blk, causal, window, streams, Tp != T,
+               masked).count(BH)
+    fits = (Tp, _head_width(D)) == (T, D)
+    _count("flash_pad_copies_total",
+           "flash-attention operands padded and results sliced, per "
+           "traced call; 0 when T is a whole number of blocks and D is "
+           "under 128 or a multiple of it",
+           0 if fits else {"k": 4, "kq": 7}[launches])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
-def _flash(q3, k3, v3, kvm, qseg, kseg, seed, scale: float, causal: bool,
+def _flash(q3, k3, v3, kvm, idq, idk, seed, scale: float, causal: bool,
            H: int, dropout_rate: float, window: Optional[int]):
-    o, _ = _fwd(q3, k3, v3, kvm, qseg, kseg, seed, scale, causal, H,
-                dropout_rate, window)
-    return o
+    return _flash_fwd(q3, k3, v3, kvm, idq, idk, seed, scale, causal, H,
+                      dropout_rate, window)[0]
 
 
-def _flash_fwd(q3, k3, v3, kvm, qseg, kseg, seed, scale, causal, H,
+def _flash_fwd(q3, k3, v3, kvm, idq, idk, seed, scale, causal, H,
                dropout_rate, window):
-    o, lse = _fwd(q3, k3, v3, kvm, qseg, kseg, seed, scale, causal, H,
+    masked = kvm is not None or idq is not None or seed is not None
+    _count_call(q3, causal, window, masked, "k")
+    o, lse = _fwd(q3, k3, v3, kvm, idq, idk, seed, scale, causal, H,
                   dropout_rate, window)
-    return o, (q3, k3, v3, o, lse, kvm, qseg, kseg, seed)
+    return o, (q3, k3, v3, o, lse, kvm, idq, idk, seed)
 
 
 def _flash_bwd(scale, causal, H, dropout_rate, window, res, do):
-    q3, k3, v3, o, lse, kvm, qseg, kseg, seed = res
-    dq, dk, dv = _bwd(q3, k3, v3, o, lse, do, kvm, qseg, kseg, seed,
+    q3, k3, v3, o, lse, kvm, idq, idk, seed = res
+    masked = kvm is not None or idq is not None or seed is not None
+    _count_call(q3, causal, window, masked, "kq")
+    dq, dk, dv = _bwd(q3, k3, v3, o, lse, do, kvm, idq, idk, seed,
                       scale, causal, H, dropout_rate, window)
     dkvm = None if kvm is None else jnp.zeros_like(kvm)
     # int primals -> float0 cotangents
     f0 = lambda a: (None if a is None
                     else np.zeros(a.shape, jax.dtypes.float0))
     return (dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype),
-            dkvm, f0(qseg), f0(kseg), f0(seed))
+            dkvm, f0(idq), f0(idk), f0(seed))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -655,15 +847,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, H, T, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    blk = _block_for(T)
+    blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
     kvm = None
     if kv_mask is not None:
         if kv_mask.shape != (B, T):
             raise ValueError(f"kv_mask must be (B, T) = {(B, T)}, got "
                              f"{kv_mask.shape}")
-        m = jnp.pad(kv_mask.astype(jnp.float32), ((0, 0), (0, Tp - T)))
-        kvm = jax.lax.broadcast_in_dim(m, (B, 8, Tp), (0, 2))
+        kvm = jnp.pad(kv_mask.astype(jnp.float32), ((0, 0), (0, Tp - T)))
     seed = None
     if dropout_rate:
         s = jnp.asarray(dropout_seed, jnp.int32).reshape(-1)
@@ -675,7 +866,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             raise ValueError("dropout_seed must be 1 or 2 int32 words, "
                              f"got {s.size}")
         seed = s.reshape(1, 2)
-    qseg = kseg = None
+    idq = idk = None
     if segment_ids is not None:
         if segment_ids.shape != (B, T):
             raise ValueError(f"segment_ids must be (B, T) = {(B, T)}, "
@@ -685,9 +876,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ids = segment_ids.astype(jnp.int32)
         idq = jnp.pad(ids, ((0, 0), (0, Tp - T)), constant_values=-1)
         idk = jnp.pad(ids, ((0, 0), (0, Tp - T)), constant_values=-2)
-        qseg = jax.lax.broadcast_in_dim(idq, (B, Tp, LANES), (0, 1))
-        kseg = jax.lax.broadcast_in_dim(idk, (B, 8, Tp), (0, 2))
     fold = lambda x: x.reshape(B * H, T, D)
-    out = _flash(fold(q), fold(k), fold(v), kvm, qseg, kseg, seed,
+    out = _flash(fold(q), fold(k), fold(v), kvm, idq, idk, seed,
                  float(scale), bool(causal), H, dropout_rate, window)
     return out.reshape(B, H, T, D)

@@ -119,7 +119,8 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             from ..ops import pallas_flash_attention as pfa
             if pfa.fits_vmem(q.shape[2], q.shape[3],
                              dropout=train_dropout,
-                             segments=segment_ids is not None):
+                             segments=segment_ids is not None,
+                             window=window):
                 # same cast policy the dense path applies through its
                 # whitelisted matmuls (op 'dot_product_attention' is in
                 # amp.lists.FP16_FUNCS), so dtype is backend-independent
