@@ -11,7 +11,7 @@ enforces those invariants mechanically:
   double-donation of shared buffers), amp dtype policy, channels-last
   layout, and collective accounting;
 - an **entry-point registry** (:mod:`.entry_points`) tracing the real
-  graphs bench.py, the examples and the serving engines execute;
+  graphs the examples and the serving engines execute;
 - machine-readable findings exported as schema-versioned JSONL through
   ``observability.exporters`` — shared by the tests
   (tests/test_step_graph_audit.py), the CI gate

@@ -1,9 +1,9 @@
 """CLI: ``python -m apex_tpu.analysis`` — lint the hot graphs.
 
-Stdout is pure schema-versioned JSONL (the bench.py contract): one
+Stdout is pure schema-versioned JSONL: one
 ``graph_lint`` record per finding plus one ``graph_lint_summary``
 record, all enriched by ``observability.exporters.JsonlExporter`` and
-validated by ``tests/ci/check_bench_schema.py``.  Human-readable
+validated by ``tests/ci/check_telemetry_schema.py``.  Human-readable
 progress goes to stderr.  Exit status: 0 = clean, 1 = any
 error-severity finding (the CI gate tests/ci/graph_lint.py relies on
 this), 2 = bad usage.
@@ -137,7 +137,7 @@ def main(argv: List[str] = None) -> int:
         # (free: reuses the cached trace) plus the compiled memory
         # plan (pays one compile per entry point, cached per process).
         # Same stdout contract as lint: pure schema-valid JSONL,
-        # check_bench_schema.py validates the stream.
+        # check_telemetry_schema.py validates the stream.
         from .entry_points import entry_point_memory_record
         failed = 0
         with exp:

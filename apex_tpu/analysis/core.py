@@ -128,9 +128,9 @@ def run_lint(entry_points=None, rules=None, emit=None,
              skip_runtime_errors: bool = False, on_skip=None,
              progress=None) -> Dict[str, Any]:
     """Drive the analyzer end to end — the shared core of the CLI
-    (``python -m apex_tpu.analysis``), the CI gate and ``bench.py
-    --graph-lint``, so severity tallies and the summary-record shape
-    cannot drift between consumers.
+    (``python -m apex_tpu.analysis``) and the CI gate, so severity
+    tallies and the summary-record shape cannot drift between
+    consumers.
 
     ``emit(record)`` receives one RAW (un-enriched) JSONL payload per
     finding plus the final ``graph_lint_summary`` — callers route it

@@ -1,5 +1,5 @@
-"""Registry of HOT entry points: the real graphs bench.py, the examples
-and the serving engines execute, traced for the rule engine.
+"""Registry of HOT entry points: the real graphs the examples and the
+serving engines execute, traced for the rule engine.
 
 Each entry point builds the same step the production path dispatches —
 DDP ResNet train steps across O0–O3 (telemetry on/off, channels-last
@@ -187,8 +187,8 @@ def _ddp_resnet_graph(ep, opt_level, channels_last=False,
                       ici_size=None, numerics=None, supervised=None,
                       world=None):
     """Trace the REAL DDP train step — shard_map over the 8-device CPU
-    mesh with the grad allreduce inside — the same graph bench.py's
-    headline and examples/imagenet execute.  ``telemetry=True`` threads
+    mesh with the grad allreduce inside — the same graph
+    examples/imagenet executes.  ``telemetry=True`` threads
     a DeviceMetrics state through the step carry (the fully
     instrumented shape of the hot loop).  ``numerics="on"`` threads a
     NumericsMonitor through the carry — per-layer grad health from
@@ -239,7 +239,7 @@ def _ddp_resnet_graph(ep, opt_level, channels_last=False,
         # bare RuntimeError = the device-count skip gate (run_lint's
         # skip_runtime_errors): a 1-device smoke host cannot trace a
         # 2-level mesh, and the old ValueError from the group builder
-        # crashed bench --graph-lint instead of skipping the EP
+        # crashed the lint run instead of skipping the EP
         raise RuntimeError(
             f"this entry point needs an axis of a multiple of "
             f"ici_size={ici_size} devices; ambient mesh has {ndev}")
@@ -690,8 +690,7 @@ def _zero_resnet_graph(ep, zero_stage, compress=False, ici_size=4,
 
     Every collective/resharding expectation is derived from
     ``parallel.zero_update_comm_plan`` under the same knobs — the
-    static plan the runtime documentation, bench ``--comm`` legs and
-    this census all share."""
+    static plan the runtime documentation and this census share."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P

@@ -409,11 +409,11 @@ class FlopAccountingRule(Rule):
 
 @register_rule
 class MemoryBudgetRule(Rule):
-    """Peak live bytes stay within budget — the static early-warning
-    for ROADMAP item 4's "pin peak-memory in bench": a refactor that
-    keeps a dead copy of the cache, un-donates a buffer upstream, or
-    upcasts a temp tree to fp32 moves the analytic liveness peak long
-    before anyone reruns the hardware bench."""
+    """Peak live bytes stay within budget — the static early warning:
+    a refactor that keeps a dead copy of the cache, un-donates a buffer
+    upstream, or upcasts a temp tree to fp32 moves the analytic
+    liveness peak long before anyone reruns the benchmark on the
+    chip."""
 
     name = "memory-budget"
     expect_key = "memory"

@@ -48,9 +48,8 @@ points actually trace):
 Consumers (wired through :mod:`.rules` and the exporters):
 
 - :func:`entry_point_sharding_record` — the **replication ledger**, a
-  schema-v13 ``kind: sharding`` record per train entry point so
-  ``check_bench_trend`` can ratchet ``replicated_bytes`` down as
-  ZeRO-2/3 stages land.
+  schema-v13 ``kind: sharding`` record per train entry point:
+  ``replicated_bytes`` is what the ZeRO-2/3 stages bring down.
 - :func:`check_shard_map_specs` — spec-vs-mesh consistency (axis-name
   existence, divisibility, replicated-output claims the propagated
   partition contradicts; ``check_vma=False`` means XLA never checks the

@@ -176,7 +176,7 @@ class FaultyReplica:
         """(Re)program fault windows at runtime.  With ``relative=True``
         (default) window offsets count from the CURRENT step counter —
         ``arm(raise_on_step=(6, None))`` means "die 6 steps from now",
-        which is how a bench arms a mid-run death AFTER its warmup
+        which is how a driver arms a mid-run death AFTER its warmup
         traffic (a constructor window would fire during warmup).
         Passing ``()`` clears a fault kind."""
         _arm_windows(self, ("raise_on_step", "raise_on_prefill",

@@ -235,8 +235,7 @@ class Fleet:
         # MTTR accounting (PR 11): a failover opens a recovery window;
         # the first subsequent tick with real progress (tokens emitted
         # or a finish harvested) closes it — fault injection to first
-        # post-recovery step, the fleet-side number bench --chaos
-        # trends.  ``recovery_in_flight`` is the controllers' flag
+        # post-recovery step.  ``recovery_in_flight`` is the controllers' flag
         # (SloController / an operator mid-world-shrink): while set,
         # the introspection server's no-steppable-replica check
         # reports the distinct degraded-but-live "recovering" state
@@ -1084,9 +1083,8 @@ class Fleet:
 
     def latency(self, rid: int) -> float:
         """Submit-to-finish seconds for a completed (or failed)
-        request — the per-request tail-latency surface ``bench.py
-        --fleet`` percentiles over; raises ``KeyError`` while the
-        request is still in flight."""
+        request; raises ``KeyError`` while the request is still in
+        flight."""
         req = self._results[rid]
         return req.t_finish - req.t_submit
 

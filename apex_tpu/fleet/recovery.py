@@ -199,8 +199,7 @@ class RecoveryLog:
                 f"was constructed before its clock was reset (an "
                 f"injected tick clock rewound past the log's t0). "
                 f"Reset the clock FIRST, then build the fleet and "
-                f"controller/trainer — the bench --chaos drive() "
-                f"precondition.")
+                f"controller/trainer.")
         # an action before ANY episode (e.g. a relax correcting a
         # mis-tuned construction) carries episode=None — stamping a
         # phantom episode 1 into a record declaring zero episodes
@@ -565,7 +564,7 @@ class ElasticTrainer:
         # exit; cause names why the LAST recovery/exit happened
         self.verdict: Optional[str] = None
         self.cause: Optional[str] = None
-        # resume accounting (the bench --chaos preempt leg's line):
+        # resume accounting:
         # wall cost of the resume=True restore, and the clock reading
         # of the first COMMITTED step of this trainer — with the
         # guard's requested_at, the preempt→first-good-step MTTR

@@ -1,88 +1,52 @@
-"""apex_tpu.observability — unified telemetry subsystem.
+"""apex_tpu.observability — telemetry the library records about itself.
 
-Three layers (docs/observability.md):
+What the package holds (docs/observability.md), by module:
 
-1. **Metrics** — :class:`MetricsRegistry` of counters / gauges /
-   fixed-bucket histograms for host-side instrumentation, plus
-   :class:`DeviceMetrics` for training-step counters that accumulate as
-   jnp arrays *inside* the jitted step (zero host syncs per step; one
-   explicit fetch at ``flush()``).
-2. **Spans/events** — :class:`SpanRecorder` wall-clock ranges layered on
-   ``utils.profiler``'s nvtx-parity ranges; exports Chrome-trace JSON
-   and a JSONL event log.  PR 6 added request-scoped distributed
-   tracing (``new_trace_id`` / thread-correct span parentage /
-   ``kind: trace`` records) that the fleet propagates end to end.
-3. **Exporters** — schema-versioned JSONL (what ``bench.py`` emits),
-   Prometheus text exposition, Chrome trace.
+- ``metrics`` — :class:`MetricsRegistry` of counters / gauges /
+  fixed-bucket histograms for host-side instrumentation, and
+  :class:`DeviceMetrics` for training-step counters that accumulate as
+  jnp arrays *inside* the jitted step (no host sync per step; one
+  explicit fetch at ``flush()``).
+- ``phases`` — the vocabulary of ``jax.named_scope`` names the training
+  step and the paged engine's tick carry; ``benchmark/`` reads
+  per-phase device time from them.
+- ``tracing`` — :class:`SpanRecorder` wall-clock spans and events on
+  ``utils.profiler``'s ranges, request-scoped trace ids with
+  thread-correct parentage, Chrome-trace and JSONL export,
+  ``kind: trace`` records.
+- ``flightrec`` — :class:`EventRing`, a bounded ring of operational
+  transitions (breaker, failover, drain, stall, scaler skips) dumped
+  on fault.
+- ``compilation`` — the trace/compile ledger over every instrumented
+  jit entry: abstract argument signatures, stage times, persistent-cache
+  hits and misses, the retrace-cause differ, ``compiled_text`` for the
+  phase join, ``/compilez``.
+- ``costmodel`` / ``memory`` — the analytic FLOPs/bytes model over
+  jaxprs and the compiled memory plans, static liveness and live-array
+  gauges behind ``kind: memory`` records and the ``flop-accounting`` /
+  ``memory-budget`` lint rules.
+- ``numerics`` — device-resident gradient-health telemetry (per-layer
+  nonfinite counts, abs-max, norms, underflow share, overflow
+  attribution, the cross-replica divergence digest), in-graph with no
+  host sync; ``kind: numerics`` records.
+- ``timeline`` — the stdlib-only Chrome-trace parser over what
+  ``jax.profiler.start_trace`` writes: device busy time, top kernels,
+  compute / collective / gap split and a measured overlap fraction;
+  ``kind: profile`` records and the server's ``/profilez`` capture.
+- ``supervisor`` — the host-side training-run supervisor (stall, loss
+  spike, NaN, throughput regression, replica divergence,
+  recompilation storm) over each step's already-flushed signals;
+  ``wrap_step`` is an identity; ``kind: run`` records.
+- ``server`` — a stdlib ``http.server`` serving ``/healthz``,
+  ``/metricsz`` (Prometheus exposition), ``/statusz``, ``/flightz``,
+  ``/tracez``, ``/compilez``, ``/tenantz``, ``/profilez`` off a live
+  registry / ring / recorder.
+- ``exporters`` — schema-versioned JSONL, Prometheus text exposition,
+  and one validator per record ``kind`` the library produces.
 
-Plus the **flight recorder** (PR 6): :class:`EventRing`, a bounded
-ring of operational transitions (breaker/failover/drain/stall/scaler
-skips) dumpable on fault, and ``steptime``, the blocked-fetch
-step-time attribution harness (compute vs per-level comm time,
-``overlap_fraction``) behind ``bench.py --comm``.
-
-And the **cost model** (PR 8): ``costmodel``, the XLA-calibrated
-analytic FLOPs/bytes model over jaxprs (valid-position conv counting,
-DCE, per-dtype matmul breakdowns, the documented ``PEAK_FLOPS`` table
-and ``mfu()`` fields on every bench train record), and ``memory``,
-the compiled memory plans / static liveness / live-array gauges
-behind ``peak_bytes`` gating, ``kind: memory`` records, and the
-``flop-accounting`` / ``memory-budget`` lint rules.
-
-And **numerics** (PR 9): ``numerics``, device-resident gradient-health
-telemetry (per-layer/per-bucket nonfinite counts, abs-max, grad norm,
-underflow fraction at the current loss scale), overflow attribution
-(a skipped step's flight-ring event names the culprit layer), bf16
-DCN-hop quantization-error accounting, and the one-psum cross-replica
-divergence digest — all in-graph with zero host syncs (the
-``numerics`` lint rule pins it) behind ``kind: numerics`` records and
-``bench.py --numerics``.
-
-And **device-time truth** (PR 13): ``timeline``, the stdlib-only
-Chrome-trace parser over what ``jax.profiler.start_trace`` already
-writes — per-step device busy time, per-kernel top-k, compute vs
-collective vs gap split, and a *measured* ``overlap_fraction`` from
-actual kernel-interval overlap (the device-timeline counterpart of
-``steptime``'s host differencing, cross-checked by
-``steptime.timeline_consistency``); ``kind: profile`` records (schema
-v8) behind ``bench.py --profile`` and the server's on-demand
-``/profilez`` capture; plus the serving KV fragmentation ledger
-(``Engine.kv_fragmentation`` / ``kv_waste_bytes`` — ROADMAP item 1's
-needle).
-
-And the **compilation plane** (PR 15): ``compilation``, the
-in-process trace/compile ledger over every instrumented jit entry —
-abstract argument signatures, wall durations, persistent-cache
-hit/miss attribution via ``jax.monitoring``, and a retrace-cause
-differ that names *which argument's* shape/dtype/static value changed
-between two traces of one entry.  Serving engines and the fleet route
-their jits through it, giving the zero-retrace steady-state contract
-(warmed engines / failover survivors add exactly 0 traces,
-tier-1-pinned), ``Engine.compile_census`` / ``Fleet.warmup``, the
-supervisor's ``recompilation_storm`` verdict, the ``/compilez``
-endpoint, and bench's schema-v10 ``cold_compile_ms`` /
-``compiles_total`` / ``steady_state_retraces`` fields.
-
-And the **operational plane** (PR 10): ``server``, a stdlib
-``http.server`` introspection endpoint serving ``/healthz`` /
-``/metricsz`` (Prometheus exposition, conformance-tested) /
-``/statusz`` / ``/flightz`` / ``/tracez`` off a live registry / ring /
-recorder, attachable to an Engine, Fleet, or supervisor with one
-``server.serve(...)`` call; and ``supervisor``, the host-side
-training-run supervisor consuming each step's already-flushed signals
-to detect stall / loss spike / NaN / throughput regression / replica
-divergence — zero additions to any jitted step (``wrap_step`` is an
-audit-pinned identity), emitting flight-ring events, schema-v5
-``kind: run`` records, and an end-of-run report artifact.
-
-Wired consumers: ``serving.Engine``/``Seq2SeqEngine`` (enriched
-``stats()``), ``parallel.distributed`` (comm accounting),
-``amp`` (loss-scale/skip introspection + ``record_scaler``),
-``optimizers`` (grad-norm gauge via ``AmpOptimizer.step`` info),
-``data.DataLoader`` (host load/wait times),
-``utils.checkpoint``/``checkpoint_orbax`` (save/restore latency +
-``checkpoint_saved`` flight events), ``fleet`` (SLO/goodput
-accounting), and ``bench.py``.
+Speed is not measured here: the benchmark is ``benchmark/run.py`` over
+``BENCHMARK.json``, and it reads this package's scopes, spans, counters
+and compile ledger.
 """
 
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
@@ -94,9 +58,8 @@ from .tracing import (SpanRecorder, get_recorder, set_recorder, span,
                       maybe_event)
 from .flightrec import EventRing, get_ring, set_ring
 from .exporters import (SCHEMA_VERSION, JsonlExporter, prometheus_text,
-                        host_info, validate_bench_record,
-                        validate_bench_jsonl)
-from .costmodel import Cost, jaxpr_cost, peak_flops, mfu
+                        host_info)
+from .costmodel import Cost, jaxpr_cost
 from .memory import (memory_plan, jaxpr_live_bytes, live_array_bytes,
                      record_live_arrays)
 from .numerics import (NumericsMonitor, divergence_check,
@@ -108,7 +71,6 @@ from .supervisor import RunSupervisor, SupervisorConfig
 from . import metrics
 from . import tracing
 from . import flightrec
-from . import steptime
 from . import timeline
 from . import exporters
 from . import costmodel
@@ -126,8 +88,7 @@ __all__ = [
     "new_trace_id", "current_trace", "maybe_span", "maybe_event",
     "EventRing", "get_ring", "set_ring",
     "SCHEMA_VERSION", "JsonlExporter", "prometheus_text", "host_info",
-    "validate_bench_record", "validate_bench_jsonl",
-    "Cost", "jaxpr_cost", "peak_flops", "mfu",
+    "Cost", "jaxpr_cost",
     "memory_plan", "jaxpr_live_bytes", "live_array_bytes",
     "record_live_arrays",
     "NumericsMonitor", "divergence_check", "divergence_digest",
@@ -135,7 +96,7 @@ __all__ = [
     "CompilationLedger", "instrumented_jit", "diff_signatures",
     "get_ledger", "set_ledger",
     "ObservabilityServer", "RunSupervisor", "SupervisorConfig",
-    "metrics", "tracing", "flightrec", "steptime", "timeline",
+    "metrics", "tracing", "flightrec", "timeline",
     "exporters", "costmodel", "memory", "numerics", "server",
     "supervisor", "compilation",
 ]

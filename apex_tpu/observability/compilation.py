@@ -3,12 +3,12 @@
 Apex's identity is "compile once, then run" — yet until this module the
 observability plane was blind to XLA compilation itself, even though
 four logged gotchas are compile-plane failures: per-replica re-jits
-making cold fleet benches measure N compiles (PR 4), the
+making a cold fleet measure N compiles (PR 4), the
 donated-executable persistent-cache reload corruption (PR 2),
 concurrent compile-cache poisoning (PR 2's parallel-pytest note), and
-compile seconds folded into a trended goodput rate (PR 10's bench --run
-fix).  :class:`CompilationLedger` records every trace of an
-instrumented jit entry — the entry label, the abstract argument
+compile seconds folded into a goodput rate (PR 10).
+:class:`CompilationLedger` records every trace of an instrumented jit
+entry — the entry label, the abstract argument
 signature (leaf shapes/dtypes + static-arg values), the dispatch's wall
 duration, the persistent-compilation-cache hit/miss attribution, and a
 signature fingerprint — and classifies each trace's CAUSE against the
@@ -92,7 +92,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["RETRACE_CAUSES", "SIGNATURE_CHANGE_CAUSES",
-           "BENCH_COMPILE_FIELDS", "STAGE_FIELDS", "CompilationLedger",
+           "STAGE_FIELDS", "CompilationLedger",
            "abstract_signature", "diff_signatures", "format_signature",
            "signature_fingerprint", "instrumented_jit",
            "get_ledger", "set_ledger"]
@@ -103,12 +103,6 @@ RETRACE_CAUSES = ("new_entry", "shape", "dtype", "static_arg",
 # the storm class: a signature actually CHANGED between two traces of
 # one entry — only these reach the flight ring / supervisor detector
 SIGNATURE_CHANGE_CAUSES = ("shape", "dtype", "static_arg")
-
-# the schema-v10 bench fields every fresh train/engine line carries —
-# duplicated stdlib-side in exporters.COMPILE_FIELDS (pinned equal in
-# tests: this module and exporters must both stay jax-free-importable)
-BENCH_COMPILE_FIELDS = ("cold_compile_ms", "compiles_total",
-                        "steady_state_retraces")
 
 # compile wall durations span sub-ms toy CPU traces to minutes-scale
 # hardware compiles
@@ -471,8 +465,8 @@ class CompilationLedger:
             # against THIS closure's own previous signature.  Diffing a
             # fresh closure against another closure's signature is not
             # evidence of shape polymorphism — two differently-shaped
-            # engines sharing an entry label (bench builds gpt w1/w8 +
-            # llama engines back to back) would otherwise emit
+            # engines sharing an entry label (gpt w1/w8 + llama
+            # engines built back to back) would otherwise emit
             # storm-class xla_retrace events and false-positive the
             # supervisor, with a "culprit" that never varied within any
             # one closure.
@@ -616,8 +610,7 @@ class CompilationLedger:
             return self._total_traces
 
     def compile_wall_s(self) -> float:
-        """Total wall seconds spent in tracing dispatches — what
-        ``bench.py`` separates out as ``cold_compile_ms``."""
+        """Total wall seconds spent in tracing dispatches."""
         with self._lock:
             return self._total_wall_s
 

@@ -1,13 +1,10 @@
 """Analytic cost model over jaxprs: FLOPs, transcendentals, and bytes.
 
-This is the machine-checked version of the hand-rolled roofline math in
-``artifacts/step_probe.py`` (which now imports it instead of
-re-deriving conv FLOPs ad hoc): walk a traced
-jaxpr, count the arithmetic each primitive performs, and report totals
-plus per-primitive / per-dtype breakdowns.  ``bench.py`` turns the
-totals into ``mfu`` / ``achieved_tflops`` fields on every train-step
-record, ``analysis.EntryPoint.cost()`` caches one per entry point, and
-``analysis.rules.FlopAccountingRule`` budgets them.
+Walk a traced jaxpr, count the arithmetic each primitive performs, and
+report totals plus per-primitive / per-dtype breakdowns.
+``analysis.EntryPoint.cost()`` caches one per entry point and
+``analysis.rules.FlopAccountingRule`` budgets them.  (Peak rates and MFU
+are the benchmark's: ``benchmark/lib/peaks.py``.)
 
 The op-cost table deliberately mirrors XLA's ``HloCostAnalysis`` (the
 engine behind ``Compiled.cost_analysis()``), calibrated primitive by
@@ -48,52 +45,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 __all__ = ["Cost", "jaxpr_cost", "eqn_flops", "conv_flops", "dot_flops",
-           "PEAK_FLOPS", "peak_flops", "mfu", "xla_cost"]
-
-
-# -- peak-FLOPs table ------------------------------------------------------
-#
-# Per-chip peak arithmetic rates by ``jax.devices()[0].device_kind``
-# (exact match, case-insensitive) and matmul operand dtype.
-# Sources:
-#  - TPU v5-lite (v5e): 197 bf16 TFLOP/s, 394 int8 TOP/s per chip
-#    (public v5e spec).  fp32 has no published MXU rate;
-#    ~1/4 of bf16 is the engineering estimate used for fp32 matmuls.
-#  - cpu: a NOMINAL 100 GFLOP/s smoke constant.  CPU-host MFU is not a
-#    hardware statement — the constant exists so CPU smoke rounds
-#    produce comparable mfu columns round-to-round (the same reason
-#    CPU timings warn rather than gate in check_bench_trend.py).
-PEAK_FLOPS: Dict[str, Dict[str, float]] = {
-    "tpu v5 lite": {"bfloat16": 197e12, "float32": 49.25e12,
-                    "int8": 394e12},
-    "tpu v5e": {"bfloat16": 197e12, "float32": 49.25e12,
-                "int8": 394e12},
-    "cpu": {"bfloat16": 100e9, "float32": 100e9, "float64": 50e9},
-}
-
-
-def peak_flops(arch: str, dtype: str) -> Optional[float]:
-    """Peak FLOP/s for a device kind + matmul dtype, or None when the
-    table has no entry (unknown hardware must not fabricate an MFU)."""
-    rates = PEAK_FLOPS.get(str(arch).lower())
-    return rates.get(str(dtype)) if rates else None
-
-
-def mfu(flops_per_step: float, step_seconds: float, arch: str,
-        dtype: str) -> Dict[str, Any]:
-    """Model-FLOPs-utilization fields for a bench record.
-
-    ``achieved_tflops`` is always computable; ``mfu`` and
-    ``peak_tflops`` are None when the peak table has no entry for the
-    hardware (absent beats fabricated)."""
-    achieved = flops_per_step / max(step_seconds, 1e-12)
-    peak = peak_flops(arch, dtype)
-    return {
-        "achieved_tflops": achieved / 1e12,
-        "peak_tflops": (peak / 1e12) if peak else None,
-        "mfu": (achieved / peak) if peak else None,
-        "mfu_dtype": str(dtype),
-    }
+           "xla_cost"]
 
 
 # -- per-eqn FLOP counting -------------------------------------------------

@@ -1,13 +1,11 @@
 """Exporters: schema-versioned JSONL, Prometheus text exposition.
 
-The JSONL exporter is the machine-readable telemetry trail the round-5
-VERDICT asked for: every emitted record carries ``schema_version``, the
-capture host, and a first-class boolean ``stale`` field (replacing the
-ad-hoc "STALE REPLAY" note strings as the *structured* staleness
-signal — the human-readable note stays for people reading artifacts).
-``bench.py`` routes every line through it, and
-``tests/ci/check_bench_schema.py`` validates the output against
-:func:`validate_bench_record`.
+The JSONL exporter is the machine-readable telemetry trail: every
+emitted record carries ``schema_version``, the capture host, and a
+first-class boolean ``stale`` field.  Each record ``kind`` the library
+produces has a validator here; :func:`validate_telemetry_record`
+dispatches on ``kind`` and rejects a record without a known one, and
+``tests/ci/check_telemetry_schema.py`` runs it over a stream.
 
 Chrome-trace export lives on :class:`tracing.SpanRecorder`; this module
 adds the registry-wide surfaces: Prometheus text exposition for
@@ -27,47 +25,32 @@ from typing import Any, Dict, IO, Iterable, List, Optional
 
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 
-__all__ = ["SCHEMA_VERSION", "OVERLAP_MODES", "OVERLAP_SCHEDULE_FIELDS",
-           "COMPILE_FIELDS", "TENANT_COUNTS", "CLASS_COUNTS",
-           "ADMISSION_MODES",
+__all__ = ["SCHEMA_VERSION", "TENANT_COUNTS", "CLASS_COUNTS",
            "host_info", "JsonlExporter",
            "prometheus_text", "parse_prometheus_text",
-           "validate_prometheus_text", "validate_bench_record",
-           "validate_bench_jsonl", "validate_lint_record",
+           "validate_prometheus_text", "validate_lint_record",
            "validate_fleet_record", "validate_trace_record",
            "validate_memory_record", "validate_numerics_record",
            "validate_run_record", "validate_recovery_record",
            "validate_profile_record", "validate_sharding_record",
            "validate_telemetry_record", "validate_telemetry_jsonl"]
 
+# What each version added to the record kinds:
 # v2: ``kind: fleet`` records REQUIRE ``trace_id`` (the fleet-record
 # <-> request-trace join key) and ``kind: trace`` records exist.
-# v3: ``kind: memory`` records exist (cost-model/memory-plan dumps);
-# fresh ``*_train_throughput`` records must carry the MFU fields
-# (``mfu`` / ``achieved_tflops`` / ``flops_per_step`` / ``peak_bytes``)
-# and fresh engine-decode records must carry ``kv_cache_bytes``.
+# v3: ``kind: memory`` records exist (cost-model/memory-plan dumps).
 # v4: ``kind: numerics`` records exist (gradient-health dumps from
-# ``NumericsMonitor.to_record`` / ``bench.py --numerics``) and fresh
-# ``numerics_overhead_*`` bench lines must carry ``step_ms_on`` /
-# ``step_ms_off`` (an overhead claim is meaningless without both
-# sides of the comparison).
+# ``NumericsMonitor.to_record``).
 # v5: ``kind: run`` records exist (training-run supervisor verdicts
-# from ``RunSupervisor.record`` / ``bench.py --run``); fresh
-# ``run_supervisor_overhead*`` bench lines must carry ``step_ms_on`` /
-# ``step_ms_off`` (same both-sides rule as the v4 numerics overhead);
-# ``kind: fleet`` records MAY carry the SLO/goodput fields
-# (``goodput_tokens_per_s`` / ``slo_attainment`` /
-# ``tokens_within_slo`` / ``deadline_exceeded`` /
+# from ``RunSupervisor.record``); ``kind: fleet`` records MAY carry
+# the SLO/goodput fields (``goodput_tokens_per_s`` /
+# ``slo_attainment`` / ``tokens_within_slo`` / ``deadline_exceeded`` /
 # ``deadline_last_sweep``), validated whenever present at any version.
 # v6: ``kind: recovery`` records exist (telemetry→action controller
 # snapshots from ``fleet.recovery.RecoveryLog.record`` — the elastic
-# training controller and the serving SLO-feedback controller — via
-# ``bench.py --chaos`` / ``tests/ci/chaos_smoke.py``); fresh
-# ``chaos_mttr*`` bench lines must carry ``mttr_s`` and fresh
-# ``chaos_spike*`` lines must carry ``slo_attainment`` +
-# ``goodput_tokens_per_s`` (a controller-vs-baseline claim is
-# meaningless without the SLO side of it); ``kind: fleet`` records MAY
-# carry the ``mttr`` aggregate, validated whenever present.
+# training controller and the serving SLO-feedback controller);
+# ``kind: fleet`` records MAY carry the ``mttr`` aggregate, validated
+# whenever present.
 # v7: preemption-safe deterministic resume.  ``kind: recovery``
 # records gain ``cause`` (one of RECOVERY_CAUSES — ``preemption`` is
 # the planned-SIGTERM exit), ``preempted`` (bool) and ``data_state``
@@ -75,47 +58,17 @@ __all__ = ["SCHEMA_VERSION", "OVERLAP_MODES", "OVERLAP_SCHEDULE_FIELDS",
 # samples_consumed/epoch/cursor plus the shard identity), all
 # validated whenever present; RECOVERY_ACTION_KINDS grows
 # ``preempt_snapshot`` (the coordinated emergency snapshot at the
-# step boundary); fresh ``chaos_preempt*`` bench lines must carry
-# ``mttr_s`` (preempt request → first committed post-resume step),
-# ``resume_overhead_s`` and ``resumed_step`` — a resume-overhead claim
-# is meaningless without the resume it measured.
-# v8: device-time truth.  ``kind: profile`` records exist (the
-# Chrome-trace device-timeline attribution from
-# ``observability.timeline``, via ``bench.py --profile`` and the
+# step boundary).
+# v8: ``kind: profile`` records exist (the Chrome-trace
+# device-timeline attribution from ``observability.timeline``, via the
 # ``/profilez`` endpoint): span/busy/compute/collective/gap/overlap
 # split in ms plus a MEASURED ``measured_overlap_fraction`` from
-# actual kernel-interval overlap — the timeline-backed counterpart of
-# steptime's differenced estimate, internally cross-checked by
-# ``validate_profile_record``.  Fresh engine-decode bench lines must
-# now carry the KV fragmentation pair ``kv_waste_bytes`` +
-# ``kv_utilization`` next to v3's ``kv_cache_bytes`` (allocated bytes
-# without the wasted bytes is exactly the blind spot ROADMAP item 1's
-# paged allocator must drive down); both fields are validated whenever
-# present at any version.
-# v9: overlapped gradient communication.  Step-time attribution
-# records (``train_step_attribution_*`` from ``bench.py --comm``) must
-# say WHICH bucket-issue schedule they measured: ``overlap_mode``
-# (one of OVERLAP_MODES — ``overlapped`` interleaves per-stage bucket
-# reductions with the backward, ``reduce_after_backward`` is the
-# classic baseline), ``n_stages`` and the stage-level ``issue_order``
-# permutation (OVERLAP_SCHEDULE_FIELDS, duplicated from
-# ``observability.steptime`` and pinned equal in tests) — a
-# comm-hidden claim is meaningless without the schedule that hid it.
-# The fields are validated whenever present at any version; fresh
-# v9 attribution lines must carry them.
-# v10: the compilation plane.  Fresh train-throughput and engine-decode
-# lines must say what their warmup COMPILED — ``cold_compile_ms``
-# (trace+lower+compile wall time, separated from every timed rate: the
-# PR 4/PR 10 gotcha class of compile seconds folded into a trended
-# number), ``compiles_total`` (tracing dispatches during warmup — a
-# cold fleet measuring N replica re-jits shows N here, not a mystery
-# slowdown) and ``steady_state_retraces`` (compilation-ledger trace
-# DELTA across the timed loop, which must be 0: a steady-state retrace
-# means the measured rate included a recompile).  All three validated
-# whenever present (COMPILE_FIELDS, duplicated from
-# observability.compilation.BENCH_COMPILE_FIELDS and pinned equal in
-# tests); required on fresh v10 lines; ``supervisor`` anomaly kinds
-# grow ``recompilation_storm``.
+# actual kernel-interval overlap, internally cross-checked by
+# ``validate_profile_record``.  Serving profiles may carry the KV
+# fragmentation fields (``kv_cache_bytes`` / ``kv_waste_bytes`` /
+# ``kv_utilization``), validated whenever present.
+# v10: the compilation plane: ``supervisor`` anomaly kinds grow
+# ``recompilation_storm``.
 # v11: the tenant plane.  ``kind: fleet`` records carry the per-tenant
 # SLO rollup — a ``tenants`` object keyed by tenant name whose buckets
 # hold the TENANT_COUNTS tallies plus ``slo_attainment`` /
@@ -125,40 +78,19 @@ __all__ = ["SCHEMA_VERSION", "OVERLAP_MODES", "OVERLAP_SCHEDULE_FIELDS",
 # whenever present; REQUIRED on fresh v11 fleet records — a fleet
 # snapshot that cannot say whose requests it served cannot answer
 # "which tenant's p99 regressed".  Untagged requests stay out of the
-# map, so per-tenant sums are <= the fleet totals, never ==.  Bench
-# grows the two-tenant open-loop leg: fresh ``*_tenant_*_goodput``
-# lines must carry ``tenant`` + ``slo_attainment``, and the
-# ``*_tenant_parity`` line must carry the token counts its ratio came
-# from (``tenants_goodput_tokens`` / ``tokens_within_slo``) and
-# reassemble from them.
-# v12: the paged serving plane.  Fresh engine-decode lines must say
-# HOW their engine admits and holds KV: ``admission_mode`` (one of
-# ADMISSION_MODES — ``fixed_slot`` reserves a whole buf_len row per
-# request, ``paged`` reserves fixed-size blocks off a shared pool and
-# admits at iteration boundaries), so trend tooling never compares a
-# paged line against a fixed-slot baseline unknowingly.  Lines from a
-# paged engine must additionally carry the pool geometry —
-# ``block_size``, ``blocks_total``, ``blocks_free`` (ints,
-# blocks_free <= blocks_total) — next to the v8 fragmentation pair
-# those fields explain: a falling ``kv_waste_bytes`` claim is
-# meaningless without the block size that produced it.  All four are
-# validated whenever present at any version; required on fresh v12
-# engine-decode lines.
+# map, so per-tenant sums are <= the fleet totals, never ==.
 # v13: the sharding plane.  ``kind: sharding`` records exist (the
 # static replication ledger from ``analysis.sharding``, via
-# ``python -m apex_tpu.analysis --sharding`` and ``bench.py
-# --graph-lint``): per entry point, the shard_map world and mesh axes,
-# the body-operand byte census split into ``unique_bytes`` +
-# ``replicated_bytes`` (world-total duplicate bytes the ZeRO-2/3
-# stages of ROADMAP item 2 exist to delete — on the ZeRO-1 DDP train
-# EPs this names the fully-replicated fp32 master/optimizer state),
-# the per-dtype replicated split, the top replicated arrays with their
-# inferred specs, and the resharding-eqn census.  The arithmetic
-# identity ``unique_bytes + replicated_bytes == world *
-# argument_bytes`` is enforced — a ledger that does not reassemble
-# from its own parts is hand-built, not propagated.  Deterministic
-# like the compiled memory plan, so ``check_bench_trend`` gates
-# ``replicated_bytes`` per entry point on every backend.
+# ``python -m apex_tpu.analysis --sharding``): per entry point, the
+# shard_map world and mesh axes, the body-operand byte census split
+# into ``unique_bytes`` + ``replicated_bytes`` (world-total duplicate
+# bytes — on the ZeRO-1 DDP train EPs this names the fully-replicated
+# fp32 master/optimizer state), the per-dtype replicated split, the
+# top replicated arrays with their inferred specs, and the
+# resharding-eqn census.  The arithmetic identity ``unique_bytes +
+# replicated_bytes == world * argument_bytes`` is enforced — a ledger
+# that does not reassemble from its own parts is hand-built, not
+# propagated.
 # v14: the QoS plane.  ``kind: fleet`` records carry the per-class
 # rollup — a ``classes`` object keyed by priority-class name whose
 # buckets hold the CLASS_COUNTS tallies (TENANT_COUNTS plus
@@ -167,53 +99,16 @@ __all__ = ["SCHEMA_VERSION", "OVERLAP_MODES", "OVERLAP_SCHEDULE_FIELDS",
 # contract) and the live queue shape (``queue_depth`` / ``queue_cap``
 # / ``weight`` / ``preemptible``), and a fleet-level ``preemptions``
 # total.  Validated whenever present; REQUIRED on fresh v14 fleet
-# records — a fleet snapshot that cannot split its SLO story by
-# priority class cannot answer "did the batch flood eat the
-# interactive tier".  RECOVERY_ACTION_KINDS grows
-# ``class_admission_tighten`` / ``class_admission_relax`` (the
-# per-class admission knob — the controller squeezes the
-# lowest-priority class's queue quota, never rank 0's).  Bench grows
-# the QoS leg: fresh per-class ``*_class_*_goodput`` lines must carry
-# ``qos_class`` + ``slo_attainment``, and the ``*_preemption_parity``
-# line (token-for-token equality of a preempted-then-readmitted
-# request vs an undisturbed run) must carry the token counts its
-# ratio came from (``matched_tokens`` / ``expected_tokens``), at
-# least one measured ``preemptions``, and reassemble from them —
-# check_bench_trend gates the parity at exactly 1.0 on EVERY backend
-# (determinism, not timing).
-# v15: the ZeRO weight-update sharding plane.  Fresh ZeRO bench lines
-# (``*zero*_train_throughput`` from the ``ddp_resnet18_o2_zero{1,2,3}``
-# / ``ddp_mlp_overlap_zero2`` legs) must carry ``zero_stage`` in
-# {1, 2, 3} — a sharded-update throughput number compared against the
-# wrong stage's baseline is the exact confusion the replication ledger
-# exists to prevent — and ``kind: sharding`` ledger records for zero
-# entry points carry the same tag so ``check_bench_trend`` can gate
-# ``replicated_bytes`` per (entry_point, backend) on every backend
-# with the stage visible in the gated record (the stage-3 ledger
-# collapse — masters ARE the params, nothing replicated but BN state
-# and scalars — is a per-stage claim, not a per-EP one).  Validated
-# whenever present at any version; required on fresh v15 records.
+# records.  RECOVERY_ACTION_KINDS grows ``class_admission_tighten`` /
+# ``class_admission_relax`` (the per-class admission knob — the
+# controller squeezes the lowest-priority class's queue quota, never
+# rank 0's).
+# v15: ``kind: sharding`` ledger records for ZeRO entry points carry
+# ``zero_stage`` in {1, 2, 3} (the stage-3 ledger collapse is a
+# per-stage claim, not a per-EP one); validated whenever present.
 # Validators gate each version's requirements on the record's DECLARED
 # version, so archived v1..v14 streams stay valid.
 SCHEMA_VERSION = 15
-
-# how a serving engine admits requests and holds KV (stdlib-side
-# duplicate of the serving engines' ``admission_mode`` class attrs —
-# this module must stay importable without jax; tests pin them in sync)
-ADMISSION_MODES = ("fixed_slot", "paged")
-
-# the compile-plane bench fields (stdlib-side duplicate of
-# observability.compilation.BENCH_COMPILE_FIELDS — this module must
-# stay importable without jax; tests pin the tuples equal)
-COMPILE_FIELDS = ("cold_compile_ms", "compiles_total",
-                  "steady_state_retraces")
-
-# which bucket-issue schedule an attribution record measured — the
-# stdlib-side duplicate of parallel.distributed.OVERLAP_MODES /
-# observability.steptime.OVERLAP_SCHEDULE_FIELDS (this module must
-# stay importable without jax; tests pin the tuples equal)
-OVERLAP_MODES = ("overlapped", "reduce_after_backward")
-OVERLAP_SCHEDULE_FIELDS = ("overlap_mode", "n_stages", "issue_order")
 
 _host_info_cache: Optional[Dict[str, Any]] = None
 
@@ -517,7 +412,7 @@ def validate_prometheus_text(text: str) -> List[str]:
     return errs
 
 
-# -- bench record schema --------------------------------------------------
+# -- shared field checks --------------------------------------------------
 
 def _need(rec, errs, key, types, allow_none=False):
     """Shared required-key type check (bool is not an int here)."""
@@ -533,9 +428,8 @@ def _need(rec, errs, key, types, allow_none=False):
 
 
 def _check_kv_fields(rec, errs):
-    """The KV fragmentation field contract, shared by bench and
-    profile records (one implementation so the two schemas cannot
-    drift): byte fields are non-negative ints, waste is a subset of
+    """The KV fragmentation field contract of serving profile
+    records: byte fields are non-negative ints, waste is a subset of
     the allocation, utilization is a fraction — all validated
     whenever present."""
     for opt in ("kv_cache_bytes", "kv_waste_bytes"):
@@ -557,57 +451,10 @@ def _check_kv_fields(rec, errs):
                         f"{v!r}")
 
 
-def _check_block_pool_fields(rec, errs):
-    """The paged-KV field contract (schema v12), validated whenever
-    present at any version: ``admission_mode`` names a known mode;
-    ``block_size`` is a positive int; ``blocks_total`` /
-    ``blocks_free`` are non-negative ints with free <= total (free
-    blocks beyond the pool would mean the allocator double-freed)."""
-    if "admission_mode" in rec:
-        am = rec["admission_mode"]
-        if am not in ADMISSION_MODES:
-            errs.append(f"'admission_mode' must be one of "
-                        f"{ADMISSION_MODES}, got {am!r}")
-    if "block_size" in rec:
-        v = rec["block_size"]
-        if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-            errs.append(f"'block_size' must be an int >= 1, got {v!r}")
-    for key in ("blocks_total", "blocks_free"):
-        if key in rec:
-            v = rec[key]
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                errs.append(f"{key!r} must be an int >= 0, got {v!r}")
-    bf, bt = rec.get("blocks_free"), rec.get("blocks_total")
-    if (isinstance(bf, int) and isinstance(bt, int)
-            and not isinstance(bf, bool) and not isinstance(bt, bool)
-            and bf > bt):
-        errs.append(f"blocks_free ({bf}) exceeds blocks_total ({bt}) "
-                    f"— free blocks are a subset of the pool")
-
-
-def _check_compile_fields(rec, errs):
-    """The compilation-plane field contract (schema v10), validated
-    whenever present: ``cold_compile_ms`` is a non-negative number,
-    ``compiles_total`` / ``steady_state_retraces`` non-negative ints.
-    (Whether a nonzero steady-state retrace count GATES is the trend
-    checker's job — schema-wise the record is honest about it.)"""
-    if "cold_compile_ms" in rec:
-        v = rec["cold_compile_ms"]
-        if (not isinstance(v, numbers.Number) or isinstance(v, bool)
-                or not (v >= 0)):
-            errs.append(f"'cold_compile_ms' must be a number >= 0, "
-                        f"got {v!r}")
-    for key in ("compiles_total", "steady_state_retraces"):
-        if key in rec:
-            v = rec[key]
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                errs.append(f"{key!r} must be an int >= 0, got {v!r}")
-
-
 def _check_envelope(rec, errs):
     """The common record envelope every exported line carries
     (schema_version / capture host / first-class ``stale``) — one
-    implementation for bench and lint records."""
+    implementation for every record kind."""
     sv = _need(rec, errs, "schema_version", int)
     if isinstance(sv, int) and not isinstance(sv, bool) and sv < 1:
         errs.append(f"schema_version must be >= 1, got {sv}")
@@ -618,463 +465,6 @@ def _check_envelope(rec, errs):
             errs.append("host.hostname must be a string")
         if not isinstance(host.get("pid"), int):
             errs.append("host.pid must be an int")
-
-
-def validate_bench_record(rec: Any) -> List[str]:
-    """Schema check for one bench JSONL record; returns a list of
-    problems (empty = valid).  Shared by the pytest coverage and the
-    tests/ci/check_bench_schema.py gate."""
-    errs: List[str] = []
-    if not isinstance(rec, dict):
-        return [f"record is {type(rec).__name__}, not an object"]
-
-    def need(key, types, allow_none=False):
-        return _need(rec, errs, key, types, allow_none)
-
-    _check_envelope(rec, errs)
-    metric = need("metric", str)
-    if isinstance(metric, str) and not metric:
-        errs.append("metric must be non-empty")
-    need("value", numbers.Number, allow_none=True)
-    need("unit", str, allow_none=True)
-    need("backend", str)
-    need("ndev", int)
-    need("arch", str)
-    for opt in ("note", "error", "recorded_at", "stale_recorded_at"):
-        if opt in rec and not isinstance(rec[opt], str):
-            errs.append(f"{opt!r} must be a string when present")
-    if "vs_baseline" in rec and rec["vs_baseline"] is not None \
-            and not isinstance(rec["vs_baseline"], numbers.Number):
-        errs.append("'vs_baseline' must be a number or null")
-    # serving decode-window fields (PR 2): ``window`` is the in-graph
-    # decode ticks per host sync — tokens/sec lines are only comparable
-    # given it, so fresh engine-decode measurements must carry it.
-    # Stale replays of pre-window records and error lines are exempt.
-    if "window" in rec:
-        w = rec["window"]
-        if not isinstance(w, int) or isinstance(w, bool) or w < 1:
-            errs.append(f"'window' must be an int >= 1, got {w!r}")
-    if "tokens_per_sync" in rec and not isinstance(
-            rec["tokens_per_sync"], numbers.Number):
-        errs.append("'tokens_per_sync' must be a number when present")
-    sv_rec = rec.get("schema_version")
-    v3 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 3)
-    v8 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 8)
-    v10 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-           and sv_rec >= 10)
-    v12 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-           and sv_rec >= 12)
-    if (isinstance(metric, str) and "engine_decode" in metric
-            and "error" not in rec and not rec.get("stale")):
-        if "window" not in rec:
-            errs.append("engine decode records must carry 'window' "
-                        "(decode ticks per host sync)")
-        unit = rec.get("unit")
-        if isinstance(unit, str) and "tokens/sec" not in unit:
-            errs.append(f"engine decode records must report a "
-                        f"tokens/sec unit, got {unit!r}")
-        if v3 and "kv_cache_bytes" not in rec:
-            errs.append("fresh engine decode records must carry "
-                        "'kv_cache_bytes' (schema v3)")
-        # v8: allocated bytes without the wasted bytes is exactly the
-        # fragmentation blind spot — fresh decode lines carry the pair
-        if v8:
-            for key in ("kv_waste_bytes", "kv_utilization"):
-                if key not in rec:
-                    errs.append(f"fresh engine decode records must "
-                                f"carry {key!r} (schema v8)")
-        # v10: a decode rate is only a steady-state claim if it says
-        # what warmup compiled and that the timed loop re-traced
-        # nothing — the compile-plane triple
-        if v10:
-            for key in COMPILE_FIELDS:
-                if key not in rec:
-                    errs.append(f"fresh engine decode records must "
-                                f"carry {key!r} (schema v10)")
-        # v12: the paged serving plane — a decode line must say HOW
-        # its engine admits and holds KV (a paged line compared
-        # against a fixed-slot baseline unknowingly is the trend
-        # checker's blind spot), and a paged line must carry the pool
-        # geometry its fragmentation numbers are denominated in
-        if v12:
-            if "admission_mode" not in rec:
-                errs.append("fresh engine decode records must carry "
-                            "'admission_mode' (schema v12)")
-            elif rec.get("admission_mode") == "paged":
-                for key in ("block_size", "blocks_total",
-                            "blocks_free"):
-                    if key not in rec:
-                        errs.append(f"fresh paged engine decode "
-                                    f"records must carry {key!r} "
-                                    f"(schema v12)")
-    # MFU / peak-memory fields (PR 8): a fresh train-step throughput
-    # line is only a roofline statement given the model FLOPs behind
-    # it — v3 records must say what they computed (flops_per_step,
-    # per device), how fast (achieved_tflops, mfu vs the costmodel
-    # peak table — null where the table has no entry for the
-    # hardware) and at what memory high-water mark (peak_bytes from
-    # the compiled plan).  Stale replays of older rounds and error
-    # lines stay exempt, as does anything declaring schema_version < 3.
-    if (v3 and isinstance(metric, str)
-            and metric.endswith("_train_throughput")
-            and "error" not in rec and not rec.get("stale")):
-        for key in ("flops_per_step", "achieved_tflops"):
-            v = _need(rec, errs, key, numbers.Number)
-            if (isinstance(v, numbers.Number) and not isinstance(v, bool)
-                    and v < 0):
-                errs.append(f"{key!r} must be >= 0, got {v}")
-        mv = _need(rec, errs, "mfu", numbers.Number, allow_none=True)
-        if (isinstance(mv, numbers.Number) and not isinstance(mv, bool)
-                and mv < 0):
-            errs.append(f"'mfu' must be >= 0 or null, got {mv}")
-        pb = _need(rec, errs, "peak_bytes", int)
-        if isinstance(pb, int) and not isinstance(pb, bool) and pb < 0:
-            errs.append(f"'peak_bytes' must be >= 0, got {pb}")
-    # v10: fresh train-throughput lines carry the compile-plane triple
-    # next to the v3 cost-model fields — a timed rate that cannot say
-    # its compile time was separated out is the gotcha class bench
-    # exists to prevent (cold compiles folded into trended numbers)
-    if (v10 and isinstance(metric, str)
-            and metric.endswith("_train_throughput")
-            and "error" not in rec and not rec.get("stale")):
-        for key in COMPILE_FIELDS:
-            if key not in rec:
-                errs.append(f"fresh train-throughput records must "
-                            f"carry {key!r} (schema v10)")
-    _check_kv_fields(rec, errs)
-    _check_compile_fields(rec, errs)
-    _check_block_pool_fields(rec, errs)
-    if "mfu" in rec and rec["mfu"] is not None and (
-            not isinstance(rec["mfu"], numbers.Number)
-            or isinstance(rec["mfu"], bool)):
-        errs.append("'mfu' must be a number or null")
-    # gradient-allreduce comm microbench fields (bench.py --comm): a
-    # record carrying ``comm_topology`` describes one topology variant
-    # of the two-level ICI/DCN reduction and must state the per-level
-    # wire bytes — the flat-vs-hierarchical comparison is meaningless
-    # without them — plus the compression flag and the level widths.
-    if "comm_topology" in rec:
-        ct = rec["comm_topology"]
-        if ct not in ("flat", "hierarchical"):
-            errs.append(f"'comm_topology' must be 'flat' or "
-                        f"'hierarchical', got {ct!r}")
-        _need(rec, errs, "compress", bool)
-        for key in ("ici_size", "dcn_size"):
-            v = _need(rec, errs, key, int)
-            if isinstance(v, int) and not isinstance(v, bool) and v < 1:
-                errs.append(f"{key!r} must be >= 1, got {v}")
-        for key in ("wire_bytes", "ici_wire_bytes", "dcn_wire_bytes"):
-            v = _need(rec, errs, key, int)
-            if isinstance(v, int) and not isinstance(v, bool) and v < 0:
-                errs.append(f"{key!r} must be >= 0, got {v}")
-    if (isinstance(metric, str) and metric.startswith("grad_allreduce_")
-            and "error" not in rec and not rec.get("stale")
-            and "comm_topology" not in rec):
-        errs.append("grad_allreduce records must carry 'comm_topology' "
-                    "(and the per-level wire-byte fields)")
-    # numerics-instrumentation overhead fields (bench.py --numerics,
-    # schema v4): an overhead line is the on-vs-off step-time
-    # comparison — both sides must be on the record, non-negative,
-    # and arithmetically consistent with the headline value.
-    for opt in ("step_ms_on", "step_ms_off", "overhead_fraction"):
-        if opt in rec:
-            v = rec[opt]
-            if (not isinstance(v, numbers.Number)
-                    or isinstance(v, bool) or v < 0):
-                errs.append(f"{opt!r} must be a number >= 0 when "
-                            f"present, got {v!r}")
-    v4 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 4)
-    v5 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 5)
-    # the v5 supervisor-overhead lines (bench.py --run) follow the
-    # same both-sides contract as the v4 numerics overhead: an
-    # overhead claim must carry the on and off step times it came from
-    if (isinstance(metric, str)
-            and ((v4 and metric.startswith("numerics_overhead"))
-                 or (v5 and metric.startswith("run_supervisor_overhead")))
-            and "error" not in rec and not rec.get("stale")):
-        on = _need(rec, errs, "step_ms_on", numbers.Number)
-        off = _need(rec, errs, "step_ms_off", numbers.Number)
-        val = rec.get("value")
-        ok_num = all(isinstance(v, numbers.Number)
-                     and not isinstance(v, bool)
-                     for v in (on, off, val))
-        if ok_num:
-            # the headline must reassemble from its own sides (bench
-            # clamps negative overhead to 0 and rounds to 4 decimals
-            # — 0.01 ms absorbs the rounding, nothing else)
-            expect = max(on - off, 0.0)
-            if abs(val - expect) > max(0.01, 0.01 * expect):
-                errs.append(
-                    f"value ({val}) inconsistent with "
-                    f"step_ms_on - step_ms_off ({on} - {off})")
-            frac = rec.get("overhead_fraction")
-            if (isinstance(frac, numbers.Number)
-                    and not isinstance(frac, bool) and off > 0
-                    and abs(frac - expect / off)
-                    > max(0.01, 0.01 * frac)):
-                errs.append(
-                    f"overhead_fraction ({frac}) inconsistent with "
-                    f"value/step_ms_off ({expect:.4g}/{off})")
-        if "opt_level" in rec and not isinstance(rec["opt_level"], str):
-            errs.append("'opt_level' must be a string when present")
-    # chaos lines (bench.py --chaos, schema v6): the MTTR line must
-    # carry the measurement it claims, and the spike lines must carry
-    # the SLO side of the controller-vs-baseline comparison
-    v6 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 6)
-    if (v6 and isinstance(metric, str)
-            and "error" not in rec and not rec.get("stale")):
-        if metric.startswith("chaos_mttr"):
-            v = _need(rec, errs, "mttr_s", numbers.Number)
-            if (isinstance(v, numbers.Number)
-                    and not isinstance(v, bool) and not (v >= 0)):
-                errs.append(f"'mttr_s' must be >= 0, got {v!r}")
-        if metric.startswith("chaos_spike"):
-            att = _need(rec, errs, "slo_attainment", numbers.Number,
-                        allow_none=True)
-            if (isinstance(att, numbers.Number)
-                    and not isinstance(att, bool)
-                    and not (0.0 <= att <= 1.0)):
-                errs.append(f"'slo_attainment' must be null or in "
-                            f"[0, 1], got {att!r}")
-            gp = _need(rec, errs, "goodput_tokens_per_s",
-                       numbers.Number)
-            if (isinstance(gp, numbers.Number)
-                    and not isinstance(gp, bool) and not (gp >= 0)):
-                errs.append(f"'goodput_tokens_per_s' must be >= 0, "
-                            f"got {gp!r}")
-    # preemption resume lines (bench.py --chaos, schema v7): the
-    # trend-gated resume-overhead claim must carry the resume it
-    # measured — the MTTR window (preempt request → first committed
-    # post-resume step), the restore overhead, and where it resumed
-    v7 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 7)
-    if (v7 and isinstance(metric, str)
-            and metric.startswith("chaos_preempt")
-            and "error" not in rec and not rec.get("stale")):
-        for key in ("mttr_s", "resume_overhead_s"):
-            v = _need(rec, errs, key, numbers.Number)
-            if (isinstance(v, numbers.Number)
-                    and not isinstance(v, bool) and not (v >= 0)):
-                errs.append(f"{key!r} must be >= 0, got {v!r}")
-        rs = _need(rec, errs, "resumed_step", int)
-        if isinstance(rs, int) and not isinstance(rs, bool) and rs < 0:
-            errs.append(f"'resumed_step' must be >= 0, got {rs}")
-    # step-time attribution fields (bench.py --comm, PR 6): a record
-    # carrying ``overlap_fraction`` decomposes a train step into
-    # compute vs comm time per fabric level and must be internally
-    # consistent — compute + critical-path comm reassemble the
-    # wall-clock step, the per-level times reassemble the isolated
-    # comm measurement, and the overlap fraction is a fraction.
-    if "overlap_fraction" in rec:
-        for key in ("step_ms", "compute_ms", "comm_ms",
-                    "comm_isolated_ms", "ici_ms", "dcn_ms",
-                    "overlap_fraction"):
-            v = _need(rec, errs, key, numbers.Number)
-            if (isinstance(v, numbers.Number) and not isinstance(v, bool)
-                    and v < 0):
-                errs.append(f"{key!r} must be >= 0, got {v}")
-        vals = {k: rec.get(k) for k in ("step_ms", "compute_ms",
-                                        "comm_ms", "comm_isolated_ms",
-                                        "ici_ms", "dcn_ms",
-                                        "overlap_fraction")}
-        if all(isinstance(v, numbers.Number) and not isinstance(v, bool)
-               for v in vals.values()):
-            if vals["overlap_fraction"] > 1.0:
-                errs.append(f"overlap_fraction must be in [0, 1], got "
-                            f"{vals['overlap_fraction']}")
-            # comm_ms is the CLAMPED step-compute difference, so the
-            # only legitimate residue is measurement noise when the
-            # compute twin times slower than the full step
-            resid = abs(vals["compute_ms"] + vals["comm_ms"]
-                        - vals["step_ms"])
-            if resid > max(0.25 * vals["step_ms"], 0.25):
-                errs.append(
-                    f"compute_ms + comm_ms ({vals['compute_ms']} + "
-                    f"{vals['comm_ms']}) inconsistent with step_ms "
-                    f"({vals['step_ms']})")
-            lvl = abs(vals["ici_ms"] + vals["dcn_ms"]
-                      - vals["comm_isolated_ms"])
-            if lvl > max(0.02 * vals["comm_isolated_ms"], 0.01):
-                errs.append(
-                    f"ici_ms + dcn_ms ({vals['ici_ms']} + "
-                    f"{vals['dcn_ms']}) must reassemble "
-                    f"comm_isolated_ms ({vals['comm_isolated_ms']})")
-    # overlap schedule fields (PR 14, schema v9): a record saying WHICH
-    # bucket-issue schedule it measured must say it coherently — a
-    # known mode, a positive stage count, and a stage-level issue order
-    # that is a permutation of the stages.  Validated whenever present;
-    # REQUIRED on fresh v9 train_step_attribution_* lines (a
-    # comm-hidden claim without its schedule is not comparable).
-    if "overlap_mode" in rec:
-        om = rec["overlap_mode"]
-        if om not in OVERLAP_MODES:
-            errs.append(f"'overlap_mode' must be one of "
-                        f"{OVERLAP_MODES}, got {om!r}")
-        # a mode claim needs its schedule shape alongside it
-        _need(rec, errs, "n_stages", int)
-        _need(rec, errs, "issue_order", list)
-    # the shape fields are coherence-checked WHENEVER present — a
-    # record carrying n_stages=0 or a non-permutation issue_order is
-    # incoherent whether or not it also names its mode
-    ns = rec.get("n_stages")
-    ns_ok = isinstance(ns, int) and not isinstance(ns, bool)
-    if "n_stages" in rec:
-        if not ns_ok:
-            errs.append(f"'n_stages' must be an int, got {ns!r}")
-        elif ns < 1:
-            errs.append(f"'n_stages' must be >= 1, got {ns}")
-    if "issue_order" in rec:
-        io = rec["issue_order"]
-        if not isinstance(io, list) or not all(
-                isinstance(s, int) and not isinstance(s, bool)
-                for s in io):
-            errs.append("'issue_order' must be a list of ints")
-        elif ns_ok and ns >= 1 and sorted(io) != list(range(ns)):
-            errs.append(
-                f"'issue_order' must be a permutation of the "
-                f"{ns} stage ids, got {io}")
-    v9 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-          and sv_rec >= 9)
-    if (v9 and isinstance(metric, str)
-            and metric.startswith("train_step_attribution")
-            and "error" not in rec and not rec.get("stale")):
-        for key in OVERLAP_SCHEDULE_FIELDS:
-            if key not in rec:
-                errs.append(f"fresh step-attribution records must "
-                            f"carry {key!r} (schema v9: which "
-                            f"bucket-issue schedule was measured)")
-    # tenant-tagged bench lines (bench.py --fleet two-tenant leg,
-    # schema v11): whenever a line names a tenant it must name it
-    # coherently, and the fresh v11 per-tenant goodput/parity lines
-    # must carry the SLO side of the claim — a per-tenant throughput
-    # without attainment cannot say whether that tenant's deadlines
-    # held, and a parity ratio without its token counts cannot be
-    # re-derived.
-    if "tenant" in rec and (not isinstance(rec["tenant"], str)
-                            or not rec["tenant"]):
-        errs.append(f"'tenant' must be a non-empty string when "
-                    f"present, got {rec['tenant']!r}")
-    v11 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-           and sv_rec >= 11)
-    if (v11 and isinstance(metric, str)
-            and "error" not in rec and not rec.get("stale")):
-        if "_tenant_" in metric and metric.endswith("_goodput"):
-            if "tenant" not in rec:
-                errs.append("fresh per-tenant goodput records must "
-                            "carry 'tenant' (schema v11)")
-            att = _need(rec, errs, "slo_attainment", numbers.Number,
-                        allow_none=True)
-            if (isinstance(att, numbers.Number)
-                    and not isinstance(att, bool)
-                    and not (0.0 <= att <= 1.0)):
-                errs.append(f"'slo_attainment' must be null or in "
-                            f"[0, 1], got {att!r}")
-        if metric.endswith("_tenant_parity"):
-            counts = {}
-            for key in ("tenants_goodput_tokens", "tokens_within_slo"):
-                v = _need(rec, errs, key, int)
-                if isinstance(v, int) and not isinstance(v, bool):
-                    if v < 0:
-                        errs.append(f"{key!r} must be >= 0, got {v}")
-                    else:
-                        counts[key] = v
-            val = rec.get("value")
-            if (len(counts) == 2 and counts["tokens_within_slo"] > 0
-                    and isinstance(val, numbers.Number)
-                    and not isinstance(val, bool)):
-                expect = (counts["tenants_goodput_tokens"]
-                          / counts["tokens_within_slo"])
-                if abs(val - expect) > 0.005:
-                    errs.append(
-                        f"value ({val}) inconsistent with "
-                        f"tenants_goodput_tokens/tokens_within_slo "
-                        f"({expect:.4g})")
-    # QoS-tagged bench lines (bench.py --fleet QoS leg, schema v14):
-    # whenever a line names a priority class it must name it
-    # coherently; fresh v14 per-class goodput lines must carry the SLO
-    # side of the claim, and the preemption-parity line must carry the
-    # token counts its ratio came from plus the preemption count it
-    # survived — an exactness claim that preempted nothing measured
-    # nothing.
-    if "qos_class" in rec and (not isinstance(rec["qos_class"], str)
-                               or not rec["qos_class"]):
-        errs.append(f"'qos_class' must be a non-empty string when "
-                    f"present, got {rec['qos_class']!r}")
-    v14 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-           and sv_rec >= 14)
-    if (v14 and isinstance(metric, str)
-            and "error" not in rec and not rec.get("stale")):
-        if "_class_" in metric and metric.endswith("_goodput"):
-            if "qos_class" not in rec:
-                errs.append("fresh per-class goodput records must "
-                            "carry 'qos_class' (schema v14)")
-            att = _need(rec, errs, "slo_attainment", numbers.Number,
-                        allow_none=True)
-            if (isinstance(att, numbers.Number)
-                    and not isinstance(att, bool)
-                    and not (0.0 <= att <= 1.0)):
-                errs.append(f"'slo_attainment' must be null or in "
-                            f"[0, 1], got {att!r}")
-        if metric.endswith("_preemption_parity"):
-            counts = {}
-            for key in ("matched_tokens", "expected_tokens"):
-                v = _need(rec, errs, key, int)
-                if isinstance(v, int) and not isinstance(v, bool):
-                    if v < 0:
-                        errs.append(f"{key!r} must be >= 0, got {v}")
-                    else:
-                        counts[key] = v
-            pre = _need(rec, errs, "preemptions", int)
-            if (isinstance(pre, int) and not isinstance(pre, bool)
-                    and pre < 1):
-                errs.append(f"'preemptions' must be >= 1 on a "
-                            f"preemption-parity line, got {pre}")
-            val = rec.get("value")
-            if (len(counts) == 2 and counts["expected_tokens"] > 0
-                    and isinstance(val, numbers.Number)
-                    and not isinstance(val, bool)):
-                expect = (counts["matched_tokens"]
-                          / counts["expected_tokens"])
-                if abs(val - expect) > 0.005:
-                    errs.append(
-                        f"value ({val}) inconsistent with "
-                        f"matched_tokens/expected_tokens "
-                        f"({expect:.4g})")
-    # ZeRO-tagged bench lines (bench.py --comm zero legs, schema v15):
-    # whenever a line names a ZeRO stage it must be a real one; fresh
-    # v15 zero train-throughput lines must say WHICH stage produced the
-    # number — trending a stage-3 rate against a stage-1 baseline
-    # unknowingly is the blind spot the tag closes.
-    if "zero_stage" in rec:
-        zs = rec["zero_stage"]
-        if not isinstance(zs, int) or isinstance(zs, bool) \
-                or zs not in (1, 2, 3):
-            errs.append(f"'zero_stage' must be 1, 2 or 3 when present, "
-                        f"got {zs!r}")
-    v15 = (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-           and sv_rec >= 15)
-    if (v15 and isinstance(metric, str) and "zero" in metric
-            and metric.endswith("_train_throughput")
-            and "error" not in rec and not rec.get("stale")
-            and "zero_stage" not in rec):
-        errs.append("fresh ZeRO train-throughput records must carry "
-                    "'zero_stage' (schema v15)")
-    try:
-        json.dumps(rec)
-    except (TypeError, ValueError) as e:
-        errs.append(f"record is not JSON-serializable: {e}")
-    return errs
-
-
-def validate_bench_jsonl(lines: Iterable[str]) -> List[str]:
-    """Validate a bench stdout stream: every non-empty line must parse
-    as JSON and pass the record schema."""
-    return _validate_jsonl(lines, validate_bench_record)
 
 
 # -- graph-lint record schema ---------------------------------------------
@@ -1491,7 +881,7 @@ def validate_fleet_record(rec: Any) -> List[str]:
 # Public: observability.memory builds its plans from THIS tuple, so
 # the producer and the validator cannot drift.  (This module stays
 # import-light — memory.py imports from here, never the reverse, so
-# tests/ci/check_bench_schema.py's jax-free loader keeps working.)
+# tests/ci/check_telemetry_schema.py's jax-free loader keeps working.)
 MEMORY_PLAN_KEYS = ("argument_bytes", "output_bytes", "temp_bytes",
                     "alias_bytes", "generated_code_bytes")
 _MEMORY_PLAN_KEYS = MEMORY_PLAN_KEYS
@@ -1500,9 +890,8 @@ _MEMORY_PLAN_KEYS = MEMORY_PLAN_KEYS
 def validate_memory_record(rec: Any) -> List[str]:
     """Schema check for one ``kind: memory`` JSONL record (the
     cost-model/memory-plan dump emitted per analysis entry point by
-    ``python -m apex_tpu.analysis --memory`` and per bench config by
-    ``bench.py``): the common envelope, a subject (``entry_point`` or
-    ``metric``), non-negative analytic FLOP/byte totals, the compiled
+    ``python -m apex_tpu.analysis --memory``): the common envelope, a
+    subject (``entry_point`` or ``metric``), non-negative analytic FLOP/byte totals, the compiled
     memory-plan components, and the arithmetic cross-check — a
     ``peak_bytes`` that does not reassemble from its own components is
     a hand-built record, not a plan."""
@@ -2277,7 +1666,7 @@ _PROFILE_KERNEL_KINDS = ("compute", "collective")
 def validate_profile_record(rec: Any) -> List[str]:
     """Schema check for one ``kind: profile`` JSONL record (the
     device-timeline attribution from ``observability.timeline`` via
-    ``bench.py --profile`` or ``/profilez``, schema v8): the common
+    ``/profilez``, schema v8): the common
     envelope, a subject (``metric`` or ``entry_point``), the six
     non-negative timing fields, and the interval arithmetic a
     hand-built record gets wrong — busy never exceeds the span, gap
@@ -2285,7 +1674,7 @@ def validate_profile_record(rec: Any) -> List[str]:
     union from both sides, overlap fits inside BOTH classes, and the
     measured fraction is overlap over collective time.  ``top_kernels``
     entries must each name a known class; the optional KV fragmentation
-    fields follow the bench-record rules (waste is a subset of the
+    fields follow ``_check_kv_fields`` (waste is a subset of the
     allocation, utilization is a fraction)."""
     errs: List[str] = []
     if not isinstance(rec, dict):
@@ -2399,8 +1788,7 @@ def validate_profile_record(rec: Any) -> List[str]:
                         or isinstance(t, bool) or not (t >= 0)):
                     errs.append(f"top_kernels[{i}].total_ms must be a "
                                 f"number >= 0, got {t!r}")
-    # KV fragmentation fields on serving profiles: the same shared
-    # contract as the bench-record fields
+    # KV fragmentation fields on serving profiles
     _check_kv_fields(rec, errs)
     try:
         json.dumps(rec)
@@ -2409,50 +1797,44 @@ def validate_profile_record(rec: Any) -> List[str]:
     return errs
 
 
+_VALIDATORS = {
+    "graph_lint": validate_lint_record,
+    "graph_lint_summary": validate_lint_record,
+    "fleet": validate_fleet_record,
+    "trace": validate_trace_record,
+    "memory": validate_memory_record,
+    "numerics": validate_numerics_record,
+    "run": validate_run_record,
+    "recovery": validate_recovery_record,
+    "profile": validate_profile_record,
+    "sharding": validate_sharding_record,
+}
+
+
 def validate_telemetry_record(rec: Any) -> List[str]:
-    """Dispatching validator: graph-lint, fleet and trace records (by
-    ``kind``) go through their own schemas, everything else through
-    the bench schema — so one stream may interleave bench
-    measurements, lint findings (``bench.py --graph-lint``), fleet
-    snapshots (``bench.py --fleet N``), request traces
-    (``kind: trace``), cost-model dumps (``kind: memory``, from
-    ``python -m apex_tpu.analysis --memory`` / ``bench.py``) and
-    gradient-health dumps (``kind: numerics``, from
-    ``bench.py --numerics`` / ``NumericsMonitor.to_record``) and
-    training-run supervisor verdicts (``kind: run``, from
-    ``bench.py --run`` / ``RunSupervisor.record``, schema v5) and
-    recovery-controller snapshots (``kind: recovery``, from
-    ``bench.py --chaos`` / ``RecoveryLog.record``, schema v6) and
-    device-timeline attributions (``kind: profile``, from
-    ``bench.py --profile`` / ``/profilez``, schema v8) and static
-    replication ledgers (``kind: sharding``, from
-    ``python -m apex_tpu.analysis --sharding`` / ``bench.py
-    --graph-lint``, schema v13)."""
-    if isinstance(rec, dict) and rec.get("kind") in (
-            "graph_lint", "graph_lint_summary"):
-        return validate_lint_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "fleet":
-        return validate_fleet_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "trace":
-        return validate_trace_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "memory":
-        return validate_memory_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "numerics":
-        return validate_numerics_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "run":
-        return validate_run_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "recovery":
-        return validate_recovery_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "profile":
-        return validate_profile_record(rec)
-    if isinstance(rec, dict) and rec.get("kind") == "sharding":
-        return validate_sharding_record(rec)
-    return validate_bench_record(rec)
+    """Dispatching validator: a record goes through the schema its
+    ``kind`` names — graph-lint findings and their summary
+    (``python -m apex_tpu.analysis``), fleet snapshots
+    (``Fleet.record``), request traces, cost-model dumps (``kind:
+    memory``, ``--memory``), gradient-health dumps
+    (``NumericsMonitor.to_record``), run verdicts
+    (``RunSupervisor.record``), recovery-controller snapshots
+    (``RecoveryLog.record``), device-timeline attributions
+    (``/profilez``) and replication ledgers (``--sharding``) may
+    interleave in one stream.  A record whose ``kind`` is absent or
+    unknown is an error: there is no default schema."""
+    if not isinstance(rec, dict):
+        return [f"record is {type(rec).__name__}, not an object"]
+    kind = rec.get("kind")
+    validate = _VALIDATORS.get(kind) if isinstance(kind, str) else None
+    if validate is None:
+        return [f"'kind' must be one of {sorted(_VALIDATORS)}, got "
+                f"{kind!r}"]
+    return validate(rec)
 
 
 def validate_telemetry_jsonl(lines: Iterable[str]) -> List[str]:
-    """Validate a mixed bench + graph-lint + fleet + trace + memory +
-    numerics + run JSONL stream."""
+    """Validate a JSONL stream of the record kinds above."""
     return _validate_jsonl(lines, validate_telemetry_record)
 
 

@@ -7,11 +7,8 @@ different trust level:
 1. **Compiled plan** (:func:`memory_plan`): XLA's own
    ``Compiled.memory_analysis()`` — argument / output / temp /
    generated-code bytes and the donation-alias credit, i.e. what the
-   executable will actually reserve.  This is the number ROADMAP item 4
-   ("pin peak-memory in bench") gates on: ``bench.py`` stamps
-   ``peak_bytes`` from it onto every train-step record and
-   ``tests/ci/check_bench_trend.py --mem-tol`` fails a round that
-   regresses it.
+   executable will actually reserve; ``kind: memory`` records carry
+   it as ``peak_bytes``.
 2. **Analytic liveness** (:func:`jaxpr_live_bytes`): a static
    last-use scan over the traced jaxpr — cheap enough for the lint
    path (no compile), good enough to catch a graph suddenly keeping a

@@ -5,7 +5,7 @@ Two accumulation domains behind one reporting surface:
 - **Host metrics** (:class:`Counter` / :class:`Gauge` / :class:`Histogram`
   owned by a :class:`MetricsRegistry`): thread-safe Python accumulation
   for eager-path instrumentation — serving step latency, data-loader
-  wait times, DDP comm accounting, bench records.
+  wait times, DDP comm accounting.
 - **Device metrics** (:class:`DeviceMetrics`): training-step counters
   that live *inside* the jitted step as jnp scalars threaded through the
   step carry.  ``inc`` / ``set`` / ``observe`` are pure jnp ops — zero

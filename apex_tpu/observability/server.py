@@ -2,8 +2,8 @@
 
 Every instrumentation surface this package grew — the metrics registry,
 the flight ring, the span recorder, engine/fleet/supervisor ``stats()``
-— was consumed through files (JSONL dumps, post-mortem ring dumps,
-bench stdout).  A long-running training job or serving fleet needs the
+— was consumed through files (JSONL dumps, post-mortem ring
+dumps).  A long-running training job or serving fleet needs the
 *live* view: a wedged replica is diagnosed by scraping the process
 while it is wedged.  This module serves exactly the existing surfaces
 over a stdlib ``http.server`` — no new accounting, no new threads in

@@ -1,12 +1,14 @@
 """Device-time truth: parse the Chrome trace ``jax.profiler`` already
 writes and attribute a step's DEVICE time — measured, not inferred.
 
-``observability.steptime`` decomposes a train step by *host wall-clock
-differencing* (full step minus compute twin) — the same indirect
-methodology Apex's README warns about for comm/compute overlap claims.
-FlexLink (arXiv:2510.15882) and the weight-update-sharding paper
-(arXiv:2004.13336) both evaluate with per-kernel device timelines; this
-module is the in-tree equivalent: a **stdlib-only** parser (gzip +
+Host wall-clock differencing (a full step minus a twin without its
+collectives) is the indirect methodology Apex's README warns about for
+comm/compute overlap claims.  FlexLink (arXiv:2510.15882) and the
+weight-update-sharding paper (arXiv:2004.13336) both evaluate with
+per-kernel device timelines; this module is the in-tree equivalent for
+the library's own ``/profilez`` and ``utils.profiler`` captures (the
+benchmark's phase reader is ``benchmark/lib/trace.py``): a
+**stdlib-only** parser (gzip +
 json; jax is imported lazily and only by the capture helpers) for the
 ``*.trace.json.gz`` that ``jax.profiler.start_trace`` drops under its
 logdir, producing per-step device-time attribution — total device busy
@@ -208,8 +210,7 @@ def attribute_timeline(events: List[Dict[str, Any]], top_k: int = 10
     """Per-capture device-time attribution over extracted events.
 
     All times are the UNION over lanes (a kernel running on 8 virtual
-    devices at once counts its wall extent once — the schedule view,
-    matching what host differencing tries to estimate):
+    devices at once counts its wall extent once — the schedule view):
 
     - ``span_ms``: first kernel start to last kernel end;
     - ``device_busy_ms``: union of all kernel intervals;
@@ -219,8 +220,7 @@ def attribute_timeline(events: List[Dict[str, Any]], top_k: int = 10
     - ``overlap_ms``: time covered by BOTH a compute and a collective
       interval — the measured comm/compute overlap;
     - ``measured_overlap_fraction``: ``overlap / collective`` (0.0
-      with no collectives) — the device-timeline counterpart of
-      ``steptime``'s differenced ``overlap_fraction``.
+      with no collectives).
     """
     comp = merge_intervals((e["ts"], e["ts"] + e["dur"])
                            for e in events if e["kind"] == "compute")
@@ -302,11 +302,14 @@ def profile_record(attribution: Dict[str, Any], metric: str,
 # -- capture helpers (the only jax-touching surface, imported lazily) ----
 
 def _blocked_fetch(out) -> None:
-    # the steptime barrier discipline: a D2H fetch cannot complete
-    # before the dispatched program finishes, so every kernel the
-    # window dispatched lands INSIDE the window
-    from .steptime import _block
-    _block(out)
+    # one D2H fetch of an output leaf: it cannot complete before the
+    # dispatched program finishes, so every kernel the window
+    # dispatched lands INSIDE the window
+    import jax
+    import jax.numpy as jnp
+    leaves = jax.tree_util.tree_leaves(out)
+    if leaves:
+        float(jnp.sum(leaves[0]).astype(jnp.float32))
 
 
 def capture(fn: Callable, *args, iters: int = 1,
@@ -343,7 +346,7 @@ def make_profiler(subject: str = "live_process",
     ``kind: profile`` record body.  Raises
     ``server.ProfileInFlight`` when a trace window is already open
     (ours or a foreign ``start_trace``), which the endpoint maps to
-    HTTP 409.  ``cleanup=True`` (the default here, unlike bench/test
+    HTTP 409.  ``cleanup=True`` (the default here, unlike test
     captures whose dirs are the artifact) deletes the capture
     directory after parsing — a monitor scraping ``/profilez``
     periodically must not grow /tmp without bound."""

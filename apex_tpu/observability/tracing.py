@@ -46,7 +46,7 @@ Exports:
 - **Chrome trace JSON** (``chrome://tracing`` / Perfetto): complete
   events (``ph: "X"``, microsecond timestamps) plus instant events.
 - **JSONL event log**: one JSON object per event, machine-readable for
-  downstream analysis (the bench/CI side of the telemetry trail).
+  downstream analysis.
 - **Trace records** (:meth:`SpanRecorder.trace_record`): one
   schema-versioned ``kind: trace`` object per trace id, validated by
   ``exporters.validate_trace_record`` — the per-request flight record.

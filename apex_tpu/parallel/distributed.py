@@ -945,11 +945,9 @@ def overlap_comm_schedule(stage_trees: Sequence[Any],
 
 def overlap_schedule_fields(schedule: Optional[Dict[str, Any]]
                             ) -> Dict[str, Any]:
-    """The schedule fields a bench/attribution record carries
-    (``exporters.OVERLAP_SCHEDULE_FIELDS``): mode, stage count, and
-    stage-level issue order.  ``None`` describes a classic
-    un-staged step — one stage, reduced after backward — so every
-    attribution record can say which schedule it measured."""
+    """How a step issues its bucket reductions, as three fields: mode,
+    stage count, and stage-level issue order.  ``None`` describes a
+    classic un-staged step — one stage, reduced after backward."""
     if schedule is None:
         return {"overlap_mode": "reduce_after_backward",
                 "n_stages": 1, "issue_order": [0]}
@@ -1163,8 +1161,8 @@ class DistributedDataParallel:
         # record_numerics() after the step's NumericsMonitor.flush()
         # (the in-step device stats ride the carry, never this attr)
         self.last_numerics: dict = {}
-        # comm_enabled=False builds the COMPUTE TWIN of a step for
-        # step-time attribution (observability.steptime): the gradient
+        # comm_enabled=False builds the COMPUTE TWIN of a step (the
+        # same step timed without its wire): the gradient
         # collectives are elided while the local average a psum would
         # have applied stays, so the twin's per-element work matches
         # the full step minus the wire.  Numerically it trains on

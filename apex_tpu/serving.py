@@ -342,8 +342,7 @@ class _SlotScheduler:
     # how this engine admits requests and holds KV: "fixed_slot" (one
     # contiguous buf_len row per slot, admission when a slot frees) or
     # "paged" (block-pool KV + iteration-boundary admission).  Exported
-    # on bench lines (schema v12) so trend tooling never compares a
-    # paged line against a fixed-slot baseline unknowingly.
+    # in ``stats()`` and ``/statusz``.
     admission_mode = "fixed_slot"
 
     def _can_admit_direct(self, prompt, max_new_tokens) -> bool:
@@ -2029,8 +2028,8 @@ class PagedEngine(_SlotScheduler):
         return self
 
     def stats(self) -> Dict[str, Any]:
-        """Base snapshot plus the block-pool fields the v12 bench
-        schema exports: pool geometry, live headroom, and how many
+        """Base snapshot plus the block-pool fields: pool geometry,
+        live headroom, and how many
         admissions happened INSIDE a window (the continuous-batching
         win made visible)."""
         s = super().stats()

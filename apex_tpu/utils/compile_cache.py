@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``, the
-examples, ``tests/conftest.py``): if ``JAX_COMPILATION_CACHE_DIR`` is
+One rule for every entry point (``chip_smoke.py``, ``benchmark/run.py``,
+the examples, ``tests/conftest.py``): if ``JAX_COMPILATION_CACHE_DIR`` is
 set, JAX reads it and nothing here sets a directory in code; otherwise
 the cache is ``<checkout>/.jax_compile_cache`` — a fixed path, because
 the directory is part of what makes a later run find the entries again.

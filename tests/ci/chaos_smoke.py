@@ -33,7 +33,7 @@ telemetry→action loop CONVERGES:
 
 Exit 0 = converged and schema-clean; 1 = any violation (each printed).
 Wired into tier-1 by tests/test_autoscale.py (subprocess), like the
-server_smoke and check_bench_trend gates.
+server_smoke gate.
 """
 
 import os

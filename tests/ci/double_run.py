@@ -60,7 +60,7 @@ _ROOT = os.path.abspath(os.path.join(os.path.dirname(
 SUITES = ["tests/test_serving.py", "tests/test_fleet.py"]
 
 # ledger entries owned by the serving engines (the donated mutators
-# this gate exists for) — fleet/bench helpers and model-level jits
+# this gate exists for) — fleet helpers and model-level jits
 # outside the engines are not part of the reload contract
 SERVING_ENTRY_PREFIXES = ("engine.", "seq2seq.")
 

@@ -19,7 +19,7 @@ registry via the module CLI.  Usage:
     python tests/ci/graph_lint.py --tags serving       # subset
     python tests/ci/graph_lint.py --entry paged        # substring
     python tests/ci/graph_lint.py | \\
-        python tests/ci/check_bench_schema.py          # schema-check it
+        python tests/ci/check_telemetry_schema.py       # schema-check it
 
 Stdout is pure schema-versioned JSONL (findings + a summary record);
 progress goes to stderr.  Exit 0 = clean, 1 = any finding.  Unlike the

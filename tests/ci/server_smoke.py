@@ -4,8 +4,8 @@ scrape it end to end.
 
 Loads ``apex_tpu.observability``'s server stack WITHOUT importing the
 apex_tpu package (pure stdlib — same loader discipline as
-check_bench_schema.py: a smoke gate that pulls in jax + the model zoo
-would cost ~15s per CI invocation for nothing), builds a registry /
+check_telemetry_schema.py: a smoke gate that pulls in jax + the model
+zoo would cost ~15s per CI invocation for nothing), builds a registry /
 flight ring / span recorder / run supervisor with representative
 content — including label values that NEED exposition escaping — then:
 
@@ -31,8 +31,7 @@ content — including label values that NEED exposition escaping — then:
    flips ``/healthz`` to 503.
 
 Exit 0 = every scrape valid; 1 = any violation (each printed).
-Wired into tier-1 by tests/test_server.py (subprocess), like the
-check_bench_trend gate.
+Wired into tier-1 by tests/test_server.py (subprocess).
 """
 
 import importlib.util

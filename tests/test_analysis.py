@@ -693,11 +693,10 @@ def test_run_record_dispatch_in_mixed_stream():
         "anomaly_counts": {k: 0 for k in
                            exporters.RUN_ANOMALY_KINDS},
         "anomalies": []})
-    bench = exporters.JsonlExporter.enrich({
-        "metric": "m", "value": 1.0, "unit": "x", "backend": "cpu",
-        "ndev": 8, "arch": "cpu"})
+    lint = _enriched(analysis.Finding(
+        rule="layout", entry_point="x", message="leak"))
     errs = exporters.validate_telemetry_jsonl(
-        [json.dumps(good), json.dumps(bench)])
+        [json.dumps(good), json.dumps(lint)])
     assert errs == []
     bad = dict(good)
     bad["verdict"] = "attention"       # lies: zero counted anomalies
@@ -716,11 +715,10 @@ def test_numerics_record_dispatch_in_mixed_stream():
         "overflow_steps": 0,
         "layers": [{"name": "w", "nonfinite": 0, "abs_max": 1.0,
                     "grad_norm": 1.0, "underflow_fraction": 0.0}]})
-    bench = JsonlExporter.enrich({
-        "metric": "m", "value": 1.0, "unit": "x", "backend": "cpu",
-        "ndev": 1, "arch": "cpu"})
+    lint = _enriched(analysis.Finding(
+        rule="layout", entry_point="x", message="leak"))
     assert validate_telemetry_jsonl(
-        [json.dumps(bench), json.dumps(good)]) == []
+        [json.dumps(lint), json.dumps(good)]) == []
     bad = dict(good)
     bad["overflow_steps"] = 7
     errs = validate_telemetry_jsonl([json.dumps(bad)])
@@ -813,7 +811,7 @@ def test_comm_plan_hierarchical_levels():
     assert h["eqns"] == {"reduce_scatter": 1, "psum": 1,
                          "all_gather": 1}
     assert h["eqn_payload_bytes"]["psum"] == h["dcn_wire_bytes"]
-    # the headline relationship the bench asserts: DCN traffic shrinks
+    # the headline relationship: DCN traffic shrinks
     # by exactly the ICI factor (modulo shard padding)
     assert h["dcn_wire_bytes"] * 4 == (flat["dcn_wire_bytes"]
                                        + h["padded_elements"] * 4)
@@ -1110,7 +1108,7 @@ def test_sharding_ledger_zero2_sharded_state_is_not_replicated():
     assert s["unique_bytes"] == 8 * s["argument_bytes"]
 
     # a shard_map-free entry point raises the bare-RuntimeError skip
-    # class the CLI and bench use to exempt single-device graphs
+    # class the CLI uses to exempt single-device graphs
     bare = _ep("ledger_no_shardmap",
                trace=lambda: jax.make_jaxpr(lambda x: x + 1.0)(
                    jnp.ones((4,))))
@@ -1271,23 +1269,10 @@ def test_lint_summary_schema():
 
 
 def test_telemetry_jsonl_validates_mixed_stream():
-    """One stream may interleave bench records, lint findings
-    (bench.py --graph-lint), fleet snapshots (bench.py --fleet N) and
+    """One stream may interleave lint findings, fleet snapshots and
     request traces; the dispatching validator checks each against its
-    own schema."""
+    own schema, and a line without a known ``kind`` against none."""
     import json
-    bench_rec = exporters.JsonlExporter.enrich(
-        {"metric": "engine_decode", "value": 100.0,
-         "unit": "tokens/sec", "backend": "cpu", "ndev": 1,
-         "arch": "gpt", "window": 8, "tokens_per_sync": 8.0,
-         "kv_cache_bytes": 65536,     # required fresh at schema v3
-         # the kv fragmentation pair, required fresh at schema v8
-         "kv_waste_bytes": 16384, "kv_utilization": 0.75,
-         # the compile-plane triple, required fresh at schema v10
-         "cold_compile_ms": 120.5, "compiles_total": 2,
-         "steady_state_retraces": 0,
-         # required fresh at schema v12 (paged serving plane)
-         "admission_mode": "fixed_slot"})
     lint_rec = _enriched(analysis.Finding(
         rule="layout", entry_point="x", message="leak"))
     fleet_rec = exporters.JsonlExporter.enrich(
@@ -1307,37 +1292,43 @@ def test_telemetry_jsonl_validates_mixed_stream():
                    {"name": "fleet_result", "ph": "i", "ts": 9.0,
                     "span_id": 2, "parent_id": 1,
                     "trace_id": "fleet-1f-1/r0"}]})
-    lines = [json.dumps(bench_rec), json.dumps(lint_rec),
-             json.dumps(fleet_rec), json.dumps(trace_rec)]
+    lines = [json.dumps(lint_rec), json.dumps(fleet_rec),
+             json.dumps(trace_rec)]
     assert exporters.validate_telemetry_jsonl(lines) == []
     # a trace violation is kind-dispatched and caught positionally
     trace_bad = dict(trace_rec, span_count=9)
     errs = exporters.validate_telemetry_jsonl(
-        [json.dumps(bench_rec), json.dumps(trace_bad)])
+        [json.dumps(lint_rec), json.dumps(trace_bad)])
     assert len(errs) == 1 and "line 2" in errs[0] \
         and "span_count" in errs[0]
     # a lint violation is caught positionally
     lint_rec2 = dict(lint_rec, message="")
-    lines = [json.dumps(bench_rec), json.dumps(lint_rec2),
+    lines = [json.dumps(trace_rec), json.dumps(lint_rec2),
              json.dumps(fleet_rec)]
     errs = exporters.validate_telemetry_jsonl(lines)
     assert len(errs) == 1 and "line 2" in errs[0]
-    # a fleet violation too (kind-dispatched, not bench-shaped)
+    # a fleet violation too
     fleet_bad = dict(fleet_rec, failovers=-1)
     errs = exporters.validate_telemetry_jsonl(
-        [json.dumps(bench_rec), json.dumps(fleet_bad)])
+        [json.dumps(lint_rec), json.dumps(fleet_bad)])
     assert len(errs) == 1 and "line 2" in errs[0] \
         and "failovers" in errs[0]
-    # and a bench violation still is too
-    bench_bad = {k: v for k, v in bench_rec.items() if k != "window"}
-    errs = exporters.validate_telemetry_jsonl([json.dumps(bench_bad)])
-    assert any("window" in e for e in errs)
+    # a line of no known kind has no schema to fall back on: a metric
+    # line without ``kind``, and a misspelt kind
+    for unknown in (exporters.JsonlExporter.enrich(
+                        {"metric": "engine_decode", "value": 100.0,
+                         "unit": "tokens/sec"}),
+                    dict(fleet_rec, kind="fleets")):
+        errs = exporters.validate_telemetry_jsonl(
+            [json.dumps(lint_rec), json.dumps(unknown)])
+        assert len(errs) == 1 and "line 2" in errs[0] \
+            and "'kind'" in errs[0]
 
 
 def test_memory_record_schema_and_dispatch():
     """``kind: memory`` record contract (satellite): required analytic
     + plan fields, the peak_bytes reassembly cross-check, and the
-    telemetry dispatcher growing bench|lint|fleet|trace|memory."""
+    telemetry dispatcher routing it by kind."""
     good = exporters.JsonlExporter.enrich({
         "kind": "memory", "entry_point": "engine_step_k",
         "source": "compiled", "flops": 1.5e6, "transcendentals": 100.0,
@@ -1347,7 +1338,7 @@ def test_memory_record_schema_and_dispatch():
         "generated_code_bytes": 0, "peak_bytes": 1600,
         "analytic_live_bytes": 1400})
     assert exporters.validate_memory_record(good) == []
-    # kind-dispatched, not bench-shaped
+    # kind-dispatched
     assert exporters.validate_telemetry_record(good) == []
     # arithmetic cross-check: a peak that doesn't reassemble flags
     assert any("peak_bytes" in e for e in
@@ -1374,7 +1365,7 @@ def test_memory_record_schema_and_dispatch():
 def test_sharding_record_schema_and_dispatch():
     """``kind: sharding`` record contract (schema v13): the ledger
     identity must reassemble, the fraction must be consistent, and the
-    telemetry dispatcher grows bench|lint|fleet|trace|memory|sharding."""
+    telemetry dispatcher routes it by kind."""
     import json
     good = exporters.JsonlExporter.enrich({
         "kind": "sharding", "entry_point": "ddp_x", "source": "jaxpr",
@@ -1388,7 +1379,7 @@ def test_sharding_record_schema_and_dispatch():
                             "replication_factor": 8, "spec": "P()"}],
         "resharding_eqns": {}})
     assert exporters.validate_sharding_record(good) == []
-    # kind-dispatched, not bench-shaped
+    # kind-dispatched
     assert exporters.validate_telemetry_record(good) == []
     # the ledger identity: unique + replicated == world x argument
     assert any("reassemble" in e for e in
@@ -1407,12 +1398,11 @@ def test_sharding_record_schema_and_dispatch():
                exporters.validate_sharding_record(
                    dict(good,
                         replicated_bytes_by_dtype={"float32": 1})))
-    # positionally caught in a mixed stream next to a bench record
-    bench = exporters.JsonlExporter.enrich(
-        {"metric": "m", "value": 1.0, "unit": "x", "backend": "cpu",
-         "ndev": 8, "arch": "cpu"})
+    # positionally caught in a mixed stream next to a lint record
+    lint = _enriched(analysis.Finding(
+        rule="layout", entry_point="x", message="leak"))
     errs = exporters.validate_telemetry_jsonl(
-        [json.dumps(bench), json.dumps(dict(good, world=0))])
+        [json.dumps(lint), json.dumps(dict(good, world=0))])
     assert len(errs) >= 1 and all("line 2" in e for e in errs)
 
 

@@ -5,7 +5,7 @@ else before it builds a model); its leg functions are ordinary functions
 of their sizes, so the suite runs them at shapes it already compiles
 (ResNet-18 at 32x32, BERT-tiny, the 2-layer GPT of the serving tests) and
 checks they emit the fields the chip run prints.  Also here: the compile
-cache helper, bench.py's exit status, and the device-plumbing refusals
+cache helper and the device-plumbing refusals
 (kernel import failure on TPU, local children on an accelerator, a failed
 native build).
 """
@@ -167,26 +167,6 @@ def test_only_the_helper_places_the_cache():
     assert set(r.stdout.split()) <= {
         "apex_tpu/utils/compile_cache.py", "tests/test_compilation.py",
         "tests/test_chip_smoke.py"}
-
-
-# -- bench.py exit status ---------------------------------------------------
-
-def test_bench_exits_nonzero_when_a_config_raises(monkeypatch, capsys):
-    import bench
-    from apex_tpu import optimizers
-
-    def boom(*a, **kw):
-        raise RuntimeError("config blew up")
-
-    monkeypatch.setattr(optimizers, "FusedAdam", boom)
-    monkeypatch.setenv("APEX_BENCH_ONLY", "optimizer_step_time")
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    assert bench.main() == 1
-    out, err = capsys.readouterr()
-    (line,) = [json.loads(ln) for ln in out.splitlines()]
-    assert line["metric"] == "optimizer_step_time"
-    assert line["value"] is None and "config blew up" in line["error"]
-    assert "config blew up" in err
 
 
 # -- device plumbing that must not hide the device ---------------------------

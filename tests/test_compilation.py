@@ -158,12 +158,6 @@ def test_ledger_dump_roundtrip(tmp_path):
     assert snap["entries"]["e"]["traces"] == 1
 
 
-def test_bench_compile_fields_tuple():
-    assert C.BENCH_COMPILE_FIELDS == ("cold_compile_ms",
-                                      "compiles_total",
-                                      "steady_state_retraces")
-
-
 # -- real jits --------------------------------------------------------------
 
 def test_instrumented_jit_counts_traces_exactly():

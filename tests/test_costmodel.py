@@ -161,26 +161,6 @@ def test_fp32_matmul_fraction():
     assert c16.dominant_matmul_dtype == "bfloat16"
 
 
-def test_peak_flops_table_and_mfu():
-    """Documented peak table: the v5-lite bf16 entry is the published
-    197 TFLOP/s, keyed on the exact ``device_kind`` the chip reports
-    (a substring is not a device); unknown hardware yields mfu None
-    (absent beats fabricated)."""
-    assert costmodel.peak_flops("TPU v5 lite", "bfloat16") == 197e12
-    assert costmodel.peak_flops("TPU", "bfloat16") is None
-    assert costmodel.peak_flops("", "bfloat16") is None
-    assert costmodel.peak_flops("cpu", "float32") == 100e9
-    assert costmodel.peak_flops("warp drive", "bfloat16") is None
-
-    m = costmodel.mfu(1.97e12, 1.0, "TPU v5 lite", "bfloat16")
-    assert m["achieved_tflops"] == pytest.approx(1.97)
-    assert m["mfu"] == pytest.approx(0.01)
-    assert m["peak_tflops"] == pytest.approx(197.0)
-    unknown = costmodel.mfu(1e9, 1.0, "warp drive", "bfloat16")
-    assert unknown["mfu"] is None and unknown["peak_tflops"] is None
-    assert unknown["achieved_tflops"] > 0
-
-
 def test_roofline_r5_flops_accounting_corrected():
     """The round-5 hand roofline math, now machine-checked — and
     CORRECTED: the hand-rolled roofline priced a resnet50 224^2

@@ -395,8 +395,8 @@ def test_stall_watchdog_fails_over_silent_replica():
 
 def test_faulty_replica_arm_after_warmup_and_fleet_close():
     """arm() programs fault windows RELATIVE to the current step
-    counter — 'die k steps from now', the post-warmup idiom bench.py
-    --fleet uses — and Fleet.close() joins the worker pool without
+    counter — 'die k steps from now', the post-warmup idiom — and
+    Fleet.close() joins the worker pool without
     retiring the fleet."""
     rep = FaultyReplica(_StubReplica())
     fl = Fleet([rep, _StubReplica()], policy="round_robin",

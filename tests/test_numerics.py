@@ -477,34 +477,3 @@ def test_numerics_record_schema_accepts_good_and_flags_mutations():
     assert validate_telemetry_record(bad)
 
 
-def test_numerics_overhead_bench_fields():
-    from apex_tpu.observability.exporters import validate_bench_record
-    base = {"metric": "numerics_overhead_o2", "value": 0.4,
-            "unit": "ms", "backend": "cpu", "ndev": 8, "arch": "cpu",
-            "opt_level": "O2", "step_ms_on": 5.4, "step_ms_off": 5.0,
-            "overhead_fraction": 0.08}
-    assert validate_bench_record(JsonlExporter.enrich(base)) == []
-    missing = {k: v for k, v in base.items() if k != "step_ms_off"}
-    errs = validate_bench_record(JsonlExporter.enrich(missing))
-    assert any("step_ms_off" in e for e in errs)
-    neg = JsonlExporter.enrich({**base, "step_ms_on": -1.0})
-    assert any("step_ms_on" in e
-               for e in validate_bench_record(neg))
-    # the headline must reassemble from its own sides, and the
-    # fraction from the headline — corrupt arithmetic is caught
-    bad_val = JsonlExporter.enrich({**base, "value": 1.5})
-    assert any("inconsistent with" in e
-               for e in validate_bench_record(bad_val))
-    bad_frac = JsonlExporter.enrich({**base, "overhead_fraction": 0.9})
-    assert any("overhead_fraction" in e and "inconsistent" in e
-               for e in validate_bench_record(bad_frac))
-    # clamped-at-zero overhead (on < off, CPU noise) is consistent
-    clamped = JsonlExporter.enrich(
-        {**base, "value": 0.0, "step_ms_on": 4.9,
-         "overhead_fraction": 0.0})
-    assert validate_bench_record(clamped) == []
-    # stale replays of pre-v4 rounds stay exempt
-    stale = JsonlExporter.enrich(
-        {k: v for k, v in base.items() if k != "step_ms_on"},
-        stale=True)
-    assert validate_bench_record(stale) == []

@@ -1,6 +1,6 @@
 """Telemetry subsystem: metrics registry (host + device-resident),
 span tracing / Chrome-trace export, exporters, engine stats, and the
-bench JSONL schema."""
+record schemas."""
 
 import json
 import threading
@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from apex_tpu import models, observability as obs, serving
+from apex_tpu import analysis, models, observability as obs, serving
 from apex_tpu.observability import exporters
 
 
@@ -485,150 +485,8 @@ def test_amp_scaler_skip_lands_in_flight_ring():
         obs.set_ring(prev)
 
 
-# -- step-time attribution (PR 6) ------------------------------------------
-
-def test_steptime_attribution_decomposition_and_schema():
-    """attribute_step on deterministic sleepers: the decomposition's
-    internal identities (comm = step - compute, clamped; per-level
-    times reassemble the isolated comm time; overlap in [0, 1]) hold
-    and the resulting bench record passes the validator."""
-    from apex_tpu.observability import steptime
-
-    def sleeper(s):
-        def fn():
-            import time as _t
-            _t.sleep(s)
-            return jnp.ones((4,))
-        return fn
-
-    plan = [{"topology": "hierarchical", "comm_dtype": "float32",
-             "ici_wire_bytes": 3000, "dcn_wire_bytes": 1000,
-             "wire_bytes": 4000},
-            {"topology": "flat", "wire_bytes": 4000}]
-    att = steptime.attribute_step(sleeper(0.03), sleeper(0.018),
-                                  sleeper(0.012), args=(), plan=plan,
-                                  iters=2, warmup=0)
-    for k in steptime.ATTRIBUTION_FIELDS:
-        assert isinstance(att[k], float) and att[k] >= 0.0, k
-    assert 0.0 <= att["overlap_fraction"] <= 1.0
-    assert att["comm_ms"] == pytest.approx(
-        max(att["step_ms"] - att["compute_ms"], 0.0), abs=2e-4)
-    # the per-level split reassembles the isolated measurement and
-    # follows the plan's byte weights (3000+4000 ici vs 1000 dcn);
-    # fields are rounded to 4 decimals, hence the absolute tolerance
-    assert att["ici_ms"] + att["dcn_ms"] == pytest.approx(
-        att["comm_isolated_ms"], abs=2e-4)
-    assert att["dcn_ms"] == pytest.approx(
-        att["comm_isolated_ms"] * 1000 / 8000, abs=2e-4)
-    assert len(att["buckets"]) == 2
-    assert att["buckets"][1]["dcn_ms"] == 0.0    # flat bucket: all ici
-    rec = exporters.JsonlExporter.enrich(
-        {"metric": "train_step_attribution_hier", "value": att["step_ms"],
-         "unit": "ms", "vs_baseline": None, "backend": "cpu", "ndev": 8,
-         "arch": "cpu",
-         **{k: att[k] for k in steptime.ATTRIBUTION_FIELDS},
-         **{k: att[k] for k in steptime.OVERLAP_SCHEDULE_FIELDS}})
-    assert exporters.validate_bench_record(rec) == []
-    with pytest.raises(ValueError, match="iters"):
-        steptime.blocked_time(sleeper(0.0), iters=0)
-
-
-def test_attribution_measured_ici_step_zero_weight_level_folds():
-    """A measured ici_step under a plan whose buckets carry no DCN
-    bytes (single-fabric): the measured non-ici residue folds into the
-    ici column instead of silently vanishing (a zero byte weight can't
-    absorb time), so the record still reassembles comm_isolated_ms and
-    passes the validator."""
-    from apex_tpu.observability import steptime
-
-    def sleeper(s):
-        def fn():
-            import time as _t
-            _t.sleep(s)
-            return jnp.ones((4,))
-        return fn
-
-    plan = [{"topology": "flat", "wire_bytes": 100}]
-    att = steptime.attribute_step(sleeper(0.02), sleeper(0.012),
-                                  sleeper(0.008), args=(), plan=plan,
-                                  iters=2, warmup=0,
-                                  ici_step=sleeper(0.003))
-    assert att["dcn_ms"] == 0.0
-    assert att["ici_ms"] == pytest.approx(att["comm_isolated_ms"],
-                                          abs=2e-4)
-    rec = exporters.JsonlExporter.enrich(
-        {"metric": "train_step_attribution_flat", "value": att["step_ms"],
-         "unit": "ms", "vs_baseline": None, "backend": "cpu", "ndev": 8,
-         "arch": "cpu",
-         **{k: att[k] for k in steptime.ATTRIBUTION_FIELDS},
-         **{k: att[k] for k in steptime.OVERLAP_SCHEDULE_FIELDS}})
-    assert exporters.validate_bench_record(rec) == []
-
-
-def test_attribution_zero_weight_plan_still_reassembles():
-    """A plan whose buckets carry NO recognized byte weight (no
-    wire_bytes/bytes, or zero) can't label the per-level split — the
-    fallback attributes everything to the ici column so ici+dcn still
-    reassembles comm_isolated_ms and the record passes its own
-    schema, instead of emitting ici=dcn=0 and failing it."""
-    from apex_tpu.observability import steptime
-
-    def sleeper(s):
-        def fn():
-            import time as _t
-            _t.sleep(s)
-            return jnp.ones((4,))
-        return fn
-
-    for plan in ([{"topology": "flat", "payload_bytes": 100}],
-                 [{"topology": "flat", "wire_bytes": 0}]):
-        att = steptime.attribute_step(sleeper(0.02), sleeper(0.012),
-                                      sleeper(0.008), args=(),
-                                      plan=plan, iters=2, warmup=0)
-        assert att["dcn_ms"] == 0.0
-        assert att["ici_ms"] == pytest.approx(att["comm_isolated_ms"],
-                                              abs=2e-4)
-        rec = exporters.JsonlExporter.enrich(
-            {"metric": "train_step_attribution_flat",
-             "value": att["step_ms"], "unit": "ms", "vs_baseline": None,
-             "backend": "cpu", "ndev": 8, "arch": "cpu",
-             **{k: att[k] for k in steptime.ATTRIBUTION_FIELDS},
-             **{k: att[k]
-                for k in steptime.OVERLAP_SCHEDULE_FIELDS}})
-        assert exporters.validate_bench_record(rec) == []
-
-
-def test_attribution_record_schema_mutations():
-    """A record carrying overlap_fraction must be internally
-    consistent: compute+comm reassemble the step, the level times
-    reassemble the isolated comm, the fraction is a fraction."""
-    base = exporters.JsonlExporter.enrich(
-        {"metric": "train_step_attribution_flat", "value": 10.0,
-         "unit": "ms", "vs_baseline": None, "backend": "cpu", "ndev": 8,
-         "arch": "cpu", "step_ms": 10.0, "compute_ms": 6.0,
-         "comm_ms": 4.0, "comm_isolated_ms": 5.0,
-         "overlap_fraction": 0.2, "ici_ms": 4.0, "dcn_ms": 1.0,
-         "overlap_mode": "reduce_after_backward", "n_stages": 1,
-         "issue_order": [0]})
-    assert exporters.validate_bench_record(base) == []
-    bad = dict(base, overlap_fraction=1.5)
-    assert any("overlap_fraction" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = dict(base, comm_ms=-1.0)
-    assert any(">= 0" in e for e in exporters.validate_bench_record(bad))
-    bad = dict(base, compute_ms=1.0)       # 1 + 4 != 10
-    assert any("inconsistent with step_ms" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = dict(base, ici_ms=1.0)           # 1 + 1 != 5
-    assert any("reassemble" in e
-               for e in exporters.validate_bench_record(bad))
-    missing = {k: v for k, v in base.items() if k != "dcn_ms"}
-    assert any("dcn_ms" in e
-               for e in exporters.validate_bench_record(missing))
-
-
 def test_ddp_comm_enabled_compute_twin_is_collective_free():
-    """comm_enabled=False (the step-time compute twin) elides every
+    """comm_enabled=False (the compute twin of a step) elides every
     gradient collective while keeping the local average, so the twin
     graph is collective-free and its values are the local mean."""
     from apex_tpu import parallel
@@ -768,476 +626,38 @@ def test_jsonl_exporter_enrich_and_emit(tmp_path):
         assert len(f.readlines()) == 2
 
 
-def test_bench_record_schema_validation():
-    good = exporters.JsonlExporter.enrich(
-        {"metric": "m", "value": 1.5, "unit": "x", "vs_baseline": None,
-         "backend": "cpu", "ndev": 8, "arch": "cpu"})
-    assert exporters.validate_bench_record(good) == []
-    # error lines (value null) are valid
-    err_line = exporters.JsonlExporter.enrich(
-        {"metric": "m", "value": None, "unit": None, "vs_baseline": None,
-         "backend": "cpu", "ndev": 8, "arch": "cpu", "error": "boom"})
-    assert exporters.validate_bench_record(err_line) == []
-    # missing stale / wrong types are caught
-    bad = dict(good)
-    del bad["stale"]
-    assert any("stale" in e for e in exporters.validate_bench_record(bad))
-    bad = dict(good, value="fast")
-    assert any("value" in e for e in exporters.validate_bench_record(bad))
-    bad = dict(good, schema_version=0)
-    assert any("schema_version" in e
-               for e in exporters.validate_bench_record(bad))
-    assert exporters.validate_bench_record([1, 2]) != []
-
-
-def test_bench_record_schema_serving_decode_window_fields():
-    """Fresh engine-decode lines must carry the decode-window fields
-    (PR 2); stale replays of pre-window records and error lines stay
-    valid without them."""
-    base = {"metric": "gpt_tiny_engine_decode_throughput", "value": 9.0,
-            "unit": "tokens/sec/chip", "vs_baseline": None,
-            "backend": "cpu", "ndev": 8, "arch": "cpu",
-            "kv_cache_bytes": 16384,    # required fresh at schema v3
-            # required fresh at schema v8 (KV fragmentation pair)
-            "kv_waste_bytes": 4096, "kv_utilization": 0.75,
-            # required fresh at schema v10 (compile-plane triple)
-            "cold_compile_ms": 350.0, "compiles_total": 2,
-            "steady_state_retraces": 0,
-            # required fresh at schema v12 (paged serving plane)
-            "admission_mode": "fixed_slot"}
-    good = exporters.JsonlExporter.enrich(
-        dict(base, window=8, tokens_per_sync=7.5))
-    assert exporters.validate_bench_record(good) == []
-    # missing window on a fresh decode line is a schema violation
-    missing = exporters.JsonlExporter.enrich(dict(base))
-    assert any("window" in e
-               for e in exporters.validate_bench_record(missing))
-    # missing kv_cache_bytes on a fresh v3 decode line too (PR 8)
-    nokv = {k: v for k, v in base.items() if k != "kv_cache_bytes"}
-    assert any("kv_cache_bytes" in e
-               for e in exporters.validate_bench_record(
-                   exporters.JsonlExporter.enrich(dict(nokv, window=8))))
-    # missing the fragmentation pair on a fresh v8 decode line (PR 13)
-    for key in ("kv_waste_bytes", "kv_utilization"):
-        nofrag = {k: v for k, v in base.items() if k != key}
-        assert any(key in e
-                   for e in exporters.validate_bench_record(
-                       exporters.JsonlExporter.enrich(
-                           dict(nofrag, window=8)))), key
-    # ...but an archived v7 line without the pair stays valid at its
-    # declared version, as does an archived v2 line without any of it
-    v7 = exporters.JsonlExporter.enrich(
-        dict({k: v for k, v in base.items()
-              if k not in ("kv_waste_bytes", "kv_utilization")},
-             window=8))
-    v7["schema_version"] = 7
-    assert exporters.validate_bench_record(v7) == []
-    v2 = exporters.JsonlExporter.enrich(dict(nokv, window=8))
-    v2["schema_version"] = 2
-    assert exporters.validate_bench_record(v2) == []
-    # wrong types / values are caught wherever the field appears
-    for w in (0, -2, 1.5, True, "8"):
-        bad = exporters.JsonlExporter.enrich(dict(base, window=w))
-        assert any("window" in e
-                   for e in exporters.validate_bench_record(bad)), w
-    bad = exporters.JsonlExporter.enrich(
-        dict(base, window=8, tokens_per_sync="lots"))
-    assert any("tokens_per_sync" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = exporters.JsonlExporter.enrich(
-        dict(base, window=8, kv_cache_bytes=-5))
-    assert any("kv_cache_bytes" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = exporters.JsonlExporter.enrich(
-        dict(base, window=8, kv_waste_bytes=999_999))   # > cache
-    assert any("kv_waste_bytes" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = exporters.JsonlExporter.enrich(
-        dict(base, window=8, kv_utilization=1.2))
-    assert any("kv_utilization" in e
-               for e in exporters.validate_bench_record(bad))
-    # a windowed line must report tokens/sec
-    bad = exporters.JsonlExporter.enrich(
-        dict(base, window=8, unit="steps/sec"))
-    assert any("tokens/sec" in e
-               for e in exporters.validate_bench_record(bad))
-    # stale replay of an old (pre-window) record: exempt
-    stale = exporters.JsonlExporter.enrich(dict(base), stale=True)
-    assert exporters.validate_bench_record(stale) == []
-    # error line for a hung decode config: exempt
-    err = exporters.JsonlExporter.enrich(
-        {"metric": "gpt_tiny_engine_decode_throughput", "value": None,
-         "unit": None, "vs_baseline": None, "backend": "cpu",
-         "ndev": 8, "arch": "cpu", "error": "config hung"})
-    assert exporters.validate_bench_record(err) == []
-
-
-def test_bench_emits_schema_valid_jsonl():
-    """A fresh train-throughput line as bench.py's emit enriches it is
-    schema-valid, and the v3 cost-model requirement bites."""
-    fresh = exporters.JsonlExporter.enrich(
-        {"metric": "resnet50_amp_o2_ddp_train_throughput",
-         "value": 1830.0,
-         "unit": "images/sec/chip", "vs_baseline": 11.7,
-         "backend": "tpu", "ndev": 1, "arch": "TPU v5 lite",
-         # schema-v3 cost-model fields every fresh train line carries
-         "flops_per_step": 3.15e12, "achieved_tflops": 45.0,
-         "mfu": 0.228, "peak_bytes": 9_000_000_000,
-         # schema-v10 compile-plane triple (fresh train lines)
-         "cold_compile_ms": 5400.0, "compiles_total": 1,
-         "steady_state_retraces": 0})
-    assert exporters.validate_bench_record(fresh) == []
-    # the v3 requirement bites: a fresh train line without them flags
-    bare = {k: v for k, v in fresh.items()
-            if k not in ("flops_per_step", "achieved_tflops", "mfu",
-                         "peak_bytes")}
-    assert any("flops_per_step" in e
-               for e in exporters.validate_bench_record(bare))
-    # archived v2 train lines (and stale replays) stay valid
-    v2 = dict(bare)
-    v2["schema_version"] = 2
-    assert exporters.validate_bench_record(v2) == []
-    assert exporters.validate_bench_record(dict(bare, stale=True)) == []
-
-
-def test_check_bench_schema_cli(tmp_path):
-    """The tests/ci gate accepts a valid stream and rejects a broken
-    one."""
+def test_check_telemetry_schema_cli(tmp_path):
+    """The tests/ci gate accepts a valid lint stream and rejects a
+    broken record and a record without a known ``kind``."""
     import subprocess
     import sys
     import os
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    script = os.path.join(root, "tests", "ci", "check_bench_schema.py")
-    good = json.dumps(exporters.JsonlExporter.enrich(
-        {"metric": "m", "value": 1.0, "unit": "x", "backend": "cpu",
-         "ndev": 8, "arch": "cpu"}))
-    r = subprocess.run([sys.executable, script], input=good + "\n",
+    script = os.path.join(root, "tests", "ci",
+                          "check_telemetry_schema.py")
+    finding = exporters.JsonlExporter.enrich(
+        {"kind": "graph_lint", "rule": "donation", "severity": "error",
+         "entry_point": "e", "message": "m"})
+    summary = exporters.JsonlExporter.enrich(
+        {"kind": "graph_lint_summary", "entry_points": 1, "rules": 1,
+         "findings": 1, "errors": 1, "warnings": 0})
+    good = json.dumps(finding) + "\n" + json.dumps(summary) + "\n"
+    r = subprocess.run([sys.executable, script], input=good,
                        capture_output=True, text=True)
     assert r.returncode == 0, r.stderr
-    r = subprocess.run([sys.executable, script],
-                       input='{"metric": "m"}\n',
+    assert "2 records OK" in r.stdout
+    for broken in (dict(finding, severity="loud"),
+                   {k: v for k, v in finding.items() if k != "kind"},
+                   dict(finding, kind="bench")):
+        r = subprocess.run([sys.executable, script],
+                           input=json.dumps(broken) + "\n",
+                           capture_output=True, text=True)
+        assert r.returncode == 1, broken
+    path = tmp_path / "records.jsonl"
+    path.write_text(good)
+    r = subprocess.run([sys.executable, script, str(path)],
                        capture_output=True, text=True)
-    assert r.returncode == 1
-
-
-def _trend_round(tmp_path, name, lines):
-    """One BENCH_r*.json runbook wrapper holding ``lines`` as its
-    JSONL tail (what check_bench_trend.py parses)."""
-    doc = {"n": name, "cmd": "python bench.py", "rc": 0,
-           "tail": "\n".join(json.dumps(ln) for ln in lines)}
-    with open(str(tmp_path / name), "w") as f:
-        json.dump(doc, f)
-
-
-def _run_trend(args):
-    import subprocess
-    import sys
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    script = os.path.join(root, "tests", "ci", "check_bench_trend.py")
-    return subprocess.run([sys.executable, script] + args,
-                          capture_output=True, text=True)
-
-
-def test_check_bench_trend_gate(tmp_path):
-    """The trend gate (acceptance pin): exit 0 on the BENCH history at
-    the repo root, nonzero on a synthetic history where a fresh
-    accelerator metric regresses past tolerance — and a record marked
-    ``stale: true`` is partitioned out of the trend."""
-    r = _run_trend([])
     assert r.returncode == 0, r.stderr
-    assert "stale replays partitioned out" in r.stderr
-
-    def tpu(value, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "resnet18_fwd_bwd_throughput", "value": value,
-             "unit": "images/sec/chip", "vs_baseline": None,
-             "backend": "tpu", "ndev": 1, "arch": "TPU v5 lite", **kw})
-
-    # fresh-vs-fresh accelerator regression past tolerance -> error
-    d3 = tmp_path / "case3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [tpu(1000.0)])
-    _trend_round(d3, "BENCH_r02.json", [tpu(600.0)])   # -40%
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 1 and "regressed" in r.stderr
-    # ...within tolerance passes
-    r = _run_trend(["--dir", str(d3), "--tol", "0.8"])
-    assert r.returncode == 0
-    # change is relative to the PREVIOUS value in both directions: a
-    # 21% rate drop is under the 25% default tol and must not gate
-    d3b = tmp_path / "case3b"
-    d3b.mkdir()
-    _trend_round(d3b, "BENCH_r01.json", [tpu(1000.0)])
-    _trend_round(d3b, "BENCH_r02.json", [tpu(790.0)])  # -21%
-    r = _run_trend(["--dir", str(d3b)])
-    assert r.returncode == 0, r.stderr
-
-    # a record marked stale: partitioned out, clean — and it must NOT
-    # count as progress (no fresh line to compare)
-    d4 = tmp_path / "case4"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json", [tpu(500.0)])
-    _trend_round(d4, "BENCH_r02.json", [tpu(1830.0, stale=True)])
-    r = _run_trend(["--dir", str(d4)])
-    assert r.returncode == 0
-    assert "1 stale replays partitioned out" in r.stderr
-
-    # CPU smoke regressions warn but do not gate... unless --strict-cpu
-    d5 = tmp_path / "case5"
-    d5.mkdir()
-
-    def cpu(value):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "fused_lamb_step_time", "value": value,
-             "unit": "ms", "vs_baseline": None, "backend": "cpu",
-             "ndev": 8, "arch": "cpu"})
-    _trend_round(d5, "BENCH_r01.json", [cpu(10.0)])
-    _trend_round(d5, "BENCH_r02.json", [cpu(47.0)])
-    r = _run_trend(["--dir", str(d5)])
-    assert r.returncode == 0 and "WARNING" in r.stderr
-    r = _run_trend(["--dir", str(d5), "--strict-cpu"])
-    assert r.returncode == 1
-
-
-def test_check_bench_trend_overlap_fields_gate(tmp_path):
-    """The PR 14 trend columns: a fresh accelerator line whose
-    overlap_fraction / measured_overlap_fraction DROPS past --tol (or
-    whose comm_visible_ms GROWS past it) gates; CPU smoke warns; and a
-    zero baseline — the reduce-after-backward world — never trends (no
-    overlap yet means nothing to lose)."""
-    def attr(backend, value, frac, visible):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "train_step_attribution_overlap",
-             "value": value, "unit": "ms", "vs_baseline": None,
-             "backend": backend, "ndev": 8,
-             "arch": "TPU v5 lite" if backend == "tpu" else "cpu",
-             "overlap_fraction": frac, "comm_visible_ms": visible,
-             "overlap_mode": "overlapped", "n_stages": 4,
-             "issue_order": [3, 2, 1, 0]})
-
-    # accelerator overlap_fraction drop past tol -> error
-    d1 = tmp_path / "ovl1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json", [attr("tpu", 10.0, 0.8, 1.0)])
-    _trend_round(d1, "BENCH_r02.json", [attr("tpu", 10.1, 0.3, 1.0)])
-    r = _run_trend(["--dir", str(d1)])
-    assert r.returncode == 1
-    assert "overlap_fraction dropped" in r.stderr
-    # ...within tolerance passes
-    r = _run_trend(["--dir", str(d1), "--tol", "0.7"])
-    assert r.returncode == 0, r.stderr
-
-    # accelerator comm_visible_ms growth past tol -> error
-    d2 = tmp_path / "ovl2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [attr("tpu", 10.0, 0.8, 1.0)])
-    _trend_round(d2, "BENCH_r02.json", [attr("tpu", 10.1, 0.8, 2.0)])
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 1
-    assert "comm_visible_ms grew" in r.stderr
-
-    # CPU smoke: warns only, unless --strict-cpu
-    d3 = tmp_path / "ovl3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [attr("cpu", 10.0, 0.8, 1.0)])
-    _trend_round(d3, "BENCH_r02.json", [attr("cpu", 10.1, 0.3, 1.0)])
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 0 and "WARNING" in r.stderr
-    r = _run_trend(["--dir", str(d3), "--strict-cpu"])
-    assert r.returncode == 1
-
-    # zero baseline never trends: 0.0 -> 0.0 is today's world, and a
-    # fraction appearing off zero is progress, not regression
-    d4 = tmp_path / "ovl4"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json", [attr("tpu", 10.0, 0.0, 1.0)])
-    _trend_round(d4, "BENCH_r02.json", [attr("tpu", 10.1, 0.0, 1.0)])
-    _trend_round(d4, "BENCH_r03.json", [attr("tpu", 10.0, 0.6, 1.0)])
-    r = _run_trend(["--dir", str(d4)])
-    assert r.returncode == 0, r.stderr
-
-    # ...but a LOWER-is-better time at 0 is the success state: comm
-    # returning from fully hidden to measurably visible is the worst
-    # regression the column exists for — gates even from a zero
-    # baseline (rounding-noise returns under 0.05 ms do not)
-    d4b = tmp_path / "ovl4b"
-    d4b.mkdir()
-    _trend_round(d4b, "BENCH_r01.json", [attr("tpu", 10.0, 0.9, 0.0)])
-    _trend_round(d4b, "BENCH_r02.json", [attr("tpu", 10.1, 0.9, 4.0)])
-    r = _run_trend(["--dir", str(d4b)])
-    assert r.returncode == 1
-    assert "returned from a zero baseline" in r.stderr
-    d4c = tmp_path / "ovl4c"
-    d4c.mkdir()
-    _trend_round(d4c, "BENCH_r01.json", [attr("tpu", 10.0, 0.9, 0.0)])
-    _trend_round(d4c, "BENCH_r02.json", [attr("tpu", 10.1, 0.9, 0.01)])
-    r = _run_trend(["--dir", str(d4c)])
-    assert r.returncode == 0, r.stderr
-
-    # measured_overlap_fraction (profile metric lines) follows the
-    # same policy
-    def prof(value, frac):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "comm_profile_overlap_comm_visible_ms",
-             "value": value, "unit": "ms", "vs_baseline": None,
-             "backend": "tpu", "ndev": 8, "arch": "TPU v5 lite",
-             "measured_overlap_fraction": frac})
-    d5 = tmp_path / "ovl5"
-    d5.mkdir()
-    _trend_round(d5, "BENCH_r01.json", [prof(1.0, 0.9)])
-    _trend_round(d5, "BENCH_r02.json", [prof(1.05, 0.2)])
-    r = _run_trend(["--dir", str(d5)])
-    assert r.returncode == 1
-    assert "measured_overlap_fraction dropped" in r.stderr
-
-
-def test_check_bench_trend_memory_and_mfu_gate(tmp_path):
-    """The PR 8 trend columns: peak-memory growth past --mem-tol gates
-    on EVERY backend (the compiled plan is deterministic — CPU noise
-    is no excuse), stale replays stay partitioned out, kind: memory
-    records trend by entry point, and MFU drops follow the same
-    accelerator-gates / CPU-warns policy as throughput."""
-
-    def train(value, peak, mfu=None, backend="cpu", **kw):
-        rec = {"metric": "resnet18_train_throughput", "value": value,
-               "unit": "images/sec/chip", "vs_baseline": None,
-               "backend": backend, "ndev": 8, "arch": backend,
-               "peak_bytes": peak}
-        if mfu is not None:
-            rec["mfu"] = mfu
-        return exporters.JsonlExporter.enrich({**rec, **kw})
-
-    # peak-memory regression on a CPU backend: throughput noise warns,
-    # but the 40% plan growth is an error
-    d1 = tmp_path / "mem1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json", [train(100.0, 1_000_000)])
-    _trend_round(d1, "BENCH_r02.json", [train(101.0, 1_400_000)])
-    r = _run_trend(["--dir", str(d1)])
-    assert r.returncode == 1
-    assert "peak memory grew 40%" in r.stderr
-    # ...within a loosened --mem-tol it passes
-    r = _run_trend(["--dir", str(d1), "--mem-tol", "0.5"])
-    assert r.returncode == 0, r.stderr
-
-    # a stale replay carrying a bigger peak is partitioned out
-    d2 = tmp_path / "mem2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [train(100.0, 1_000_000)])
-    _trend_round(d2, "BENCH_r02.json",
-                 [train(100.0, 9_000_000, stale=True)])
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 0, r.stderr
-
-    # kind: memory records trend by entry point
-    def memrec(peak):
-        return exporters.JsonlExporter.enrich(
-            {"kind": "memory", "entry_point": "engine_step_k",
-             "source": "compiled", "flops": 1e6, "backend": "cpu",
-             "peak_bytes": peak})
-
-    d3 = tmp_path / "mem3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [memrec(1_000_000)])
-    _trend_round(d3, "BENCH_r02.json", [memrec(1_500_000)])
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 1 and "engine_step_k" in r.stderr
-    # identical plans across rounds are the normal case: clean
-    d3b = tmp_path / "mem3b"
-    d3b.mkdir()
-    _trend_round(d3b, "BENCH_r01.json", [memrec(1_000_000)])
-    _trend_round(d3b, "BENCH_r02.json", [memrec(1_000_000)])
-    assert _run_trend(["--dir", str(d3b)]).returncode == 0
-
-    # MFU: accelerator drop past tol gates, CPU drop warns
-    d4 = tmp_path / "mfu1"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json",
-                 [train(1000.0, 1_000_000, mfu=0.20, backend="tpu",
-                        arch="TPU v5 lite")])
-    _trend_round(d4, "BENCH_r02.json",
-                 [train(990.0, 1_000_000, mfu=0.10, backend="tpu",
-                        arch="TPU v5 lite")])
-    r = _run_trend(["--dir", str(d4)])
-    assert r.returncode == 1 and "MFU regressed" in r.stderr
-    d5 = tmp_path / "mfu2"
-    d5.mkdir()
-    _trend_round(d5, "BENCH_r01.json", [train(100.0, 1_000_000,
-                                              mfu=0.02)])
-    _trend_round(d5, "BENCH_r02.json", [train(99.0, 1_000_000,
-                                              mfu=0.01)])
-    r = _run_trend(["--dir", str(d5)])
-    assert r.returncode == 0 and "MFU regressed" in r.stderr \
-        and "WARNING" in r.stderr
-    assert _run_trend(["--dir", str(d5), "--strict-cpu"]).returncode == 1
-
-
-def test_check_bench_trend_zero_peak_memory_ratchet(tmp_path):
-    """The ZeRO memory ratchet on the --comm zero legs: a stage
-    landing DROPS the leg's compiled peak_bytes and the trend accepts
-    the new floor without ceremony; the next round regressing back
-    toward the unsharded peak gates at --mem-tol on EVERY backend —
-    the compiled plan is deterministic, so CPU noise is no excuse
-    (same policy as the replication-ledger gate)."""
-
-    def zleg(peak, stage=3):
-        return exporters.JsonlExporter.enrich(
-            {"metric": f"ddp_mlp_zero{stage}_train_throughput",
-             "value": 5000.0, "unit": "samples/sec/chip",
-             "vs_baseline": None, "backend": "cpu", "ndev": 8,
-             "arch": "cpu", "peak_bytes": peak, "zero_stage": stage,
-             "flops_per_step": 1e6, "achieved_tflops": 0.001,
-             "mfu": None, "cold_compile_ms": 10.0,
-             "compiles_total": 1, "steady_state_retraces": 0})
-
-    # ratchet DOWN: the stage-3 peak collapse vs last round is clean
-    d1 = tmp_path / "zmem1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json", [zleg(151_000_000)])
-    _trend_round(d1, "BENCH_r02.json", [zleg(128_000_000)])
-    r = _run_trend(["--dir", str(d1), "--mem-tol", "0.05"])
-    assert r.returncode == 0, r.stderr
-
-    # ...and the ratcheted-down floor HOLDS: regressing back up past
-    # --mem-tol gates, even on the CPU backend
-    d2 = tmp_path / "zmem2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [zleg(128_000_000)])
-    _trend_round(d2, "BENCH_r02.json", [zleg(145_000_000)])  # +13%
-    r = _run_trend(["--dir", str(d2), "--mem-tol", "0.1"])
-    assert r.returncode == 1
-    assert "peak memory grew" in r.stderr
-    # the same growth inside a loosened tolerance passes
-    r = _run_trend(["--dir", str(d2), "--mem-tol", "0.25"])
-    assert r.returncode == 0, r.stderr
-
-
-def test_check_bench_trend_partitions_numerics_records(tmp_path):
-    """kind: numerics gradient-health dumps (PR 9) are per-run
-    diagnostics, not a cross-round trend: fresh ones pass through
-    without entering the measurement trend, stale replays count
-    toward the partition tally like every other record family."""
-    def numrec(overflow, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"kind": "numerics", "metric": "resnet18_o2_ddp_numerics",
-             "steps": 10, "overflow_steps": overflow,
-             "backend": "cpu",
-             "layers": [{"name": "w", "nonfinite": 0, "abs_max": 1.0,
-                         "grad_norm": 1.0,
-                         "underflow_fraction": 0.0}], **kw})
-
-    d = tmp_path / "num1"
-    d.mkdir()
-    _trend_round(d, "BENCH_r01.json", [numrec(0)])
-    # a later round with MORE overflows must not read as a metric
-    # regression — numerics records carry no trend value
-    _trend_round(d, "BENCH_r02.json", [numrec(5),
-                                       numrec(0, stale=True)])
-    r = _run_trend(["--dir", str(d)])
-    assert r.returncode == 0, r.stderr
-    assert "0 fresh measurements counted" in r.stderr
-    assert "1 stale replays partitioned out" in r.stderr
 
 
 # -- engine telemetry -----------------------------------------------------
@@ -1830,143 +1250,44 @@ def test_validate_run_record_edges():
     assert exporters.validate_run_record(rec(duration_s=-2))
 
 
-def test_check_bench_trend_partitions_run_records(tmp_path):
-    """kind: run supervisor verdicts are per-run diagnostics, not a
-    cross-round trend: a later round's anomalous run must not read as
-    a regression, stale replays count toward the partition tally —
-    while the run_supervisor_overhead METRIC lines do trend."""
-    def runrec(n_nan, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"kind": "run", "run": "resnet18_o2_ddp",
-             "verdict": "attention" if n_nan else "ok",
-             "observations": 10, "watermark": 9,
-             "anomaly_counts": {"nan": n_nan}, "anomalies": [],
-             "backend": "cpu", **kw})
-
-    d = tmp_path / "run1"
-    d.mkdir()
-    _trend_round(d, "BENCH_r01.json", [runrec(0)])
-    _trend_round(d, "BENCH_r02.json", [runrec(5),
-                                       runrec(0, stale=True)])
-    r = _run_trend(["--dir", str(d)])
-    assert r.returncode == 0, r.stderr
-    assert "1 stale replays partitioned out" in r.stderr
-
-    # the overhead metric lines DO trend (tpu backend gates)
-    def ov(value, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "run_supervisor_overhead_o2", "value": value,
-             "unit": "ms", "vs_baseline": None, "backend": "tpu",
-             "ndev": 1, "arch": "TPU v5 lite",
-             "step_ms_on": 10.0 + value, "step_ms_off": 10.0, **kw})
-
-    d2 = tmp_path / "run2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [ov(1.0)])
-    _trend_round(d2, "BENCH_r02.json", [ov(2.0)])   # 100% worse (ms)
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 1
-    assert "regressed" in r.stderr
+_RECOVERY_BASE = {"kind": "recovery", "role": "training",
+                  "subject": "run", "episodes": 0, "actions_total": 0,
+                  "max_actions_in_episode": 0, "actions": [],
+                  "mttr_s": {"last": None, "mean": None, "count": 0},
+                  "in_flight": False, "duration_s": 1.0}
 
 
-def test_v5_requirements_gate_on_declared_version():
-    """Schema v5's run_supervisor_overhead both-sides requirement (and
-    the run-record family itself) gate on the record's DECLARED
-    schema_version — archived v4-and-earlier streams re-validate
-    clean."""
-    line = {"metric": "run_supervisor_overhead_o2", "value": 1.0,
-            "unit": "ms", "vs_baseline": None, "backend": "cpu",
-            "ndev": 8, "arch": "cpu"}
-    # fresh v5 line WITHOUT the on/off pair: error
-    v5 = exporters.JsonlExporter.enrich(dict(line))
-    assert v5["schema_version"] >= 5
-    errs = exporters.validate_bench_record(v5)
-    assert any("step_ms_on" in e for e in errs)
-    # the same line declaring v4 (an archived pre-supervisor stream):
-    # clean — v4 never defined the metric, so no requirement applies
-    v4 = exporters.JsonlExporter.enrich(
-        {**line, "schema_version": 4})
-    assert exporters.validate_bench_record(v4) == []
-    # and the complete v5 line is clean
-    full = exporters.JsonlExporter.enrich(
-        {**line, "step_ms_on": 11.0, "step_ms_off": 10.0})
-    assert exporters.validate_bench_record(full) == []
-    # v4 numerics_overhead contract unchanged by the bump
-    num = exporters.JsonlExporter.enrich(
-        {"metric": "numerics_overhead_o2", "value": 1.0, "unit": "ms",
-         "vs_baseline": None, "backend": "cpu", "ndev": 8,
-         "arch": "cpu", "schema_version": 4})
-    assert any("step_ms_on" in e
-               for e in exporters.validate_bench_record(num))
-
-
-def test_v7_requirements_gate_on_declared_version():
-    """Schema v7: fresh chaos_preempt* lines must carry the resume
-    they measured (mttr_s / resume_overhead_s / resumed_step);
-    recovery records validate cause/preempted/data_state whenever
-    present.  Archived v6-and-earlier streams re-validate clean."""
-    line = {"metric": "chaos_preempt_resume", "value": 0.01,
-            "unit": "s", "vs_baseline": None, "backend": "cpu",
-            "ndev": 1, "arch": "cpu"}
-    v7 = exporters.JsonlExporter.enrich(dict(line))
-    assert v7["schema_version"] >= 7
-    errs = exporters.validate_bench_record(v7)
-    assert any("mttr_s" in e for e in errs)
-    assert any("resumed_step" in e for e in errs)
-    # the same line declaring v6 (an archived pre-preemption stream):
-    # clean — v6 never defined the metric
-    v6 = exporters.JsonlExporter.enrich({**line, "schema_version": 6})
-    assert exporters.validate_bench_record(v6) == []
-    # and the complete v7 line is clean
-    full = exporters.JsonlExporter.enrich(
-        {**line, "mttr_s": 0.02, "resume_overhead_s": 0.01,
-         "resumed_step": 7})
-    assert exporters.validate_bench_record(full) == []
-
-    # recovery-record preemption fields, validated whenever present
-    base = {"kind": "recovery", "role": "training", "subject": "run",
-            "episodes": 0, "actions_total": 0,
-            "max_actions_in_episode": 0, "actions": [],
-            "mttr_s": {"last": None, "mean": None, "count": 0},
-            "in_flight": False, "duration_s": 1.0}
-    ok = exporters.JsonlExporter.enrich(
-        {**base, "cause": "preemption", "preempted": True,
-         "data_state": {"samples_consumed": 80, "epoch": 1,
-                        "cursor": 16, "shard_id": 0,
-                        "num_shards": 4}})
-    assert exporters.validate_recovery_record(ok) == []
-    bad_cause = exporters.JsonlExporter.enrich(
-        {**base, "cause": "cosmic_rays"})
-    assert any("cause" in e for e in
-               exporters.validate_recovery_record(bad_cause))
-    bad_ds = exporters.JsonlExporter.enrich(
-        {**base, "data_state": {"samples_consumed": -1}})
-    assert any("samples_consumed" in e for e in
-               exporters.validate_recovery_record(bad_ds))
-    bad_shard = exporters.JsonlExporter.enrich(
-        {**base, "data_state": {"shard_id": 5, "num_shards": 4}})
-    assert any("shard_id" in e for e in
-               exporters.validate_recovery_record(bad_shard))
-    bad_pre = exporters.JsonlExporter.enrich(
-        {**base, "preempted": "yes"})
-    assert any("preempted" in e for e in
-               exporters.validate_recovery_record(bad_pre))
-    # the new action kind is known to the validator
-    act = exporters.JsonlExporter.enrich(
-        {**base, "episodes": 1, "actions_total": 1,
-         "max_actions_in_episode": 1,
-         "actions": [{"kind": "preempt_snapshot", "episode": 1,
-                      "t_s": 0.5}]})
-    assert exporters.validate_recovery_record(act) == []
+@pytest.mark.parametrize("extra, named", [
+    ({"cause": "preemption", "preempted": True,
+      "data_state": {"samples_consumed": 80, "epoch": 1, "cursor": 16,
+                     "shard_id": 0, "num_shards": 4}}, None),
+    # the v7 action kind is known to the validator
+    ({"episodes": 1, "actions_total": 1, "max_actions_in_episode": 1,
+      "actions": [{"kind": "preempt_snapshot", "episode": 1,
+                   "t_s": 0.5}]}, None),
+    ({"cause": "cosmic_rays"}, "cause"),
+    ({"data_state": {"samples_consumed": -1}}, "samples_consumed"),
+    ({"data_state": {"shard_id": 5, "num_shards": 4}}, "shard_id"),
+    ({"preempted": "yes"}, "preempted"),
+], ids=["preempted_ok", "preempt_snapshot_ok", "bad_cause",
+        "negative_samples", "shard_out_of_range", "preempted_not_bool"])
+def test_v7_requirements_gate_on_declared_version(extra, named):
+    """Schema v7: recovery records validate cause / preempted /
+    data_state whenever present, and know the ``preempt_snapshot``
+    action kind."""
+    rec = exporters.JsonlExporter.enrich({**_RECOVERY_BASE, **extra})
+    assert rec["schema_version"] >= 7
+    errs = exporters.validate_recovery_record(rec)
+    if named is None:
+        assert errs == []
+    else:
+        assert any(named in e for e in errs), errs
 
 
 def test_v8_profile_records_and_version_gating():
     """Schema v8: ``kind: profile`` records dispatch to their own
-    validator, and the engine-decode kv-fragmentation requirement
-    gates on the DECLARED version — archived v7-and-earlier streams
-    re-validate clean (the full archived-stream sweep rides
-    test_check_bench_trend_gate's real BENCH_r*.json files through
-    check_bench_schema)."""
+    validator, alone and in a mixed stream; the KV fragmentation
+    fields a serving profile may carry are value-checked."""
     prof = exporters.JsonlExporter.enrich(
         {"kind": "profile", "metric": "resnet18_o2_ddp_flat_profile",
          "span_ms": 10.0, "device_busy_ms": 8.0, "compute_ms": 7.0,
@@ -1977,462 +1298,50 @@ def test_v8_profile_records_and_version_gating():
                           "count": 24, "total_ms": 3.0}]})
     assert prof["schema_version"] >= 8
     assert exporters.validate_profile_record(prof) == []
-    # the dispatcher routes on kind — the same record through the
-    # telemetry validator hits the profile schema, not the bench one
+    # the dispatcher routes on kind
     assert exporters.validate_telemetry_record(prof) == []
     broken = dict(prof, device_busy_ms=99.0)
     assert exporters.validate_telemetry_record(broken) != []
-    # a mixed stream with a profile line stays check_bench_schema clean
-    bench_line = exporters.JsonlExporter.enrich(
-        {"metric": "m", "value": 1.0, "unit": "x", "vs_baseline": None,
-         "backend": "cpu", "ndev": 8, "arch": "cpu"})
+    # a mixed stream with a profile line stays clean; a line without a
+    # kind in it does not
+    lint = exporters.JsonlExporter.enrich(
+        {"kind": "graph_lint", "rule": "donation", "severity": "error",
+         "entry_point": "e", "message": "m"})
     assert exporters.validate_telemetry_jsonl(
-        [json.dumps(prof), json.dumps(bench_line)]) == []
-
-
-def test_check_bench_trend_partitions_profile_records(tmp_path):
-    """kind: profile device-timeline attributions are per-capture
-    stories, not a cross-round trend: a later round's worse split
-    must not read as a metric regression, and stale replays count
-    toward the partition tally (the numerics/run/recovery rule)."""
-    def profrec(busy, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"kind": "profile", "metric": "resnet18_o2_ddp_profile",
-             "backend": "cpu", "span_ms": busy + 1.0,
-             "device_busy_ms": busy, "compute_ms": busy,
-             "collective_ms": 0.0, "gap_ms": 1.0, "overlap_ms": 0.0,
-             "measured_overlap_fraction": 0.0, **kw})
-
-    d = tmp_path / "prof1"
-    d.mkdir()
-    _trend_round(d, "BENCH_r01.json", [profrec(5.0)])
-    _trend_round(d, "BENCH_r02.json", [profrec(50.0),
-                                       profrec(5.0, stale=True)])
-    r = _run_trend(["--dir", str(d)])
-    assert r.returncode == 0, r.stderr
-    assert "0 fresh measurements counted" in r.stderr
-    assert "1 stale replays partitioned out" in r.stderr
+        [json.dumps(prof), json.dumps(lint)]) == []
+    no_kind = exporters.JsonlExporter.enrich(
+        {"metric": "m", "value": 1.0, "unit": "x"})
+    errs = exporters.validate_telemetry_jsonl(
+        [json.dumps(prof), json.dumps(no_kind)])
+    assert len(errs) == 1 and "line 2" in errs[0] and "kind" in errs[0]
+    # KV fragmentation fields on a serving profile (_check_kv_fields)
+    kv = dict(prof, kv_cache_bytes=16384, kv_waste_bytes=4096,
+              kv_utilization=0.75)
+    assert exporters.validate_profile_record(kv) == []
+    for key, bad in (("kv_cache_bytes", -5),
+                     ("kv_waste_bytes", 999_999),     # > the allocation
+                     ("kv_utilization", 1.2)):
+        assert any(key in e for e in exporters.validate_profile_record(
+            dict(kv, **{key: bad}))), key
 
 
 # -- PR 15: the compilation plane ------------------------------------------
 
-def test_v10_compile_fields_and_version_gating():
-    """Schema v10 (the compilation plane): fresh train-throughput and
-    engine-decode lines must carry the compile-plane triple
-    (cold_compile_ms / compiles_total / steady_state_retraces); the
-    fields are value-checked wherever they appear; archived v1-v9
-    streams re-validate clean at their declared versions."""
-    assert exporters.SCHEMA_VERSION >= 10
-    base = {"metric": "resnet18_o2_train_throughput", "value": 100.0,
-            "unit": "images/sec/chip", "vs_baseline": None,
-            "backend": "tpu", "ndev": 1, "arch": "TPU v5 lite",
-            "flops_per_step": 1e12, "achieved_tflops": 10.0,
-            "mfu": 0.1, "peak_bytes": 1_000_000,
-            "cold_compile_ms": 1234.5, "compiles_total": 1,
-            "steady_state_retraces": 0}
-    assert exporters.validate_bench_record(
-        exporters.JsonlExporter.enrich(dict(base))) == []
-    # fresh v10 train line missing any of the triple flags
-    for key in exporters.COMPILE_FIELDS:
-        rec = exporters.JsonlExporter.enrich(
-            {k: v for k, v in base.items() if k != key})
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), key
-    # ...but the same line DECLARING v9 (an archived stream) is valid
-    v9 = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items()
-         if k not in exporters.COMPILE_FIELDS})
-    v9["schema_version"] = 9
-    assert exporters.validate_bench_record(v9) == []
-    # stale replays and error lines stay exempt
-    stale = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items()
-         if k not in exporters.COMPILE_FIELDS}, stale=True)
-    assert exporters.validate_bench_record(stale) == []
-    err = exporters.JsonlExporter.enrich(
-        {"metric": "resnet18_o2_train_throughput", "value": None,
-         "unit": None, "vs_baseline": None, "backend": "tpu",
-         "ndev": 1, "arch": "TPU v5 lite", "error": "hung"})
-    assert exporters.validate_bench_record(err) == []
-    # field VALUES are checked wherever the fields appear (any metric)
-    plain = {"metric": "m", "value": 1.0, "unit": "x",
-             "vs_baseline": None, "backend": "cpu", "ndev": 8,
-             "arch": "cpu"}
-    for key, bad in (("cold_compile_ms", -1.0),
-                     ("cold_compile_ms", "slow"),
-                     ("compiles_total", -1),
-                     ("compiles_total", 1.5),
-                     ("compiles_total", True),
-                     ("steady_state_retraces", -2),
-                     ("steady_state_retraces", "none")):
-        rec = exporters.JsonlExporter.enrich(dict(plain, **{key: bad}))
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), \
-            (key, bad)
-    # a nonzero steady-state retrace count is schema-VALID (the record
-    # is honest about it) — gating it is the trend checker's job
-    assert exporters.validate_bench_record(
-        exporters.JsonlExporter.enrich(
-            dict(plain, steady_state_retraces=3))) == []
-
-
-def test_compile_fields_pinned_to_compilation_module():
-    """exporters.COMPILE_FIELDS is the stdlib-side duplicate of
-    compilation.BENCH_COMPILE_FIELDS (both modules must stay
-    importable without jax) — pinned equal so the two cannot drift."""
-    from apex_tpu.observability import compilation
-    assert exporters.COMPILE_FIELDS == compilation.BENCH_COMPILE_FIELDS
-
-
-def test_check_bench_trend_compile_gate(tmp_path):
-    """The compile-plane trend gates: a fresh line with a nonzero
-    steady_state_retraces errors on EVERY backend (the ledger count is
-    deterministic — the timed loop included a recompile), and
-    cold_compile_ms growth past --tol gates on accelerators / warns on
-    CPU smoke like every timing-derived column."""
-    def line(backend, value, cold_ms, retraces=0):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "gpt_tiny_engine_decode_throughput",
-             "value": value, "unit": "tokens/sec/chip",
-             "vs_baseline": None, "backend": backend, "ndev": 8,
-             "arch": "TPU v5 lite" if backend == "tpu" else "cpu",
-             "window": 8, "tokens_per_sync": 7.5,
-             "admission_mode": "fixed_slot",
-             "kv_cache_bytes": 16384, "kv_waste_bytes": 4096,
-             "kv_utilization": 0.75,
-             "cold_compile_ms": cold_ms, "compiles_total": 2,
-             "steady_state_retraces": retraces})
-
-    # nonzero steady-state retraces: error even on CPU smoke
-    d1 = tmp_path / "comp1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json", [line("cpu", 100.0, 300.0,
-                                             retraces=2)])
-    r = _run_trend(["--dir", str(d1)])
-    assert r.returncode == 1
-    assert "steady-state retrace" in r.stderr
-    # accelerator cold_compile_ms growth past tol: error
-    d2 = tmp_path / "comp2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [line("tpu", 100.0, 1000.0)])
-    _trend_round(d2, "BENCH_r02.json", [line("tpu", 100.0, 2000.0)])
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 1
-    assert "cold_compile_ms" in r.stderr
-    # the same growth on CPU smoke: warning only (strict-cpu gates)
-    d3 = tmp_path / "comp3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [line("cpu", 100.0, 1000.0)])
-    _trend_round(d3, "BENCH_r02.json", [line("cpu", 100.0, 2000.0)])
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 0 and "cold_compile_ms" in r.stderr
-    r = _run_trend(["--dir", str(d3), "--strict-cpu"])
-    assert r.returncode == 1
-    # growth inside tol, zero retraces: clean
-    d4 = tmp_path / "comp4"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json", [line("tpu", 100.0, 1000.0)])
-    _trend_round(d4, "BENCH_r02.json", [line("tpu", 101.0, 1100.0)])
-    r = _run_trend(["--dir", str(d4)])
-    assert r.returncode == 0, r.stderr
-    # a STALE replay carrying old compile fields never trends
-    d5 = tmp_path / "comp5"
-    d5.mkdir()
-    _trend_round(d5, "BENCH_r01.json", [line("tpu", 100.0, 1000.0)])
-    _trend_round(d5, "BENCH_r02.json",
-                 [dict(line("tpu", 100.0, 9000.0, retraces=5),
-                       stale=True)])
-    r = _run_trend(["--dir", str(d5)])
-    assert r.returncode == 0, r.stderr
-
 
 def test_v11_tenant_fields_and_version_gating():
-    """Schema v11 (the tenant plane): fresh per-tenant goodput lines
-    must carry ``tenant`` + ``slo_attainment``, the parity line its
-    token counts (arithmetically consistent); archived v10 streams
-    re-validate clean at their declared version; TENANT_COUNTS is
-    pinned to the SLO tracker's actual bucket keys so the validator
-    and the producer cannot drift."""
+    """Schema v11 (the tenant plane): TENANT_COUNTS is pinned to the
+    SLO tracker's actual bucket keys so the fleet-record validator and
+    the producer cannot drift."""
     assert exporters.SCHEMA_VERSION >= 11
     from apex_tpu.fleet import slo as fleet_slo
     assert exporters.TENANT_COUNTS == tuple(
         k for k in fleet_slo._new_tenant_bucket()
         if k not in ("t_first", "t_last", "tenant"))
 
-    tline = {"metric": "gpt_tiny_fleet2_tenant_interactive_goodput",
-             "value": 42.0, "unit": "tokens/sec", "vs_baseline": None,
-             "backend": "cpu", "ndev": 1, "arch": "cpu",
-             "tenant": "interactive", "slo_attainment": 1.0}
-    assert exporters.validate_bench_record(
-        exporters.JsonlExporter.enrich(dict(tline))) == []
-    # fresh v11 tenant-goodput line missing either required field
-    for key in ("tenant", "slo_attainment"):
-        rec = exporters.JsonlExporter.enrich(
-            {k: v for k, v in tline.items() if k != key})
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), key
-    # ...but the same line DECLARING v10 (archived) is valid
-    v10 = exporters.JsonlExporter.enrich(
-        {k: v for k, v in tline.items()
-         if k not in ("tenant", "slo_attainment")})
-    v10["schema_version"] = 10
-    assert exporters.validate_bench_record(v10) == []
-    # null attainment (no deadlined request resolved) is valid
-    assert exporters.validate_bench_record(exporters.JsonlExporter
-        .enrich(dict(tline, slo_attainment=None))) == []
-    # field VALUES checked wherever they appear
-    for key, bad in (("slo_attainment", 1.5),
-                     ("slo_attainment", -0.1),
-                     ("tenant", ""), ("tenant", 7)):
-        rec = exporters.JsonlExporter.enrich(dict(tline, **{key: bad}))
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), \
-            (key, bad)
-
-    pline = {"metric": "gpt_tiny_fleet2_tenant_parity", "value": 1.0,
-             "unit": "ratio", "vs_baseline": None, "backend": "cpu",
-             "ndev": 1, "arch": "cpu",
-             "tenants_goodput_tokens": 120, "tokens_within_slo": 120}
-    assert exporters.validate_bench_record(
-        exporters.JsonlExporter.enrich(dict(pline))) == []
-    # the ratio must reassemble from its own counts
-    assert any("tenants_goodput_tokens" in e or "reassemble" in e
-               for e in exporters.validate_bench_record(
-                   exporters.JsonlExporter.enrich(
-                       dict(pline, value=0.9))))
-    # fresh v11 parity line missing its counts
-    for key in ("tenants_goodput_tokens", "tokens_within_slo"):
-        rec = exporters.JsonlExporter.enrich(
-            {k: v for k, v in pline.items() if k != key})
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), key
-    # archived v10 parity-free streams unaffected; stale exempt
-    stale = exporters.JsonlExporter.enrich(
-        {k: v for k, v in pline.items()
-         if k not in ("tenants_goodput_tokens", "tokens_within_slo")},
-        stale=True)
-    assert exporters.validate_bench_record(stale) == []
-
-
-def test_check_bench_trend_tenant_gate(tmp_path):
-    """The tenant-plane trend gates: a fresh parity line off 1.0 by
-    more than 1% errors on EVERY backend (exact token accounting — the
-    leg tags every request), while a per-tenant slo_attainment drop
-    past --tol follows the accelerator-gates / CPU-warns policy like
-    every timing-derived column; stale replays never trend."""
-    def tline(backend, attain):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "gpt_tiny_fleet2_tenant_interactive_goodput",
-             "value": 50.0, "unit": "tokens/sec", "vs_baseline": None,
-             "backend": backend, "ndev": 1,
-             "arch": "TPU v5 lite" if backend == "tpu" else "cpu",
-             "tenant": "interactive", "slo_attainment": attain})
-
-    def parity(backend, value, tg, tw):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "gpt_tiny_fleet2_tenant_parity", "value": value,
-             "unit": "ratio", "vs_baseline": None, "backend": backend,
-             "ndev": 1,
-             "arch": "TPU v5 lite" if backend == "tpu" else "cpu",
-             "tenants_goodput_tokens": tg, "tokens_within_slo": tw})
-
-    # parity off 1.0: error even on CPU smoke, first round
-    d1 = tmp_path / "ten1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json", [parity("cpu", 0.9, 90, 100)])
-    r = _run_trend(["--dir", str(d1)])
-    assert r.returncode == 1
-    assert "parity" in r.stderr
-    # accelerator attainment drop past tol: error
-    d2 = tmp_path / "ten2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [tline("tpu", 1.0)])
-    _trend_round(d2, "BENCH_r02.json", [tline("tpu", 0.5)])
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 1
-    assert "slo_attainment" in r.stderr
-    # same drop on CPU smoke: warning only (strict-cpu gates)
-    d3 = tmp_path / "ten3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [tline("cpu", 1.0)])
-    _trend_round(d3, "BENCH_r02.json", [tline("cpu", 0.5)])
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 0 and "slo_attainment" in r.stderr
-    r = _run_trend(["--dir", str(d3), "--strict-cpu"])
-    assert r.returncode == 1
-    # steady attainment + exact parity: clean
-    d4 = tmp_path / "ten4"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json",
-                 [tline("tpu", 1.0), parity("tpu", 1.0, 100, 100)])
-    _trend_round(d4, "BENCH_r02.json",
-                 [tline("tpu", 1.0), parity("tpu", 1.0, 120, 120)])
-    r = _run_trend(["--dir", str(d4)])
-    assert r.returncode == 0, r.stderr
-    # a STALE replay with broken parity / cratered attainment: ignored
-    d5 = tmp_path / "ten5"
-    d5.mkdir()
-    _trend_round(d5, "BENCH_r01.json", [tline("tpu", 1.0)])
-    _trend_round(d5, "BENCH_r02.json",
-                 [dict(tline("tpu", 0.1), stale=True),
-                  dict(parity("tpu", 0.5, 50, 100), stale=True)])
-    r = _run_trend(["--dir", str(d5)])
-    assert r.returncode == 0, r.stderr
-
-
-def test_v12_block_pool_fields_and_version_gating():
-    """Schema v12 (the paged serving plane): fresh engine-decode lines
-    must say which allocator produced them (``admission_mode``), paged
-    lines must expose the block pool, field VALUES are checked
-    wherever they appear, and archived v11 streams re-validate clean
-    at their declared version."""
-    assert exporters.SCHEMA_VERSION >= 12
-    assert exporters.ADMISSION_MODES == ("fixed_slot", "paged")
-    from apex_tpu import serving
-    assert serving.Engine.admission_mode in exporters.ADMISSION_MODES
-    assert serving.PagedEngine.admission_mode in exporters.ADMISSION_MODES
-
-    base = {"metric": "gpt_tiny_engine_decode_paged_throughput",
-            "value": 9.0, "unit": "tokens/sec/chip",
-            "vs_baseline": None, "backend": "cpu", "ndev": 8,
-            "arch": "cpu", "window": 8, "tokens_per_sync": 7.5,
-            "kv_cache_bytes": 16384, "kv_waste_bytes": 4096,
-            "kv_utilization": 0.75, "cold_compile_ms": 350.0,
-            "compiles_total": 2, "steady_state_retraces": 0,
-            "admission_mode": "paged", "block_size": 8,
-            "blocks_total": 16, "blocks_free": 5}
-    assert exporters.validate_bench_record(
-        exporters.JsonlExporter.enrich(dict(base))) == []
-    # fresh v12 engine line without admission_mode
-    rec = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items() if k != "admission_mode"})
-    assert any("admission_mode" in e
-               for e in exporters.validate_bench_record(rec))
-    # a fixed-slot line needs no block fields
-    fixed = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items()
-         if k not in ("block_size", "blocks_total", "blocks_free")}
-        | {"admission_mode": "fixed_slot"})
-    assert exporters.validate_bench_record(fixed) == []
-    # ...but a paged line missing any of them fails
-    for key in ("block_size", "blocks_total", "blocks_free"):
-        rec = exporters.JsonlExporter.enrich(
-            {k: v for k, v in base.items() if k != key})
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), key
-    # archived v11 stream without any of it: valid at its version
-    v11 = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items()
-         if k not in ("admission_mode", "block_size", "blocks_total",
-                      "blocks_free")})
-    v11["schema_version"] = 11
-    assert exporters.validate_bench_record(v11) == []
-    # field VALUES checked wherever they appear
-    for key, bad in (("admission_mode", "slab"), ("admission_mode", 3),
-                     ("block_size", 0), ("block_size", 8.5),
-                     ("blocks_total", -1), ("blocks_free", True)):
-        rec = exporters.JsonlExporter.enrich(dict(base, **{key: bad}))
-        assert any(key in e
-                   for e in exporters.validate_bench_record(rec)), \
-            (key, bad)
-    # blocks_free beyond the pool is an accounting bug
-    rec = exporters.JsonlExporter.enrich(dict(base, blocks_free=99))
-    assert any("blocks_free" in e
-               for e in exporters.validate_bench_record(rec))
-    # stale replay of a pre-paged record: exempt
-    stale = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items()
-         if k not in ("admission_mode", "block_size", "blocks_total",
-                      "blocks_free")}, stale=True)
-    assert exporters.validate_bench_record(stale) == []
-
-
-def test_check_bench_trend_kv_gate(tmp_path):
-    """The KV-plane trend gates: kv_waste_bytes growth past --tol
-    errors on accelerators / warns on CPU smoke (the sampled waste is
-    timing-adjacent), waste returning from a ZERO baseline gates like
-    comm coming back onto the critical path, waste dropping (the paged
-    engine's whole purpose) is clean, and the v12 field contract —
-    fresh engine lines must carry admission_mode — gates on every
-    backend while archived v11 rounds stay exempt."""
-    def kline(backend, waste, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "gpt_tiny_engine_decode_throughput",
-             "value": 100.0, "unit": "tokens/sec/chip",
-             "vs_baseline": None, "backend": backend, "ndev": 8,
-             "arch": "TPU v5 lite" if backend == "tpu" else "cpu",
-             "window": 8, "tokens_per_sync": 7.5,
-             "admission_mode": "fixed_slot",
-             "kv_cache_bytes": 16384, "kv_waste_bytes": waste,
-             "kv_utilization": 0.75, "cold_compile_ms": 300.0,
-             "compiles_total": 2, "steady_state_retraces": 0, **kw})
-
-    # accelerator waste growth past tol: error
-    d1 = tmp_path / "kv1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json", [kline("tpu", 4096)])
-    _trend_round(d1, "BENCH_r02.json", [kline("tpu", 9000)])
-    r = _run_trend(["--dir", str(d1)])
-    assert r.returncode == 1
-    assert "kv_waste_bytes" in r.stderr
-    # the same growth on CPU smoke: warning only (strict-cpu gates)
-    d2 = tmp_path / "kv2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [kline("cpu", 4096)])
-    _trend_round(d2, "BENCH_r02.json", [kline("cpu", 9000)])
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 0 and "kv_waste_bytes" in r.stderr
-    r = _run_trend(["--dir", str(d2), "--strict-cpu"])
-    assert r.returncode == 1
-    # waste DROPPING (the paged win) is clean
-    d3 = tmp_path / "kv3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [kline("tpu", 4096)])
-    _trend_round(d3, "BENCH_r02.json", [kline("tpu", 128)])
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 0, r.stderr
-    # waste returning from a zero baseline: the leak signature
-    d4 = tmp_path / "kv4"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json", [kline("tpu", 0)])
-    _trend_round(d4, "BENCH_r02.json", [kline("tpu", 2048)])
-    r = _run_trend(["--dir", str(d4)])
-    assert r.returncode == 1
-    assert "zero baseline" in r.stderr
-    # fresh v12 line without admission_mode: error on every backend
-    d5 = tmp_path / "kv5"
-    d5.mkdir()
-    noam = kline("cpu", 4096)
-    del noam["admission_mode"]
-    _trend_round(d5, "BENCH_r01.json", [noam])
-    r = _run_trend(["--dir", str(d5)])
-    assert r.returncode == 1
-    assert "admission_mode" in r.stderr
-    # a paged line missing its block fields: error
-    d6 = tmp_path / "kv6"
-    d6.mkdir()
-    _trend_round(d6, "BENCH_r01.json",
-                 [kline("cpu", 4096, admission_mode="paged")])
-    r = _run_trend(["--dir", str(d6)])
-    assert r.returncode == 1
-    assert "block" in r.stderr
-    # ...but an archived round DECLARING v11 is exempt, and a stale
-    # replay with cratered waste never trends
-    d7 = tmp_path / "kv7"
-    d7.mkdir()
-    old = kline("tpu", 4096)
-    del old["admission_mode"]
-    old["schema_version"] = 11
-    _trend_round(d7, "BENCH_r01.json", [old])
-    _trend_round(d7, "BENCH_r02.json",
-                 [dict(kline("tpu", 999999), stale=True)])
-    r = _run_trend(["--dir", str(d7)])
-    assert r.returncode == 0, r.stderr
-
 
 def _ledger_rec(entry_point="ddp_resnet18_o2", repl=7000, **kw):
-    """A schema-complete v13 replication-ledger record (what bench.py
-    --graph-lint and the --sharding CLI emit)."""
+    """A schema-complete v13 replication-ledger record (what the
+    --sharding CLI emits)."""
     arg = 1000
     return exporters.JsonlExporter.enrich({
         "kind": "sharding", "entry_point": entry_point,
@@ -2459,108 +1368,22 @@ def test_v13_sharding_records_and_version_gating():
                exporters.validate_sharding_record(
                    dict(good, replicated_bytes=6999,
                         replicated_bytes_by_dtype={"float32": 6999})))
-    # archived pre-v13 records of every enveloped kind stay valid at
-    # their declared version after the bump
-    old_kinds = [
-        exporters.JsonlExporter.enrich(
-            {"metric": "m", "value": 1.0, "unit": "x",
-             "backend": "cpu", "ndev": 8, "arch": "cpu"}),
-        exporters.JsonlExporter.enrich(
-            {"kind": "graph_lint", "rule": "donation",
-             "severity": "error", "entry_point": "e", "message": "m"}),
-    ]
-    for rec in old_kinds:
-        for v in range(1, 13):
-            archived = dict(rec, schema_version=v)
-            assert exporters.validate_telemetry_record(archived) == [], v
-
-
-def test_check_bench_trend_sharding_gate(tmp_path):
-    """The replication-ledger trend gate (schema v13): duplicate-bytes
-    growth past --mem-tol gates on EVERY backend (the ledger is
-    statically derived, the peak_bytes rule), a zero baseline
-    returning to nonzero is the un-sharded signature, shrinkage (the
-    ZeRO direction) is clean, and stale replays partition out."""
-    # growth past mem-tol on CPU smoke still errors — no noise excuse
-    d1 = tmp_path / "sh1"
-    d1.mkdir()
-    _trend_round(d1, "BENCH_r01.json",
-                 [_ledger_rec(repl=7000, backend="cpu")])
-    _trend_round(d1, "BENCH_r02.json",
-                 [_ledger_rec(repl=7900, backend="cpu")])  # +13%
-    r = _run_trend(["--dir", str(d1)])
-    assert r.returncode == 0, r.stderr          # within default 25%
-    r = _run_trend(["--dir", str(d1), "--mem-tol", "0.1"])
-    assert r.returncode == 1
-    assert "replicated_bytes" in r.stderr
-    # shrinking the duplicate bytes (a ZeRO shard landing) is clean
-    d2 = tmp_path / "sh2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [_ledger_rec(repl=7000)])
-    _trend_round(d2, "BENCH_r02.json", [_ledger_rec(repl=1000)])
-    r = _run_trend(["--dir", str(d2), "--mem-tol", "0.1"])
-    assert r.returncode == 0, r.stderr
-    # a fully-sharded (zero) baseline returning to replication gates
-    d3 = tmp_path / "sh3"
-    d3.mkdir()
-    _trend_round(d3, "BENCH_r01.json", [_ledger_rec(repl=0)])
-    _trend_round(d3, "BENCH_r02.json", [_ledger_rec(repl=2048)])
-    r = _run_trend(["--dir", str(d3)])
-    assert r.returncode == 1
-    assert "zero baseline" in r.stderr
-    # distinct entry points trend independently; a stale replay with
-    # inflated bytes never enters the trend
-    d4 = tmp_path / "sh4"
-    d4.mkdir()
-    _trend_round(d4, "BENCH_r01.json",
-                 [_ledger_rec("ep_a", 7000), _ledger_rec("ep_b", 100)])
-    _trend_round(d4, "BENCH_r02.json",
-                 [_ledger_rec("ep_a", 7000),
-                  dict(_ledger_rec("ep_b", 999999), stale=True)])
-    r = _run_trend(["--dir", str(d4), "--mem-tol", "0.01"])
-    assert r.returncode == 0, r.stderr
-    assert "stale replays partitioned" in r.stderr
+    # an archived pre-v13 lint record stays valid at its declared
+    # version after the bump
+    rec = exporters.JsonlExporter.enrich(
+        {"kind": "graph_lint", "rule": "donation",
+         "severity": "error", "entry_point": "e", "message": "m"})
+    for v in range(1, 13):
+        archived = dict(rec, schema_version=v)
+        assert exporters.validate_telemetry_record(archived) == [], v
 
 
 def test_v15_zero_stage_records_and_version_gating():
-    """Schema v15 (the ZeRO weight-update plane): fresh zero
-    train-throughput lines and zero-EP sharding ledgers must carry
-    ``zero_stage`` in {1, 2, 3}; the field is value-checked wherever
-    it appears; archived v1..v14 streams re-validate clean at their
-    declared versions."""
+    """Schema v15 (the ZeRO weight-update plane): zero-EP sharding
+    ledgers must carry ``zero_stage`` in {1, 2, 3}; the field is
+    value-checked; archived v14 ledgers re-validate clean at their
+    declared version."""
     assert exporters.SCHEMA_VERSION == 15
-    base = {"metric": "ddp_resnet18_o2_zero3_train_throughput",
-            "value": 100.0, "unit": "images/sec/chip",
-            "vs_baseline": None, "backend": "cpu", "ndev": 8,
-            "arch": "cpu", "flops_per_step": 1e12,
-            "achieved_tflops": 10.0, "mfu": None,
-            "peak_bytes": 1_000_000, "cold_compile_ms": 10.0,
-            "compiles_total": 1, "steady_state_retraces": 0,
-            "zero_stage": 3}
-    assert exporters.validate_bench_record(
-        exporters.JsonlExporter.enrich(dict(base))) == []
-    # fresh v15 zero line without the stage tag gates
-    rec = exporters.JsonlExporter.enrich(
-        {k: v for k, v in base.items() if k != "zero_stage"})
-    assert any("zero_stage" in e for e in
-               exporters.validate_bench_record(rec))
-    # ...but the same record declaring v14 rolls back clean
-    v14 = dict(rec, schema_version=14)
-    assert exporters.validate_bench_record(v14) == []
-    # non-zero train lines never need the tag
-    plain = exporters.JsonlExporter.enrich(
-        dict({k: v for k, v in base.items() if k != "zero_stage"},
-             metric="ddp_resnet18_o2_train_throughput"))
-    assert exporters.validate_bench_record(plain) == []
-    # the stage is value-checked wherever it appears (any metric)
-    for bad in (0, 4, True, "3", 2.0):
-        rec = exporters.JsonlExporter.enrich(
-            {"metric": "m", "value": 1.0, "unit": "x",
-             "vs_baseline": None, "backend": "cpu", "ndev": 8,
-             "arch": "cpu", "zero_stage": bad})
-        assert any("zero_stage" in e for e in
-                   exporters.validate_bench_record(rec)), bad
-
     # sharding plane: fresh v15 ledgers for zero EPs carry the stage
     zled = _ledger_rec("ddp_resnet18_o2_zero2", zero_stage=2)
     assert exporters.validate_sharding_record(zled) == []
@@ -2576,35 +1399,186 @@ def test_v15_zero_stage_records_and_version_gating():
     assert exporters.validate_sharding_record(_ledger_rec()) == []
 
 
-def test_check_bench_trend_skips_twin_anomaly_overlap_records(tmp_path):
-    """A record whose attribution flagged its own compute twin as
-    slower than the step (compute_twin_excess_ms > 0) carries CLAMPED
-    perfect-overlap numbers (comm_ms=0, overlap_fraction=1.0) — it
-    must not seed the overlap trend, or the next HEALTHY round gates
-    as a phantom regression."""
-    def attr(frac, visible, **kw):
-        return exporters.JsonlExporter.enrich(
-            {"metric": "train_step_attribution_overlap",
-             "value": 5.0, "unit": "ms", "vs_baseline": None,
-             "backend": "tpu", "ndev": 8, "arch": "TPU v5 lite",
-             "overlap_fraction": frac, "comm_visible_ms": visible,
-             "overlap_mode": "overlapped", "n_stages": 4,
-             "issue_order": [3, 2, 1, 0], **kw})
 
-    d = tmp_path / "twin1"
-    d.mkdir()
-    # round 1: the twin anomaly (clamped to perfect overlap)
-    _trend_round(d, "BENCH_r01.json",
-                 [attr(1.0, 0.0, compute_twin_excess_ms=2.5)])
-    # round 2: a healthy real measurement — must NOT gate against the
-    # clamped 1.0/0.0 baseline
-    _trend_round(d, "BENCH_r02.json", [attr(0.5, 1.2)])
-    r = _run_trend(["--dir", str(d)])
-    assert r.returncode == 0, r.stderr
-    # sanity: without the anomaly marker the same pair DOES gate
-    d2 = tmp_path / "twin2"
-    d2.mkdir()
-    _trend_round(d2, "BENCH_r01.json", [attr(1.0, 0.0)])
-    _trend_round(d2, "BENCH_r02.json", [attr(0.5, 1.2)])
-    r = _run_trend(["--dir", str(d2)])
-    assert r.returncode == 1
+
+# -- one validator per kind, each fed by the library's own producer --------
+
+def _tiny_sharded_entry_point(name, body):
+    """A synthetic analysis entry point over an 8-way shard_map of
+    ``body``, with a real lowering (the memory record compiles it)."""
+    from apex_tpu.analysis.entry_points import EntryPoint
+    from apex_tpu.analysis.graphs import Graph
+    mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                           out_specs=P(), check_vma=False)
+    x = jnp.ones((1024,))
+    return EntryPoint(name, lambda ep: Graph(
+        trace=lambda: jax.make_jaxpr(mapped)(x),
+        lower=lambda: jax.jit(mapped).lower(x)))
+
+
+def _lint_records():
+    def leaky(x):
+        return jax.pure_callback(
+            lambda a: np.asarray(a),
+            jax.ShapeDtypeStruct(x.shape, x.dtype), x)
+
+    ep = _tiny_sharded_entry_point("producer_lint", leaky)
+    out = []
+    analysis.run_lint(entry_points=[ep], rules=["host-transfer"],
+                      emit=out.append)
+    (finding, summary) = out
+    return finding, summary
+
+
+def _ledger_record(build):
+    ep = _tiny_sharded_entry_point("producer_ledger",
+                                   lambda x: lax.psum(x, "data"))
+    return build(ep)
+
+
+def _fleet_record():
+    from apex_tpu.fleet import Fleet
+    m, p = _gpt()
+    fl = Fleet([serving.Engine(m, p, slots=2, buf_len=24)],
+               step_workers=1)
+    try:
+        fl.submit([1, 2, 3], max_new_tokens=2, tenant="a")
+        while fl.live():
+            fl.step()
+        return fl.record()
+    finally:
+        fl.close()
+
+
+def _trace_record():
+    rec = obs.SpanRecorder()
+    tid = obs.new_trace_id()
+    root = rec.event("submit", trace_id=tid)
+    with rec.activate(tid, root):
+        with rec.span("dispatch"):
+            rec.event("tick")
+    return rec.trace_record(tid)
+
+
+def _numerics_record():
+    from apex_tpu.observability import numerics
+    g = {"a": jnp.asarray([8.0, -16.0]), "b": jnp.asarray([4.0])}
+    nm = numerics.NumericsMonitor(g, half_dtype="bfloat16",
+                                  registry=obs.MetricsRegistry())
+    tele = nm.update(nm.init(), grad_stats=nm.leaf_stats(g, 1.0),
+                     found_inf=jnp.zeros(()), loss_scale=1.0)
+    return nm.to_record(nm.flush(tele), metric="producer")
+
+
+def _run_record():
+    sup = obs.RunSupervisor(
+        "t", ring=obs.EventRing(capacity=64),
+        registry=obs.MetricsRegistry(),
+        config=obs.SupervisorConfig(stall_observations=4,
+                                    warmup_observations=3))
+    for i in range(4):
+        sup.observe_step(step=i, loss=1.0, step_time_s=0.01)
+    sup.observe_step(step=4, loss=float("nan"))
+    return sup.record(metric="producer")
+
+
+def _recovery_record():
+    from apex_tpu.fleet.recovery import RecoveryLog
+    log = RecoveryLog("serving", "t", ring=obs.EventRing(64))
+    log.open_episode("spike")
+    log.action("admission_tighten", max_queue_from=8, max_queue_to=4)
+    log.close_episode(mttr_s=3.0)
+    return log.record()
+
+
+def _profile_record():
+    from apex_tpu.observability import timeline
+
+    def kernel(name, ts, dur, tid):
+        return {"ph": "X", "pid": 7, "tid": tid, "ts": ts, "dur": dur,
+                "name": name,
+                "args": {"hlo_op": name, "hlo_module": "jit_step"}}
+
+    doc = {"traceEvents": [kernel("dot.1", 0.0, 100.0, 2),
+                           kernel("all-reduce.2", 200.0, 100.0, 3),
+                           kernel("fusion.7", 200.0, 50.0, 2)]}
+    att = timeline.attribute_timeline(timeline.device_events(doc))
+    return timeline.profile_record(att, metric="producer")
+
+
+# kind -> the library's own producer of it, at the smallest input that
+# producer's own test builds
+_PRODUCERS = {
+    "graph_lint": lambda: _lint_records()[0],
+    "graph_lint_summary": lambda: _lint_records()[1],
+    "fleet": _fleet_record,
+    "trace": _trace_record,
+    "memory": lambda: _ledger_record(analysis.entry_point_memory_record),
+    "numerics": _numerics_record,
+    "run": _run_record,
+    "recovery": _recovery_record,
+    "profile": _profile_record,
+    "sharding": lambda: _ledger_record(
+        analysis.entry_point_sharding_record),
+}
+
+
+@pytest.mark.parametrize("kind", list(_PRODUCERS))
+def test_library_producer_passes_its_validator(kind):
+    """Each record kind the library produces validates through the
+    dispatcher as its producer makes it, and the same record with
+    ``kind`` removed or misspelt is rejected by name: the dispatcher
+    has no default schema."""
+    rec = exporters.JsonlExporter.enrich(_PRODUCERS[kind]())
+    assert rec["kind"] == kind
+    assert exporters.validate_telemetry_record(rec) == []
+    assert json.loads(json.dumps(rec)) == rec      # a JSONL line
+    for broken in ({k: v for k, v in rec.items() if k != "kind"},
+                   dict(rec, kind=kind + "s"), dict(rec, kind=None)):
+        errs = exporters.validate_telemetry_record(broken)
+        assert len(errs) == 1 and "'kind'" in errs[0], errs
+
+
+def test_dispatcher_knows_exactly_the_produced_kinds():
+    """No schema is reachable without a producer above, none of the
+    producers lacks one, and what is not a record of a known kind is
+    an error that names ``kind``."""
+    assert set(exporters._VALIDATORS) == set(_PRODUCERS)
+    for rec in ({}, {"kind": "bench"}, {"kind": ["fleet"]},
+                exporters.JsonlExporter.enrich(
+                    {"metric": "m", "value": 1.0, "unit": "x"})):
+        errs = exporters.validate_telemetry_record(rec)
+        assert len(errs) == 1 and "'kind'" in errs[0], errs
+    assert exporters.validate_telemetry_record([1, 2]) != []
+    assert exporters.validate_telemetry_jsonl([]) == ["no records found"]
+    assert any("not JSON" in e
+               for e in exporters.validate_telemetry_jsonl(["{oops"]))
+
+
+def _lint_finding():
+    return exporters.JsonlExporter.enrich(
+        {"kind": "graph_lint", "rule": "donation", "severity": "error",
+         "entry_point": "e", "message": "m"})
+
+
+@pytest.mark.parametrize("mutate, named", [
+    (lambda r: r.pop("stale"), "stale"),
+    (lambda r: r.update(stale="no"), "stale"),
+    (lambda r: r.pop("schema_version"), "schema_version"),
+    (lambda r: r.update(schema_version=0), "schema_version"),
+    (lambda r: r.update(schema_version=True), "schema_version"),
+    (lambda r: r.pop("host"), "host"),
+    (lambda r: r.update(host={"hostname": 7, "pid": 1}), "host.hostname"),
+    (lambda r: r.update(host={"hostname": "h", "pid": "1"}), "host.pid"),
+], ids=["no_stale", "stale_not_bool", "no_version", "version_zero",
+        "version_bool", "no_host", "hostname_not_str", "pid_not_int"])
+def test_record_envelope_is_required(mutate, named):
+    """The envelope every kind shares (schema_version / capture host /
+    boolean ``stale``), held on a lint record: ``_check_envelope`` is
+    one implementation for all ten kinds."""
+    rec = _lint_finding()
+    assert exporters.validate_telemetry_record(rec) == []
+    mutate(rec)
+    errs = exporters.validate_telemetry_record(rec)
+    assert any(named in e for e in errs), errs

@@ -31,7 +31,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import parallel
 from apex_tpu.analysis import graphs as G
-from apex_tpu.observability import exporters, steptime
 
 S, H, B = 4, 32, 8
 _rng = np.random.RandomState(14)
@@ -313,115 +312,6 @@ def test_overlap_collective_expectations_derivation():
             assert "interleaving" not in exp
 
 
-def test_attribute_step_schedule_fields_and_v9_schema():
-    """attribute_step stamps OVERLAP_SCHEDULE_FIELDS on every
-    attribution (defaulting to the classic single-stage
-    reduce-after-backward shape), and the v9 schema requires them on
-    fresh attribution records while rejecting incoherent ones."""
-
-    def sleeper(s):
-        def fn():
-            import time as _t
-            _t.sleep(s)
-            return jnp.ones((4,))
-        return fn
-
-    sched = parallel.overlap_comm_schedule(
-        STAGE_PARAMS, comm_topology="hierarchical", ici_size=4,
-        world=8, nproc=1)
-    att = steptime.attribute_step(sleeper(0.02), sleeper(0.012),
-                                  sleeper(0.008), args=(),
-                                  plan=sched["buckets"],
-                                  schedule=sched, iters=2, warmup=0)
-    assert att["overlap_mode"] == "overlapped"
-    assert att["n_stages"] == S
-    assert att["issue_order"] == [3, 2, 1, 0]
-    # bucket stage labels ride into the output buckets
-    assert [b["stage"] for b in att["buckets"]] == [3, 2, 1, 0]
-    rec = exporters.JsonlExporter.enrich(
-        {"metric": "train_step_attribution_overlap",
-         "value": att["step_ms"], "unit": "ms", "vs_baseline": None,
-         "backend": "cpu", "ndev": 8, "arch": "cpu",
-         **{k: att[k] for k in steptime.ATTRIBUTION_FIELDS},
-         **{k: att[k] for k in steptime.OVERLAP_SCHEDULE_FIELDS}})
-    assert exporters.validate_bench_record(rec) == []
-
-    # defaulted schedule: classic shape, still v9-valid
-    att0 = steptime.attribute_step(sleeper(0.02), sleeper(0.012),
-                                   sleeper(0.008), args=(), iters=2,
-                                   warmup=0)
-    assert att0["overlap_mode"] == "reduce_after_backward"
-    assert att0["n_stages"] == 1 and att0["issue_order"] == [0]
-
-    # v9 gating: a fresh attribution record without the schedule
-    # fields fails; archived records at a declared older version pass
-    naked = {k: v for k, v in rec.items()
-             if k not in exporters.OVERLAP_SCHEDULE_FIELDS}
-    assert any("schema v9" in e
-               for e in exporters.validate_bench_record(naked))
-    archived = dict(naked, schema_version=8)
-    assert exporters.validate_bench_record(archived) == []
-    stale = dict(naked, stale=True)
-    assert exporters.validate_bench_record(stale) == []
-    # incoherent schedule fields flag at any version
-    bad = dict(rec, overlap_mode="sometimes")
-    assert any("overlap_mode" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = dict(rec, issue_order=[0, 1, 1, 2])
-    assert any("permutation" in e
-               for e in exporters.validate_bench_record(bad))
-    bad = dict(rec, n_stages=0)
-    assert any("n_stages" in e
-               for e in exporters.validate_bench_record(bad))
-    # the shape fields are coherence-checked whenever PRESENT — even
-    # on a record that never names its overlap_mode
-    bad = {k: v for k, v in rec.items() if k != "overlap_mode"}
-    bad.update(schema_version=8, n_stages=0)
-    assert any("n_stages" in e
-               for e in exporters.validate_bench_record(bad)), bad
-    bad = {k: v for k, v in rec.items() if k != "overlap_mode"}
-    bad.update(schema_version=8, n_stages=2, issue_order=[5, 5])
-    assert any("permutation" in e
-               for e in exporters.validate_bench_record(bad)), bad
-
-
-def test_overlap_schedule_fields_pinned_across_modules():
-    """The stdlib-side duplicates (exporters must import without jax)
-    stay equal to the owning modules' tuples."""
-    assert exporters.OVERLAP_SCHEDULE_FIELDS == \
-        steptime.OVERLAP_SCHEDULE_FIELDS
-    assert exporters.OVERLAP_MODES == parallel.OVERLAP_MODES
-
-
-def test_attribute_step_clamps_slow_compute_twin():
-    """A compute twin that times slower than the full step (routine on
-    the oversubscribed CPU mesh) clamps to the decomposition model —
-    compute+comm still reassemble step — and surfaces the excess as
-    compute_twin_excess_ms instead of publishing a record that fails
-    its own schema."""
-
-    def sleeper(s):
-        def fn():
-            import time as _t
-            _t.sleep(s)
-            return jnp.ones((4,))
-        return fn
-
-    att = steptime.attribute_step(sleeper(0.01), sleeper(0.02),
-                                  sleeper(0.005), args=(), iters=2,
-                                  warmup=0)
-    assert att["compute_ms"] == att["step_ms"]
-    assert att["comm_ms"] == 0.0
-    assert att["compute_twin_excess_ms"] > 0.0
-    rec = exporters.JsonlExporter.enrich(
-        {"metric": "train_step_attribution_flat",
-         "value": att["step_ms"], "unit": "ms", "vs_baseline": None,
-         "backend": "cpu", "ndev": 8, "arch": "cpu",
-         **{k: att[k] for k in steptime.ATTRIBUTION_FIELDS},
-         **{k: att[k] for k in steptime.OVERLAP_SCHEDULE_FIELDS}})
-    assert exporters.validate_bench_record(rec) == []
-
-
 # -- the fused ZeRO-2 staged step ------------------------------------------
 
 def make_zero2_step(overlap, compress=False):
@@ -475,8 +365,8 @@ def test_staged_zero2_schedule_tag_and_runtime_stats():
     ``overlap_comm_schedule(zero_stage=2)`` and the traced
     ``comm_stats`` agree bucket-for-bucket (stage, issue order, cause,
     topology, wire bytes, both fabric levels), the traced schedule is
-    tagged ``zero_stage=2``, and the tag rides into the bench-record
-    schedule fields."""
+    tagged ``zero_stage=2``, and the tag rides into
+    ``overlap_schedule_fields``."""
     ddp, fz = make_zero2_step(True)
     jax.make_jaxpr(fz)(STAGE_PARAMS, (X, Y))
     sched = parallel.overlap_comm_schedule(
@@ -498,7 +388,7 @@ def test_staged_zero2_schedule_tag_and_runtime_stats():
     assert fields["zero_stage"] == 2
     assert fields["overlap_mode"] == "overlapped"
     # the non-zero schedule carries NO zero_stage key at all — absent,
-    # not None, so exporters can gate on presence
+    # not None, so a reader can gate on presence
     assert "zero_stage" not in parallel.overlap_schedule_fields(
         ddp.last_overlap_schedule | {"zero_stage": None})
 

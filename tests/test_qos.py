@@ -375,7 +375,7 @@ def test_preemption_victim_selection_deterministic():
 def test_preemption_fires_over_queue_behind_busy_slots():
     """The priority-inversion path: every candidate replica has queue
     room but NO free slot — a high-class request must evict a
-    lower-class decode instead of queueing behind it (the paged-bench
+    lower-class decode instead of queueing behind it (the paged
     regression: a paged replica's internal queue kept it a candidate
     forever, so preemption never fired)."""
     ring = obs.EventRing(capacity=64)
@@ -572,48 +572,6 @@ def test_v14_fleet_record_validates_and_mutations_reject():
                exporters.validate_fleet_record(mutated(weight=0)))
 
 
-def test_v14_bench_class_lines_validate_and_mutations_reject():
-    base = {"unit": "tokens/sec", "backend": "cpu", "ndev": 1,
-            "arch": "cpu"}
-    cls = exporters.JsonlExporter.enrich(dict(
-        base, metric="gpt_tiny_fleet2_qos_class_interactive_goodput",
-        value=100.0, qos_class="interactive", slo_attainment=1.0))
-    assert exporters.validate_bench_record(cls) == []
-    # a fresh v14 per-class goodput line must carry its labels
-    for missing in ("qos_class", "slo_attainment"):
-        bad = {k: v for k, v in cls.items() if k != missing}
-        assert exporters.validate_bench_record(bad) != [], missing
-    assert exporters.validate_bench_record(
-        dict(cls, qos_class="")) != []
-    assert exporters.validate_bench_record(
-        dict(cls, slo_attainment=1.5)) != []
-
-    parity = exporters.JsonlExporter.enrich(dict(
-        base, metric="gpt_tiny_fleet_qos_preemption_parity",
-        unit="ratio", value=1.0, matched_tokens=16,
-        expected_tokens=16, preemptions=1, steady_state_retraces=0))
-    assert exporters.validate_bench_record(parity) == []
-    # the parity line must PROVE an eviction happened...
-    assert any("preemptions" in e for e in
-               exporters.validate_bench_record(
-                   dict(parity, preemptions=0)))
-    # ...and its value must reassemble from the token counts
-    assert any("inconsistent" in e for e in
-               exporters.validate_bench_record(
-                   dict(parity, value=0.5)))
-    for missing in ("matched_tokens", "expected_tokens"):
-        bad = {k: v for k, v in parity.items() if k != missing}
-        assert exporters.validate_bench_record(bad) != [], missing
-    # archived pre-v14 streams re-validate clean at their declared
-    # versions: the class fields were never required before the bump
-    plain = exporters.JsonlExporter.enrich(dict(
-        base, metric="gpt_tiny_fleet2_qos_class_interactive_goodput",
-        value=100.0))
-    for v in range(1, 14):
-        old = dict(plain, schema_version=v)
-        assert exporters.validate_telemetry_record(old) == [], v
-
-
 # -- the engine-backed pins: exactness, zero retraces, failover -----------
 
 def _gpt(seed=0):
@@ -691,7 +649,7 @@ def test_warmed_fleet_preemption_episode_zero_retraces():
                qos=_two_class())
     fl.warmup()
     # settle one request end to end so every steady-state shape is
-    # traced before the watermark (the bench episode's discipline)
+    # traced before the watermark (the warm-up discipline)
     settle = fl.submit([1, 2, 3], max_new_tokens=4, tenant="bob")
     _drive(fl)
     assert fl.status(settle) == "finished"
