@@ -252,10 +252,10 @@ def collect_kernel_sites() -> List[KernelSite]:
     import jax.numpy as jnp
     from ..ops import (pallas_adam, pallas_common, pallas_flash_attention,
                        pallas_lamb, pallas_layer_norm,
-                       pallas_multi_tensor, pallas_syncbn)
+                       pallas_multi_tensor, pallas_rope, pallas_syncbn)
 
     _clear_jit_caches(pallas_adam, pallas_flash_attention, pallas_lamb,
-                      pallas_layer_norm, pallas_multi_tensor,
+                      pallas_layer_norm, pallas_multi_tensor, pallas_rope,
                       pallas_syncbn)
     sites: List[KernelSite] = []
     rng = np.random.RandomState(18)
@@ -300,6 +300,22 @@ def collect_kernel_sites() -> List[KernelSite]:
         jax.grad(lambda a: jnp.sum(
             pallas_flash_attention.flash_attention(a, k, vv,
                                                    causal=True)))(q)
+        # the same three on token-major operands with K/V once per K/V
+        # head: fp32 heads go one a step, so a group's six query heads
+        # are six steps of dk/dv's sequential axis (the chunked index
+        # maps, over Python ints); bf16 heads share a step and its K/V
+        # block, under a band with dead steps
+        tm = pallas_flash_attention.flash_attention_token_major
+        q, k = f32(1, 384, 6, 128), f32(1, 384, 1, 128)
+        jax.grad(lambda a: jnp.sum(tm(a, k, k, causal=True)))(q)
+        q, k = (f32(2, 512, 8, 128).astype(jnp.bfloat16),
+                f32(2, 512, 2, 128).astype(jnp.bfloat16))
+        jax.grad(lambda a: jnp.sum(
+            tm(a, k, k, causal=True, window=200).astype(jnp.float32)))(q)
+        # rotary embedding on a projection's output, half of each head
+        ang = f32(64, 32)
+        pallas_rope.rope_token_major(
+            f32(2, 64, 3 * 128), jnp.cos(ang), jnp.sin(ang), 128)
     return sites
 
 

@@ -10,7 +10,8 @@ fused chunked head), with
 
 - attention: ``H_l`` query heads over ``num_key_value_heads`` K/V heads,
   causal, a band of ``sliding_window`` keys in a sliding layer (handed to
-  ``dot_product_attention(window=)``, so the flash kernels apply it), RoPE
+  ``dot_product_attention_token_major(window=)``, so the flash kernels
+  apply it, on q, k, v where the projections wrote them), RoPE
   per kind (``default``: theta, the whole or a leading part of the head;
   ``yarn``: blended frequencies and a scale on cos/sin, as ``transformers``
   computes them), and — ``gating`` — a sigmoid gate per head on the
@@ -37,8 +38,9 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..nn import functional as F
+from ..ops.pallas_common import token_tile_axes
 from ..parallel.expert_parallel import ExpertParallelMLP
-from ..transformer.attention import dot_product_attention
+from ..transformer.attention import dot_product_attention_token_major
 from ._remat import _MODES, wrap_block
 from .llama import LlamaMLP, RMSNorm, _rotate_half
 
@@ -179,35 +181,49 @@ class LagunaAttention(nn.Module):
             self.g_proj = nn.Linear(E, self.H, bias=False)
         self.gating = cfg.gating
 
-    def _rope(self, x, T):
-        """x: (B, heads, T, D); the first ``2 * len(inv_freq)`` dims of each
-        head rotate (rotate-half pairing), the rest pass through."""
-        rd = 2 * self.inv_freq.shape[0]
+    def _rope(self, x):
+        """x: (B, T, heads * D), as the projection wrote it; the first
+        ``2 * len(inv_freq)`` dims of each head rotate (rotate-half
+        pairing), the rest pass through.  fp32 arithmetic between a read
+        and a write in x's dtype; cos and sin are a token's, shared by its
+        heads.  On TPU one Pallas pass each way (``ops.pallas_rope``)."""
+        B, T, _ = x.shape
         ang = jnp.arange(T, dtype=jnp.float32)[:, None] * self.inv_freq
         emb = jnp.concatenate([ang, ang], axis=-1)
         cos, sin = (jnp.cos(emb) * self.rope_scale,
                     jnp.sin(emb) * self.rope_scale)
+        from ..ops import dispatch, pallas_rope
+        if (dispatch.use_pallas_for(x) and self.D % 128 == 0
+                and pallas_rope.rows_per_block(T)):
+            return pallas_rope.rope_token_major(x, cos, sin, self.D)
+        rd = emb.shape[-1]
+        x = x.reshape(B, T, -1, self.D)
         xr = x[..., :rd].astype(jnp.float32)
-        out = (xr * cos + _rotate_half(xr) * sin).astype(x.dtype)
-        return out if rd == self.D else jnp.concatenate(
-            [out, x[..., rd:]], axis=-1)
+        out = (xr * cos[:, None] + _rotate_half(xr) * sin[:, None]).astype(
+            x.dtype)
+        if rd < self.D:
+            out = jnp.concatenate([out, x[..., rd:]], axis=-1)
+        return out.reshape(B, T, -1)
 
     def forward(self, p, x):
         B, T, _ = x.shape
-        heads = lambda y, n: jnp.moveaxis(y.reshape(B, T, n, self.D), 2, 1)
-        q = self._rope(heads(self.q_proj(p["q_proj"], x), self.H), T)
-        k = self._rope(heads(self.k_proj(p["k_proj"], x), self.Hkv), T)
-        v = heads(self.v_proj(p["v_proj"], x), self.Hkv)
-        rep = self.H // self.Hkv
-        if rep > 1:                 # query head h reads K/V head h // rep
-            k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-        ctx = dot_product_attention(q, k, v, causal=True,
-                                    window=self.window)
-        ctx = jnp.moveaxis(ctx, 1, 2)                       # (B, T, H, D)
+        q = self._rope(self.q_proj(p["q_proj"], x))
+        k = self._rope(self.k_proj(p["k_proj"], x))
+        v = self.v_proj(p["v_proj"], x)
+        # q, k, v stay where the projections wrote them: query head h reads
+        # K/V head h // (H // Hkv), no axis is moved and no K/V head
+        # repeated around the kernels
+        heads = lambda y: y.reshape(B, T, -1, self.D)
+        ctx = dot_product_attention_token_major(
+            heads(q), heads(k), heads(v), causal=True, window=self.window)
         if self.gating:
             gate = jax.nn.sigmoid(
                 self.g_proj(p["g_proj"], x).astype(jnp.float32))
-            ctx = ctx * gate[..., None].astype(ctx.dtype)
+            # in the view whose tiles are the array's own on a TPU, not
+            # (B, T, H, D)'s
+            tiles = (*token_tile_axes(B, T), self.H)
+            ctx = ctx.reshape(*tiles, self.D) * gate.reshape(
+                *tiles, 1).astype(ctx.dtype)
         return self.o_proj(p["o_proj"], ctx.reshape(B, T, self.H * self.D))
 
 

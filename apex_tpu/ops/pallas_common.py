@@ -38,6 +38,19 @@ BLOCK_ROWS = 512
 BLOCK_ELEMS = BLOCK_ROWS * LANES
 
 
+def token_tile_axes(B: int, T: int) -> Tuple[int, int, int]:
+    """Leading axes ``(B, T / 8, 8)`` of the view ``(B, T / 8, 8, heads, D)``
+    of a token-major ``(B, T, heads * D)`` array: the one in which
+    elementwise work and reductions per head cost no copy on a TPU.  A
+    tile of such an array is 8 tokens x 128 lanes, so this view's tiles
+    are whole and the compiler takes the reshape as a bitcast, where the
+    tiles of ``(B, T, heads, D)``, 8 heads x 128 lanes, make it a relayout
+    of the array on the way in and another on the way out.  ``(B, T, 1)``
+    where 8 does not divide T."""
+    t8 = 8 if T % 8 == 0 else 1
+    return B, T // t8, t8
+
+
 def interpret() -> bool:
     from . import dispatch
     return dispatch.interpret_mode()

@@ -10,7 +10,8 @@ with ppermutes and XLA fuses them against the collective.)
 Design (FlashAttention-style, true blocked form): each of the three
 kernels runs a grid (batch*heads / hb, rows, steps) whose last axis is
 sequential ("arbitrary"); a step takes ``hb`` heads of one batch entry
-(``_heads_per_step``), which share its masks and its fixed cost.  A row
+(``_heads_per_step``), which share its masks, its fixed cost and, where
+K/V heads are shared, their K/V block.  A row
 accumulates into one output block in VMEM
 scratch while the blocks of the other side are *streamed* past it one
 (BLK, D) block a step — nothing scales with T in VMEM: K and V past a q
@@ -28,8 +29,24 @@ in T.  A visited pair is *interior* when every one of its scores is
 visible (below the diagonal, inside the band, no padded key, no
 ``kv_mask``, segments or dropout) and runs a body with no iota, compare
 or select; an *edge* pair runs the masked body.  The counters
-``flash_block_pairs_total{kind}`` and ``flash_pad_copies_total`` say at
-trace time what a call's grids visit and what it copies.
+``flash_block_pairs_total{kind}``, ``flash_pad_copies_total`` and
+``flash_calls_total{layout, kv}`` say at trace time what a call's grids
+visit, what it copies and which form its operands took.
+
+Operands come in one of two forms (``_Layout``), named by the entry the
+caller takes, and the kernels address both through the block shape and the
+index map of a spec and one accessor for "head h of this block":
+head-major, ``flash_attention`` on (B, H, T, D), a step's ``hb`` heads the
+leading ``hb`` of a (hb, blk, D) block of the (B*H, T, D) fold; and
+token-major, ``flash_attention_token_major`` on (B, T, H, D), which is a
+projection's own (B, T, H*D) output, a step's heads ``hb`` lane tiles of a
+(1, blk, hb*D) block (D a multiple of 128).  In both, K and V may come at
+fewer heads than q (``Hkv`` dividing ``H``, read from the shapes): a
+step's heads are then of one group and fetch its one K/V block, and dk/dv
+adds the group's query heads up in its fp32 accumulator and writes each
+K/V head once.  A caller with token-major, grouped operands moves no axis
+and repeats no head on the way in or out; a head-major call with K/V at
+q's head count launches what it launched before there was a choice.
 
 Operands reach the kernels as they are when T is a whole number of blocks
 and D is under 128 or a multiple of it (a block's last dimension may equal
@@ -62,7 +79,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_common import LANES, interpret
+from .pallas_common import LANES, interpret, token_tile_axes
 
 _VMEM_BUDGET = 12 * 1024 * 1024
 _BLK = 512          # q/k rows per block (clamped to the padded seq len)
@@ -137,7 +154,7 @@ def _head_width(D: int) -> int:
 
 
 def _heads_per_step(H: int, Dp: int, itemsize: int, masked: bool,
-                    blk: int) -> int:
+                    blk: int, rep: int = 1) -> int:
     """Heads of one batch entry a grid step takes.  They share the step's
     masks and its fixed cost, about 0.3 us on a v5e, a third of an
     interior forward pair of 512 x 512: 4 heads take 13 % off the three
@@ -147,10 +164,19 @@ def _heads_per_step(H: int, Dp: int, itemsize: int, masked: bool,
     4 heads in bf16, 14 with dropout; 10 / 14 / 24 in fp32, of the 16 MiB
     a kernel may use), so: 4 for half-precision heads of one lane tile,
     2 when mask, segment or dropout operands come along, twice that at
-    blocks of 256 or under, 1 otherwise."""
+    blocks of 256 or under, 1 otherwise.  Where ``rep`` query heads read
+    one K/V head, a step's heads are of one group (a divisor of ``rep``),
+    so that they share its K/V block; K, V, dK, dV and their accumulators
+    are then the group's and not a head's, and half as many heads again
+    fit: 6 of a group of 6 at blk 512 take 44.5 ms through the three
+    kernels at 48 heads x 8192 x 128 where 3 take 47.3, 2 49.8 and the
+    ungrouped 4 of the same call 45.1 (PERF.md section 6, PR 29)."""
     if Dp > LANES or itemsize > 2:
         return 1
     most = (2 if masked else 4) * (1 if blk > 256 else 2)
+    if rep > 1:
+        return max(hb for hb in range(1, min(8, most * 3 // 2) + 1)
+                   if rep % hb == 0)
     return next(hb for hb in (8, 4, 2, 1) if hb <= most and H % hb == 0)
 
 
@@ -204,6 +230,83 @@ def _pad_to(x, T, D):
 
 def _unpad(x, T, D):
     return x if x.shape[-2:] == (T, D) else x[..., :T, :D]
+
+
+# ---------------------------------------------------------------------------
+# which array and which block a kernel's operand is
+# ---------------------------------------------------------------------------
+
+class _Layout:
+    """The two forms the kernels take their operands in, and the block of
+    each that a grid step holds.
+
+    - head-major: q, do, o, dq as ``(B*H, T, D)`` folds and k, v, dk, dv as
+      ``(B*Hkv, T, D)``; a step's ``hb`` heads are the leading ``hb`` of a
+      ``(hb, blk, D)`` block.
+    - token-major: ``(B, T, H*D)`` and ``(B, T, Hkv*D)``, what a projection
+      writes; a step's heads are ``hb`` lane tiles of a ``(1, blk, hb*D)``
+      block, its lane offset chosen by the index map, and a head inside
+      the kernel is a static slice of ``D`` lanes (``D % 128 == 0``).
+
+    ``rep = H // Hkv`` query heads read one K/V head: a step's heads are of
+    one group (``hb`` divides ``rep``) and fetch that head's one block; with
+    ``rep == 1`` the step holds ``hb`` K/V heads.  The grids of the forward
+    and of dq run ``B*H/hb`` leading steps; dk/dv runs one leading step a
+    K/V block, and the ``chunks = rep // hb`` steps of its query heads sit
+    on the sequential axis, ``chunks`` of them a streamed block, adding
+    into the one dk/dv accumulator.
+
+    Index maps and kernels address through this class alone; ``_Sweep``
+    knows nothing of it."""
+
+    def __init__(self, q, k, H: int, token_major: bool, masked: bool,
+                 blk: int):
+        """``q``, ``k``: the operands as the kernels get them (padded)."""
+        if token_major:
+            self.B, _, HD = q.shape
+            self.D = HD // H
+            self.Hkv = k.shape[2] // self.D
+        else:
+            BH, _, self.D = q.shape
+            self.B = BH // H
+            self.Hkv = k.shape[0] // self.B
+        self.H, self.token_major = H, token_major
+        self.rep = H // self.Hkv
+        self.hb = hb = _heads_per_step(H, self.D, q.dtype.itemsize, masked,
+                                       blk, self.rep)
+        self.hkv = hb if self.rep == 1 else 1      # K/V heads a step holds
+        self.chunks = max(1, self.rep // hb)       # steps sharing a K/V block
+        self.entry = H // hb                       # leading steps a batch entry
+
+    def block(self, blk, kv=False):
+        """Block shape of a q-like operand, or (``kv``) of a k-like one."""
+        heads = self.hkv if kv else self.hb
+        return ((1, blk, heads * self.D) if self.token_major
+                else (heads, blk, self.D))
+
+    def spec(self, blk, at, step, kv=False):
+        """BlockSpec of a q-like or k-like operand.  ``at(g, t)``: the block
+        along T; ``step(b, p)`` -> (leading step as the forward counts it,
+        t).  A leading step's K/V block is that of its group."""
+        c = self.chunks if kv else 1
+
+        def index(b, g, p):
+            b, t = step(b, p)
+            if self.token_major:
+                entry, heads = b // self.entry, b % self.entry
+                return entry, at(g, t), heads if c == 1 else heads // c
+            return b if c == 1 else b // c, at(g, t), 0
+        return pl.BlockSpec(self.block(blk, kv), index)
+
+    def head(self, h):
+        """Index of query head ``h``'s (blk, D) in a q-like block."""
+        return ((0, slice(None), pl.ds(h * self.D, self.D))
+                if self.token_major else h)
+
+    def kv_head(self, h):
+        """Index of the K/V head query head ``h`` reads, in a k-like
+        block."""
+        return self.head(h * self.hkv // self.hb)
 
 
 # ---------------------------------------------------------------------------
@@ -401,12 +504,12 @@ def _column(ref):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
-                T_real, Tp):
+def _fwd_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
+                dropout_rate, T_real, Tp):
     ((q_ref, k_ref, v_ref), kvm_ref, qseg_ref, kseg_ref, seed_ref,
      (o_ref, lse_ref, m_ref, l_ref, acc_ref)) = _split_refs(
         refs, 3, has_mask, has_segments, dropout_rate)
-    hb, blk, D = q_ref.shape
+    hb, blk, D = lay.hb, sweep.blk, lay.D
     b = pl.program_id(0)
     i, j, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
 
@@ -430,8 +533,9 @@ def _fwd_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
             # m, l and alpha are (blk, LANES) tiles with equal lanes from
             # scratch to scratch: they meet the score tile as whole copies
             # of their vregs and the accumulator as they are
-            v = v_ref[h]
-            s = _dot(q_ref[h], k_ref[h], ((1,), (1,))) * scale
+            q_h, kv_h = lay.head(h), lay.kv_head(h)
+            v = v_ref[kv_h]
+            s = _dot(q_ref[q_h], k_ref[kv_h], ((1,), (1,))) * scale
             if valid is not None:
                 s = jnp.where(valid, s, _NEG)
             m_prev = m_ref[h]
@@ -456,7 +560,7 @@ def _fwd_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
                 p = jnp.where(u >= dropout_rate, p, 0.0) * (
                     1.0 / (1.0 - dropout_rate))
             pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))
-            acc_ref[h] = acc_ref[h] * _tile_lanes(alpha, D) + pv
+            acc_ref[q_h] = acc_ref[q_h] * _tile_lanes(alpha, D) + pv
 
     _run_pair(pair, live, sweep.interior(i, j))
 
@@ -465,34 +569,53 @@ def _fwd_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
         for h in range(hb):
             l = l_ref[h]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[h] = (acc_ref[h] / _tile_lanes(l_safe, D)).astype(
+            q_h = lay.head(h)
+            o_ref[q_h] = (acc_ref[q_h] / _tile_lanes(l_safe, D)).astype(
                 o_ref.dtype)
             lse_ref[h] = _to_rows(m_ref[h] + jnp.log(l_safe))
 
 
-def _specs(sweep, blk, D, H, hb):
+def _each_step(b, p):
+    return b, p
+
+
+def _dkv_step(lay):
+    """(leading step, sequential step) of the dk/dv grid -> (the leading
+    step as the forward counts it, the sweep's step): the ``chunks`` steps
+    of a K/V group's query heads lie side by side on the sequential axis."""
+    c = lay.chunks
+    if c == 1:
+        return _each_step
+    return lambda b, p: (b * c + p % c, p // c)
+
+
+def _specs(sweep, lay, step=_each_step):
     """BlockSpecs of one kernel's grid, by what a block follows: the
-    grid's row or its streamed step; ``hb`` heads of one batch entry a
-    step, or that entry's own tile."""
+    grid's row or its streamed step; the ``hb`` heads of a step (``q``), the
+    K/V heads they read (``kv``), or their batch entry's own tile."""
+    blk = sweep.blk
     row = lambda g, t: sweep.at(g, t)[0]
     stream = lambda g, t: sweep.at(g, t)[2]
-    entry = H // hb                 # steps of the leading axis a batch entry
 
-    def operand(at):
-        return pl.BlockSpec((hb, blk, D), lambda b, g, t: (b, at(g, t), 0))
+    def q(at):
+        return lay.spec(blk, at, step)
+
+    def kv(at):
+        return lay.spec(blk, at, step, kv=True)
 
     def row_tile(at, per_head=True):        # (., 8, Tp) sublane-broadcast
-        if per_head:
-            return pl.BlockSpec((hb, 8, blk),
-                                lambda b, g, t: (b, 0, at(g, t)))
-        return pl.BlockSpec((1, 8, blk),
-                            lambda b, g, t: (b // entry, 0, at(g, t)))
+        def index(b, g, p):
+            b, t = step(b, p)
+            return (b if per_head else b // lay.entry), 0, at(g, t)
+        return pl.BlockSpec((lay.hb if per_head else 1, 8, blk), index)
 
     def column_tile(at):                    # (B, Tp, LANES) lane-broadcast
-        return pl.BlockSpec((1, blk, LANES),
-                            lambda b, g, t: (b // entry, at(g, t), 0))
+        def index(b, g, p):
+            b, t = step(b, p)
+            return b // lay.entry, at(g, t), 0
+        return pl.BlockSpec((1, blk, LANES), index)
 
-    return row, stream, operand, row_tile, column_tile
+    return row, stream, q, kv, row_tile, column_tile
 
 
 def _optional(sweep, specs, kvm, idq, idk, seed):
@@ -503,7 +626,7 @@ def _optional(sweep, specs, kvm, idq, idk, seed):
     lane-broadcast (B, Tp, LANES) columns: forward and dq stream the keys
     on the lanes and hold a row's queries on the sublanes, dk/dv the other
     way round."""
-    row, stream, _, row_tile, column_tile = specs
+    row, stream, _, _, row_tile, column_tile = specs
     on_lanes = lambda x: (row_tile(stream, per_head=False), lax.broadcast_in_dim(
         x, (x.shape[0], 8, x.shape[1]), (0, 2)))
     on_sublanes = lambda x: (column_tile(row), lax.broadcast_in_dim(
@@ -524,55 +647,65 @@ _SEM = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _padded(x, Tp, token_major):
+    """An operand as the kernels take it: T a whole number of blocks, a
+    head-major head of the width ``_head_width`` names."""
+    last = x.shape[-1]
+    return _pad_to(x, Tp, last if token_major else _head_width(last))
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
-                                             "dropout_rate", "window"))
+                                             "dropout_rate", "window",
+                                             "token_major"))
 def _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H, dropout_rate,
-         window=None):
-    """kvm: (B, Tp) fp32 key validity or None.  idq/idk: (B, Tp) int32
-    segment ids as the query and the key side see them, or None.  seed:
-    (1, 2) int32 dropout seed or None.  Returns o (BH, T, D) and the
-    logsumexp as (BH, 8, Tp) sublane-broadcast row tiles."""
-    BH, T, D = q.shape
+         window=None, token_major=False):
+    """q, k, v: head-major (B*H, T, D), (B*Hkv, T, D) or token-major
+    (B, T, H*D), (B, T, Hkv*D).  kvm: (B, Tp) fp32 key validity or None.
+    idq/idk: (B, Tp) int32 segment ids as the query and the key side see
+    them, or None.  seed: (1, 2) int32 dropout seed or None.  Returns o in
+    q's form and the logsumexp as (B*H, 8, Tp) sublane-broadcast row
+    tiles."""
+    T = q.shape[1]
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
-    Dp = _head_width(D)
-    qp, kp, vp = (_pad_to(x, Tp, Dp) for x in (q, k, v))
+    qp, kp, vp = (_padded(x, Tp, token_major) for x in (q, k, v))
     masked = kvm is not None or idq is not None or seed is not None
-    hb = _heads_per_step(H, Dp, q.dtype.itemsize, masked, blk)
+    lay = _Layout(qp, kp, H, token_major, masked, blk)
+    hb, BH = lay.hb, lay.B * H
     sweep = _Sweep(Tp // blk, blk, causal, window, "k", Tp != T, masked)
-    specs = row, stream, operand, row_tile, _ = _specs(sweep, blk, Dp, H, hb)
+    specs = row, stream, q_like, kv_like, row_tile, _ = _specs(sweep, lay)
     more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, sweep=sweep,
+        functools.partial(_fwd_kernel, scale=scale, sweep=sweep, lay=lay,
                           has_mask=kvm is not None,
                           has_segments=idq is not None,
                           dropout_rate=dropout_rate, T_real=T, Tp=Tp),
         grid=(BH // hb, sweep.rows, sweep.steps),
-        in_specs=[operand(row), operand(stream), operand(stream),
+        in_specs=[q_like(row), kv_like(stream), kv_like(stream),
                   *more_specs],
-        out_specs=[operand(row), row_tile(row)],
-        out_shape=[jax.ShapeDtypeStruct((BH, Tp, Dp), q.dtype),
+        out_specs=[q_like(row), row_tile(row)],
+        out_shape=[jax.ShapeDtypeStruct(qp.shape, q.dtype),
                    jax.ShapeDtypeStruct((BH, 8, Tp), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hb, blk, LANES), jnp.float32),
                         pltpu.VMEM((hb, blk, LANES), jnp.float32),
-                        pltpu.VMEM((hb, blk, Dp), jnp.float32)],
+                        pltpu.VMEM(lay.block(blk), jnp.float32)],
         compiler_params=_SEM,
         interpret=interpret(),
         name="flash_fwd",
     )(qp, kp, vp, *more)
-    return _unpad(o, T, D), lse
+    return _unpad(o, T, q.shape[-1]), lse
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
-               T_real, Tp):
+def _dq_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
+               dropout_rate, T_real, Tp):
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), kvm_ref, qseg_ref,
      kseg_ref, seed_ref, (dq_ref, dq_acc, lse_w, delta_w)) = _split_refs(
         refs, 6, has_mask, has_segments, dropout_rate)
-    hb, blk, _ = q_ref.shape
+    hb, blk = lay.hb, sweep.blk
     b = pl.program_id(0)
     i, j, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
 
@@ -594,12 +727,13 @@ def _dq_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
                 kvm=_row(kvm_ref), qseg=_column(qseg_ref),
                 kseg=_row(kseg_ref))
         for h in range(hb):
-            k = k_ref[h]
-            s = _dot(q_ref[h], k, ((1,), (1,))) * scale
+            q_h, kv_h = lay.head(h), lay.kv_head(h)
+            k = k_ref[kv_h]
+            s = _dot(q_ref[q_h], k, ((1,), (1,))) * scale
             p = jnp.exp(s - _tile_lanes(lse_w[h], blk))
             if valid is not None:
                 p = jnp.where(valid, p, 0.0)
-            dp = _dot(do_ref[h], v_ref[h], ((1,), (1,)))
+            dp = _dot(do_ref[q_h], v_ref[kv_h], ((1,), (1,)))
             if dropout_rate:
                 # dS = P ∘ (M ∘ (dO Vᵀ)/keep − delta): same counter hash
                 # as the forward, so the mask is bitwise-identical
@@ -608,7 +742,7 @@ def _dq_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
                 dp = jnp.where(u >= dropout_rate, dp, 0.0) * (
                     1.0 / (1.0 - dropout_rate))
             ds = (p * (dp - _tile_lanes(delta_w[h], blk))).astype(k.dtype)
-            dq_acc[h] += _dot(ds, k, ((1,), (0,)))
+            dq_acc[q_h] += _dot(ds, k, ((1,), (0,)))
 
     _run_pair(pair, live, sweep.interior(i, j))
 
@@ -617,18 +751,24 @@ def _dq_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
-                T_real, Tp):
+def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
+                dropout_rate, T_real, Tp):
     """In transposed form: the score tile is k-major, (k rows, q columns),
     so p and ds are born as the left operands dV = Pᵀ dO and dK = dSᵀ Q
     want, and a query's statistics are (1, blk) rows that broadcast along
-    sublanes."""
+    sublanes.  The query heads of a K/V group add into one accumulator in
+    fp32: ``lay.chunks`` steps a streamed block, ``hb`` heads each."""
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), kvm_ref, qseg_ref,
      kseg_ref, seed_ref, (dk_ref, dv_ref, dk_acc, dv_acc)) = _split_refs(
         refs, 6, has_mask, has_segments, dropout_rate)
-    hb, blk, _ = q_ref.shape
-    b = pl.program_id(0)
-    j, i, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
+    hb, blk = lay.hb, sweep.blk
+    b, g, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, t = _dkv_step(lay)(b, p)
+    j, i, live, first, last = sweep.qk(g, t)
+    if lay.chunks > 1:
+        chunk = p % lay.chunks
+        first = jnp.logical_and(first, chunk == 0)
+        last = jnp.logical_and(last, chunk == lay.chunks - 1)
 
     @pl.when(first)
     def _init():
@@ -646,14 +786,15 @@ def _dkv_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
                 kvm=_column(kvm_ref), qseg=_row(qseg_ref),
                 kseg=_column(kseg_ref))
         for h in range(hb):
-            q = q_ref[h]
-            do = do_ref[h]
-            s = _dot(k_ref[h], q, ((1,), (1,))) * scale        # (bk, bq)
+            q_h, kv_h = lay.head(h), lay.kv_head(h)
+            q = q_ref[q_h]
+            do = do_ref[q_h]
+            s = _dot(k_ref[kv_h], q, ((1,), (1,))) * scale     # (bk, bq)
             # padded q rows contribute nothing: their do rows are zero
             p = jnp.exp(s - lse_ref[h][:1, :])
             if valid is not None:
                 p = jnp.where(valid, p, 0.0)
-            dp = _dot(v_ref[h], do, ((1,), (1,)))
+            dp = _dot(v_ref[kv_h], do, ((1,), (1,)))
             p_acc = p
             if dropout_rate:
                 u = _keep_unit(seed_ref[0, 0], seed_ref[0, 1], b * hb + h,
@@ -662,9 +803,9 @@ def _dkv_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
                 inv_keep = 1.0 / (1.0 - dropout_rate)
                 p_acc = jnp.where(keep, p, 0.0) * inv_keep
                 dp = jnp.where(keep, dp, 0.0) * inv_keep
-            dv_acc[h] += _dot(p_acc.astype(do.dtype), do, ((1,), (0,)))
+            dv_acc[kv_h] += _dot(p_acc.astype(do.dtype), do, ((1,), (0,)))
             ds = (p * (dp - delta_ref[h][:1, :])).astype(q.dtype)
-            dk_acc[h] += _dot(ds, q, ((1,), (0,)))
+            dk_acc[kv_h] += _dot(ds, q, ((1,), (0,)))
 
     _run_pair(pair, live, sweep.interior(j, i))
 
@@ -675,82 +816,103 @@ def _dkv_kernel(*refs, scale, sweep, has_mask, has_segments, dropout_rate,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
-                                             "dropout_rate", "window"))
+                                             "dropout_rate", "window",
+                                             "token_major"))
 def _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed, scale, causal, H,
-         dropout_rate, window=None):
-    """lse: the forward's (BH, 8, Tp) row tiles; the rest as in _fwd."""
-    BH, T, D = q.shape
+         dropout_rate, window=None, token_major=False):
+    """lse: the forward's (B*H, 8, Tp) row tiles; the rest as in _fwd.
+    dk and dv come back in k's form: one head a K/V head, the sum over its
+    query heads taken in the kernel's fp32 accumulator."""
+    T = q.shape[1]
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
-    Dp = _head_width(D)
-    qp, kp, vp, dop = (_pad_to(x, Tp, Dp) for x in (q, k, v, do))
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    qp, kp, vp, dop = (_padded(x, Tp, token_major) for x in (q, k, v, do))
+    masked = kvm is not None or idq is not None or seed is not None
+    lay = _Layout(qp, kp, H, token_major, masked, blk)
+    hb, BH = lay.hb, lay.B * H
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if token_major:         # (B, T, H*D) -> a row of T a head
+        delta = jnp.sum(prod.reshape(*token_tile_axes(lay.B, T), H, -1), -1)
+        delta = jnp.moveaxis(delta, 3, 1).reshape(BH, T)
+    else:
+        delta = jnp.sum(prod, -1)
     if Tp != T:
         delta = jnp.pad(delta, ((0, 0), (0, Tp - T)))
     delta = lax.broadcast_in_dim(delta, (BH, 8, Tp), (0, 2))
-    masked = kvm is not None or idq is not None or seed is not None
-    hb = _heads_per_step(H, Dp, q.dtype.itemsize, masked, blk)
-    kinds = dict(scale=scale, has_mask=kvm is not None,
+    kinds = dict(scale=scale, lay=lay, has_mask=kvm is not None,
                  has_segments=idq is not None, dropout_rate=dropout_rate,
                  T_real=T, Tp=Tp)
-    acc = lambda width: pltpu.VMEM((hb, blk, width), jnp.float32)
+    acc = lambda *shape: pltpu.VMEM(shape, jnp.float32)
 
     # dq: a row is a q block (with do and its statistics); K, V and the
     # key-side tiles stream
     sweep = _Sweep(Tp // blk, blk, causal, window, "k", Tp != T, masked)
-    specs = row, stream, operand, row_tile, _ = _specs(sweep, blk, Dp, H, hb)
+    specs = row, stream, q_like, kv_like, row_tile, _ = _specs(sweep, lay)
     more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sweep=sweep, **kinds),
         grid=(BH // hb, sweep.rows, sweep.steps),
-        in_specs=[operand(row), operand(stream), operand(stream),
-                  operand(row), row_tile(row), row_tile(row), *more_specs],
-        out_specs=operand(row),
-        out_shape=jax.ShapeDtypeStruct((BH, Tp, Dp), q.dtype),
-        scratch_shapes=[acc(Dp), acc(LANES), acc(LANES)],
+        in_specs=[q_like(row), kv_like(stream), kv_like(stream),
+                  q_like(row), row_tile(row), row_tile(row), *more_specs],
+        out_specs=q_like(row),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        scratch_shapes=[acc(*lay.block(blk)), acc(hb, blk, LANES),
+                        acc(hb, blk, LANES)],
         compiler_params=_SEM,
         interpret=interpret(),
         name="flash_dq",
     )(qp, kp, vp, dop, lse, delta, *more)
 
     # dk/dv: a row is a k block (with v and the key-side tiles); Q, dO,
-    # their statistics and the q ids stream
+    # their statistics and the q ids stream, a K/V group's query heads
+    # ``lay.chunks`` steps a block
     sweep = _Sweep(Tp // blk, blk, causal, window, "q", Tp != T, masked)
-    specs = row, stream, operand, row_tile, _ = _specs(sweep, blk, Dp, H, hb)
+    specs = row, stream, q_like, kv_like, row_tile, _ = _specs(
+        sweep, lay, _dkv_step(lay))
     more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sweep=sweep, **kinds),
-        grid=(BH // hb, sweep.rows, sweep.steps),
-        in_specs=[operand(stream), operand(row), operand(row),
-                  operand(stream), row_tile(stream), row_tile(stream),
+        grid=(BH // hb // lay.chunks, sweep.rows, sweep.steps * lay.chunks),
+        in_specs=[q_like(stream), kv_like(row), kv_like(row),
+                  q_like(stream), row_tile(stream), row_tile(stream),
                   *more_specs],
-        out_specs=[operand(row), operand(row)],
-        out_shape=[jax.ShapeDtypeStruct((BH, Tp, Dp), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Tp, Dp), v.dtype)],
-        scratch_shapes=[acc(Dp), acc(Dp)],
+        out_specs=[kv_like(row), kv_like(row)],
+        out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                   jax.ShapeDtypeStruct(kp.shape, v.dtype)],
+        scratch_shapes=[acc(*lay.block(blk, kv=True))] * 2,
         compiler_params=_SEM,
         interpret=interpret(),
         name="flash_dkv",
     )(qp, kp, vp, dop, lse, delta, *more)
-    return _unpad(dq, T, D), _unpad(dk, T, D), _unpad(dv, T, D)
+    return (_unpad(dq, T, q.shape[-1]), _unpad(dk, T, k.shape[-1]),
+            _unpad(dv, T, k.shape[-1]))
 
 
 # ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
 
-def _count_call(q3, causal, window, masked, launches):
+def _count_call(q, k, H, token_major, causal, window, masked, launches):
     """Trace-time counters of one flash call (docs/observability.md): the
-    block pairs each launch's grid visits by kind, and the operands padded
-    and results sliced around the kernels (forward: q, k, v in and o out;
-    backward: q, k, v, do in and dq, dk, dv out)."""
-    BH, T, D = q3.shape
+    form its operands took, the block pairs each launch's grid visits by
+    kind, and the operands padded and results sliced around the kernels
+    (forward: q, k, v in and o out; backward: q, k, v, do in and dq, dk,
+    dv out)."""
+    T, last = q.shape[1:]
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
+    grouped = q.size != k.size
+    _count("flash_calls_total",
+           "flash-attention calls traced (a forward, or a backward), by the "
+           "form of their operands: token_major (B, T, H*D) or head_major "
+           "(B*H, T, D); K/V once per K/V head (grouped) or per query head",
+           layout="token_major" if token_major else "head_major",
+           kv="grouped" if grouped else "per_query_head")
+    heads = q.shape[0] * (H if token_major else 1)      # B * H
     for streams in launches:
         _Sweep(Tp // blk, blk, causal, window, streams, Tp != T,
-               masked).count(BH)
-    fits = (Tp, _head_width(D)) == (T, D)
+               masked).count(heads)
+    fits = Tp == T and (token_major or _head_width(last) == last)
     _count("flash_pad_copies_total",
            "flash-attention operands padded and results sliced, per "
            "traced call; 0 when T is a whole number of blocks and D is "
@@ -758,81 +920,62 @@ def _count_call(q3, causal, window, masked, launches):
            0 if fits else {"k": 4, "kq": 7}[launches])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
-def _flash(q3, k3, v3, kvm, idq, idk, seed, scale: float, causal: bool,
-           H: int, dropout_rate: float, window: Optional[int]):
-    return _flash_fwd(q3, k3, v3, kvm, idq, idk, seed, scale, causal, H,
-                      dropout_rate, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
+def _flash(q, k, v, kvm, idq, idk, seed, scale: float, causal: bool,
+           H: int, dropout_rate: float, window: Optional[int],
+           token_major: bool):
+    return _flash_fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
+                      dropout_rate, window, token_major)[0]
 
 
-def _flash_fwd(q3, k3, v3, kvm, idq, idk, seed, scale, causal, H,
-               dropout_rate, window):
+def _flash_fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
+               dropout_rate, window, token_major):
     masked = kvm is not None or idq is not None or seed is not None
-    _count_call(q3, causal, window, masked, "k")
-    o, lse = _fwd(q3, k3, v3, kvm, idq, idk, seed, scale, causal, H,
-                  dropout_rate, window)
-    return o, (q3, k3, v3, o, lse, kvm, idq, idk, seed)
+    _count_call(q, k, H, token_major, causal, window, masked, "k")
+    o, lse = _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
+                  dropout_rate, window, token_major)
+    return o, (q, k, v, o, lse, kvm, idq, idk, seed)
 
 
-def _flash_bwd(scale, causal, H, dropout_rate, window, res, do):
-    q3, k3, v3, o, lse, kvm, idq, idk, seed = res
+def _flash_bwd(scale, causal, H, dropout_rate, window, token_major, res,
+               do):
+    q, k, v, o, lse, kvm, idq, idk, seed = res
     masked = kvm is not None or idq is not None or seed is not None
-    _count_call(q3, causal, window, masked, "kq")
-    dq, dk, dv = _bwd(q3, k3, v3, o, lse, do, kvm, idq, idk, seed,
-                      scale, causal, H, dropout_rate, window)
+    _count_call(q, k, H, token_major, causal, window, masked, "kq")
+    dq, dk, dv = _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed,
+                      scale, causal, H, dropout_rate, window, token_major)
     dkvm = None if kvm is None else jnp.zeros_like(kvm)
     # int primals -> float0 cotangents
     f0 = lambda a: (None if a is None
                     else np.zeros(a.shape, jax.dtypes.float0))
-    return (dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype),
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             dkvm, f0(idq), f0(idk), f0(seed))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = False,
-                    scale: Optional[float] = None,
-                    kv_mask: Optional[jax.Array] = None,
-                    dropout_rate: float = 0.0,
-                    dropout_seed: Optional[jax.Array] = None,
-                    segment_ids: Optional[jax.Array] = None,
-                    window: Optional[int] = None) -> jax.Array:
-    """softmax(q k^T * scale [+ causal mask]) v without materializing the
-    score matrix in HBM.  q, k, v: (B, H, T, D) self-attention operands
-    (equal sequence lengths).  K/V are streamed through VMEM in blocks,
-    so the sequence length is bounded by HBM, not VMEM.
-
-    ``kv_mask``: optional (B, T) bool key-validity (True = attend) — the
-    key-padding mask of BERT-style batches, streamed alongside the K/V
-    blocks as sublane-broadcast (B, 8, T) tiles (the upstream
-    jax.experimental flash kernel's SegmentIds layout).  Composes with
-    ``causal``.  Queries whose keys are ALL masked produce zero output
-    rows (the dense softmax would give a uniform average instead).
-
-    ``dropout_rate`` + ``dropout_seed`` (int32 scalar, e.g. drawn per
-    step from a PRNGKey): attention-probability dropout computed INSIDE
-    the kernel from a counter-based hash of the absolute positions —
-    no (T, T) mask materializes, and the backward passes regenerate the
-    identical mask from the same counters (FlashAttention's dropout
-    placement: the softmax normalizer is undropped, the value
-    accumulation is dropped and rescaled by 1/keep).
-
-    ``segment_ids``: optional (B, T) int32 for packed sequences —
-    position pairs attend only within equal ids (q-ids stream as
-    lane-broadcast tiles, k-ids as sublane tiles).  Composes with
-    ``causal``/``kv_mask``/dropout.  Rows whose segment has no other
-    member still see themselves (the diagonal id always matches).
-
-    ``window``: a static sliding window on top of ``causal=True`` — key j
-    is visible to query i iff ``i - window < j <= i``.  Each of the three
-    kernels then visits only the block pairs the band touches; with
-    ``window=None`` they are the programs they are without it."""
+def _attend(q, k, v, token_major, causal, scale, kv_mask, dropout_rate,
+            dropout_seed, segment_ids, window):
+    """The checks and operand preparation both entries share.  q, k, v
+    arrive 4-D, heads on axis 1 (head-major) or 2 (token-major)."""
+    h_axis, t_axis = (2, 1) if token_major else (1, 2)
     if q.ndim != 4:
-        raise ValueError(f"expected (B, H, T, D), got {q.shape}")
-    if q.shape != k.shape or k.shape != v.shape:
-        raise ValueError("flash_attention requires matching q/k/v shapes")
+        raise ValueError("expected "
+                         f"{'(B, T, H, D)' if token_major else '(B, H, T, D)'}"
+                         f", got {q.shape}")
+    B, T, D = q.shape[0], q.shape[t_axis], q.shape[3]
+    H, Hkv = q.shape[h_axis], k.shape[h_axis]
+    grouped = list(q.shape)
+    grouped[h_axis] = Hkv
+    if k.shape != v.shape or list(k.shape) != grouped or H % Hkv:
+        raise ValueError(
+            "flash_attention requires k and v of q's shape, or of a number "
+            f"of heads that divides q's: got {q.shape}, {k.shape}, "
+            f"{v.shape}")
+    if token_major and D % LANES:
+        raise ValueError("token-major operands need a head of whole lane "
+                         f"tiles (D % {LANES} == 0), got D = {D}")
     dropout_rate = float(dropout_rate)
     if not 0.0 <= dropout_rate < 1.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got "
@@ -844,7 +987,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if not causal or window < 1:
             raise ValueError("window needs causal=True and window >= 1, "
                              f"got causal={causal}, window={window}")
-    B, H, T, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     blk = _block_for(T, window)
@@ -876,7 +1018,73 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ids = segment_ids.astype(jnp.int32)
         idq = jnp.pad(ids, ((0, 0), (0, Tp - T)), constant_values=-1)
         idk = jnp.pad(ids, ((0, 0), (0, Tp - T)), constant_values=-2)
-    fold = lambda x: x.reshape(B * H, T, D)
+    if token_major:         # the projection's own output: a free reshape
+        fold = lambda x: x.reshape(B, T, -1)
+    else:
+        fold = lambda x: x.reshape(-1, T, D)
     out = _flash(fold(q), fold(k), fold(v), kvm, idq, idk, seed,
-                 float(scale), bool(causal), H, dropout_rate, window)
-    return out.reshape(B, H, T, D)
+                 float(scale), bool(causal), H, dropout_rate, window,
+                 token_major)
+    return out.reshape(q.shape)
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = False,
+                    scale: Optional[float] = None,
+                    kv_mask: Optional[jax.Array] = None,
+                    dropout_rate: float = 0.0,
+                    dropout_seed: Optional[jax.Array] = None,
+                    segment_ids: Optional[jax.Array] = None,
+                    window: Optional[int] = None) -> jax.Array:
+    """softmax(q k^T * scale [+ causal mask]) v without materializing the
+    score matrix in HBM.  Head-major self-attention operands (equal
+    sequence lengths): q (B, H, T, D); k, v (B, Hkv, T, D) with ``Hkv``
+    dividing ``H`` — query head h reads K/V head ``h // (H // Hkv)``, and
+    dk, dv come back at ``Hkv`` heads.  K/V are streamed through VMEM in
+    blocks, so the sequence length is bounded by HBM, not VMEM.
+
+    ``kv_mask``: optional (B, T) bool key-validity (True = attend) — the
+    key-padding mask of BERT-style batches, streamed alongside the K/V
+    blocks as sublane-broadcast (B, 8, T) tiles (the upstream
+    jax.experimental flash kernel's SegmentIds layout).  Composes with
+    ``causal``.  Queries whose keys are ALL masked produce zero output
+    rows (the dense softmax would give a uniform average instead).
+
+    ``dropout_rate`` + ``dropout_seed`` (int32 scalar, e.g. drawn per
+    step from a PRNGKey): attention-probability dropout computed INSIDE
+    the kernel from a counter-based hash of the absolute positions —
+    no (T, T) mask materializes, and the backward passes regenerate the
+    identical mask from the same counters (FlashAttention's dropout
+    placement: the softmax normalizer is undropped, the value
+    accumulation is dropped and rescaled by 1/keep).
+
+    ``segment_ids``: optional (B, T) int32 for packed sequences —
+    position pairs attend only within equal ids (q-ids stream as
+    lane-broadcast tiles, k-ids as sublane tiles).  Composes with
+    ``causal``/``kv_mask``/dropout.  Rows whose segment has no other
+    member still see themselves (the diagonal id always matches).
+
+    ``window``: a static sliding window on top of ``causal=True`` — key j
+    is visible to query i iff ``i - window < j <= i``.  Each of the three
+    kernels then visits only the block pairs the band touches; with
+    ``window=None`` they are the programs they are without it."""
+    return _attend(q, k, v, False, causal, scale, kv_mask, dropout_rate,
+                   dropout_seed, segment_ids, window)
+
+
+def flash_attention_token_major(q: jax.Array, k: jax.Array, v: jax.Array,
+                                causal: bool = False,
+                                scale: Optional[float] = None,
+                                kv_mask: Optional[jax.Array] = None,
+                                dropout_rate: float = 0.0,
+                                dropout_seed: Optional[jax.Array] = None,
+                                segment_ids: Optional[jax.Array] = None,
+                                window: Optional[int] = None) -> jax.Array:
+    """``flash_attention`` on operands where the projections wrote them:
+    q (B, T, H, D); k, v (B, T, Hkv, D); the result (B, T, H, D).  The
+    kernels read them as (B, T, H*D) — no axis is moved and no K/V head
+    repeated on the way in or out, forward or backward.  Needs
+    ``D % 128 == 0`` (a head is then whole lane tiles of a token's row);
+    everything else as in ``flash_attention``, the same three kernels."""
+    return _attend(q, k, v, True, causal, scale, kv_mask, dropout_rate,
+                   dropout_seed, segment_ids, window)

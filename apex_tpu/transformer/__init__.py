@@ -5,6 +5,8 @@ New capability relative to the 2019 reference (which has no attention,
 SURVEY.md §5): long-context support is first-class in apex_tpu.
 """
 
-from .attention import dot_product_attention, MultiheadAttention
+from .attention import (dot_product_attention,
+                        dot_product_attention_token_major,
+                        MultiheadAttention)
 from .ring_attention import ring_attention, ring_self_attention
 from .ulysses import ulysses_attention, ulysses_self_attention

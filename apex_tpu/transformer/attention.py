@@ -23,8 +23,8 @@ from ..nn import functional as F
 from ..nn.module import Module, current_context
 from ..nn.layers import Linear, Dropout
 
-__all__ = ["dot_product_attention", "MultiheadAttention",
-           "set_path_hook"]
+__all__ = ["dot_product_attention", "dot_product_attention_token_major",
+           "MultiheadAttention", "set_path_hook"]
 
 # Trace-time debug hook: parity harnesses comparing backends need to know
 # which path a call compiled to, because flash vs dense differ
@@ -167,6 +167,71 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         key = dropout_rng if dropout_rng is not None else ctx.make_rng()
         probs = F.dropout(probs, dropout_rate, key)
     return F.matmul(probs.astype(v.dtype), v)
+
+
+def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
+                                      v: jax.Array, causal: bool = False,
+                                      scale: Optional[float] = None,
+                                      window: Optional[int] = None,
+                                      kv_mask: Optional[jax.Array] = None,
+                                      segment_ids: Optional[jax.Array] = None
+                                      ) -> jax.Array:
+    """``dot_product_attention`` on operands where the projections wrote
+    them: q (B, T, H, D); k, v (B, T, Hkv, D) with ``Hkv`` dividing ``H``
+    (query head h reads K/V head ``h // (H // Hkv)``); -> (B, T, H, D).
+    A caller that has ``x @ W`` as (B, T, H*D) reshapes it for free, moves
+    no axis, repeats no K/V head, and reshapes the result for its output
+    projection.
+
+    On TPU, with a head of whole lane tiles (``D % 128 == 0``), the flash
+    kernels read these arrays as they are (``ops.pallas_flash_attention``:
+    token-major operands, K/V once per K/V head); elsewhere the dense path
+    groups the query heads over the K/V heads in one einsum, softmax in
+    fp32.  ``causal``, ``window``, ``segment_ids`` as in
+    ``dot_product_attention``; ``kv_mask``: (B, T) bool key validity (True
+    = attend), with its caveat on fully-masked rows."""
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    if k.shape != v.shape or k.shape != (B, T, Hkv, D) or H % Hkv:
+        raise ValueError("expected q (B, T, H, D) and k, v (B, T, Hkv, D) "
+                         f"with Hkv dividing H, got {q.shape}, {k.shape}, "
+                         f"{v.shape}")
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    if window is not None and (not causal or window < 1):
+        raise ValueError("window needs causal=True and window >= 1, got "
+                         f"causal={causal}, window={window}")
+    # the cast policy the dense matmuls of dot_product_attention apply (op
+    # 'dot_product_attention' is in amp.lists.FP16_FUNCS), on either path
+    from ..amp import policy as _pol
+    (q, k, v), _ = _pol.cast_op_args("dot_product_attention", (q, k, v), {})
+    from ..ops import dispatch
+    if dispatch.use_pallas_for(q) and D % 128 == 0:
+        from ..ops import pallas_flash_attention as pfa
+        if pfa.fits_vmem(T, D, segments=segment_ids is not None,
+                         window=window):
+            _note_path("flash")
+            return pfa.flash_attention_token_major(
+                q, k, v, causal=causal, scale=scale, kv_mask=kv_mask,
+                segment_ids=segment_ids, window=window)
+    _note_path("dense")
+    see = None if kv_mask is None else kv_mask[:, None, None, None, :]
+    both = lambda a, b: b if a is None else jnp.logical_and(a, b)
+    if causal:
+        i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+        see = both(see, j <= i)
+        if window is not None:
+            see = both(see, j > i - window)
+    if segment_ids is not None:
+        see = both(see, (segment_ids[:, None, None, :, None]
+                         == segment_ids[:, None, None, None, :]))
+    grouped = q.reshape(B, T, Hkv, H // Hkv, D)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", grouped, k,
+                        preferred_element_type=jnp.float32) * scale
+    if see is not None:
+        scores = jnp.where(see, scores, jnp.full_like(scores, -1e30))
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(B, T, H, D)
 
 
 class MultiheadAttention(Module):

@@ -5,8 +5,11 @@ to the band's blocks, ``window=None`` the program it was, the dispatch in
 ``dot_product_attention`` and in the Llama attention; every path the kernels
 separate (interior and edge pairs, the folded causal triangle, heads of 64 and
 128 unpadded, ragged lengths, masks, segments and dropout) against one dense
-reference, and the block-pair counters; and, compiled for a described v5e,
-the kernels at the widths of the benchmark's cells."""
+reference, and the block-pair counters; the token-major operand form with
+K/V once per K/V head against the same reference and against the head-major
+call on the same data moved and repeated by hand; and, compiled for a
+described v5e, the kernels at the widths of the benchmark's cells and the
+decoder's attention layer around them."""
 
 import math
 
@@ -187,7 +190,7 @@ PATHS = {
     "kv-mask-with-a-fully-masked-row": (2, 1, 256, 64, dict(kv_mask=_half_masked)),
     "dropout-segments-causal": (1, 2, 384, 32, dict(
         causal=True, dropout_rate=0.2, dropout_seed=77,
-        segment_ids=lambda B, T: jnp.asarray(np.repeat([0, 1, 2], T // 3)[None].repeat(B, 0)))),
+        segment_ids=lambda B, T: _thirds(B, T))),
 }
 
 
@@ -257,6 +260,156 @@ def test_heads_sharing_a_grid_step_equal_heads_taken_one_a_step(features, T, hea
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=4e-2)
 
 
+# -- token-major operands, K/V once per K/V head --------------------------------
+
+def _thirds(B, T):
+    return jnp.asarray(np.repeat([0, 1, 2], -(-T // 3))[:T][None].repeat(B, 0))
+
+
+# name: (B, H, Hkv, T, features); D = 128, a head being whole lane tiles
+TOKEN_MAJOR = {
+    "causal-rep1": (1, 2, 2, 1024, dict(causal=True)),
+    "causal-rep6": (1, 6, 1, 1024, dict(causal=True)),
+    "causal-rep8": (1, 8, 1, 1024, dict(causal=True)),
+    "window-512-rep1": (1, 2, 2, 1024, dict(causal=True, window=512)),
+    "window-512-rep6": (1, 6, 1, 1024, dict(causal=True, window=512)),
+    "window-512-rep8-two-groups": (1, 16, 2, 1024, dict(causal=True, window=512)),
+    "kv-mask-segments-rep6-ragged": (2, 12, 2, 600, dict(causal=True, kv_mask=_half_masked,
+                                                         segment_ids=_thirds)),
+    "whole-rep2-dropout": (1, 4, 2, 512, dict(dropout_rate=0.2, dropout_seed=11)),
+}
+
+
+def _token_major(name, dtype):
+    B, H, Hkv, T, features = TOKEN_MAJOR[name]
+    ks = jax.random.split(jax.random.PRNGKey(len(name) + T), 4)
+    q, do = (jax.random.normal(kk, (B, T, H, 128), dtype) for kk in ks[::3])
+    k, v = (jax.random.normal(kk, (B, T, Hkv, 128), dtype) for kk in ks[1:3])
+    features = {f: (x(B, T) if callable(x) else x) for f, x in features.items()}
+    return (q, k, v), do, features
+
+
+def _by_hand(fn, features):
+    """``fn`` (head-major, K/V at the query head count) on token-major, grouped
+    operands: axes moved and K/V heads repeated around it."""
+    def run(q, k, v):
+        heads = lambda x: jnp.repeat(jnp.moveaxis(x, 2, 1), q.shape[2] // x.shape[2], axis=1)
+        return jnp.moveaxis(fn(heads(q), heads(k), heads(v), **features), 1, 2)
+    return run
+
+
+def _value_and_grads(fn, qkv, do):
+    f32 = lambda x: x.astype(jnp.float32)
+    return jax.value_and_grad(lambda *a: jnp.sum(f32(fn(*a)) * f32(do)), (0, 1, 2))(*qkv)
+
+
+@pytest.mark.parametrize("name", TOKEN_MAJOR)
+def test_token_major_matches_dense_forward_and_gradients(name):
+    """fp32 heads go one a step, so a K/V group's query heads are ``rep`` steps
+    of the dk/dv grid's sequential axis adding into one accumulator."""
+    qkv, do, features = _token_major(name, jnp.float32)
+    got = pfa.flash_attention_token_major(*qkv, **features)
+    want = _by_hand(_dense_all, features)(*qkv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-6)
+    (_, gg), (_, gw) = (_value_and_grads(fn, qkv, do) for fn in (
+        lambda *a: pfa.flash_attention_token_major(*a, **features), _by_hand(_dense_all, features)))
+    for x, g, w in zip("qkv", gg, gw):
+        assert g.shape == w.shape and np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=4e-5, err_msg=f"d{x}")
+
+
+@pytest.mark.parametrize("name", TOKEN_MAJOR)
+def test_token_major_grouped_equals_head_major_moved_and_repeated_by_hand(name):
+    """bf16, so heads share steps (6 of a group of 6, 8 of 8 at blocks of 256):
+    o and dq bitwise; dk and dv to bf16 rounding, because the kernel sums a
+    group's heads in fp32 before its one cast where the repeat's transpose
+    sums the casts."""
+    qkv, do, features = _token_major(name, jnp.bfloat16)
+    (o, (dq, dk, dv)), (o_, (dq_, dk_, dv_)) = (_value_and_grads(fn, qkv, do) for fn in (
+        lambda *a: pfa.flash_attention_token_major(*a, **features),
+        _by_hand(pfa.flash_attention, features)))
+    f32 = lambda x: np.asarray(x, np.float32)
+    assert float(o) == float(o_) and (f32(dq) == f32(dq_)).all()
+    for x, g, w in (("k", dk, dk_), ("v", dv, dv_)):
+        rep = qkv[0].shape[2] // g.shape[2]
+        if rep == 1:
+            assert (f32(g) == f32(w)).all(), f"d{x}"
+        assert np.abs(f32(g) - f32(w)).max() <= 2.0 ** -6 * np.abs(f32(w)).max(), f"d{x}"
+    # the same operands head-major, K/V still once per K/V head
+    mv = lambda x: jnp.moveaxis(x, 2, 1)
+    o_hm, g_hm = _value_and_grads(lambda q, k, v: mv(pfa.flash_attention(mv(q), mv(k), mv(v), **features)),
+                                  qkv, do)
+    assert float(o_hm) == float(o)
+    for a, b in zip(g_hm, (dq, dk, dv)):
+        assert (f32(a) == f32(b)).all()
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_heads_of_one_group_sharing_a_step_equal_one_head_a_step(monkeypatch, window):
+    """Any divisor of ``rep`` heads a step: the fp32 additions into dk/dv's
+    accumulator come in the same order (streamed block, then head), bitwise."""
+    B, H, Hkv, T = 1, 12, 2, 1024
+    blk = pfa._block_for(T, window)
+    assert pfa._heads_per_step(H, 128, 2, False, blk, H // Hkv) == 6
+    assert pfa._heads_per_step(48, 128, 2, False, 512, 6) == 6       # the decoder's full layers
+    assert pfa._heads_per_step(64, 128, 2, False, 256, 8) == 8       # and its sliding ones
+    assert pfa._heads_per_step(64, 128, 2, False, 512, 8) == 4
+    assert pfa._heads_per_step(48, 128, 2, True, 512, 6) == 3
+    assert pfa._heads_per_step(48, 128, 4, False, 512, 6) == 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, do = (jax.random.normal(kk, (B, T, H, 128), jnp.bfloat16) for kk in ks[::3])
+    k, v = (jax.random.normal(kk, (B, T, Hkv, 128), jnp.bfloat16) for kk in ks[1:3])
+    f32 = lambda x: np.asarray(x, np.float32)
+    results = []
+    for hb in (6, 3, 2, 1):
+        monkeypatch.setattr(pfa, "_heads_per_step", lambda *a, **kw: hb)
+        pfa._fwd.clear_cache(), pfa._bwd.clear_cache()
+        results.append(_value_and_grads(lambda *a: pfa.flash_attention_token_major(
+            *a, causal=True, window=window), (q, k, v), do))
+    pfa._fwd.clear_cache(), pfa._bwd.clear_cache()
+    for o, grads in results[1:]:
+        assert float(o) == float(results[0][0])
+        for a, b in zip(grads, results[0][1]):
+            assert (f32(a) == f32(b)).all()
+
+
+def test_token_major_needs_whole_lane_tiles_and_heads_that_divide():
+    x = jnp.zeros((1, 256, 4, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="lane tiles"):
+        pfa.flash_attention_token_major(x, x, x)
+    q, k = jnp.zeros((1, 256, 6, 128)), jnp.zeros((1, 256, 4, 128))
+    with pytest.raises(ValueError, match="divides"):
+        pfa.flash_attention_token_major(q, k, k)
+    with pytest.raises(ValueError, match="divides"):
+        pfa.flash_attention(q[:, :, :3], k, k)
+
+
+def test_dot_product_attention_token_major_dispatches_like_the_head_major_entry(monkeypatch):
+    """Dense on the CPU (query heads grouped over K/V heads in one einsum), the
+    flash kernels where Pallas is on and a head is whole lane tiles."""
+    qkv, _, _ = _token_major("window-512-rep6", jnp.float32)
+    seg = _thirds(1, 1024)
+    want = _by_hand(_dense_all, dict(causal=True, window=512, segment_ids=seg))(*qkv)
+    paths = []
+    attention.set_path_hook(paths.append)
+    try:
+        dense = attention.dot_product_attention_token_major(*qkv, causal=True, window=512,
+                                                            segment_ids=seg)
+        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "1")
+        monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
+        flash = attention.dot_product_attention_token_major(*qkv, causal=True, window=512,
+                                                            segment_ids=seg)
+        narrow = attention.dot_product_attention_token_major(
+            *(x[..., :64] for x in qkv), causal=True)          # D = 64: no lane tile
+    finally:
+        attention.set_path_hook(None)
+    assert paths == ["dense", "flash", "dense"] and narrow.shape == (1, 1024, 6, 64)
+    for got in (dense, flash):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-6)
+    with pytest.raises(ValueError, match="causal"):
+        attention.dot_product_attention_token_major(*qkv, window=8)
+
+
 # -- the counters: what a call's grids visit, and what it copies ---------------
 
 def _counted(fn, *shapes):
@@ -271,6 +424,19 @@ def _counted(fn, *shapes):
     before = read()
     jax.eval_shape(fn, *shapes)
     return {k: int(v - before[k]) for k, v in read().items()}
+
+
+def _calls_counted(fn, *shapes):
+    """``flash_calls_total`` by (layout, kv) over one trace of ``fn``."""
+    from apex_tpu.observability.metrics import get_registry
+
+    def read():
+        calls = get_registry().get("flash_calls_total")
+        return {tuple(v for _, v in sorted(k)): c.value
+                for k, c in calls.children().items()} if calls else {}
+    before = read()
+    jax.eval_shape(fn, *shapes)
+    return {k: int(v - before.get(k, 0)) for k, v in read().items() if v != before.get(k, 0)}
 
 
 def _qkv(BH, T, D):
@@ -312,6 +478,60 @@ def test_counter_pad_copies_are_zero_when_length_and_head_fit(T, D, pads):
     text = str(jax.make_jaxpr(lambda q, k, v: pfa.flash_attention(q, k, v))(
         *[jnp.zeros(s.shape, s.dtype) for s in _qkv(2, T, D)]))
     assert ("pad[" in text) == bool(pads)
+
+
+def test_counter_says_which_form_a_call_took():
+    """(kv, layout): a forward counts one call, a gradient its forward and its
+    backward."""
+    x = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    loss = lambda f: (lambda q, k, v: jnp.sum(f(q, k, v, causal=True).astype(jnp.float32)))
+    tm, hm = pfa.flash_attention_token_major, pfa.flash_attention
+    assert _calls_counted(jax.grad(loss(tm)), x(2, 512, 6, 128), x(2, 512, 1, 128), x(2, 512, 1, 128)) \
+        == {("grouped", "token_major"): 2}
+    assert _calls_counted(loss(tm), x(2, 512, 4, 128), x(2, 512, 4, 128), x(2, 512, 4, 128)) \
+        == {("per_query_head", "token_major"): 1}
+    assert _calls_counted(jax.grad(loss(hm)), *_qkv(16, 512, 64)) == {("per_query_head", "head_major"): 2}
+    assert _calls_counted(loss(hm), x(1, 8, 256, 64), x(1, 2, 256, 64), x(1, 2, 256, 64)) \
+        == {("grouped", "head_major"): 1}
+    # a grouped call visits the pairs of its query heads, and pads nothing
+    got = _counted(jax.grad(loss(tm)), x(1, 8192, 6, 128), x(1, 8192, 1, 128), x(1, 8192, 1, 128))
+    assert got == {"interior": 6 * 3 * 120, "edge": 6 * 3 * 16, "dead": 0, "pads": 0}
+
+
+# what the parent of PR 29 launched for these head-major calls: grid, q block, k block
+PARENTS_LAUNCHES = [
+    ("bert", (8, 16, 512, 64), dict(), (32, 1, 1), (4, 512, 64)),
+    ("bert-masked", (8, 16, 512, 64), dict(kv_mask=True), (64, 1, 1), (2, 512, 64)),
+    ("decoder-full-repeated", (2, 48, 8192, 128), dict(causal=True), (24, 8, 17), (4, 512, 128)),
+    ("decoder-sliding-repeated", (2, 64, 8192, 128), dict(causal=True, window=512), (16, 32, 3),
+     (8, 256, 128)),
+]
+
+
+@pytest.mark.parametrize("name,shape,features,grid,block", PARENTS_LAUNCHES,
+                         ids=[c[0] for c in PARENTS_LAUNCHES])
+def test_head_major_calls_launch_what_they_launched(name, shape, features, grid, block):
+    """Same grids, same blocks, same ``hb``, same kernel names, K/V blocks like
+    q's, the counters of a call as they were (and its form named)."""
+    from apex_tpu.analysis import pallas_lint
+    features = dict(features)
+    if features.pop("kv_mask", False):
+        features["kv_mask"] = jnp.ones(shape[::2], bool)
+    pfa._fwd.clear_cache(), pfa._bwd.clear_cache()
+    sites = []
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    with pallas_lint.capture_kernel_sites(sites):
+        calls = _calls_counted(jax.grad(lambda q, k, v: jnp.sum(
+            pfa.flash_attention(q, k, v, **features).astype(jnp.float32)), (0, 1, 2)), x, x, x)
+    pfa._fwd.clear_cache(), pfa._bwd.clear_cache()
+    assert calls == {("per_query_head", "head_major"): 2}
+    assert [s.name for s in sites] == ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+    for site in sites:
+        assert site.grid == grid, site.describe()
+        operands = [spec.block_shape for spec, (sh, _) in zip(site.in_specs, site.in_shapes)
+                    if len(sh) == 3 and sh[1:] == shape[2:]]
+        assert operands and set(operands) == {block}, site.describe()
+        assert pallas_lint.check_site(site) == []
 
 
 def test_index_maps_of_the_folded_triangle_cover_every_pair_once():
@@ -372,6 +592,57 @@ def test_v5e_compiles_the_flash_kernels_at_the_decoder_cells_widths(one_chip, fo
         assert kernel in text
     assert "f32[8,8192,8192]" not in text and "bf16[8,8192,8192]" not in text
     del fetched
+
+
+@pytest.mark.parametrize("kind,heads", [("sliding_attention", 64), ("full_attention", 48)])
+def test_v5e_compiles_the_decoders_attention_layer_without_copies_around_the_kernels(
+        one_chip, for_the_chip, kind, heads):
+    """``LagunaAttention`` forward + gradient under the cell's remat mode at the
+    cell's widths (B 2, T 8192, bf16): between the projections and the kernels
+    the entry computation moves no axis, repeats no K/V head and widens
+    nothing of q's size.  What keeps the copies from coming back with a later
+    edit of the model: a (B, T, H, D) view of a projection's output is a
+    relayout on a TPU, whose tiles are 8 tokens x 128 lanes."""
+    import json
+    import os
+    import re
+    from apex_tpu.models import laguna
+    from apex_tpu.models._remat import wrap_block
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "laguna-xs2.json")) as f:
+        cfg = laguna.LagunaConfig.from_dict(json.load(f))
+    layer = cfg.layer_types.index(kind)
+    assert cfg.num_attention_heads_per_layer[layer] == heads
+    attn = laguna.LagunaAttention(cfg, layer)
+    shapes = jax.eval_shape(lambda k: attn.init(k)[0], jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(wrap_block(lambda pp, xx: attn(pp, xx), "dots")(p, x).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv", "rope"):
+        assert re.search(rf"%{kernel}[.\d]* = ", text), kernel
+    q_elements = 2 * 8192 * heads * 128
+    entry = text[text.index("ENTRY"):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?.*?\)?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", shape):
+            dims = [int(d) for d in dims.split(",")]
+            if int(np.prod(dims)) < q_elements:
+                continue
+            # K or V at the query head count, in any arrangement of its axes
+            assert not (op == "broadcast" and heads // 8 in dims and 8 in dims), line[:200]
+            assert dtype != "f32", f"an fp32 array of q's size outside a fusion: {line[:200]}"
+            assert op not in ("copy", "transpose", "reshape", "convert", "broadcast"), \
+                f"{op} of q's size: {line[:200]}"
+    # K and V reach the kernels at their own 8 heads: dk, dv come back so
+    assert re.search(r"%flash_dkv[.\d]* = \(bf16\[2,8192,1024\]", text)
 
 
 def test_v5e_compiles_the_flash_kernels_at_the_encoder_cells_widths(one_chip, for_the_chip):

@@ -1,7 +1,9 @@
 """The per-layer decoder (models/laguna.py) against the benchmark's plain
 reference (benchmark/references/laguna.py) at a tiny config that keeps every
 kind of layer: a dense layer and one whole period (three sliding, one full),
-two head counts, both RoPE kinds, routed experts with a shared one.  And the
+two head counts, both RoPE kinds, routed experts with a shared one; its
+attention layer at a head of 128 through the kernels (the rotary pass and the
+token-major flash kernels, interpreted).  And the
 expert layer's contract: the shares of an expert-parallel group add up to the
 uncut layer, nothing is dropped under skew, and what a too small row buffer
 loses is counted."""
@@ -98,6 +100,68 @@ def test_gradients_match_the_reference(tiny, remat):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-6, rtol=2e-4,
                                    err_msg=jax.tree_util.keystr(path))
         assert float(jnp.abs(w).max()) > 0, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("kind,rotated", [("full_attention", 64), ("sliding_attention", 128)])
+def test_attention_layer_through_the_kernels_matches_the_reference(monkeypatch, kind, rotated):
+    """Heads of a whole lane tile with Pallas on (interpreted here): q, k, v go
+    from the projections through the rotary pass to the flash kernels
+    token-major, K/V at their own head count; half-rotary and whole, full and
+    banded, forward and gradients against the reference's attention."""
+    from apex_tpu.models.laguna import LagunaAttention
+    from apex_tpu.ops import pallas_rope
+    from apex_tpu.transformer import attention
+    cfg = dict(TINY, hidden_size=64, head_dim=128, num_hidden_layers=1, layer_types=[kind],
+               num_attention_heads_per_layer=[4], mlp_layer_types=["dense"], sliding_window=24)
+    layer = LagunaAttention(models.LagunaConfig.from_dict(cfg), 0)
+    assert 2 * layer.inv_freq.shape[0] == rotated
+    params, _ = layer.init(jax.random.PRNGKey(2))
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 64, 64), jnp.float32)
+    want_fn = lambda p, x: jnp.stack([ref.attention(p, row, 4, cfg, kind, "float32") for row in x])
+    weigh = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+    loss = lambda fn: (lambda p, x: jnp.sum(fn(p, x) * weigh))
+    want, want_grads = want_fn(params, x), jax.grad(loss(want_fn), (0, 1))(params, x)
+    plain = layer(params, x)                     # the CPU's forms: dense, jnp rotation
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "1")
+    monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
+    paths, passes = [], []
+    real = pallas_rope.rope_token_major
+    monkeypatch.setattr(pallas_rope, "rope_token_major",
+                        lambda x, *a: passes.append(x.shape) or real(x, *a))
+    attention.set_path_hook(paths.append)
+    try:
+        got, got_grads = layer(params, x), jax.grad(loss(layer), (0, 1))(params, x)
+    finally:
+        attention.set_path_hook(None)
+    assert set(paths) == {"flash"} and passes[:2] == [(2, 64, 4 * 128), (2, 64, 2 * 128)]
+    for a in (got, plain):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(want), atol=2e-5, rtol=2e-4)
+    for (path, g), (_, w) in zip(*(jax.tree_util.tree_leaves_with_path(t)
+                                   for t in (got_grads, want_grads))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4, rtol=2e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("rd,dtype", [(128, jnp.float32), (64, jnp.float32), (64, jnp.bfloat16)])
+def test_rotary_pass_matches_the_reference_rotation_and_its_gradient(rd, dtype):
+    from apex_tpu.ops import pallas_rope
+    rope = {"rope_theta": 10000, "partial_rotary_factor": rd / 128}
+    cos, sin = ref.rope_tables(rope, 128, 72)
+    x, g = (jax.random.normal(jax.random.PRNGKey(i), (2, 72, 3 * 128), dtype) for i in (5, 6))
+    want_fn = lambda x: jax.vmap(lambda row: ref.apply_rope(
+        row.reshape(72, 3, 128).astype(jnp.float32), cos, sin))(x).reshape(x.shape)
+    f32 = lambda a: np.asarray(a, np.float32)
+    got = pallas_rope.rope_token_major(x, cos, sin, 128)
+    assert got.dtype == dtype
+    tol = dict(atol=1e-5) if dtype == jnp.float32 else dict(atol=0.04, rtol=0.01)
+    np.testing.assert_allclose(f32(got), f32(want_fn(x)), **tol)
+    back = lambda fn: jax.grad(lambda x: jnp.sum(f32_j(fn(x)) * f32_j(g)))(x)
+    f32_j = lambda a: a.astype(jnp.float32)
+    np.testing.assert_allclose(f32(back(lambda x: pallas_rope.rope_token_major(x, cos, sin, 128))),
+                               f32(back(want_fn)), **tol)
+    assert pallas_rope.rows_per_block(8192) == 512 and pallas_rope.rows_per_block(300) == 0
+    with pytest.raises(ValueError, match="lane tiles"):
+        pallas_rope.rope_token_major(x[..., :192], cos, sin, 64)
 
 
 def test_o2_keeps_the_router_in_float32_and_trains():

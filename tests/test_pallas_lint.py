@@ -107,8 +107,9 @@ def test_smem_scalar_spec_is_exempt():
 def test_real_kernel_family_lints_clean():
     """Every pallas_call the ops package launches — Adam (both
     write-out arities), LAMB stages, layer-norm fwd/bwd, the
-    multi-tensor family, fused BN apply fwd/bwd, and flash attention
-    fwd/dq/dkv — satisfies the block/index/alias preconditions."""
+    multi-tensor family, fused BN apply fwd/bwd, flash attention
+    fwd/dq/dkv on head-major and on token-major, grouped operands, and
+    the rotary pass — satisfies the block/index/alias preconditions."""
     sites, problems = pallas_lint.lint_pallas_kernels()
     assert problems == []
     names = {s.name for s in sites}
@@ -116,9 +117,17 @@ def test_real_kernel_family_lints_clean():
     # that silently stops launching is as much a failure as a bad spec
     for expected in ("_adam_kernel", "_stage1_kernel", "_stage2_kernel",
                      "_scale_kernel", "_axpby_kernel", "_l2norm_kernel",
-                     "_dq_kernel", "_dkv_kernel"):
+                     "_dq_kernel", "_dkv_kernel", "_kernel"):
         assert expected in names, (expected, sorted(names))
     assert len(sites) >= 12, [s.describe() for s in sites]
+    # token-major launches: a (1, blk, hb * D) block of a (B, T, H * D)
+    # array, and dk/dv's sequential axis six times its sweep where six
+    # query heads go one a step
+    dkv = [s for s in sites if s.name == "_dkv_kernel"]
+    assert [s.grid for s in dkv] == [(2, 1, 1), (1, 2, 18), (4, 2, 2)]
+    assert dkv[1].in_specs[0].block_shape == (1, 128, 128)
+    assert dkv[2].in_specs[0].block_shape == (1, 256, 4 * 128)
+    assert dkv[2].in_specs[1].block_shape == (1, 256, 128)
 
 
 def test_aliased_kernels_record_their_donations():
