@@ -1,12 +1,18 @@
 """A decoder whose layers differ: per layer an attention kind (full or
 sliding-window), a query-head count, a RoPE kind and an MLP kind (dense
-SwiGLU or routed experts with a shared expert).
+SwiGLU or routed experts, with or without a shared expert).
 
-The shape is the published ``laguna`` config's (``layer_types``,
-``num_attention_heads_per_layer``, ``mlp_layer_types``, one
-``rope_parameters`` group per attention kind, ``sliding_window``).  Pre-norm
-residual blocks on the Llama parts (models/llama.py: RMSNorm, SwiGLU, the
-fused chunked head), with
+The configuration says which decoder it is, in the keys of the published
+config files of the families built on this shape (``layer_types``,
+``mlp_layer_types``, one ``rope_parameters`` group per attention kind,
+``sliding_window``; ``num_attention_heads_per_layer`` or one
+``num_attention_heads`` for every layer; ``norm_topk_prob``).  Two run in the
+benchmark: ``laguna`` (a leading dense layer, two head counts, a gate on the
+attention output, a sigmoid router with a scaling factor and a shared
+expert: the defaults below) and ``mellum`` (every layer sparse, one head
+count, no gate, a softmax router, no shared expert).  Pre-norm residual
+blocks on the Llama parts (models/llama.py: RMSNorm, SwiGLU, the fused
+chunked head), with
 
 - attention: ``H_l`` query heads over ``num_key_value_heads`` K/V heads,
   causal, a band of ``sliding_window`` keys in a sliding layer (handed to
@@ -16,10 +22,13 @@ fused chunked head), with
   ``yarn``: blended frequencies and a scale on cos/sin, as ``transformers``
   computes them), and — ``gating`` — a sigmoid gate per head on the
   attention output, ``o_h <- sigmoid(x w_h) * o_h``;
-- sparse MLP: ``parallel.ExpertParallelMLP`` with a sigmoid router over the
-  published ``router_experts``, the ``num_experts_per_tok`` largest
-  renormalized and scaled by ``moe_routed_scaling_factor``, the experts
-  this chip holds (``experts_held``) and a shared expert.
+- sparse MLP: ``parallel.ExpertParallelMLP`` with a ``router_type``
+  (``sigmoid`` or ``softmax``) router over the published
+  ``router_experts``, the ``num_experts_per_tok`` largest renormalized
+  (a config's ``norm_topk_prob``: one that states false is refused) and
+  scaled by ``moe_routed_scaling_factor``, the
+  experts this chip holds (``experts_held``) and, where
+  ``shared_expert_intermediate_size`` is not 0, a shared expert.
 
 Training and full-sequence forward only: a cache for decoding would have to
 hold window and global layers side by side (ROADMAP, Reach).
@@ -29,7 +38,7 @@ from __future__ import annotations
 
 import inspect
 import math
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -52,20 +61,29 @@ FULL, SLIDING = "full_attention", "sliding_attention"
 class LagunaConfig:
     """Sizes per layer.  ``num_experts`` experts are HELD here, from
     ``experts_held_start``, of the ``router_experts`` the router scores
-    (default: all of them are held)."""
+    (default: all of them are held).  ``num_attention_heads_per_layer``
+    may be left None where one ``num_attention_heads`` serves every
+    layer; ``shared_expert_intermediate_size`` 0 is no shared expert."""
 
     def __init__(self, vocab_size, hidden_size, intermediate_size,
                  layer_types: Sequence[str],
-                 num_attention_heads_per_layer: Sequence[int],
+                 num_attention_heads_per_layer: Optional[Sequence[int]],
                  mlp_layer_types: Sequence[str],
                  num_key_value_heads, head_dim, rope_parameters: dict,
                  sliding_window, num_experts, num_experts_per_tok,
-                 moe_intermediate_size, shared_expert_intermediate_size,
+                 moe_intermediate_size, shared_expert_intermediate_size=0,
                  moe_routed_scaling_factor=1.0, router_experts=None,
                  experts_held_start=0, moe_row_buffer_factor=None,
                  gating=True, rms_norm_eps=1e-6,
-                 max_position_embeddings=8192, remat=None, head_chunk=8192):
+                 max_position_embeddings=8192, remat=None, head_chunk=8192,
+                 num_attention_heads=None, router_type="sigmoid",
+                 norm_topk_prob=True):
         n = len(layer_types)
+        if num_attention_heads_per_layer is None:
+            if num_attention_heads is None:
+                raise ValueError("neither num_attention_heads_per_layer "
+                                 "nor num_attention_heads is given")
+            num_attention_heads_per_layer = [num_attention_heads] * n
         if not (len(num_attention_heads_per_layer) == n
                 and len(mlp_layer_types) == n):
             raise ValueError("layer_types, num_attention_heads_per_layer "
@@ -106,6 +124,11 @@ class LagunaConfig:
         self.moe_routed_scaling_factor = moe_routed_scaling_factor
         self.moe_row_buffer_factor = moe_row_buffer_factor
         self.gating = gating
+        if not norm_topk_prob:
+            raise ValueError("norm_topk_prob false: the expert layer "
+                             "renormalizes the chosen weights of every "
+                             "router with more than one expert a token")
+        self.router_type = router_type
         self.rms_norm_eps = rms_norm_eps
         self.max_position_embeddings = max_position_embeddings
         self.remat = remat
@@ -121,6 +144,7 @@ class LagunaConfig:
         held and ``num_experts_published`` the router's width."""
         names = inspect.signature(cls.__init__).parameters
         kw = {k: d[k] for k in names if k in d}
+        kw.setdefault("num_attention_heads_per_layer", None)
         if "num_experts_published" in d:
             kw["router_experts"] = d["num_experts_published"]
         kw.update(over)
@@ -240,7 +264,7 @@ class LagunaBlock(nn.Module):
                 cfg.hidden_size, cfg.moe_intermediate_size,
                 cfg.router_experts, capacity_factor=None,
                 top_k=cfg.num_experts_per_tok, expert_type="swiglu",
-                router_type="sigmoid",
+                router_type=cfg.router_type,
                 routed_scaling=cfg.moe_routed_scaling_factor,
                 experts_held=(cfg.experts_held_start, cfg.num_experts),
                 shared_hidden=cfg.shared_expert_intermediate_size,

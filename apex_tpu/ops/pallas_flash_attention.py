@@ -133,13 +133,16 @@ def _block_for(T: int, window: Optional[int] = None) -> int:
     length — bounds zero-padding at 127 rows (a fixed 512 block would pad
     T=600 to 1024, wasting 41% of every MXU contraction).  A row of blocks
     under a causal ``window`` computes ``blk + window - 1`` keys for the
-    ``window`` a query sees, so a window under two 512-blocks takes 256-row
-    blocks: 768 keys a row for a window of 512, not 1024.  (On a v5e that
-    pays only because ``_heads_per_step`` then puts 8 heads into a grid
-    step, whose fixed cost a 256-block cannot carry alone; 128 loses to
-    both.  PERF.md section 6, PR 27.)"""
+    ``window`` a query sees, so a window of up to two 512-blocks takes
+    256-row blocks: 768 keys a row for a window of 512, not 1024, and 1280
+    for a window of 1024, not 1536.  (On a v5e that pays only because
+    ``_heads_per_step`` then puts 8 heads into a grid step, whose fixed
+    cost a 256-block cannot carry alone; 128 loses to both: PERF.md
+    section 6, PR 27.  At a window of 1024, 32 heads over 4 K/V heads x
+    8192 x 128: 5.64 ms through the three kernels at 256 x 8 heads, 6.15
+    at 512 x 4: PR 31.)"""
     Tp = -(-T // LANES) * LANES
-    cap = 256 if window is not None and window < 2 * _BLK else _BLK
+    cap = 256 if window is not None and window <= 2 * _BLK else _BLK
     for blk in (_BLK, 256, LANES):
         if blk <= cap and Tp % blk == 0:
             return min(blk, Tp)

@@ -43,7 +43,13 @@ counted over all k assignments) is returned by ``forward`` when
 
 The work is written under the scopes ``moe.route`` / ``moe.dispatch`` /
 ``moe.experts`` / ``moe.combine`` (observability/phases.py), and
-``return_stats`` hands back the counters :data:`MOE_COUNTERS`.
+``return_stats`` hands back the counters :data:`MOE_COUNTERS`.  What a
+traced layer is built as goes to the registry on the host, while the
+program is traced (no output of the program): ``moe_router_calls_total
+{router, top_k}``, ``moe_row_buffer_rows_total``, and
+``moe_experts_held_total`` beside ``moe_router_experts_total``, whose ratio
+says which share of an expert-parallel group a step is
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -258,6 +264,12 @@ class ExpertParallelMLP(Module):
         """The local path: (y (T, d), aux, counters)."""
         T, d = x2d.shape
         k, n = self.top_k, self.n_held
+        rows = T * min(k, n)
+        if self.row_buffer_factor is not None:
+            want = math.ceil(self.row_buffer_factor * T * k * n
+                             / self.n_experts)
+            rows = min(rows, -(-want // 8) * 8)
+        self._count_traced_layer(rows)
         with jax.named_scope("moe.route"):
             gates, experts, aux = self._route(x2d, params["router"],
                                               want_aux)
@@ -268,11 +280,6 @@ class ExpertParallelMLP(Module):
             sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
                             dtype=jnp.int32)                   # (n,)
             held = jnp.sum(sizes)
-            rows = T * min(k, n)
-            if self.row_buffer_factor is not None:
-                want = math.ceil(self.row_buffer_factor * T * k * n
-                                 / self.n_experts)
-                rows = min(rows, -(-want // 8) * 8)
             order = jnp.argsort(key, stable=True)[:rows]
             key_s = key[order]
             starts = jnp.cumsum(sizes) - sizes
@@ -299,6 +306,23 @@ class ExpertParallelMLP(Module):
             if shared is not None:
                 y = y + shared.astype(jnp.float32)
         return y.astype(x2d.dtype), aux, stats
+
+    def _count_traced_layer(self, rows: int) -> None:
+        """Host side, once a trace of the sorted dispatch: the router's
+        kind, the row buffer it was built with and the share it holds."""
+        from ..observability.metrics import get_registry
+        reg = get_registry()
+        reg.counter("moe_router_calls_total",
+                    help="sorted-dispatch expert layers traced, by the "
+                    "router's score function and experts a token").labels(
+                        router=self.router_type, top_k=str(self.top_k)).inc()
+        for name, value, what in (
+                ("moe_row_buffer_rows_total", rows, "rows of the row buffers"),
+                ("moe_experts_held_total", self.n_held, "experts held by"),
+                ("moe_router_experts_total", self.n_experts,
+                 "experts scored by the routers of")):
+            reg.counter(name, help=f"{what} the sorted-dispatch expert "
+                        "layers traced").inc(value)
 
     @staticmethod
     def reduce_stats(stats):

@@ -11,9 +11,12 @@ Rehearse on the CPU mesh:
   python examples/gpt/main_amp.py --config tiny --iters 20 --generate 64
 On a TPU host (one process per chip set; `python chip_smoke.py` first):
   python examples/gpt/main_amp.py --config small -b 8
-The per-layer decoder with routed experts, from a published config file:
+The per-layer decoder with routed experts, from a published config file
+(--arch names the file's ``model_type``):
   python examples/gpt/main_amp.py --arch laguna -b 2 --block-size 8192 \
       --model-config benchmark/configs/laguna-xs2.json
+  python examples/gpt/main_amp.py --arch mellum -b 1 --block-size 8192 \
+      --model-config benchmark/configs/mellum2-12b.json
 
 ``build(args)`` returns the model, mesh, state and jitted train step that
 ``main()`` loops over; the benchmark and the tests drive the same objects.
@@ -32,6 +35,9 @@ import numpy as np
 _repo = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 if os.path.isdir(os.path.join(_repo, "apex_tpu")) and _repo not in sys.path:
     sys.path.insert(0, _repo)
+
+# --arch values that models/laguna.py builds from a --model-config file
+PER_LAYER_ARCHS = ("laguna", "mellum")
 
 # enough structure to be learnable at tiny scale: a looping pangram
 _BUILTIN_TEXT = ("the quick brown fox jumps over the lazy dog. " * 200)
@@ -70,16 +76,21 @@ def _stdlib_corpus(mb: float) -> str:
 def parse_args(argv=None):
     p = argparse.ArgumentParser(description="apex_tpu GPT training")
     p.add_argument("--arch", default="gpt",
-                   choices=["gpt", "llama", "laguna"],
+                   choices=["gpt", "llama", *PER_LAYER_ARCHS],
                    help="decoder family: GPT-2 (LayerNorm + learned "
                         "positions), Llama (RMSNorm + RoPE + SwiGLU "
-                        "+ GQA), or the per-layer decoder of "
+                        "+ GQA), or a per-layer decoder of "
                         "models/laguna.py (window and full attention, "
-                        "routed experts) built from --model-config")
+                        "routed experts) built from --model-config: "
+                        "laguna (dense first layer, gated attention, "
+                        "sigmoid router, shared expert) or mellum (every "
+                        "layer sparse, softmax router, no shared expert)")
     p.add_argument("--model-config", default=None, metavar="JSON",
-                   help="laguna: a config file with the published keys "
-                        "(benchmark/configs/laguna-xs2.json); the "
-                        "sequence length is --block-size")
+                   help="laguna, mellum: a config file with the "
+                        "published keys, whose model_type is --arch "
+                        "(benchmark/configs/laguna-xs2.json, "
+                        "mellum2-12b.json); the sequence length is "
+                        "--block-size")
     p.add_argument("--n-kv-head", type=int, default=None,
                    help="grouped-query attention KV heads (llama; "
                         "default MHA)")
@@ -134,11 +145,15 @@ def _corpus(args):
 def _network(args, n_chars):
     """(module, sequence length) of ``--arch``."""
     from apex_tpu import models
-    if args.arch == "laguna":
+    if args.arch in PER_LAYER_ARCHS:
         if not args.model_config:
-            raise SystemExit("--arch laguna needs --model-config <file>")
+            raise SystemExit(f"--arch {args.arch} needs --model-config "
+                             f"<file>")
         with open(args.model_config) as f:
             file_cfg = json.load(f)
+        if file_cfg.get("model_type", args.arch) != args.arch:
+            raise SystemExit(f"--arch {args.arch}: {args.model_config} is "
+                             f"a {file_cfg['model_type']!r} config")
         T = args.block_size or file_cfg.get("seq_len", 512)
         cfg = models.LagunaConfig.from_dict(
             file_cfg, max_position_embeddings=max(
@@ -350,7 +365,7 @@ def main(argv=None):
             raise SystemExit(1)
 
     if args.generate:
-        if args.arch == "laguna":
+        if args.arch in PER_LAYER_ARCHS:
             raise SystemExit("--generate: models/laguna.py has no cached "
                              "decoding (training and full forward only)")
         params, stoi = state[0], run.stoi

@@ -81,10 +81,12 @@ def test_window_none_is_the_program_it_was_and_a_whole_band_equals_it_bitwise():
 
 
 @pytest.mark.parametrize("T,W,blk,blocks", [(8192, 512, 256, 3), (8192, 513, 256, 3), (8192, 514, 256, 4),
-                                            (1024, 1, 256, 1), (8192, 1024, 512, 3), (1024, 4096, 512, 2)])
+                                            (1024, 1, 256, 1), (8192, 1024, 256, 5), (8192, 1025, 512, 3),
+                                            (1024, 4096, 512, 2)])
 def test_streamed_axis_covers_the_band_only(T, W, blk, blocks):
     """The grid's last axis: 3 of 32 blocks for the decoder cell's layers, whose
-    window of 512 selects blocks of 256; from two 512-blocks up, blocks of 512."""
+    window of 512 selects blocks of 256, as a window of 1024 does (5 of 32: 1280
+    keys a row, not 1536); past two 512-blocks, blocks of 512."""
     assert pfa._block_for(T, W) == blk and pfa._block_for(T) == 512
     assert pfa._band_blocks(W, blk, T // blk) == blocks
     q = jax.ShapeDtypeStruct((1, T, 128), jnp.bfloat16)
