@@ -25,7 +25,11 @@ Checks per site:
   the pad reads partial tiles;
 - **index-map bounds**: the block index the spec's ``index_map``
   returns at every grid corner must stay within
-  ``[0, shape[d] // block[d])`` for every dim;
+  ``[0, shape[d] // block[d])`` for every dim; a map that reads
+  scalar-prefetch operands (the grouped products' work items) is
+  evaluated with the values the launch was traced with, at every point
+  of its grid, and the driver traces such a launch once per
+  representative set of values;
 - **aliasing declared exactly once**: ``input_output_aliases`` maps
   distinct inputs to distinct outputs, indices in range, and the
   aliased pair agrees on shape + dtype (donating a buffer of the
@@ -58,6 +62,10 @@ class KernelSite:
     in_shapes: List[Tuple[Tuple[int, ...], str]]
     out_shapes: List[Tuple[Tuple[int, ...], str]]
     input_output_aliases: Dict[int, int] = field(default_factory=dict)
+    # a ``PrefetchScalarGridSpec`` launch: the values of its scalar
+    # operands, which its index maps take after the grid indices; None
+    # where the launch was traced with abstract ones
+    scalar_prefetch: Optional[List[Any]] = field(default_factory=list)
 
     def describe(self) -> str:
         return (f"{self.name}: grid={self.grid}, "
@@ -94,12 +102,24 @@ def capture_kernel_sites(into: List[KernelSite]) -> Iterator[None]:
     def record(kernel, *call_args, **kw):
         inner = real(kernel, *call_args, **kw)
 
-        def run(*args):
+        def run(*all_args):
+            # grid and specs arrive as arguments or inside a grid_spec,
+            # whose scalar-prefetch operands lead the call's
+            spec = kw.get("grid_spec")
+            given = kw.get if spec is None else functools.partial(getattr,
+                                                                  spec)
+            n_scalars = getattr(spec, "num_scalar_prefetch", 0)
+            try:
+                scalars = [np.asarray(a) for a in all_args[:n_scalars]]
+            except Exception:       # tracers: traced under a jit
+                scalars = None
+            args = all_args[n_scalars:]
             into.append(KernelSite(
                 name=_kernel_name(kernel),
-                grid=tuple(int(g) for g in _as_seq(kw.get("grid"))),
-                in_specs=_as_seq(kw.get("in_specs")),
-                out_specs=_as_seq(kw.get("out_specs")),
+                grid=tuple(int(g) for g in _as_seq(given("grid"))),
+                in_specs=_as_seq(given("in_specs")),
+                out_specs=_as_seq(given("out_specs")),
+                scalar_prefetch=scalars,
                 in_shapes=[(tuple(int(d) for d in a.shape),
                             str(a.dtype)) for a in args],
                 out_shapes=[(tuple(int(d) for d in s.shape),
@@ -107,7 +127,7 @@ def capture_kernel_sites(into: List[KernelSite]) -> Iterator[None]:
                             for s in _as_seq(kw.get("out_shape"))],
                 input_output_aliases=dict(
                     kw.get("input_output_aliases") or {})))
-            return inner(*args)
+            return inner(*all_args)
         return run
 
     pallas_mod.pallas_call = record
@@ -151,12 +171,20 @@ def _check_operand(site: KernelSite, kind: str, i: int, spec,
         return
     # evaluate the index map at every grid corner: the extremes bound
     # the affine maps these kernels use, so a step past the last block
-    # shows up at a corner
+    # shows up at a corner.  A map that looks its block up in
+    # scalar-prefetch operands is bounded by no corner: every point then
+    if site.scalar_prefetch is None:
+        problems.append(
+            f"{site.name}: {kind}[{i}] index_map reads scalar-prefetch "
+            f"operands that were traced without values: trace the launch "
+            f"outside a jit so that it can be evaluated")
+        return
+    scalars = list(site.scalar_prefetch)
     corners = itertools.product(
-        *(sorted({0, g - 1}) for g in site.grid))
+        *(range(g) if scalars else sorted({0, g - 1}) for g in site.grid))
     for corner in corners:
         try:
-            idx = index_map(*corner)
+            idx = index_map(*corner, *scalars)
         except Exception as e:       # a map that cannot even evaluate
             problems.append(
                 f"{site.name}: {kind}[{i}] index_map failed at grid "
@@ -251,12 +279,13 @@ def collect_kernel_sites() -> List[KernelSite]:
     import jax
     import jax.numpy as jnp
     from ..ops import (pallas_adam, pallas_common, pallas_flash_attention,
-                       pallas_lamb, pallas_layer_norm,
-                       pallas_multi_tensor, pallas_rope, pallas_syncbn)
+                       pallas_grouped_matmul, pallas_lamb,
+                       pallas_layer_norm, pallas_multi_tensor, pallas_rope,
+                       pallas_syncbn)
 
-    _clear_jit_caches(pallas_adam, pallas_flash_attention, pallas_lamb,
-                      pallas_layer_norm, pallas_multi_tensor, pallas_rope,
-                      pallas_syncbn)
+    _clear_jit_caches(pallas_adam, pallas_flash_attention,
+                      pallas_grouped_matmul, pallas_lamb, pallas_layer_norm,
+                      pallas_multi_tensor, pallas_rope, pallas_syncbn)
     sites: List[KernelSite] = []
     rng = np.random.RandomState(18)
     f32 = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
@@ -316,6 +345,21 @@ def collect_kernel_sites() -> List[KernelSite]:
         ang = f32(64, 32)
         pallas_rope.rope_token_major(
             f32(2, 64, 3 * 128), jnp.cos(ang), jnp.sin(ang), 128)
+        # the grouped products, forward and both gradients: their index
+        # maps look blocks up in work items computed from the groups'
+        # sizes, so one trace a representative split of the rows (all in
+        # the first group, all in the last, the cells' near-uniform one
+        # with a dead tail, none at all), outside the launches' jit so
+        # that the items reach the recorder as values
+        rows, stack = f32(512, 256), f32(4, 256, 128)
+        for sizes in ((512, 0, 0, 0), (0, 0, 0, 512), (70, 61, 66, 59),
+                      (0, 0, 0, 0)):
+            items = pallas_grouped_matmul.work_items(
+                jnp.asarray(sizes, jnp.int32), 512, 128)
+            with jax.disable_jit():
+                jax.grad(lambda a, w, items=items: jnp.sum(
+                    pallas_grouped_matmul.grouped_matmul(a, w, items, 128)),
+                    (0, 1))(rows, stack)
     return sites
 
 

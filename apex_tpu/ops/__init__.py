@@ -12,6 +12,9 @@ Kernel inventory (TPU-native equivalents of the reference csrc/ tree):
                         (csrc/welford.cu:298-318,325-410)
   pallas_flash_attention — fused attention fwd/bwd (no reference
                         equivalent: the 2019 snapshot predates attention)
+  pallas_rope         — rotary embedding on token-major projections
+  pallas_grouped_matmul — the routed experts' grouped matrix products over
+                        rows sorted by group, forward and both gradients
 """
 
 from . import dispatch

@@ -9,8 +9,10 @@ sorted by expert (stable, choice-major: every token's first choice queues
 before any token's second); the rows of the experts this layer *holds*
 (``experts_held`` = a start and a count, default all of them) are
 gathered into one row buffer of a static size; the experts run as grouped
-matrix products over the contiguous row groups (``lax.ragged_dot``); and
-a scatter-add with the gate weights brings the rows home.  Nothing of a
+matrix products over the contiguous row groups (on the chip the Mosaic
+kernels of ``ops/pallas_grouped_matmul.py``, which visit no tile outside
+every group; ``lax.ragged_dot`` off it and for shapes they do not take);
+and a scatter-add with the gate weights brings the rows home.  Nothing of a
 ``tokens x experts x slots`` shape exists.  Assignments to experts held
 elsewhere add nothing here — that is the layer of ONE chip of an
 expert-parallel group, without its exchange.  With ``capacity_factor=None``
@@ -48,8 +50,9 @@ traced layer is built as goes to the registry on the host, while the
 program is traced (no output of the program): ``moe_router_calls_total
 {router, top_k}``, ``moe_row_buffer_rows_total``, and
 ``moe_experts_held_total`` beside ``moe_router_experts_total``, whose ratio
-says which share of an expert-parallel group a step is
-(docs/observability.md).
+says which share of an expert-parallel group a step is, and
+``moe_grouped_dot_calls_total{impl, tile}``, what implements each grouped
+product (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -245,10 +248,26 @@ class ExpertParallelMLP(Module):
     def _grouped_mlp(self, params, xs, group_sizes, live):
         """xs: (R, d) rows sorted by expert, ``group_sizes`` (n,) rows each
         expert held takes from the front, ``live`` (R, 1) the rows inside a
-        group -> (R, d).  Rows outside every group are forced to zero on
-        both sides of each product: a grouped product need not write (nor,
-        transposed, read) them."""
+        group -> (R, d).  On the chip a product is
+        ``ops.pallas_grouped_matmul`` wherever its tile chooser takes the
+        shapes: the kernel owns the rows outside every group (zeros in its
+        results, never read into a live row), accumulates in fp32 and
+        rounds once.  Elsewhere it is ``lax.ragged_dot``, which need not
+        write (nor, transposed, read) those rows, so they are forced to
+        zero on both sides of it."""
+        from ..ops import dispatch, pallas_grouped_matmul as pgm
+        rows = xs.shape[0]
+        items = {}              # the kernels' work items, once a row tile
+
         def gdot(t, w):
+            tile = (pgm.row_tile(rows, *w.shape[1:], w.shape[0], w.dtype)
+                    if dispatch.pallas_enabled() else 0)
+            if tile:
+                if tile not in items:
+                    items[tile] = pgm.work_items(group_sizes, rows, tile)
+                return pgm.grouped_matmul(t.astype(w.dtype), w, items[tile],
+                                          tile).astype(t.dtype)
+            pgm.count_product("ragged_dot", 0)
             y = lax.ragged_dot(jnp.where(live, t, 0).astype(w.dtype), w,
                                group_sizes,
                                preferred_element_type=jnp.float32)

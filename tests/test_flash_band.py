@@ -659,18 +659,53 @@ def test_v5e_compiles_the_flash_kernels_at_the_encoder_cells_widths(one_chip, fo
     assert "bf16[128,512,128]" not in text and "f32[128,512,128]" not in text
 
 
-def test_v5e_compiles_the_grouped_expert_products(one_chip, for_the_chip):
+@pytest.mark.parametrize("cell,width,hidden,scored,router,shared,tokens", [
+    ("laguna-xs2", 2048, 512, 256, "sigmoid", 512, 16384),
+    ("mellum2-12b", 2304, 896, 64, "softmax", None, 8192)])
+def test_v5e_compiles_the_grouped_expert_products(one_chip, for_the_chip, cell, width, hidden,
+                                                  scored, router, shared, tokens):
+    """One expert layer at each decoder cell's widths, forward and backward:
+    under ``moe.experts`` nine Mosaic kernels, a product each (the six of the
+    ``custom_vjp``'s backward keep the scope), nothing of the compiler's own
+    grouped product, no fp32 array of the row buffer's size and no transposed
+    copy of a weight stack.  ``benchmark/readers/grouped_dot_roofline.py``
+    reads nothing where the kernels a layer are not the products."""
+    import re
+    from apex_tpu.observability.phases import instruction_phases
     from apex_tpu.parallel.expert_parallel import ExpertParallelMLP
-    layer = ExpertParallelMLP(2048, 512, 256, capacity_factor=None, top_k=8,
-                              expert_type="swiglu", router_type="sigmoid", routed_scaling=2.5,
-                              experts_held=(0, 16), shared_hidden=512, row_buffer_factor=2.0)
+    layer = ExpertParallelMLP(width, hidden, scored, capacity_factor=None, top_k=8,
+                              expert_type="swiglu", router_type=router,
+                              routed_scaling=2.5 if router == "sigmoid" else 1.0,
+                              experts_held=(0, 16), shared_hidden=shared, row_buffer_factor=2.0)
+    rows = 2 * tokens * 8 * 16 // scored
     shapes = jax.eval_shape(lambda k: layer.init(k)[0], jax.random.PRNGKey(0))
     params = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32 if s.ndim == 2 and s.shape[1] == 256
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32 if s.shape == (width, scored)
                                        else jnp.bfloat16, sharding=one_chip), shapes)
-    x = jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)))).lower(
+    x = jax.ShapeDtypeStruct((tokens, width), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)), (0, 1))).lower(
         params, x).compile().as_text()
-    assert "ragged-dot" in text
-    # 16 384 tokens x 256 experts x any slots would be >= 4 M x slots elements
-    assert "16384,256,16" not in text and "16384,16,16384" not in text
+    assert "ragged-dot" not in text
+    # tokens x experts x any slots would be >= 4 M x slots elements
+    assert f"{tokens},{scored},16" not in text and f"{tokens},16,{tokens}" not in text
+    phases = instruction_phases(text)
+    kernels, entry = [], text[text.index("ENTRY"):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?.*?\)?) ([\w\-]+)\(", line)
+        if not m or "moe.experts" not in phases.get(m.group(1), ((), False))[0]:
+            continue
+        name, shape, op = m.groups()
+        if op == "custom-call":
+            assert "tpu_custom_call" in line, line[:200]
+            kernels.append((re.sub(r"[.\d]+$", "", name), phases[name][1]))
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", shape):
+            dims = [int(d) for d in dims.split(",")]
+            assert not (dtype == "f32" and dims[0] == rows and len(dims) == 2), \
+                f"an fp32 array of the row buffer's size: {line[:200]}"
+            assert not (op in ("copy", "transpose") and sorted(dims) == sorted([16, width, hidden])), \
+                f"a copy of a weight stack: {line[:200]}"
+    forward = [k for k, backward in kernels if not backward]
+    backward = [k for k, backward in kernels if backward]
+    assert len(forward) == 3 and len(backward) == 6, kernels
+    assert sum("grouped_stack" in k for k in backward) == 3, kernels
+    assert sum("grouped_rows_t" in k for k in backward) == 3, kernels

@@ -117,7 +117,8 @@ def test_real_kernel_family_lints_clean():
     # that silently stops launching is as much a failure as a bad spec
     for expected in ("_adam_kernel", "_stage1_kernel", "_stage2_kernel",
                      "_scale_kernel", "_axpby_kernel", "_l2norm_kernel",
-                     "_dq_kernel", "_dkv_kernel", "_kernel"):
+                     "_dq_kernel", "_dkv_kernel", "_kernel", "_rows_kernel",
+                     "_stack_kernel"):
         assert expected in names, (expected, sorted(names))
     assert len(sites) >= 12, [s.describe() for s in sites]
     # token-major launches: a (1, blk, hb * D) block of a (B, T, H * D)
@@ -128,6 +129,52 @@ def test_real_kernel_family_lints_clean():
     assert dkv[1].in_specs[0].block_shape == (1, 128, 128)
     assert dkv[2].in_specs[0].block_shape == (1, 256, 4 * 128)
     assert dkv[2].in_specs[1].block_shape == (1, 256, 128)
+
+
+def test_grouped_products_are_linted_with_their_work_items():
+    """The grouped products' index maps look their blocks up in
+    scalar-prefetch operands: the recorder keeps the values each launch
+    was traced with (all rows in the first group, all in the last, a
+    near-uniform split with a dead tail, no rows), the lint evaluates the
+    maps with them at every grid point, and a launch is the forward, the
+    rows' gradient or the stack's, three a split."""
+    sites = [s for s in pallas_lint.collect_kernel_sites()
+             if s.name in ("_rows_kernel", "_stack_kernel")]
+    assert [s.name for s in sites] == ["_rows_kernel", "_rows_kernel",
+                                       "_stack_kernel"] * 4
+    for site in sites:
+        offsets, group = site.scalar_prefetch[:2]
+        assert len(site.scalar_prefetch) == (5 if site.name == "_rows_kernel" else 4)
+        assert len(group) == site.grid[-1] == 512 // 128 + 4 - 1
+        assert check_site(site) == []
+    # all rows in the last group: every item reads the last weight block
+    first, last = sites[0].scalar_prefetch, sites[3].scalar_prefetch
+    assert set(first[1].tolist()) == {0} and set(last[1].tolist()) == {3}
+    # the split with a dead tail: two tiles of products, two of zeros
+    assert sites[6].scalar_prefetch[4].tolist().count(3) == 2
+
+
+def test_a_scalar_prefetch_index_map_is_checked_between_the_corners():
+    """A block looked up in a table is bounded by no corner: the table's
+    one bad entry sits in the middle of the grid."""
+    import numpy as np
+    table = np.asarray([0, 1, 7, 3])
+    spec = _spec((512, 128), lambda i, t: (t[i], 0))
+    bad = _site(in_specs=[spec], out_specs=[spec], input_output_aliases={},
+                scalar_prefetch=[table])
+    problems = check_site(bad)
+    assert any("grid point (2,)" in p and "block index 7" in p
+               for p in problems), problems
+    good = _site(in_specs=[spec], out_specs=[spec], input_output_aliases={},
+                 scalar_prefetch=[np.asarray([0, 1, 2, 3])])
+    assert check_site(good) == []
+
+
+def test_a_scalar_prefetch_launch_traced_without_values_is_flagged_not_skipped():
+    bad = _site(in_specs=[_spec((512, 128), lambda i, t: (t[i], 0))],
+                input_output_aliases={}, scalar_prefetch=None)
+    problems = check_site(bad)
+    assert any("traced without values" in p for p in problems), problems
 
 
 def test_aliased_kernels_record_their_donations():
