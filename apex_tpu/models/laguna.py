@@ -1,16 +1,19 @@
-"""A decoder whose layers differ: per layer an attention kind (full or
-sliding-window), a query-head count, a RoPE kind and an MLP kind (dense
-SwiGLU or routed experts, with or without a shared expert).
+"""A decoder whose layers differ: per layer a token mixer (full or
+sliding-window attention, or a gated short convolution), a query-head count,
+a RoPE kind and an MLP kind (dense SwiGLU or routed experts, with or without
+a shared expert).
 
 The configuration says which decoder it is, in the keys of the published
 config files of the families built on this shape (``layer_types``,
 ``mlp_layer_types``, one ``rope_parameters`` group per attention kind,
 ``sliding_window``; ``num_attention_heads_per_layer`` or one
-``num_attention_heads`` for every layer; ``norm_topk_prob``).  Two run in the
+``num_attention_heads`` for every layer; ``norm_topk_prob``).  Three run in the
 benchmark: ``laguna`` (a leading dense layer, two head counts, a gate on the
 attention output, a sigmoid router with a scaling factor and a shared
-expert: the defaults below) and ``mellum`` (every layer sparse, one head
-count, no gate, a softmax router, no shared expert).  Pre-norm residual
+expert: the defaults below), ``mellum`` (every layer sparse, one head
+count, no gate, a softmax router, no shared expert) and ``lfm2_moe`` (``conv``
+layers 3:1 with full attention at a head of 64 with QK-norm, a leading dense
+layer, a sigmoid router with a selection bias, a tied head).  Pre-norm residual
 blocks on the Llama parts (models/llama.py: RMSNorm, SwiGLU, the fused
 chunked head), with
 
@@ -20,15 +23,24 @@ chunked head), with
   apply it, on q, k, v where the projections wrote them), RoPE
   per kind (``default``: theta, the whole or a leading part of the head;
   ``yarn``: blended frequencies and a scale on cos/sin, as ``transformers``
-  computes them), and — ``gating`` — a sigmoid gate per head on the
-  attention output, ``o_h <- sigmoid(x w_h) * o_h``;
+  computes them), — ``gating`` — a sigmoid gate per head on the
+  attention output, ``o_h <- sigmoid(x w_h) * o_h``, and — ``qk_norm`` — an
+  RMSNorm over each head of q and of k before RoPE (one gain vector each,
+  shared by the heads).  A head that is not whole lane tiles (64) reaches the
+  flash kernels head-major behind one transpose each way
+  (``dot_product_attention_token_major``), with the norm and the rotation
+  written on the (B, T, heads, D) view that transpose reads;
+- a ``conv`` layer: ``transformer.GatedShortConv`` in attention's place
+  (module ``conv``; ``conv_L_cache`` taps), which needs no ``rope_parameters``;
 - sparse MLP: ``parallel.ExpertParallelMLP`` with a ``router_type``
   (``sigmoid`` or ``softmax``) router over the published
   ``router_experts``, the ``num_experts_per_tok`` largest renormalized
   (a config's ``norm_topk_prob``: one that states false is refused) and
-  scaled by ``moe_routed_scaling_factor``, the
+  scaled by ``moe_routed_scaling_factor``, — ``use_expert_bias`` — a
+  selection bias that enters the choice and not the weights, the
   experts this chip holds (``experts_held``) and, where
-  ``shared_expert_intermediate_size`` is not 0, a shared expert.
+  ``shared_expert_intermediate_size`` is not 0, a shared expert;
+- ``tie_word_embeddings``: the head's matrix is the embedding's, one leaf.
 
 Training and full-sequence forward only: a cache for decoding would have to
 hold window and global layers side by side (ROADMAP, Reach).
@@ -51,11 +63,12 @@ from ..ops.pallas_common import token_tile_axes
 from ..parallel.expert_parallel import ExpertParallelMLP
 from ..transformer.attention import dot_product_attention_token_major
 from ._remat import _MODES, wrap_block
+from ..transformer.short_conv import GatedShortConv
 from .llama import LlamaMLP, RMSNorm, _rotate_half
 
 __all__ = ["LagunaConfig", "Laguna", "rope_inv_freq"]
 
-FULL, SLIDING = "full_attention", "sliding_attention"
+FULL, SLIDING, CONV = "full_attention", "sliding_attention", "conv"
 
 
 class LagunaConfig:
@@ -63,7 +76,9 @@ class LagunaConfig:
     ``experts_held_start``, of the ``router_experts`` the router scores
     (default: all of them are held).  ``num_attention_heads_per_layer``
     may be left None where one ``num_attention_heads`` serves every
-    layer; ``shared_expert_intermediate_size`` 0 is no shared expert."""
+    layer; ``shared_expert_intermediate_size`` 0 is no shared expert.  A
+    ``conv`` entry of ``layer_types`` is a gated short convolution of
+    ``conv_L_cache`` taps (its head count is not read)."""
 
     def __init__(self, vocab_size, hidden_size, intermediate_size,
                  layer_types: Sequence[str],
@@ -77,7 +92,9 @@ class LagunaConfig:
                  gating=True, rms_norm_eps=1e-6,
                  max_position_embeddings=8192, remat=None, head_chunk=8192,
                  num_attention_heads=None, router_type="sigmoid",
-                 norm_topk_prob=True):
+                 norm_topk_prob=True, qk_norm=False, conv_L_cache=3,
+                 use_expert_bias=False, tie_word_embeddings=False,
+                 router_out_in=False):
         n = len(layer_types)
         if num_attention_heads_per_layer is None:
             if num_attention_heads is None:
@@ -90,9 +107,9 @@ class LagunaConfig:
                              "and mlp_layer_types must have one entry a "
                              "layer")
         for kind in layer_types:
-            if kind not in (FULL, SLIDING):
+            if kind not in (FULL, SLIDING, CONV):
                 raise ValueError(f"unknown layer type {kind!r}")
-            if kind not in rope_parameters:
+            if kind != CONV and kind not in rope_parameters:
                 raise ValueError(f"no rope_parameters for {kind!r}")
         for kind in mlp_layer_types:
             if kind not in ("dense", "sparse"):
@@ -129,6 +146,11 @@ class LagunaConfig:
                              "renormalizes the chosen weights of every "
                              "router with more than one expert a token")
         self.router_type = router_type
+        self.qk_norm = qk_norm
+        self.conv_L_cache = conv_L_cache
+        self.use_expert_bias = use_expert_bias
+        self.tie_word_embeddings = tie_word_embeddings
+        self.router_out_in = router_out_in
         self.rms_norm_eps = rms_norm_eps
         self.max_position_embeddings = max_position_embeddings
         self.remat = remat
@@ -145,6 +167,8 @@ class LagunaConfig:
         names = inspect.signature(cls.__init__).parameters
         kw = {k: d[k] for k in names if k in d}
         kw.setdefault("num_attention_heads_per_layer", None)
+        if "norm_eps" in d:             # the lfm2 files' name for it
+            kw.setdefault("rms_norm_eps", d["norm_eps"])
         if "num_experts_published" in d:
             kw["router_experts"] = d["num_experts_published"]
         kw.update(over)
@@ -204,6 +228,10 @@ class LagunaAttention(nn.Module):
         if cfg.gating:
             self.g_proj = nn.Linear(E, self.H, bias=False)
         self.gating = cfg.gating
+        self.qk_norm = cfg.qk_norm
+        if cfg.qk_norm:
+            self.q_layernorm = RMSNorm(self.D, cfg.rms_norm_eps)
+            self.k_layernorm = RMSNorm(self.D, cfg.rms_norm_eps)
 
     def _rope(self, x):
         """x: (B, T, heads * D), as the projection wrote it; the first
@@ -229,10 +257,23 @@ class LagunaAttention(nn.Module):
             out = jnp.concatenate([out, x[..., rd:]], axis=-1)
         return out.reshape(B, T, -1)
 
+    def _head_norm(self, norm, p, x):
+        """RMSNorm over each head's ``D`` numbers of a projection's output
+        (B, T, heads * D), one gain vector for all heads."""
+        with jax.named_scope("attn.qk_norm"):
+            return norm(p, x.reshape(*x.shape[:2], -1, self.D)).reshape(
+                x.shape)
+
     def forward(self, p, x):
         B, T, _ = x.shape
-        q = self._rope(self.q_proj(p["q_proj"], x))
-        k = self._rope(self.k_proj(p["k_proj"], x))
+        def rotated(proj, norm):
+            y = getattr(self, proj)(p[proj], x)
+            if self.qk_norm:
+                y = self._head_norm(getattr(self, norm), p[norm], y)
+            return self._rope(y)
+
+        q = rotated("q_proj", "q_layernorm")
+        k = rotated("k_proj", "k_layernorm")
         v = self.v_proj(p["v_proj"], x)
         # q, k, v stay where the projections wrote them: query head h reads
         # K/V head h // (H // Hkv), no axis is moved and no K/V head
@@ -255,7 +296,11 @@ class LagunaBlock(nn.Module):
     def __init__(self, cfg: LagunaConfig, layer: int):
         super().__init__()
         self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-        self.self_attn = LagunaAttention(cfg, layer)
+        self.mixer = "conv" if cfg.layer_types[layer] == CONV else "self_attn"
+        if self.mixer == "conv":
+            self.conv = GatedShortConv(cfg.hidden_size, cfg.conv_L_cache)
+        else:
+            self.self_attn = LagunaAttention(cfg, layer)
         self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
                                                 cfg.rms_norm_eps)
         self.sparse = cfg.mlp_layer_types[layer] == "sparse"
@@ -268,13 +313,15 @@ class LagunaBlock(nn.Module):
                 routed_scaling=cfg.moe_routed_scaling_factor,
                 experts_held=(cfg.experts_held_start, cfg.num_experts),
                 shared_hidden=cfg.shared_expert_intermediate_size,
-                row_buffer_factor=cfg.moe_row_buffer_factor)
+                row_buffer_factor=cfg.moe_row_buffer_factor,
+                router_bias=cfg.use_expert_bias,
+                router_out_in=cfg.router_out_in)
         else:
             self.mlp = LlamaMLP(cfg)
 
     def forward(self, p, x):
         """-> (x, the expert layer's counters or None)."""
-        x = x + self.self_attn(p["self_attn"], self.input_layernorm(
+        x = x + getattr(self, self.mixer)(p[self.mixer], self.input_layernorm(
             p["input_layernorm"], x))
         h = self.post_attention_layernorm(p["post_attention_layernorm"], x)
         if self.sparse:
@@ -292,8 +339,15 @@ class Laguna(nn.Module):
         self.layers = nn.ModuleList(
             [LagunaBlock(cfg, i) for i in range(cfg.num_hidden_layers)])
         self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-        self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
-                                 bias=False)
+        if not cfg.tie_word_embeddings:
+            self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
+                                     bias=False)
+
+    def _head_weight(self, p):
+        """The head's (vocabulary, hidden) matrix: the embedding's own leaf
+        where the two are tied."""
+        tied = self.cfg.tie_word_embeddings
+        return p["embed_tokens" if tied else "lm_head"]["weight"]
 
     def _backbone(self, p, input_ids):
         """-> (final hidden states, the step's MoE counters or {})."""
@@ -316,7 +370,7 @@ class Laguna(nn.Module):
 
     def forward(self, p, input_ids):
         x, _ = self._backbone(p, input_ids)
-        return F.matmul(x, p["lm_head"]["weight"].T.astype(x.dtype))
+        return F.matmul(x, self._head_weight(p).T.astype(x.dtype))
 
     def loss(self, p, input_ids, return_stats: bool = False):
         """Mean next-token cross-entropy over every position but each row's
@@ -330,7 +384,7 @@ class Laguna(nn.Module):
             labels = jnp.concatenate(
                 [input_ids[:, 1:], jnp.zeros((B, 1), input_ids.dtype)], 1)
             nll = linear_cross_entropy(
-                x.reshape(B * T, -1), p["lm_head"]["weight"],
+                x.reshape(B * T, -1), self._head_weight(p),
                 labels.reshape(-1), int(self.cfg.head_chunk)).reshape(B, T)
             valid = jnp.arange(T) < T - 1
             loss = jnp.sum(nll * valid) / (B * (T - 1))

@@ -50,6 +50,12 @@ PHASES = (
     "moe.dispatch",        # sort of the assignments, group sizes, row gather
     "moe.experts",         # grouped products and the shared expert
     "moe.combine",         # weighted scatter-add back to the tokens
+    # transformer/short_conv.py, the gated short convolution (inside ``model``)
+    "conv.in_proj",        # u W_in: the three gates' projection
+    "conv.mix",            # B * z, the taps along the sequence, C * c
+    "conv.out_proj",       # (C * c) W_out
+    # models/laguna.py, attention with ``qk_norm``
+    "attn.qk_norm",        # RMSNorm over each head of q and of k, before RoPE
 )
 MODEL = "model"
 

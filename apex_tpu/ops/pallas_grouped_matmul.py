@@ -65,6 +65,15 @@ _OPENS, _CLOSES, _ADDS = 1, 2, 4
 # of the 16 MiB a kernel gets unless it asks, what the compiler does not
 # keep for itself
 _VMEM_BUDGET = 14 * 2 ** 20
+# what the rows' kernels may ask for where a group's weight block does not
+# fit the default twice (``_limit`` states the ask at the launch; a v5e core
+# has 128 MiB of VMEM), and the tiles tried under it: at K x N = 2048 x 1792,
+# 8 groups of about 2048 rows in a 32 768-row buffer, the three products take
+# 2.80 ms at 256, 2.88 at 512 (the rows' kernels lose 7 %, the stack's gains
+# 3) and 3.00 at 128, against 6.8-8.0 for ``lax.ragged_dot`` (PERF.md
+# section 6, PR 33)
+_VMEM_ASK = 32 * 2 ** 20
+_ASK_TILES = (256, 128)
 _COLUMNS = 1024                 # most result columns a matrix step writes
 _ACCUMULATOR = 3 * 2 ** 20      # bytes of the stack kernel's fp32 block
 
@@ -121,16 +130,22 @@ def row_tile(R: int, K: int, N: int, groups: int, dtype) -> int:
     ``groups - 1`` tiles more than the rows need).  The largest of 512, 256,
     128 that divides R, fits VMEM with the group's whole weight block
     resident in both directions, and is at most a quarter of a group's
-    expected rows (PERF.md section 5 has the timings on a v5e)."""
+    expected rows (PERF.md section 5 has the timings on a v5e).  Where no
+    tile fits the kernel's default VMEM (a block of 2048 x 1792 in bf16 is
+    7.3 MB, twice 14.7), the same rule over ``_ASK_TILES`` under ``_VMEM_ASK``,
+    which the launch then asks for."""
     dtype = jnp.dtype(dtype)
     if (dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
             or K % LANES or N % LANES or groups < 1):
         return 0
-    fits = [tm for tm in (512, 256, 128) if R % tm == 0
-            and _rows_vmem(tm, K, N, dtype.itemsize) <= _VMEM_BUDGET]
-    if not fits:
-        return 0
-    return next((tm for tm in fits if 4 * tm <= R // groups), fits[-1])
+    for room, tiles in ((_VMEM_BUDGET, (512, 256, 128)),
+                        (_VMEM_ASK, _ASK_TILES)):
+        fits = [tm for tm in tiles if R % tm == 0
+                and _rows_vmem(tm, K, N, dtype.itemsize) <= room]
+        if fits:
+            return next((tm for tm in fits if 4 * tm <= R // groups),
+                        fits[-1])
+    return 0
 
 
 def work_items(group_sizes, R: int, tm: int):

@@ -37,7 +37,11 @@ TP does.
 Router: ``"softmax"`` — top-1 (Switch) by default; ``top_k=2`` with
 ``expert_type="swiglu"`` gives the Mixtral shape (renormalized gate
 weights, SwiGLU experts) — or ``"sigmoid"``: independent scores, the k
-largest renormalized to sum 1 and scaled by ``routed_scaling``.  The
+largest renormalized to sum 1 and scaled by ``routed_scaling``.
+``router_bias`` adds a selection bias ``expert_bias`` (E,): the choice is
+the k largest of ``score + bias``, the weights are the chosen scores alone
+(over their sum + 1e-6); the bias takes no gradient (the balancing rule that
+would move it is not part of this layer).  The
 auxiliary load-balancing loss (Switch eq. 4: E * sum_e f_e * P_e, fraction
 counted over all k assignments) is returned by ``forward`` when
 ``return_aux_loss`` — add ``aux_weight * aux`` to the task loss.
@@ -48,7 +52,8 @@ The work is written under the scopes ``moe.route`` / ``moe.dispatch`` /
 ``return_stats`` hands back the counters :data:`MOE_COUNTERS`.  What a
 traced layer is built as goes to the registry on the host, while the
 program is traced (no output of the program): ``moe_router_calls_total
-{router, top_k}``, ``moe_row_buffer_rows_total``, and
+{router, top_k}`` (and ``moe_router_bias_calls_total`` for the layers with a
+selection bias), ``moe_row_buffer_rows_total``, and
 ``moe_experts_held_total`` beside ``moe_router_experts_total``, whose ratio
 says which share of an expert-parallel group a step is, and
 ``moe_grouped_dot_calls_total{impl, tile}``, what implements each grouped
@@ -88,7 +93,9 @@ def _swiglu(x, w_gate, w_in, w_out):
 class ExpertParallelMLP(Module):
     """Top-k routed MoE MLP; experts sharded over ``axis_name``.
 
-    Params: ``router`` (d, E) replicated and kept fp32 under amp; ``w_in``
+    Params: ``router`` (d, E) ((E, d) with ``router_out_in``; and
+    ``expert_bias`` (E,) with ``router_bias``) replicated and kept fp32
+    under amp; ``w_in``
     (n, d, hidden) and ``w_out`` (n, hidden, d) for the ``n`` experts held
     (all E by default), sharded on the expert dim (see ``param_specs``);
     gated experts add ``w_gate`` (n, d, hidden); ``shared_hidden`` adds
@@ -105,7 +112,7 @@ class ExpertParallelMLP(Module):
     ``(silu(x@w_gate) * (x@w_in)) @ w_out`` (Mixtral's expert).
     """
 
-    fp32_param_names = ("router",)
+    fp32_param_names = ("router", "expert_bias")
 
     def __init__(self, embed_dim: int, hidden_dim: int, n_experts: int,
                  capacity_factor: Optional[float] = 1.25,
@@ -117,7 +124,9 @@ class ExpertParallelMLP(Module):
                  routed_scaling: float = 1.0,
                  experts_held: Optional[Tuple[int, int]] = None,
                  shared_hidden: Optional[int] = None,
-                 row_buffer_factor: Optional[float] = None):
+                 row_buffer_factor: Optional[float] = None,
+                 router_bias: bool = False,
+                 router_out_in: bool = False):
         super().__init__()
         if not 1 <= top_k <= n_experts:
             raise ValueError(f"top_k={top_k} not in [1, {n_experts}]")
@@ -142,6 +151,9 @@ class ExpertParallelMLP(Module):
         self.held_start, self.n_held = start, count
         self.shared_hidden = shared_hidden
         self.row_buffer_factor = row_buffer_factor
+        self.router_bias = router_bias
+        # the ``router`` leaf as (E, d), torch's (out, in), and not (d, E)
+        self.router_out_in = router_out_in
 
     def create_params(self, key):
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
@@ -150,13 +162,17 @@ class ExpertParallelMLP(Module):
         s_in = (2.0 / d) ** 0.5
         s_out = (2.0 / h) ** 0.5
         p = {
-            "router": jax.random.normal(k1, (d, E), jnp.float32) * 0.02,
+            "router": jax.random.normal(
+                k1, (E, d) if self.router_out_in else (d, E),
+                jnp.float32) * 0.02,
             "w_in": jax.random.normal(k2, (n, d, h), jnp.float32) * s_in,
             "w_out": jax.random.normal(k3, (n, h, d), jnp.float32) * s_out,
         }
         if self.expert_type == "swiglu":
             p["w_gate"] = (jax.random.normal(k4, (n, d, h), jnp.float32)
                            * s_in)
+        if self.router_bias:
+            p["expert_bias"] = jnp.zeros((E,), jnp.float32)
         if self.shared_hidden:
             hs = self.shared_hidden
             ks = jax.random.split(k5, 3)
@@ -173,6 +189,8 @@ class ExpertParallelMLP(Module):
              "w_out": P(self.axis_name, None, None)}
         if self.expert_type == "swiglu":
             s["w_gate"] = P(self.axis_name, None, None)
+        if self.router_bias:
+            s["expert_bias"] = P()
         if self.shared_hidden:
             s["shared"] = {"w_gate": P(), "w_in": P(), "w_out": P()}
         return s
@@ -186,19 +204,32 @@ class ExpertParallelMLP(Module):
                                 * self.top_k / self.n_experts))
 
     # -- routing ----------------------------------------------------------
-    def _route(self, x2d: jax.Array, router: jax.Array, want_aux: bool):
+    def _router(self, params) -> jax.Array:
+        """The router's (d, E) matrix, however the leaf is kept."""
+        return params["router"].T if self.router_out_in else params["router"]
+
+    def _route(self, x2d: jax.Array, router: jax.Array, want_aux: bool,
+               bias: Optional[jax.Array] = None):
         """(gates (T, k) fp32, experts (T, k) int32, aux loss) — scores in
-        fp32 over all ``n_experts``, whatever this layer holds."""
+        fp32 over all ``n_experts``, whatever this layer holds.  With a
+        selection ``bias`` (E,) the choice reads ``score + bias`` and the
+        weights the scores alone."""
         E, k = self.n_experts, self.top_k
         logits = x2d.astype(jnp.float32) @ router.astype(jnp.float32)
         if self.router_type == "sigmoid":
             probs = jax.nn.sigmoid(logits)
         else:
             probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = lax.top_k(probs, k)                   # (T,k)
-        if k > 1 or self.router_type == "sigmoid":
-            # Mixtral: gate weights renormalized over the chosen k
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if bias is None:
+            gates, experts = lax.top_k(probs, k)               # (T,k)
+            if k > 1 or self.router_type == "sigmoid":
+                # Mixtral: gate weights renormalized over the chosen k
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        else:
+            _, experts = lax.top_k(lax.stop_gradient(
+                probs + bias.astype(jnp.float32)), k)
+            gates = jnp.take_along_axis(probs, experts, axis=-1)
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-6)
         gates = gates * self.routed_scaling
         aux = 0.0
         if want_aux:
@@ -290,8 +321,9 @@ class ExpertParallelMLP(Module):
             rows = min(rows, -(-want // 8) * 8)
         self._count_traced_layer(rows)
         with jax.named_scope("moe.route"):
-            gates, experts, aux = self._route(x2d, params["router"],
-                                              want_aux)
+            gates, experts, aux = self._route(
+                x2d, self._router(params), want_aux,
+                params["expert_bias"] if self.router_bias else None)
         with jax.named_scope("moe.dispatch"):
             # choice-major queue: assignment a = choice * T + token
             local = experts.T.reshape(-1) - self.held_start
@@ -335,6 +367,10 @@ class ExpertParallelMLP(Module):
                     help="sorted-dispatch expert layers traced, by the "
                     "router's score function and experts a token").labels(
                         router=self.router_type, top_k=str(self.top_k)).inc()
+        if self.router_bias:
+            reg.counter("moe_router_bias_calls_total",
+                        help="of moe_router_calls_total, the layers whose "
+                        "choice reads a selection bias").inc()
         for name, value, what in (
                 ("moe_row_buffer_rows_total", rows, "rows of the row buffers"),
                 ("moe_experts_held_total", self.n_held, "experts held by"),
@@ -380,13 +416,14 @@ class ExpertParallelMLP(Module):
         T, d = x2d.shape
         E, e_loc = self.n_experts, self.n_experts // ep
         if (self.capacity_factor is None or self.n_held != E
-                or self.shared_hidden):
+                or self.shared_hidden or self.router_bias):
             raise NotImplementedError(
                 "the all_to_all dispatch needs a capacity_factor and has "
-                "no experts_held / shared expert; the sorted dispatch is "
+                "no experts_held / shared expert / selection bias; the "
+                "sorted dispatch is "
                 "not exchanged across an expert axis yet")
         capacity = self.capacity(T)
-        dispatch, combine, aux = self._dispatch(x2d, params["router"],
+        dispatch, combine, aux = self._dispatch(x2d, self._router(params),
                                                 capacity)
         # (T,E,C) x (T,d) -> (E,C,d): the local contribution per expert
         sent = jnp.einsum("tec,td->ecd", dispatch.astype(x2d.dtype), x2d)

@@ -8,5 +8,6 @@ SURVEY.md §5): long-context support is first-class in apex_tpu.
 from .attention import (dot_product_attention,
                         dot_product_attention_token_major,
                         MultiheadAttention)
+from .short_conv import GatedShortConv, gated_short_conv
 from .ring_attention import ring_attention, ring_self_attention
 from .ulysses import ulysses_attention, ulysses_self_attention

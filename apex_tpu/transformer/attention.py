@@ -185,9 +185,14 @@ def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
 
     On TPU, with a head of whole lane tiles (``D % 128 == 0``), the flash
     kernels read these arrays as they are (``ops.pallas_flash_attention``:
-    token-major operands, K/V once per K/V head); elsewhere the dense path
-    groups the query heads over the K/V heads in one einsum, softmax in
-    fp32.  ``causal``, ``window``, ``segment_ids`` as in
+    token-major operands, K/V once per K/V head).  A head under one lane
+    tile (64) is not a lane-aligned slice of a token's row, so it goes to the
+    same kernels head-major, which take ``D < 128`` as it is and K/V at
+    ``Hkv`` heads: one transpose each of q, k, v on the way in and of the
+    result on the way out, never the dense path
+    (``flash_calls_total{layout="head_major", kv="grouped"}`` says so).
+    Elsewhere the dense path groups the query heads over the K/V heads in one
+    einsum, softmax in fp32.  ``causal``, ``window``, ``segment_ids`` as in
     ``dot_product_attention``; ``kv_mask``: (B, T) bool key validity (True
     = attend), with its caveat on fully-masked rows."""
     B, T, H, D = q.shape
@@ -206,14 +211,18 @@ def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
     from ..amp import policy as _pol
     (q, k, v), _ = _pol.cast_op_args("dot_product_attention", (q, k, v), {})
     from ..ops import dispatch
-    if dispatch.use_pallas_for(q) and D % 128 == 0:
+    if dispatch.use_pallas_for(q) and (D % 128 == 0 or D < 128):
         from ..ops import pallas_flash_attention as pfa
         if pfa.fits_vmem(T, D, segments=segment_ids is not None,
                          window=window):
             _note_path("flash")
-            return pfa.flash_attention_token_major(
-                q, k, v, causal=causal, scale=scale, kv_mask=kv_mask,
-                segment_ids=segment_ids, window=window)
+            how = dict(causal=causal, scale=scale, kv_mask=kv_mask,
+                       segment_ids=segment_ids, window=window)
+            if D % 128 == 0:
+                return pfa.flash_attention_token_major(q, k, v, **how)
+            heads_first = lambda x: jnp.swapaxes(x, 1, 2)
+            return heads_first(pfa.flash_attention(
+                heads_first(q), heads_first(k), heads_first(v), **how))
     _note_path("dense")
     see = None if kv_mask is None else kv_mask[:, None, None, None, :]
     both = lambda a, b: b if a is None else jnp.logical_and(a, b)

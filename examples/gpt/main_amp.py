@@ -17,6 +17,8 @@ The per-layer decoder with routed experts, from a published config file
       --model-config benchmark/configs/laguna-xs2.json
   python examples/gpt/main_amp.py --arch mellum -b 1 --block-size 8192 \
       --model-config benchmark/configs/mellum2-12b.json
+  python examples/gpt/main_amp.py --arch lfm2_moe -b 2 --block-size 8192 \
+      --model-config benchmark/configs/lfm2-8b-a1b.json
 
 ``build(args)`` returns the model, mesh, state and jitted train step that
 ``main()`` loops over; the benchmark and the tests drive the same objects.
@@ -37,7 +39,7 @@ if os.path.isdir(os.path.join(_repo, "apex_tpu")) and _repo not in sys.path:
     sys.path.insert(0, _repo)
 
 # --arch values that models/laguna.py builds from a --model-config file
-PER_LAYER_ARCHS = ("laguna", "mellum")
+PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe")
 
 # enough structure to be learnable at tiny scale: a looping pangram
 _BUILTIN_TEXT = ("the quick brown fox jumps over the lazy dog. " * 200)
@@ -83,14 +85,17 @@ def parse_args(argv=None):
                         "models/laguna.py (window and full attention, "
                         "routed experts) built from --model-config: "
                         "laguna (dense first layer, gated attention, "
-                        "sigmoid router, shared expert) or mellum (every "
-                        "layer sparse, softmax router, no shared expert)")
+                        "sigmoid router, shared expert), mellum (every "
+                        "layer sparse, softmax router, no shared expert) "
+                        "or lfm2_moe (gated short-convolution layers 3:1 "
+                        "with attention, a selection bias on the router, "
+                        "a tied head)")
     p.add_argument("--model-config", default=None, metavar="JSON",
-                   help="laguna, mellum: a config file with the "
-                        "published keys, whose model_type is --arch "
+                   help="laguna, mellum, lfm2_moe: a config file with "
+                        "the published keys, whose model_type is --arch "
                         "(benchmark/configs/laguna-xs2.json, "
-                        "mellum2-12b.json); the sequence length is "
-                        "--block-size")
+                        "mellum2-12b.json, lfm2-8b-a1b.json); the "
+                        "sequence length is --block-size")
     p.add_argument("--n-kv-head", type=int, default=None,
                    help="grouped-query attention KV heads (llama; "
                         "default MHA)")

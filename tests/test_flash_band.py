@@ -12,6 +12,7 @@ described v5e, the kernels at the widths of the benchmark's cells and the
 decoder's attention layer around them."""
 
 import math
+import re
 
 import numpy as np
 import pytest
@@ -388,7 +389,8 @@ def test_token_major_needs_whole_lane_tiles_and_heads_that_divide():
 
 def test_dot_product_attention_token_major_dispatches_like_the_head_major_entry(monkeypatch):
     """Dense on the CPU (query heads grouped over K/V heads in one einsum), the
-    flash kernels where Pallas is on and a head is whole lane tiles."""
+    flash kernels where Pallas is on: token-major where a head is whole lane
+    tiles, head-major behind one transpose each way where it is under one."""
     qkv, _, _ = _token_major("window-512-rep6", jnp.float32)
     seg = _thirds(1, 1024)
     want = _by_hand(_dense_all, dict(causal=True, window=512, segment_ids=seg))(*qkv)
@@ -397,15 +399,18 @@ def test_dot_product_attention_token_major_dispatches_like_the_head_major_entry(
     try:
         dense = attention.dot_product_attention_token_major(*qkv, causal=True, window=512,
                                                             segment_ids=seg)
+        narrow_dense = attention.dot_product_attention_token_major(
+            *(x[..., :64] for x in qkv), causal=True)
         monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "1")
         monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
         flash = attention.dot_product_attention_token_major(*qkv, causal=True, window=512,
                                                             segment_ids=seg)
         narrow = attention.dot_product_attention_token_major(
-            *(x[..., :64] for x in qkv), causal=True)          # D = 64: no lane tile
+            *(x[..., :64] for x in qkv), causal=True)          # D = 64: half a lane tile
     finally:
         attention.set_path_hook(None)
-    assert paths == ["dense", "flash", "dense"] and narrow.shape == (1, 1024, 6, 64)
+    assert paths == ["dense", "dense", "flash", "flash"] and narrow.shape == (1, 1024, 6, 64)
+    np.testing.assert_allclose(np.asarray(narrow), np.asarray(narrow_dense), atol=5e-6)
     for got in (dense, flash):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-6)
     with pytest.raises(ValueError, match="causal"):
@@ -709,3 +714,52 @@ def test_v5e_compiles_the_grouped_expert_products(one_chip, for_the_chip, cell, 
     assert len(forward) == 3 and len(backward) == 6, kernels
     assert sum("grouped_stack" in k for k in backward) == 3, kernels
     assert sum("grouped_rows_t" in k for k in backward) == 3, kernels
+
+
+def test_v5e_compiles_the_grouped_products_at_a_block_that_asks_for_more_vmem(one_chip,
+                                                                              for_the_chip):
+    """``lfm2-8b-a1b``'s expert layer, forward and backward: a group's
+    2048 x 1792 block does not fit the kernels' default VMEM twice, so the
+    chooser names a tile under a stated larger ask; the chip's compiler takes
+    the nine kernels, and nothing of the compiler's own grouped product is
+    left."""
+    from apex_tpu.ops import pallas_grouped_matmul as pgm
+    from apex_tpu.parallel.expert_parallel import ExpertParallelMLP
+    layer = ExpertParallelMLP(2048, 1792, 32, capacity_factor=None, top_k=4,
+                              expert_type="swiglu", router_type="sigmoid", experts_held=(0, 8),
+                              row_buffer_factor=2.0, router_bias=True, router_out_in=True)
+    tokens = 16384
+    assert pgm.row_tile(2 * tokens * 4 * 8 // 32, 2048, 1792, 8, jnp.bfloat16) == 256
+    shapes = jax.eval_shape(lambda k: layer.init(k)[0], jax.random.PRNGKey(0))
+    assert shapes["router"].shape == (32, 2048) and shapes["expert_bias"].shape == (32,)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32 if s.shape[0] == 32 else jnp.bfloat16,
+                                       sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((tokens, 2048), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)), (0, 1))).lower(
+        params, x).compile().as_text()
+    assert "ragged-dot" not in text
+    names = re.findall(r"%(grouped_rows_t|grouped_rows|grouped_stack)[.\d]* = ", text)
+    assert sorted(names) == ["grouped_rows"] * 3 + ["grouped_rows_t"] * 3 + ["grouped_stack"] * 3
+
+
+def test_v5e_compiles_a_grouped_head_of_64_through_the_head_major_kernels(one_chip, for_the_chip):
+    """``lfm2-8b-a1b``'s attention call: 32 query heads over 8 K/V heads of 64
+    from ``dot_product_attention_token_major``: the three flash kernels with
+    K/V at their 8 heads (the dkv kernel writes 16 = 2 x 8 folds), and no
+    array of scores."""
+    from apex_tpu.transformer import attention
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16, sharding=one_chip)
+    paths = []
+    attention.set_path_hook(paths.append)
+    try:
+        text = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attention.dot_product_attention_token_major(
+                q, k, v, causal=True).astype(jnp.float32)), (0, 1, 2))).lower(
+                    q, kv, kv).compile().as_text()
+    finally:
+        attention.set_path_hook(None)
+    assert set(paths) == {"flash"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "bf16[16,8192,64]" in text and "8192,8192]" not in text

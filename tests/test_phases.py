@@ -129,6 +129,19 @@ def programs():
         text = jax.jit(jax.grad(lambda p: lag.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
             lparams).compile().as_text()
         out["moe"] = (_scopes(text), phases.instruction_phases(text))
+
+        # the same decoder with a gated short-convolution layer and QK-norm
+        mix = models.Laguna(models.LagunaConfig(
+            vocab_size=64, hidden_size=16, intermediate_size=32,
+            layer_types=["conv", "full_attention"], num_attention_heads_per_layer=[2, 2],
+            mlp_layer_types=["dense", "dense"], num_key_value_heads=2, head_dim=8,
+            sliding_window=None, num_experts=4, num_experts_per_tok=2, moe_intermediate_size=8,
+            rope_parameters={"full_attention": {"rope_theta": 10000.0}}, qk_norm=True,
+            tie_word_embeddings=True, head_chunk=32))
+        mparams, _ = mix.init(jax.random.PRNGKey(3))
+        text = jax.jit(jax.grad(lambda p: mix.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
+            mparams).compile().as_text()
+        out["conv"] = (_scopes(text), phases.instruction_phases(text))
     finally:
         C.set_ledger(prev)
     return out
@@ -139,7 +152,9 @@ CASES = ([("mesh", s) for s in TRAIN_SCOPES + DDP_SCOPES]
          + [("lamb", "optim.lamb"), ("lion", "optim.lion"), ("lion", "amp.grad_norm"),
             ("paged", "paged.gather"), ("paged", "paged.scatter"), ("paged", "paged.attend"),
             ("moe", "moe.route"), ("moe", "moe.dispatch"), ("moe", "moe.experts"),
-            ("moe", "moe.combine")])
+            ("moe", "moe.combine"),
+            ("conv", "conv.in_proj"), ("conv", "conv.mix"), ("conv", "conv.out_proj"),
+            ("conv", "attn.qk_norm")])
 
 
 def test_every_scope_of_the_vocabulary_has_a_case():
