@@ -347,13 +347,35 @@ def _cast_like(tree, like):
             jnp.result_type(l), jnp.floating) else x, tree, like)
 
 
+def _cut(buf: jax.Array, offset: int, size: int, shape) -> jax.Array:
+    """``buf[offset:offset + size]`` as a leaf of ``shape``, the piece
+    held whole before it is reshaped (``_FlatLayout``'s docstring has
+    what the compiler does to a slice that is reshaped)."""
+    piece = jax.lax.slice_in_dim(buf, offset, offset + size)
+    return jax.lax.optimization_barrier(piece).reshape(shape)
+
+
 class _FlatLayout:
     """Static description of a float-leaf flattening, computed once at
     ``AmpOptimizer.init``.  The reference flattens each param group once at
     construction (apex/optimizers/fp16_optimizer.py:57-70); round-1 apex_tpu
     instead re-packed the whole tree every step
-    (round-2 VERDICT weak-item 2) — this layout makes pack/unpack a single
-    concat / static-slice set that XLA folds into neighbouring ops.
+    (round-2 VERDICT weak-item 2) — this layout makes pack a single concat
+    and unpack one static slice a leaf.
+
+    How a leaf leaves a buffer (``_cut``).  A slice that is reshaped is not
+    what the TPU's compiler runs: it turns ``slice(buf).reshape(rows, W)``
+    round into ``slice(buf.reshape(N / W, W))``, a reshape of the WHOLE
+    buffer once for every distinct last dimension W among the leaves (a
+    relayout, so a copy of the buffer each; a float32 leaf narrower than a
+    lane tile has the master buffer copied lane-padded), and only then cuts
+    rows out of that.  So the cut piece is held whole behind an
+    ``optimization_barrier`` before it is reshaped: the compiler then
+    writes each leaf twice, one slice and one reshape of the leaf's own
+    size, whatever the widths are, and plans no temporary of the buffer's
+    size (``tests/test_flat_storage.py`` reads the program compiled for a
+    v5e).  The second pass is the relayout from the 1-D tiling to the
+    leaf's; one pass would take a kernel of our own.
 
     Two lengths.  ``total`` is the LOGICAL element count, the sum of the
     float leaves' sizes: offsets, ``rebuild``, ``unpack_masters``, the
@@ -450,8 +472,7 @@ class _FlatLayout:
                 continue
             dt = jnp.dtype(self.dtypes[i])
             src = half if (half is not None and dt == half.dtype) else flat32
-            piece = jax.lax.dynamic_slice_in_dim(
-                src, self.offsets[i], self.sizes[i]).reshape(shape)
+            piece = _cut(src, self.offsets[i], self.sizes[i], shape)
             if piece.dtype != dt:
                 piece = piece.astype(dt)
             out.append(piece)
@@ -472,8 +493,7 @@ class _FlatLayout:
             if not f:
                 out.append(None)
                 continue
-            out.append(jax.lax.dynamic_slice_in_dim(
-                flat32, self.offsets[i], self.sizes[i]).reshape(shape))
+            out.append(_cut(flat32, self.offsets[i], self.sizes[i], shape))
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
 

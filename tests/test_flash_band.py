@@ -557,31 +557,14 @@ def test_index_maps_of_the_folded_triangle_cover_every_pair_once():
                 assert len(runs) == len(set(runs))
 
 
-# -- compiled for a described (not attached) v5e -------------------------------
-
-@pytest.fixture(scope="module")
-def one_chip():
-    import os
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:                      # no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
+# -- compiled for a described (not attached) v5e (``one_chip``: conftest.py) ---
 
 @pytest.fixture
-def for_the_chip(monkeypatch):
+def for_the_chip(monkeypatch, past_the_cache):
     """Kernels as the chip runs them (Mosaic, not the interpreter), compiled
-    past the persistent cache, which cannot read such an entry back."""
+    past the persistent cache (``past_the_cache``: conftest.py)."""
     from apex_tpu.ops import dispatch
     monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
 
 
 @pytest.mark.parametrize("window,fetched", [(512, 2), (None, 16)])

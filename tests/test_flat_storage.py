@@ -9,7 +9,11 @@ zero, also over a skipped step, (c) under Pallas dispatch the flat step
 holds no pad and no slice of the flat length and the registry's counter
 reads 0, while an unaligned direct call still pads, matches and is
 counted, (d) a snapshot saved at the old length restores and steps
-identically."""
+identically, (e) a leaf leaves a flat buffer as a piece of its own size:
+``rebuild`` and ``unpack_masters`` equal numpy's slices bit for bit, and
+compiled for a described v5e they hold no result of the buffer's size."""
+
+import re
 
 import numpy as np
 import pytest
@@ -362,3 +366,91 @@ def test_restore_still_refuses_a_wrong_length(tmp_path):
     ckpt.save_checkpoint(str(tmp_path), 1, bad)
     with pytest.raises(ValueError, match="shape mismatch"):
         ckpt.restore_checkpoint(str(tmp_path), state)
+
+
+# -- (e) how a leaf leaves a flat buffer --------------------------------------
+def _leaves_of_widths(aligned: bool, rows: int = 8):
+    """bf16 leaves of five last dimensions (64, 256, 512, 1024 and a 1-D
+    bias), a float32 (rows, 64) leaf as a router keeps, a float32 norm and
+    an int leaf; ``aligned`` puts every offset on a multiple of 128."""
+    bias, taps = (256, 128) if aligned else (77, 15)
+    z = jnp.zeros
+    return {"a_qkv": z((rows, 1024), jnp.bfloat16),
+            "b_bias": z((bias,), jnp.bfloat16),
+            "c_head": z((rows * 2, 64), jnp.bfloat16),
+            "d_router": z((rows * 3, 64), jnp.float32),
+            "e_experts": z((2, rows, 512), jnp.bfloat16),
+            "f_norm": z((taps,), jnp.float32),
+            "g_down": z((rows * 4, 256), jnp.bfloat16),
+            "step": jnp.asarray(3, jnp.int32)}
+
+
+@pytest.mark.parametrize("aligned", [True, False], ids=["aligned", "unaligned"])
+def test_leaves_come_out_of_the_buffers_as_numpy_slices_them(aligned):
+    params = _leaves_of_widths(aligned)
+    lay = _FlatLayout(params)
+    on_tiles = all(o % LANES == 0 for o in lay.offsets)
+    assert on_tiles == aligned
+    rng = np.random.RandomState(5)
+    flat32 = jnp.asarray(rng.randn(lay.storage), jnp.float32)
+    half = flat32.astype(jnp.bfloat16)
+    like = jax.tree_util.tree_leaves(params)
+
+    def bits(x):
+        x = np.asarray(x)
+        return x.view(np.uint16) if x.dtype == jnp.bfloat16 else x
+
+    def want(src, i, dtype):
+        cut = np.asarray(src)[lay.offsets[i]:lay.offsets[i] + lay.sizes[i]]
+        return bits(cut.reshape(lay.shapes[i]).astype(dtype))
+
+    for rebuild in (lay.rebuild, jax.jit(lay.rebuild)):
+        with_half = jax.tree_util.tree_leaves(rebuild(flat32, half, like))
+        cast = jax.tree_util.tree_leaves(rebuild(flat32, None, like))
+        for i, (l, f) in enumerate(zip(like, lay.is_float)):
+            if not f:
+                assert int(with_half[i]) == int(cast[i]) == 3
+                continue
+            src = half if l.dtype == jnp.bfloat16 else flat32
+            for got in (with_half[i], cast[i]):
+                assert got.dtype == l.dtype and got.shape == l.shape
+                np.testing.assert_array_equal(bits(got), want(src, i, l.dtype))
+    masters = lay.unpack_masters(flat32)
+    assert masters["step"] is None
+    got = jax.tree_util.tree_leaves(masters)          # the None leaf drops out
+    floats = [i for i, f in enumerate(lay.is_float) if f]
+    assert len(got) == len(floats)
+    for g, i in zip(got, floats):
+        assert g.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(g), want(flat32, i, np.float32))
+
+
+@pytest.mark.parametrize("source", ["bfloat16", "float32"])
+def test_v5e_cuts_each_leaf_out_of_the_buffers_before_it_is_reshaped(one_chip, past_the_cache,
+                                                                     source):
+    """The TPU's compiler turns a slice that is reshaped round into a
+    reshape of the WHOLE buffer, once for every distinct last dimension
+    among the leaves, and cuts rows out of that.  With the piece held
+    whole (``_cut``) no result of the compiled program has the buffer's
+    size and no temporary of that size is planned: ``bfloat16`` reads
+    ``rebuild`` (the kernel's half copy, and the float32 buffer for the
+    router and the norm), ``float32`` reads ``unpack_masters``."""
+    lay = _FlatLayout(_leaves_of_widths(True, rows=2048))
+    assert lay.total > 16 * BLOCK_ELEMS
+    flat32 = jax.ShapeDtypeStruct((lay.storage,), jnp.float32, sharding=one_chip)
+    half = jax.ShapeDtypeStruct((lay.storage,), jnp.bfloat16, sharding=one_chip)
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    if source == "bfloat16":
+        def leaves(flat32, half, step):
+            like = [step if not f else None for f in lay.is_float]
+            return lay.rebuild(flat32, half, like)
+        compiled = jax.jit(leaves).lower(flat32, half, step).compile()
+    else:
+        compiled = jax.jit(lay.unpack_masters).lower(flat32).compile()
+    results = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                         compiled.as_text(), re.M)
+    assert len(results) > len(lay.shapes)
+    whole = [(op, dims) for dims, op in results if op != "parameter"
+             and np.prod([int(d) for d in dims.split(",") if d]) >= lay.total]
+    assert whole == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * lay.total
