@@ -251,23 +251,266 @@ ENTRY %main.253 (args_0: bf16[512,1024], args_1: f32[1024]) -> (f32[265572352]) 
 '''
 
 
-@pytest.mark.parametrize("name,expected", [
-    ("_adam_flat.1", (("amp.update", "optim.adam"), False)),
-    ("flash_fwd.3", (("model", "BertForPretraining/bert/3/attention"), False)),
-    ("fusion.431", (("model", "BertForPretraining/bert/3/intermediate"), True)),
-    ("slice.40", (("amp.update", "amp.rebuild"), False)),
-    # no op_name, inside the branch: the conditional's phase
-    ("copy-start.7", (("amp.update",), False)),
-    ("copy-done.7", (("amp.update",), False)),
+# excerpts of the same step's text as PR 38's tree compiles it (cut from the
+# step compiled for a described v5e, which is the text the chip compiles;
+# names, operands and metadata as printed, shapes' tilings and the configs
+# cut): the fp32 gradient pack as the compiler splits it (a chain of
+# in-place updates over one buffer, of which only the links that fuse the
+# pack's ``convert`` keep an ``op_name``, and a ``concatenate`` joined to it
+# under ``amp.unscale``), a weight gradient's relayout on its way into the
+# pack, and a weight staged into the fast memory space for the backward pass
+V5E_DATAFLOW = """
+HloModule jit_step, is_scheduled=true
+
+%fused_computation.1442 (param_0.3253: f32[1024]) -> f32[265572352] {
+  %custom-call.13 = f32[265572352]{0} custom-call(), custom_call_target="AllocateBuffer"
+  %param_0.3253 = f32[1024]{0} parameter(0)
+  %constant.1307 = s32[] constant(0)
+  ROOT %dynamic-update-slice.511 = f32[265572352]{0} dynamic-update-slice(%custom-call.13, %param_0.3253, %constant.1307)
+}
+
+%fused_computation.1439 (param_0.3189: f32[265572352], param_1.2783: f32[1024]) -> f32[265572352] {
+  %param_0.3189 = f32[265572352]{0} parameter(0)
+  %param_1.2783 = f32[1024]{0} parameter(1)
+  %constant.1303 = s32[] constant(1024)
+  ROOT %dynamic-update-slice.510 = f32[265572352]{0} dynamic-update-slice(%param_0.3189, %param_1.2783, %constant.1303)
+}
+
+%fused_computation.1438 (param_0.3188: f32[265572352], param_1.2827: bf16[1024]) -> f32[265572352] {
+  %param_0.3188 = f32[265572352]{0} parameter(0)
+  %param_1.2827 = bf16[1024]{0} parameter(1)
+  %convert_element_type.520 = f32[1024]{0} convert(%param_1.2827), metadata={op_name="jit(step)/amp.pack/convert_element_type" stack_frame_id=2}
+  %constant.1302 = s32[] constant(2048)
+  ROOT %dynamic-update-slice.509 = f32[265572352]{0} dynamic-update-slice(%param_0.3188, %convert_element_type.520, %constant.1302)
+}
+
+%fused_computation.1206 (param_0.2956: f32[265572352], param_1.2529: f32[1048576]) -> f32[265572352] {
+  %param_0.2956 = f32[265572352]{0} parameter(0)
+  %param_1.2529 = f32[1048576]{0:S(1)} parameter(1)
+  %constant.1049 = s32[] constant(264523776)
+  ROOT %dynamic-update-slice.277 = f32[265572352]{0} dynamic-update-slice(%param_0.2956, %param_1.2529, %constant.1049)
+}
+
+%fused_computation.1096 (param_0.2808: bf16[8,512,1024], param_1.2353: bf16[8,512,1024]) -> f32[128,8,8,128] {
+  %param_0.2808 = bf16[8,512,1024]{2,1,0} parameter(0)
+  %param_1.2353 = bf16[8,512,1024]{2,1,0} parameter(1)
+  %convolution.763 = bf16[1024,1024,1]{1,0,2} convolution(%param_0.2808, %param_1.2353), window={size=8}, dim_labels=0fb_0io->bf0, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/mlm_dense/dot_general" stack_frame_id=2}
+  %bitcast.2539 = bf16[1024,1024]{1,0} bitcast(%convolution.763), metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/mlm_dense/dot_general" stack_frame_id=2}
+  %convert.333 = f32[1024,1024]{1,0} convert(%bitcast.2539), metadata={op_name="jit(step)/amp.pack/convert_element_type" stack_frame_id=2}
+  ROOT %bitcast.2513 = f32[128,8,8,128]{3,2,1,0} bitcast(%convert.333)
+}
+
+%fused_computation.9 (param_0.22: f32[70627328], param_1.2902: f32[265572352]) -> f32[2626560,128] {
+  %param_1.2902 = f32[265572352]{0} parameter(1)
+  %constant.1320 = f32[] constant(-inf)
+  %pad.16 = f32[336199680]{0} pad(%param_1.2902, %constant.1320), padding=0_70627328, metadata={op_name="jit(step)/amp.pack/concatenate" stack_frame_id=2}
+  %param_0.22 = f32[70627328]{0} parameter(0)
+  %pad.15 = f32[336199680]{0} pad(%param_0.22, %constant.1320), padding=265572352_0, metadata={op_name="jit(step)/amp.pack/concatenate" stack_frame_id=2}
+  %maximum.3 = f32[336199680]{0} maximum(%pad.16, %pad.15), metadata={op_name="jit(step)/amp.pack/concatenate" stack_frame_id=2}
+  ROOT %bitcast.1883 = f32[2626560,128]{1,0} bitcast(%maximum.3), metadata={op_name="jit(step)/amp.unscale/jit(_scale_flat)/reshape" stack_frame_id=96}
+}
+
+%fused_computation.1093 (param_0.2790: bf16[4096,1024], param_1.2341: bf16[1024,1024]) -> bf16[4096,1024] {
+  %param_0.2790 = bf16[4096,1024]{1,0} parameter(0)
+  %param_1.2341 = bf16[1024,1024]{0,1:S(1)} parameter(1)
+  ROOT %convolution.760 = bf16[4096,1024]{1,0} convolution(%param_0.2790, %param_1.2341), dim_labels=bf_io->bf, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/4/attention/out/dot_general" stack_frame_id=2}
+}
+
+ENTRY %main.253 (args_0: bf16[1024,1024], args_1: bf16[8,512,1024], args_2: s32[8]) -> (f32[2626560,128], bf16[4096,1024], s32[8], s32[8], s32[8]) {
+  %args_0 = bf16[1024,1024]{1,0} parameter(0), metadata={op_name="args[0][0][\'bert\'][\'layer\'][\'4\'][\'attention\'][\'out\'][\'weight\']"}
+  %args_1 = bf16[8,512,1024]{2,1,0} parameter(1)
+  %args_2 = s32[8]{0} parameter(2)
+  %slice-start.1088 = ((bf16[1024,1024]{1,0}), bf16[512,1024]{1,0:S(1)}, s32[]{:S(2)}) slice-start(%args_0), slice={[0:512], [0:1024]}
+  %slice-start.1089 = ((bf16[1024,1024]{1,0}), bf16[512,1024]{1,0:S(1)}, s32[]{:S(2)}) slice-start(%args_0), slice={[512:1024], [0:1024]}
+  %slice-done.1088 = bf16[512,1024]{1,0:S(1)} slice-done(%slice-start.1088)
+  %slice-done.1089 = bf16[512,1024]{1,0:S(1)} slice-done(%slice-start.1089)
+  %custom-call.286 = bf16[1024,1024]{1,0:S(1)} custom-call(%slice-done.1088, %slice-done.1089), custom_call_target="ConcatBitcast"
+  %copy.829 = bf16[1024,1024]{0,1} copy(%custom-call.286), metadata={op_name="args[0][0][\'bert\'][\'layer\'][\'4\'][\'attention\'][\'out\'][\'weight\']"}
+  %copy-start.152 = (bf16[1024,1024]{0,1:S(1)}, bf16[1024,1024]{0,1}, u32[]{:S(2)}) copy-start(%copy.829)
+  %layer_norm_bwd.99 = (bf16[4096,1024]{1,0:S(1)}, f32[1,1024]{1,0}, f32[1,1024]{1,0}) custom-call(%args_1), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/embeddings_ln/jit(_bwd)/layer_norm_bwd/pallas_call" stack_frame_id=78}
+  %pallas_call.593 = bf16[4096,1024]{1,0:S(1)} get-tuple-element(%layer_norm_bwd.99), index=0, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/embeddings_ln/jit(_bwd)/layer_norm_bwd/pallas_call" stack_frame_id=78}
+  %pallas_call.595 = f32[1,1024]{1,0} get-tuple-element(%layer_norm_bwd.99), index=2, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/embeddings_ln/jit(_bwd)/layer_norm_bwd/pallas_call" stack_frame_id=78}
+  %bitcast.3922 = f32[1024]{0} bitcast(%pallas_call.595)
+  %pallas_call.594 = f32[1,1024]{1,0} get-tuple-element(%layer_norm_bwd.99), index=1, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/embeddings_ln/jit(_bwd)/layer_norm_bwd/pallas_call" stack_frame_id=78}
+  %bitcast.3921 = f32[1024]{0} bitcast(%pallas_call.594)
+  %constant_dynamic-update-slice_fusion.234 = f32[265572352]{0} fusion(%bitcast.3922), kind=kLoop, calls=%fused_computation.1442
+  %constant_dynamic-update-slice_fusion.233 = f32[265572352]{0} fusion(%constant_dynamic-update-slice_fusion.234, %bitcast.3921), kind=kLoop, calls=%fused_computation.1439
+  %copy-done.152 = bf16[1024,1024]{0,1:S(1)} copy-done(%copy-start.152)
+  %fusion.1093 = bf16[4096,1024]{1,0} fusion(%pallas_call.593, %copy-done.152), kind=kOutput, calls=%fused_computation.1093, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/4/attention/out/dot_general" stack_frame_id=2}
+  %reduce_sum.1667 = bf16[1024]{0} reduce(%fusion.1093, %args_2), dimensions={0}, to_apply=%region_212.232, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/bert/0/attention/out/reduce_sum" stack_frame_id=2}
+  %constant_dynamic-update-slice_fusion.232 = f32[265572352]{0} fusion(%constant_dynamic-update-slice_fusion.233, %reduce_sum.1667), kind=kLoop, calls=%fused_computation.1438
+  %convert_bitcast_fusion.72 = f32[128,8,8,128]{3,2,1,0:S(1)} fusion(%args_1, %args_1), kind=kOutput, calls=%fused_computation.1096, metadata={op_name="jit(step)/transpose(jvp(model))/BertForPretraining/mlm_dense/dot_general" stack_frame_id=2}
+  %copy.1216 = f32[128,8,8,128]{3,1,2,0} copy(%convert_bitcast_fusion.72)
+  %bitcast.3612 = f32[1048576]{0} bitcast(%copy.1216)
+  %constant_dynamic-update-slice_fusion = f32[265572352]{0} fusion(%constant_dynamic-update-slice_fusion.232, %bitcast.3612), kind=kLoop, calls=%fused_computation.1206
+  %get-tuple-element.660 = f32[3,1024]{1,0} get-tuple-element(%layer_norm_bwd.99), index=1
+  %reshape.1939 = f32[3072]{0} reshape(%get-tuple-element.660), metadata={op_name="jit(step)/amp.pack/convert_element_type" stack_frame_id=2}
+  %copy.1203 = f32[128,8,8,128]{3,1,2,0} copy(%convert_bitcast_fusion.72)
+  %bitcast.3251 = f32[1048576]{0} bitcast(%copy.1203)
+  %concatenate.64 = f32[70627328]{0} concatenate(%reshape.1939, %bitcast.3251)
+  %maximum_bitcast_fusion.1 = f32[2626560,128]{1,0} fusion(%concatenate.64, %constant_dynamic-update-slice_fusion), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(step)/amp.unscale/jit(_scale_flat)/reshape" stack_frame_id=96}
+  %copy.2711 = s32[8]{0:S(1)} copy(%args_2)
+  %iota.5 = s32[8]{0} iota(), iota_dimension=0
+  %copy-start.254 = (s32[8]{0}, s32[8]{0:S(1)}, u32[]{:S(2)}) copy-start(%copy.2711)
+  %copy-done.254 = s32[8]{0} copy-done(%copy-start.254)
+  ROOT %tuple.99 = (f32[2626560,128]{1,0}, bf16[4096,1024]{1,0}, s32[8]{0}, s32[8]{0}, s32[8]{0}) tuple(%maximum_bitcast_fusion.1, %fusion.1093, %copy-done.254, %iota.5, %copy.2711)
+}
+"""
+
+TEXTS = {"step": V5E_TEXT, "dataflow": V5E_DATAFLOW}
+PACK, ATTN_OUT_BWD = (("amp.pack",), False), (("model", "BertForPretraining/bert/4/attention/out"),
+                                              True)
+
+# (excerpt, instruction, its phase, where the phase came from)
+ON_V5E_TEXT = [
+    ("step", "_adam_flat.1", (("amp.update", "optim.adam"), False), "own"),
+    ("step", "flash_fwd.3", (("model", "BertForPretraining/bert/3/attention"), False), "own"),
+    ("step", "fusion.431", (("model", "BertForPretraining/bert/3/intermediate"), True), "own"),
+    ("step", "slice.40", (("amp.update", "amp.rebuild"), False), "own"),
+    # no op_name, inside the branch: the conditional's phase, whoever reads it
+    ("step", "copy-start.7", (("amp.update",), False), "container"),
+    ("step", "copy-done.7", (("amp.update",), False), "container"),
     # no op_name, a fusion: what it fused
-    ("convert_bitcast_fusion.98", (("amp.pack",), False)),
-    # no op_name anywhere near: unscoped
-    ("constant_dynamic-update-slice_fusion.234", ((), False)),
-    ("copy.2711", ((), False)),
-    ("conditional.1", (("amp.update",), False)),
+    ("step", "convert_bitcast_fusion.98", (("amp.pack",), False), "fused"),
+    # no op_name anywhere near, and nothing with a phase along the dataflow: unscoped
+    ("step", "constant_dynamic-update-slice_fusion.234", ((), False), "none"),
+    ("step", "copy.2711", ((), False), "none"),
+    ("step", "conditional.1", (("amp.update",), False), "own"),
+    # the pack's chain: a link that fused the pack's convert keeps it ..
+    ("dataflow", "constant_dynamic-update-slice_fusion.232", PACK, "fused"),
+    # .. the first link (its buffer is allocated inside it, its update is a
+    # gradient of ``model``) and the next take it from the links after them ..
+    ("dataflow", "constant_dynamic-update-slice_fusion.234", PACK, "sibling"),
+    ("dataflow", "constant_dynamic-update-slice_fusion.233", PACK, "sibling"),
+    # .. and the last one, which ``amp.unscale`` reads, from the link before it
+    ("dataflow", "constant_dynamic-update-slice_fusion", PACK, "sibling"),
+    ("dataflow", "dynamic-update-slice.277", PACK, "container"),
+    # the second piece: no chain, a first operand under amp.pack, the reader amp.unscale's
+    ("dataflow", "concatenate.64", PACK, "sibling"),
+    ("dataflow", "maximum_bitcast_fusion.1", (("amp.unscale",), False), "own"),
+    # a weight gradient's relayout is made for the pack that reads it, not for
+    # the backward matmul that wrote its operand
+    ("dataflow", "convert_bitcast_fusion.72", (("model", "BertForPretraining/mlm_dense"), True),
+     "own"),
+    ("dataflow", "copy.1216", PACK, "reader"),
+    ("dataflow", "copy.1203", PACK, "reader"),
+    # a weight staged for the backward matmul: the slices' and the copy's time
+    # is on their ``-done``, and the relayout between them (its op_name is the
+    # argument's: no scope) is read through the join of the slices ..
+    ("dataflow", "slice-done.1088", ATTN_OUT_BWD, "reader"),
+    ("dataflow", "slice-done.1089", ATTN_OUT_BWD, "reader"),
+    ("dataflow", "copy.829", ATTN_OUT_BWD, "reader"),
+    ("dataflow", "copy-done.152", ATTN_OUT_BWD, "reader"),
+    # .. while a ``-start`` (the ``Async XLA Ops`` line names a start-to-done
+    # span by it, and ``ddp.step_ms`` adds such spans whole) and the compiler's
+    # own ``custom-call`` (``*.grouped_dot_roofline`` counts every custom-call
+    # under ``moe.experts`` as a product's kernel) stay what they were
+    ("dataflow", "slice-start.1088", ((), False), "none"),
+    ("dataflow", "copy-start.152", ((), False), "none"),
+    ("dataflow", "custom-call.286", ((), False), "none"),
+    ("dataflow", "fusion.1093", ATTN_OUT_BWD, "own"),
+    # every operand and every reader without a phase: no rule explains them
+    ("dataflow", "copy.2711", ((), False), "none"),
+    ("dataflow", "copy-start.254", ((), False), "none"),
+    ("dataflow", "copy-done.254", ((), False), "none"),
+    # not a move and not a join: no rule is tried
+    ("dataflow", "iota.5", ((), False), "none"),
+]
+
+
+@pytest.mark.parametrize("text,name,expected,source", ON_V5E_TEXT)
+def test_instruction_phases_on_v5e_text(text, name, expected, source):
+    assert phases.instruction_phases(TEXTS[text])[name] == expected
+
+
+@pytest.mark.parametrize("text,name,expected,source", ON_V5E_TEXT)
+def test_instruction_phase_sources_on_v5e_text(text, name, expected, source):
+    assert phases.instruction_phase_sources(TEXTS[text])[name] == source
+    assert source in phases.SOURCES
+
+
+def test_sources_and_phases_cover_the_same_instructions_and_agree():
+    for text in TEXTS.values():
+        got, sources = phases.instruction_phases(text), phases.instruction_phase_sources(text)
+        assert set(got) == set(sources)
+        assert all((sources[n] == "none") == (not got[n][0]) for n in got)
+
+
+@pytest.mark.parametrize("text,fusion,held", [
+    # a weight-gradient matmul of the backward pass with the pack's fp32 convert on its output
+    ("dataflow", "convert_bitcast_fusion.72", {"model.bwd": 2, "amp.pack": 1}),
+    # the join of the pack's two pieces, filed under its root's amp.unscale
+    ("dataflow", "maximum_bitcast_fusion.1", {"amp.pack": 3, "amp.unscale": 1}),
+    ("dataflow", "constant_dynamic-update-slice_fusion.232", None),     # one phase: not mixed
+    ("dataflow", "fusion.1093", None),
+    ("step", "fusion.431", None),
 ])
-def test_instruction_phases_on_v5e_text(name, expected):
-    assert phases.instruction_phases(V5E_TEXT)[name] == expected
+def test_fusion_phase_mix_on_v5e_text(text, fusion, held):
+    assert phases.fusion_phase_mix(TEXTS[text]).get(fusion) == held
+
+
+# what PR 37's ``instruction_phases`` gave the instructions of V5E_DATAFLOW
+# that had a phase then (recorded from that function): none of them may move
+PHASES_BEFORE_THE_DATAFLOW_RULES = {
+    "convert_element_type.520": PACK, "constant_dynamic-update-slice_fusion.232": PACK,
+    "convert.333": PACK, "pad.16": PACK, "pad.15": PACK, "maximum.3": PACK,
+    "reshape.1939": PACK,
+    **dict.fromkeys(("maximum_bitcast_fusion.1", "bitcast.1883", "param_1.2902", "param_0.22",
+                     "constant.1320"), (("amp.unscale",), False)),
+    **dict.fromkeys(("convert_bitcast_fusion.72", "convolution.763", "bitcast.2539",
+                     "bitcast.2513", "param_0.2808", "param_1.2353"),
+                    (("model", "BertForPretraining/mlm_dense"), True)),
+    **dict.fromkeys(("fusion.1093", "convolution.760", "param_0.2790", "param_1.2341"),
+                    ATTN_OUT_BWD),
+    **dict.fromkeys(("layer_norm_bwd.99", "pallas_call.593", "pallas_call.594",
+                     "pallas_call.595"),
+                    (("model", "BertForPretraining/bert/embeddings_ln"), True)),
+    "reduce_sum.1667": (("model", "BertForPretraining/bert/0/attention/out"), True),
+}
+
+
+def test_a_phase_read_from_metadata_is_never_changed_by_the_dataflow():
+    got = phases.instruction_phases(V5E_DATAFLOW)
+    sources = phases.instruction_phase_sources(V5E_DATAFLOW)
+    for name, before in PHASES_BEFORE_THE_DATAFLOW_RULES.items():
+        assert got[name] == before, name
+        assert sources[name] in ("own", "fused", "container"), name
+    moved = {n for n in got if got[n][0] and n not in PHASES_BEFORE_THE_DATAFLOW_RULES}
+    assert moved and all(sources[n] in ("sibling", "reader", "operand", "container")
+                         for n in moved)
+
+
+def test_the_same_text_gives_the_same_answer():
+    first = (phases.instruction_phases(V5E_DATAFLOW), phases.instruction_phase_sources(
+        V5E_DATAFLOW), phases.fusion_phase_mix(V5E_DATAFLOW))
+    phases.instruction_phases(V5E_TEXT)                 # another text in between
+    again = (phases.instruction_phases(V5E_DATAFLOW + ""), phases.instruction_phase_sources(
+        V5E_DATAFLOW), phases.fusion_phase_mix(V5E_DATAFLOW))
+    assert first == again
+    assert list(first[0]) == list(again[0])             # and in the text's order
+    first[0].clear()                                    # a caller's dict is its own
+    assert phases.instruction_phases(V5E_DATAFLOW) == again[0]
+
+
+def test_the_walks_end_where_the_dataflow_gives_out():
+    """Moves that read each other (no such text comes from a compiler), a
+    join whose chain and operands hold no phase, a move between two of them:
+    every walk ends and the answer is ``unscoped``."""
+    text = """HloModule m
+ENTRY %main (p: f32[8]) -> f32[16] {
+  %p = f32[8]{0} parameter(0)
+  %copy.1 = f32[8]{0} copy(%copy.2)
+  %copy.2 = f32[8]{0} copy(%copy.1)
+  %copy-start.3 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%copy.2)
+  %copy-done.3 = f32[8]{0} copy-done(%copy-start.3)
+  %dynamic-update-slice.4 = f32[16]{0} dynamic-update-slice(%dynamic-update-slice.5, %copy-done.3, %p)
+  %dynamic-update-slice.5 = f32[16]{0} dynamic-update-slice(%dynamic-update-slice.4, %copy.1, %p)
+  ROOT %concatenate.6 = f32[16]{0} concatenate(%copy.2, %copy-done.3)
+}
+"""
+    assert set(phases.instruction_phases(text).values()) == {((), False)}
+    assert set(phases.instruction_phase_sources(text).values()) == {"none"}
+    assert phases.fusion_phase_mix(text) == {}
 
 
 @pytest.mark.parametrize("kernel", ["_adam_flat", "_scale_flat", "layer_norm_fwd",
@@ -408,3 +651,7 @@ ENTRY %main (p: f32[8,8]) -> f32[8,8] {
     assert got["ragged-dot-none.1"] == (("model", "layers/1/mlp", "moe.experts"), True)
     assert got["custom-call.9"] == ((), False)
     assert got["flash_fwd.3"] == (("model", "layers/1/self_attn"), False)
+    # through the one walk of PR 38: operands first for a call the compiler named
+    sources = phases.instruction_phase_sources(text)
+    assert sources["ragged-dot-none"] == "operand" and sources["ragged-dot-none.1"] == "reader"
+    assert sources["custom-call.9"] == "none" and sources["flash_fwd.3"] == "own"
