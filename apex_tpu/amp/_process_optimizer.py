@@ -20,8 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from .scaler import LossScaler, ScalerState
-from ..ops.pallas_common import aligned_len
-from ..optimizers.base import Optimizer
+from ..ops.pallas_common import (LANES, aligned_len, count_grad_pack,
+                                 count_unscale, pick_block_rows)
+from ..optimizers.base import GradSegments, Optimizer
 
 
 def _axis_in_scope(name: str) -> bool:
@@ -105,12 +106,8 @@ def zero_optimizer_specs(optimizer: "AmpOptimizer", params: Any,
             "zero_axis requires master weights and an elementwise inner "
             "optimizer (the flat-buffer path)")
     _validate_zero_knobs(zero_stage, zero_ici_size, zero_compress_bf16)
-    layout = _FlatLayout(params)
-    layout.zero_axis = axis_name
-    layout.zero_stage = int(zero_stage)
-    layout.zero_ici = (int(zero_ici_size) if zero_ici_size is not None
-                       else None)
-    layout.zero_compress = bool(zero_compress_bf16)
+    layout = _FlatLayout(params, axis_name, zero_stage, zero_ici_size,
+                         zero_compress_bf16)
 
     def leaf_spec(l):
         return P() if getattr(l, "ndim", 0) == 0 else P(axis_name)
@@ -347,12 +344,31 @@ def _cast_like(tree, like):
             jnp.result_type(l), jnp.floating) else x, tree, like)
 
 
+def _found_nonfinite(tree) -> jax.Array:
+    """1.0 where any float leaf holds an inf or a nan, else 0.0: one
+    reduction a leaf, in the leaf's own dtype (the flag
+    ``multi_tensor_scale`` raises, without its pass that writes)."""
+    ok = [jnp.all(jnp.isfinite(l)) for l in jax.tree_util.tree_leaves(tree)
+          if jnp.issubdtype(jnp.result_type(l), jnp.floating)]
+    if not ok:
+        return jnp.zeros((), jnp.float32)
+    return jnp.where(jnp.all(jnp.stack(ok)), 0.0, 1.0).astype(jnp.float32)
+
+
 def _cut(buf: jax.Array, offset: int, size: int, shape) -> jax.Array:
     """``buf[offset:offset + size]`` as a leaf of ``shape``, the piece
     held whole before it is reshaped (``_FlatLayout``'s docstring has
     what the compiler does to a slice that is reshaped)."""
     piece = jax.lax.slice_in_dim(buf, offset, offset + size)
     return jax.lax.optimization_barrier(piece).reshape(shape)
+
+
+def _joined(parts, dtype) -> jax.Array:
+    """``parts`` end to end, the empty ones left out."""
+    parts = [p for p in parts if p.shape[0]]
+    if not parts:
+        return jnp.zeros((0,), dtype)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 class _FlatLayout:
@@ -362,6 +378,39 @@ class _FlatLayout:
     instead re-packed the whole tree every step
     (round-2 VERDICT weak-item 2) — this layout makes pack a single concat
     and unpack one static slice a leaf.
+
+    Order.  The un-sharded layout keeps its float leaves BY DTYPE, as the
+    reference keeps its parameter groups (``fp16_groups`` and
+    ``fp32_from_fp32_groups``, apex/amp/_process_optimizer.py): the leaves
+    of the one half dtype first, in tree order, then from the next block
+    boundary the float32 leaves (norms, routers, biases and taps that stay
+    float32 under O2), in tree order, inside the ONE buffer each of
+    masters and moments.  ``segments`` lists them, ``(start, length)`` in
+    elements with both on block boundaries, an empty one left out.  A
+    gradient can then be packed one array a segment in the dtype the
+    backward wrote it (``pack_grads``), float32 leaves unrounded, and the
+    Adam kernel runs once a segment over that segment's blocks of the
+    buffers; half leaves come first so that the half copy the kernel
+    writes for their segment is indexed by the same ``offsets`` as the
+    float32 buffer.  Where a leaf sits is this class's private matter:
+    everything outside reads ``offsets``.  A tree with two half dtypes
+    and every ZeRO layout (whose shard arithmetic and comm plans count in
+    tree order, and whose reduce is float32) keep TREE order, one segment.
+
+    Three lengths.  ``total`` is the LOGICAL element count, the sum of the
+    float leaves' sizes: the ZeRO shard arithmetic and the comm plans
+    count in it.  ``offsets[i]`` is where float leaf ``i`` starts in a
+    buffer: ``rebuild`` and ``unpack_masters`` cut ``sizes[i]`` elements
+    from there.  ``storage`` is the length the un-sharded path keeps its
+    persistent buffers at (masters, the inner optimizer's moments, a
+    float32 pack): every segment rounded up to the kernels' block
+    (``ops.pallas_common.pick_block_rows(total)`` rows), so the Adam and
+    unscale kernels view them without a pad or a slice and update them in
+    place; with one segment that is ``aligned_len(total)``.  The elements
+    no leaf owns (after each segment's last leaf) are zero and stay zero
+    under every elementwise inner optimizer (g = m = v = p = 0 updates to
+    0).  ZeRO shards keep their own length, ``ceil(total / population)``,
+    with no tail.
 
     How a leaf leaves a buffer (``_cut``).  A slice that is reshaped is not
     what the TPU's compiler runs: it turns ``slice(buf).reshape(rows, W)``
@@ -377,56 +426,64 @@ class _FlatLayout:
     v5e).  The second pass is the relayout from the 1-D tiling to the
     leaf's; one pass would take a kernel of our own.
 
-    Two lengths.  ``total`` is the LOGICAL element count, the sum of the
-    float leaves' sizes: offsets, ``rebuild``, ``unpack_masters``, the
-    ZeRO shard arithmetic and the comm plans all count in it.
-    ``storage`` is the length the un-sharded path keeps its persistent
-    buffers at (masters, the packed gradient, the inner optimizer's
-    moments): ``total`` rounded up to the kernels' block
-    (``ops.pallas_common.aligned_len``), so the Adam and unscale kernels
-    view them without a pad or a slice and update them in place.  The
-    tail past ``total`` is zero and stays zero under every elementwise
-    inner optimizer (g = m = v = p = 0 updates to 0).  ZeRO shards keep
-    their own length, ``ceil(total / population)``, with no tail."""
+    ZeRO: with ``zero_axis`` the flat master/moment buffers hold only
+    THIS device's slice (sharded over the named data axis); the step
+    reduce-scatters grads and all-gathers the updated params.
+      stage 1 — shard over the FULL axis (world-concat layout)
+      stage 2 — shard over the ICI slice of the hierarchical fabric
+                (zero_ici devices); state replicated across slices,
+                grads DCN-reduced on the 1/ici shard, params
+                re-gathered within the slice only
+      stage 3 — like 2, but params are NEVER gathered back by the
+                step: the fp32 master shard IS the parameter store
+                and the forward regathers just-in-time
+                (zero_gather_params)"""
 
-    def __init__(self, params):
+    def __init__(self, params, zero_axis: Optional[str] = None,
+                 zero_stage: int = 1, zero_ici: Optional[int] = None,
+                 zero_compress: bool = False):
         leaves, self.treedef = jax.tree_util.tree_flatten(params)
         self.shapes = tuple(tuple(l.shape) for l in leaves)
         self.dtypes = tuple(str(jnp.result_type(l)) for l in leaves)
         self.is_float = tuple(
             jnp.issubdtype(jnp.result_type(l), jnp.floating) for l in leaves)
-        sizes, offsets, off = [], [], 0
-        for shape, f in zip(self.shapes, self.is_float):
-            n = int(math.prod(shape)) if f else 0
-            sizes.append(n)
-            offsets.append(off)
-            off += n
-        self.sizes = tuple(sizes)
-        self.offsets = tuple(offsets)
-        self.total = off
+        self.zero_axis = zero_axis
+        self.zero_stage = int(zero_stage)
+        self.zero_ici = int(zero_ici) if zero_ici is not None else None
+        self.zero_compress = bool(zero_compress)   # bf16 DCN grad hop
+        self.sizes = tuple(int(math.prod(shape)) if f else 0
+                           for shape, f in zip(self.shapes, self.is_float))
+        self.total = sum(self.sizes)
         halves = {d for d, f in zip(self.dtypes, self.is_float)
                   if f and d != "float32"}
         # the single non-fp32 float dtype (O2's cast_model_type), if any —
         # lets the fused Adam kernel emit the half model copy in-pass
-        self.half_dtype = (jnp.dtype(halves.pop()) if len(halves) == 1
-                           else None)
-
-    # ZeRO: when zero_axis is set, the flat master/moment buffers hold
-    # only THIS device's slice (sharded over the named data axis); the
-    # step reduce-scatters grads and all-gathers the updated params.
-    #   stage 1 — shard over the FULL axis (world-concat layout)
-    #   stage 2 — shard over the ICI slice of the hierarchical fabric
-    #             (zero_ici devices); state replicated across slices,
-    #             grads DCN-reduced on the 1/ici shard, params
-    #             re-gathered within the slice only
-    #   stage 3 — like 2, but params are NEVER gathered back by the
-    #             step: the fp32 master shard IS the parameter store
-    #             and the forward regathers just-in-time
-    #             (zero_gather_params)
-    zero_axis: Optional[str] = None
-    zero_stage: int = 1
-    zero_ici: Optional[int] = None
-    zero_compress: bool = False       # bf16 DCN hop on the grad reduce
+        self.half_dtype = (jnp.dtype(next(iter(halves)))
+                           if len(halves) == 1 else None)
+        floats = [i for i, f in enumerate(self.is_float) if f]
+        # "dtype": half leaves, then float32 leaves; "tree": as they come
+        self.order = ("dtype" if zero_axis is None and len(halves) <= 1
+                      else "tree")
+        if self.order == "dtype":
+            groups = [[i for i in floats if self.dtypes[i] != "float32"],
+                      [i for i in floats if self.dtypes[i] == "float32"]]
+        else:
+            groups = [floats]
+        block = pick_block_rows(self.total) * LANES
+        offsets, segments, members, at = [0] * len(leaves), [], [], 0
+        for group in filter(None, groups):
+            start = at
+            for i in group:
+                offsets[i] = at
+                at += self.sizes[i]
+            if zero_axis is None:
+                at = start + -(-(at - start) // block) * block
+            segments.append((start, at - start))
+            members.append(tuple(group))
+        self.offsets = tuple(offsets)
+        self.segments = tuple(segments)
+        self._members = tuple(members)
+        self._end = at
 
     # layouts are jit-cache keys via FlatMasters aux_data
     def _key(self):
@@ -442,22 +499,47 @@ class _FlatLayout:
     @property
     def storage(self) -> int:
         """Length of the un-sharded path's persistent flat buffers."""
-        return aligned_len(self.total)
+        return aligned_len(self.total) if self.zero_axis else self._end
 
     def pack(self, tree) -> jax.Array:
-        """Float leaves → one flat fp32 buffer (single concat): of
-        ``storage`` elements, the zero tail one more operand of the
-        concat, on the un-sharded path; of ``total`` elements under
-        ZeRO, whose callers pad to their shard population."""
+        """Float leaves → one flat fp32 buffer (single concat), every
+        leaf at its offset: of ``storage`` elements, the zeros after a
+        segment's last leaf more operands of the concat, on the
+        un-sharded path; of ``total`` elements under ZeRO, whose callers
+        pad to their shard population."""
         leaves = jax.tree_util.tree_leaves(tree)
-        parts = [l.reshape(-1).astype(jnp.float32)
-                 for l, f in zip(leaves, self.is_float) if f]
-        tail = self.storage - self.total if self.zero_axis is None else 0
-        if parts and tail:
-            parts.append(jnp.zeros((tail,), jnp.float32))
-        if not parts:
-            return jnp.zeros((0,), jnp.float32)
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        parts, at = [], 0
+        for (start, length), group in zip(self.segments, self._members):
+            parts.append(jnp.zeros((start - at,), jnp.float32))
+            parts += [leaves[i].reshape(-1).astype(jnp.float32)
+                      for i in group]
+            at = start + sum(self.sizes[i] for i in group)
+        if parts:
+            parts.append(jnp.zeros((start + length - at,), jnp.float32))
+        return _joined(parts, jnp.float32)
+
+    def pack_grads(self, tree) -> GradSegments:
+        """A gradient tree → one flat array a segment, each in the dtype
+        its leaves came in (bf16 as the backward or
+        ``ddp.allreduce_grads`` wrote them; float32 leaves unrounded): the
+        operand the Adam kernel widens in its registers.  A segment whose
+        leaves came in more than one dtype is packed in their common one.
+        For the un-sharded layouts."""
+        leaves = jax.tree_util.tree_leaves(tree)
+        out = []
+        for (_, length), group in zip(self.segments, self._members):
+            own = [leaves[i].reshape(-1) for i in group]
+            dt = jnp.result_type(*own)
+            count_grad_pack("native" if all(l.dtype == dt for l in own)
+                            else "float32", str(dt))
+            used = sum(self.sizes[i] for i in group)
+            # the zeros behind a barrier: as a constant operand the TPU's
+            # compiler writes the concatenate and then a pad of it, a
+            # second pass over the packed gradient
+            tail = jax.lax.optimization_barrier(
+                jnp.zeros((length - used,), dt))
+            out.append(_joined([l.astype(dt) for l in own] + [tail], dt))
+        return GradSegments(tuple(out))
 
     def rebuild(self, flat32: jax.Array, half: Optional[jax.Array],
                 like_leaves) -> Any:
@@ -570,12 +652,8 @@ class AmpOptimizer(Optimizer):
                     "elementwise inner optimizer (the flat-buffer path)")
             _validate_zero_knobs(zero_stage, zero_ici_size,
                                  zero_compress_bf16)
-            layout = _FlatLayout(params)
-            layout.zero_axis = zero_axis
-            layout.zero_stage = int(zero_stage)
-            layout.zero_ici = (int(zero_ici_size)
-                               if zero_ici_size is not None else None)
-            layout.zero_compress = bool(zero_compress_bf16)
+            layout = _FlatLayout(params, zero_axis, zero_stage,
+                                 zero_ici_size, zero_compress_bf16)
             if zero_stage == 3 and not all(layout.is_float):
                 raise ValueError(
                     "ZeRO-3 rebuilds every param from the flat fp32 "
@@ -685,6 +763,13 @@ class AmpOptimizer(Optimizer):
             raise RuntimeError(
                 f"optimizer state is ZeRO-sharded over axis {zaxis!r} "
                 f"but step() was called outside a shard_map mapping it")
+        # what the code can observe chooses the gradient's path: an inner
+        # optimizer that unscales in its kernel, the un-sharded layout
+        # with its leaves by dtype
+        in_kernel = (flat and zaxis is None
+                     and opt_state.masters.layout.order == "dtype"
+                     and getattr(self.inner, "unscales_grads", False))
+        count_unscale("kernel" if in_kernel else "pass")
         zstage = (opt_state.masters.layout.zero_stage if zero else 1)
         zero_groups = (_zero_slice_groups(
             zaxis, opt_state.masters.layout.zero_ici)
@@ -703,6 +788,15 @@ class AmpOptimizer(Optimizer):
                     f"zero_gather_params transpose produces "
                     f"(shape {opt_state.masters.buf.shape}), got "
                     f"{getattr(scaled_grads, 'shape', type(scaled_grads))}")
+        elif in_kernel:
+            # the gradient reaches the kernel as the backward wrote it:
+            # read once here for the finite flag, copied once, in its own
+            # dtype, into the kernel's operand, unscaled and widened in
+            # the kernel's registers
+            with jax.named_scope("amp.pack"):
+                grads32 = opt_state.masters.layout.pack_grads(scaled_grads)
+            with jax.named_scope("amp.unscale"):
+                found_inf = _found_nonfinite(grads32)
         elif flat:
             # fused-buffer hot path: one concat, one fused unscale, one
             # optimizer kernel, static slices back out
@@ -749,8 +843,10 @@ class AmpOptimizer(Optimizer):
                 scaled_grads = jax.lax.psum_scatter(
                     scaled_grads, zaxis, scatter_dimension=0, tiled=True)
             scaled_grads = scaled_grads / dp
-        with jax.named_scope("amp.unscale"):
-            grads32, found_inf = self.scaler.unscale(scaled_grads, sstate)
+        if not in_kernel:
+            with jax.named_scope("amp.unscale"):
+                grads32, found_inf = self.scaler.unscale(scaled_grads,
+                                                         sstate)
         if found_inf_extra is not None:
             found_inf = jnp.maximum(found_inf, found_inf_extra)
         if zero:
@@ -806,7 +902,8 @@ class AmpOptimizer(Optimizer):
             def do_update(operand):
                 p, masters, inner = operand
                 new_buf, new_inner, half = self._flat_inner_step(
-                    masters, inner, grads32)
+                    masters, inner, grads32,
+                    sstate.loss_scale if in_kernel else None)
                 with jax.named_scope("amp.rebuild"):
                     new_p = masters.layout.rebuild(
                         new_buf, half, jax.tree_util.tree_leaves(p))
@@ -856,6 +953,8 @@ class AmpOptimizer(Optimizer):
                     jnp.sum(jnp.square(grads32)), zaxis))
             else:
                 grad_norm = global_grad_norm(grads32)
+                if in_kernel:
+                    grad_norm = grad_norm * (1.0 / sstate.loss_scale)
         info = {"found_inf": found_inf,
                 "loss_scale": new_sstate.loss_scale,
                 "steps_skipped": new_sstate.steps_skipped,
@@ -865,12 +964,21 @@ class AmpOptimizer(Optimizer):
         return new_params, AmpOptState(inner=new_inner, masters=new_masters,
                                        scalers=scalers), info
 
-    def _flat_inner_step(self, masters: FlatMasters, inner_state, flat_g32):
+    def _flat_inner_step(self, masters: FlatMasters, inner_state, flat_g32,
+                         scale=None):
         """Inner update on the flat master buffer.  When the inner
         optimizer can emit the half model copy inside its kernel (FusedAdam
         output_params_dtype, reference fused_adam_cuda_kernel.cu:94-115)
-        that saves the separate cast pass; otherwise one astype."""
+        that saves the separate cast pass; otherwise one astype.  With
+        ``scale`` the gradient is ``GradSegments``, still scaled, for an
+        inner optimizer with ``unscales_grads``."""
         half_dtype = masters.layout.half_dtype
+        if scale is not None:
+            out = self.inner.step(masters.buf, inner_state, flat_g32,
+                                  scale=scale,
+                                  output_params_dtype=half_dtype)
+            return out[0], out[1], (out[2] if half_dtype is not None
+                                    else None)
         if (half_dtype is not None
                 and getattr(self.inner, "supports_output_params_dtype",
                             False)):

@@ -31,8 +31,9 @@ __all__ = ["PHASES", "SOURCES", "phase_of_op_name", "instruction_phases",
 # scope -> where it is opened
 PHASES = (
     "amp.scale_loss",      # amp/handle.py: loss * scale, and the divide back
-    "amp.pack",            # AmpOptimizer.step: layout.pack(scaled_grads)
-    "amp.unscale",         # scaler.unscale as called from step
+    "amp.pack",            # AmpOptimizer.step: layout.pack / pack_grads
+    "amp.unscale",         # scaler.unscale as called from step, or the
+                           # finite read where the kernel unscales
     "amp.scaler_update",   # scaler.update as called from step
     "amp.update",          # the apply-or-skip lax.cond and its do_update
     "amp.rebuild",         # layout.rebuild / master -> model copy (in amp.update)

@@ -17,6 +17,18 @@ a buffer across steps and can choose its length (amp's flat masters,
 gradients and moments do); the two counters ``flat_pad_copies_total`` /
 ``flat_pad_copy_elements_total`` in the observability registry count, per
 traced program, the copies that were made anyway.
+
+Beside them, counted the same way (once per traced program), what amp's
+step does with the gradient on its way into these kernels:
+``amp_unscale_total{where="kernel"|"pass"}``, once a traced
+``AmpOptimizer.step``: unscaled in the inner optimizer's kernel
+(``FusedAdam``'s, which widens its gradient operand in registers and
+takes ``scale=``), or by a pass of its own that writes an unscaled
+float32 copy (``_scale_flat``); and
+``amp_grad_pack_total{path="native"|"float32", dtype}``, once a
+segment of the flat layout packed for a ``kernel`` step: in the dtype
+its leaves came in, or widened to float32 before the concatenate
+(``docs/observability.md``).
 """
 
 from __future__ import annotations
@@ -107,6 +119,30 @@ def _count_copy(elements: int) -> None:
         "flat_pad_copy_elements_total",
         help="elements those pads and slices copy per traced program"
     ).inc(elements)
+
+
+def count_grad_pack(path: str, dtype: str) -> None:
+    """One segment of a gradient packed for amp's flat optimizer step, at
+    TRACE time like ``_count_copy``."""
+    from ..observability.metrics import get_registry
+    get_registry().counter(
+        "amp_grad_pack_total",
+        help="gradient segments amp packed for the flat optimizer step, "
+             "per traced program: path=native in the dtype the leaves "
+             "came in, path=float32 widened before the concatenate"
+    ).labels(path=path, dtype=dtype).inc()
+
+
+def count_unscale(where: str) -> None:
+    """One amp step traced, by where its gradient is unscaled."""
+    from ..observability.metrics import get_registry
+    get_registry().counter(
+        "amp_unscale_total",
+        help="amp steps traced, by where the gradient is unscaled: "
+             "where=kernel in the inner optimizer's kernel (one finite "
+             "read outside it), where=pass by a pass of its own that "
+             "writes the unscaled float32 gradient"
+    ).labels(where=where).inc()
 
 
 def to_2d(flat: jax.Array, block_rows: int = BLOCK_ROWS

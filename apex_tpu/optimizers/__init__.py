@@ -5,7 +5,8 @@ Reference exports FusedAdam and FP16_Optimizer
 reference's LAMB stage1/stage2 kernel semantics (SURVEY.md §2.2 gap).
 """
 
-from .base import Optimizer, SGD, SGDState, resolve_lr, global_grad_norm
+from .base import (Optimizer, SGD, SGDState, GradSegments, resolve_lr,
+                   global_grad_norm)
 from .fused_adam import FusedAdam, AdamState
 from .fused_lamb import FusedLAMB, LambState
 from .fused_lion import FusedLion, LionState

@@ -19,7 +19,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Optimizer", "SGD", "SGDState", "resolve_lr",
+__all__ = ["Optimizer", "SGD", "SGDState", "GradSegments", "resolve_lr",
            "global_grad_norm"]
 
 Schedule = Union[float, Callable[[jax.Array], jax.Array]]
@@ -42,6 +42,17 @@ def global_grad_norm(grads: Any) -> jax.Array:
         return jnp.zeros((), jnp.float32)
     sq = sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves)
     return jnp.sqrt(sq)
+
+
+class GradSegments(NamedTuple):
+    """The gradient of a flat parameter buffer as consecutive pieces, each
+    in the dtype the backward wrote it: piece ``k`` covers the elements
+    after pieces ``0..k-1`` and the lengths add up to the buffer's
+    (``amp._FlatLayout.pack_grads``: the half leaves' segment, then the
+    float32 leaves').  What an inner optimizer with ``unscales_grads``
+    is handed in place of an unscaled float32 buffer, together with
+    ``scale=``."""
+    parts: Tuple[jax.Array, ...]
 
 
 class Optimizer:

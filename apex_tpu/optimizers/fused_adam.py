@@ -29,7 +29,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .base import Optimizer, resolve_lr
+from .base import GradSegments, Optimizer, resolve_lr
 from ..multi_tensor_apply import multi_tensor_l2norm
 from ..multi_tensor_apply.flatten import pack_flat, unpack_flat
 
@@ -43,16 +43,27 @@ class AdamState(NamedTuple):
 
 
 def _adam_kernel(p, m, v, g, step_size, combined_scale, beta1, beta2, eps,
-                 eps_inside_sqrt, weight_decay, half_dtype=None):
-    """The fused elementwise update on flat fp32 buffers; returns
-    (new_p, new_m, new_v, optional half copy of new_p)."""
+                 eps_inside_sqrt, weight_decay, half_dtype=None, start=0):
+    """The fused elementwise update on flat fp32 buffers, of the
+    ``g.shape[0]`` elements from ``start`` (``g`` in any float dtype,
+    widened here); returns (new_p, new_m, new_v, optional half copy of
+    those elements of new_p)."""
     from ..ops import dispatch
     if dispatch.use_pallas_for(p):
         from ..ops import pallas_adam
         return pallas_adam.fused_adam(
             p, m, v, g, step_size, combined_scale, beta1, beta2, eps,
-            eps_inside_sqrt, weight_decay, half_dtype)
-    gs = g / combined_scale
+            eps_inside_sqrt, weight_decay, half_dtype, start)
+    if g.shape[0] != p.shape[0]:
+        stop = start + g.shape[0]
+        new = _adam_kernel(
+            *(jax.lax.slice_in_dim(x, start, stop) for x in (p, m, v)), g,
+            step_size, combined_scale, beta1, beta2, eps, eps_inside_sqrt,
+            weight_decay, half_dtype)
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(x, y, start, 0)
+            for x, y in zip((p, m, v), new)) + new[3:]
+    gs = g.astype(jnp.float32) / combined_scale
     new_m = beta1 * m + (1.0 - beta1) * gs
     new_v = beta2 * v + (1.0 - beta2) * gs * gs
     if eps_inside_sqrt:
@@ -72,6 +83,10 @@ class FusedAdam(Optimizer):
     # the kernel can emit the half model copy in the same pass
     elementwise = True
     supports_output_params_dtype = True
+    # ``step`` takes the scaled gradient as ``GradSegments`` with
+    # ``scale=`` and unscales it in the kernel's registers: amp then makes
+    # no pass of its own to unscale and packs each segment in its own dtype
+    unscales_grads = True
 
     def __init__(self, lr=1e-3, bias_correction: bool = True,
                  betas: Tuple[float, float] = (0.9, 0.999), eps: float = 1e-8,
@@ -111,15 +126,24 @@ class FusedAdam(Optimizer):
         ``max_grad_norm`` is set and none is given.
         ``output_params_dtype``: emit a half-precision copy of the updated
         params in the same pass (the kernel's p_copy, :94-115).
+        ``grads`` as ``GradSegments`` (``params`` is then the flat
+        buffer): one kernel launch a piece over that piece's elements,
+        the piece widened in the kernel; the half copy is written for the
+        FIRST piece only, where amp's layout keeps the half leaves.
         Returns (new_params, new_state[, half_params]).
         """
-        flat_g, _, _ = pack_flat(grads, jnp.float32)
+        if isinstance(grads, GradSegments):
+            pieces = grads.parts
+        else:
+            pieces = (pack_flat(grads, jnp.float32)[0],)
         flat_p, p_leaves, p_treedef = pack_flat(params, jnp.float32)
 
         combined_scale = jnp.asarray(scale, jnp.float32)
         if self.max_grad_norm > 0:
             if grad_norm is None:
-                grad_norm, _ = multi_tensor_l2norm(flat_g)
+                norms = [multi_tensor_l2norm(g)[0] for g in pieces]
+                grad_norm = (norms[0] if len(norms) == 1 else
+                             jnp.sqrt(sum(n * n for n in norms)))
             clip = ((grad_norm / combined_scale) + 1e-6) / self.max_grad_norm
             combined_scale = jnp.where(clip > 1.0, clip * combined_scale,
                                        combined_scale)
@@ -135,10 +159,14 @@ class FusedAdam(Optimizer):
         else:
             step_size = lr
 
-        new_p, new_m, new_v, half = _adam_kernel(
-            flat_p, state.m, state.v, flat_g, step_size, combined_scale,
-            beta1, beta2, self.eps, self.eps_inside_sqrt, self.weight_decay,
-            output_params_dtype)
+        new_p, new_m, new_v, half, start = flat_p, state.m, state.v, None, 0
+        for g in pieces:
+            new_p, new_m, new_v, h = _adam_kernel(
+                new_p, new_m, new_v, g, step_size, combined_scale, beta1,
+                beta2, self.eps, self.eps_inside_sqrt, self.weight_decay,
+                output_params_dtype if start == 0 else None, start)
+            half = h if start == 0 else half
+            start += g.shape[0]
 
         new_params = unpack_flat(new_p, p_leaves, p_treedef)
         new_state = AdamState(step=t, m=new_m, v=new_v)

@@ -702,7 +702,10 @@ def zero_update_comm_plan(params: Any, *, zero_stage: int,
     from ..amp._process_optimizer import (_FlatLayout,
                                           _validate_zero_knobs)
     _validate_zero_knobs(zero_stage, ici_size, zero_compress_bf16)
-    layout = _FlatLayout(params)
+    # a ZeRO layout (tree order), as the step's own: the plan counts the
+    # float32 elements of each shard by their offsets
+    layout = _FlatLayout(params, "data", zero_stage, ici_size,
+                         zero_compress_bf16)
     n = layout.total
     isz = 4                                    # grads reduce in fp32
     if zero_stage >= 2:

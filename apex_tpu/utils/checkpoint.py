@@ -51,8 +51,14 @@ _RE = re.compile(r"ckpt_(\d{8})\.npz$")
 # The data-state blob sits UNDER the checksum: it is part of the leaf
 # dict the crc covers, so a torn or tampered cursor fails verification
 # like any other leaf.
+# __flat_order__ (a JSON dict stored like the data state, under the
+# checksum too) says in which order the snapshot's amp flat buffers hold
+# their leaves: {buffer length: "dtype" | "tree"} (``_FlatLayout.order``).
+# The buffers themselves are bare arrays, and two orders can have one
+# length; a snapshot without the entry predates it and holds tree order.
 _CHECKSUM_KEY = "__checksum__"
 _DATA_STATE_KEY = "__data_state__"
+_FLAT_ORDER_KEY = "__flat_order__"
 
 
 class CheckpointCorrupt(RuntimeError):
@@ -158,12 +164,16 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: Any,
     os.makedirs(ckpt_dir, exist_ok=True)
     t0 = time.perf_counter()
     leaves = _leaf_dict(tree)
-    for reserved in (_CHECKSUM_KEY, _DATA_STATE_KEY):
+    for reserved in (_CHECKSUM_KEY, _DATA_STATE_KEY, _FLAT_ORDER_KEY):
         if reserved in leaves:
             raise ValueError(f"{reserved!r} is a reserved key")
     if data_state is not None:
         blob = json.dumps(data_state, sort_keys=True).encode()
         leaves[_DATA_STATE_KEY] = np.frombuffer(blob, np.uint8)
+    orders = flat_orders(tree)
+    if orders:
+        blob = json.dumps(orders, sort_keys=True).encode()
+        leaves[_FLAT_ORDER_KEY] = np.frombuffer(blob, np.uint8)
     # content checksum over exactly the arrays being written: restore
     # recomputes it from what it read, so a torn/partial write (or
     # later bit rot) can never load silently.  Because the checksum is
@@ -275,18 +285,47 @@ def latest_durable_step(ckpt_dir: str) -> Optional[int]:
     return None
 
 
-def _flat_tails(template: Any) -> dict:
-    """``{logical length: storage length}`` of the amp flat layouts in
-    ``template`` whose buffers carry a zero tail.  A snapshot written
-    before the tail existed holds masters and moments at the logical
-    length; restoring appends the zeros they would have had."""
+def _flat_layouts(tree: Any) -> dict:
+    """``{buffer length: layout}`` of the un-sharded amp flat states in
+    ``tree`` (masters; the inner optimizer's moments have the same
+    length)."""
     from ..amp._process_optimizer import FlatMasters
     nodes = jax.tree_util.tree_leaves(
-        template, is_leaf=lambda n: isinstance(n, FlatMasters))
-    return {n.layout.total: n.buf.shape[0] for n in nodes
-            if isinstance(n, FlatMasters)
-            and n.layout.zero_axis is None
-            and n.buf.shape[0] != n.layout.total}
+        tree, is_leaf=lambda n: isinstance(n, FlatMasters))
+    return {int(n.buf.shape[0]): n.layout for n in nodes
+            if isinstance(n, FlatMasters) and n.layout.zero_axis is None}
+
+
+def flat_orders(tree: Any) -> dict:
+    """``{str(buffer length): order}`` of the amp flat buffers in
+    ``tree``: what a snapshot of it says under ``__flat_order__``."""
+    return {str(n): lay.order for n, lay in _flat_layouts(tree).items()}
+
+
+def _place_flat(arr: np.ndarray, layout, saved_order: str, key: str
+                ) -> np.ndarray:
+    """A stored flat buffer as ``layout`` keeps it.  A snapshot in tree
+    order (any from before the layout kept its leaves by dtype; at the
+    logical length from before PR 25, at the block-aligned one since) is
+    moved leaf by leaf to the layout's offsets; the zeros no leaf owns
+    are made here.  What cannot be placed is refused."""
+    from ..ops.pallas_common import aligned_len
+    if saved_order != "tree":
+        if saved_order != layout.order:
+            raise ValueError(
+                f"{key!r}: the snapshot holds its flat buffers in "
+                f"{saved_order!r} order and the template's layout keeps "
+                f"{layout.order!r} order: it cannot be placed (build the "
+                f"template as the saved state was built)")
+        return arr
+    if arr.shape[0] not in (layout.total, aligned_len(layout.total)):
+        return arr              # not this layout's: the shape check names it
+    out = np.zeros((layout.storage,), arr.dtype)
+    at = 0
+    for off, n in zip(layout.offsets, layout.sizes):
+        out[off:off + n] = arr[at:at + n]
+        at += n
+    return out
 
 
 def restore_checkpoint(ckpt_dir: str, template: Any,
@@ -306,8 +345,11 @@ def restore_checkpoint(ckpt_dir: str, template: Any,
     t0 = time.perf_counter()
     stored = _load_verified(path)
     stored.pop(_DATA_STATE_KEY, None)   # read via load_data_state
+    blob = stored.pop(_FLAT_ORDER_KEY, None)
+    orders = ({} if blob is None else
+              json.loads(np.asarray(blob, np.uint8).tobytes().decode()))
     flat, treedef = jax.tree_util.tree_flatten_with_path(template)
-    tails = _flat_tails(template)
+    layouts = _flat_layouts(template)
     out = []
     for kp, leaf in flat:
         key = jax.tree_util.keystr(kp)
@@ -317,8 +359,9 @@ def restore_checkpoint(ckpt_dir: str, template: Any,
                 "structure does not match the saved state")
         arr = stored[key]
         if (arr.ndim == 1 and getattr(leaf, "ndim", None) == 1
-                and tails.get(arr.shape[0]) == leaf.shape[0]):
-            arr = np.pad(arr, (0, leaf.shape[0] - arr.shape[0]))
+                and leaf.shape[0] in layouts):
+            arr = _place_flat(arr, layouts[leaf.shape[0]],
+                              orders.get(str(arr.shape[0]), "tree"), key)
         if hasattr(leaf, "shape") and tuple(arr.shape) != tuple(leaf.shape):
             raise ValueError(
                 f"shape mismatch for {key!r}: checkpoint {arr.shape} vs "

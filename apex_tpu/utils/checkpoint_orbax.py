@@ -47,8 +47,8 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from .checkpoint import (CheckpointCorrupt, record_checkpoint_io,
-                         tree_bytes, tree_checksum)
+from .checkpoint import (CheckpointCorrupt, _flat_layouts, flat_orders,
+                         record_checkpoint_io, tree_bytes, tree_checksum)
 
 __all__ = ["CheckpointCorrupt", "save_checkpoint", "restore_checkpoint",
            "latest_step", "available_steps", "load_data_state"]
@@ -84,7 +84,8 @@ def _chain_data_state(crc: int, data_state: Optional[dict]) -> int:
 
 
 def _write_checksum(path: str, crc: int, nbytes: int, dtypes: dict,
-                    data_state: Optional[dict] = None) -> None:
+                    data_state: Optional[dict] = None,
+                    flat_order: Optional[dict] = None) -> None:
     side = os.path.join(path, _CHECKSUM_FILE)
     tmp = side + ".tmp"
     with open(tmp, "w") as f:
@@ -98,6 +99,10 @@ def _write_checksum(path: str, crc: int, nbytes: int, dtypes: dict,
         # written only at the join, verified on read.
         meta = {"crc32": int(crc), "tree_bytes": int(nbytes),
                 "dtypes": dtypes}
+        if flat_order:
+            # the order amp's flat buffers hold their leaves in
+            # (checkpoint.flat_orders); absent = tree order
+            meta["flat_order"] = flat_order
         if data_state is not None:
             meta["data_state"] = data_state
             # a crc over the blob ALONE, so load_data_state can verify
@@ -144,6 +149,7 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: Any,
     leaves = _keyed_leaves(tree)
     crc = _chain_data_state(tree_checksum(leaves), data_state)
     dtypes = {k: str(np.asarray(v).dtype) for k, v in leaves.items()}
+    flat_order = flat_orders(tree)
     # pending marker BEFORE the write starts: a process dying mid-save
     # leaves marker-without-sidecar, which restore distinguishes from
     # a legacy (pre-checksum) snapshot and flags as corrupt
@@ -158,7 +164,7 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: Any,
     ckptr.save(path, tree, force=True)
     if not async_save:
         ckptr.close()
-        _write_checksum(path, crc, nbytes, dtypes, data_state)
+        _write_checksum(path, crc, nbytes, dtypes, data_state, flat_order)
         os.unlink(pending)
         record_checkpoint_io("save", time.perf_counter() - t0,
                              step=int(step), nbytes=nbytes, path=path)
@@ -174,7 +180,7 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: Any,
         # JOINED (durable) save gets one, so a torn background write
         # is visibly unverified.
         _pending = (ckptr, ckpt_dir, keep, int(step), path, nbytes,
-                    crc, dtypes, data_state, t0)
+                    crc, dtypes, data_state, flat_order, t0)
     return path
 
 
@@ -188,11 +194,11 @@ def wait() -> None:
     global _pending
     if _pending is not None:
         (ckptr, ckpt_dir, keep, step, path, nbytes, crc, dtypes,
-         data_state, t0) = _pending
+         data_state, flat_order, t0) = _pending
         _pending = None
         ckptr.wait_until_finished()
         ckptr.close()
-        _write_checksum(path, crc, nbytes, dtypes, data_state)
+        _write_checksum(path, crc, nbytes, dtypes, data_state, flat_order)
         try:
             os.unlink(os.path.join(
                 _mgr_dir(ckpt_dir), _PENDING_FMT.format(step=step)))
@@ -222,6 +228,30 @@ def latest_step(ckpt_dir: str) -> Optional[int]:
     return steps[-1] if steps else None
 
 
+def _refuse_other_flat_order(path: str, template: Any) -> None:
+    """Orbax restores by shape alone, and amp's flat buffers can have one
+    length in two orders (tree order before the layout kept its leaves by
+    dtype): a snapshot whose sidecar does not say the template's order
+    is refused where the two orders differ (more than one segment);
+    ``utils.checkpoint`` (npz) moves such a snapshot leaf by leaf."""
+    saved = {}
+    side = os.path.join(path, _CHECKSUM_FILE)
+    if os.path.exists(side):
+        try:
+            with open(side) as f:
+                saved = json.load(f).get("flat_order", {})
+        except (OSError, ValueError):
+            pass                  # restore's own read of it reports that
+    for n, lay in _flat_layouts(template).items():
+        if len(lay.segments) > 1 and saved.get(str(n), "tree") != lay.order:
+            raise ValueError(
+                f"{path}: its flat optimizer buffers are in "
+                f"{saved.get(str(n), 'tree')!r} order, the template's "
+                f"layout keeps {lay.order!r} order: restore it with the "
+                f"code that wrote it and save it through utils.checkpoint "
+                f"(npz), whose restore moves the leaves")
+
+
 def restore_checkpoint(ckpt_dir: str, template: Any,
                        step: Optional[int] = None) -> Any:
     """Restore into ``template``'s structure, dtypes, AND shardings.
@@ -235,6 +265,7 @@ def restore_checkpoint(ckpt_dir: str, template: Any,
         if step is None:
             raise FileNotFoundError(f"no checkpoints in {ckpt_dir}")
     path = os.path.join(_mgr_dir(ckpt_dir), f"step_{int(step)}")
+    _refuse_other_flat_order(path, template)
 
     def to_abstract(leaf):
         if hasattr(leaf, "sharding"):

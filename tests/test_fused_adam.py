@@ -178,3 +178,68 @@ def test_flat_masters_nonfloat_leaf_roundtrip():
                            np.asarray(params["w"], np.float32))
     mt = opt.masters_tree(new_st)
     assert mt["idx"] is None and mt["w"].dtype == jnp.float32
+
+
+# -- the gradient as segments, unscaled and widened in the kernel -------------
+def _segmented(seed, n_half, n_f32, scale):
+    from apex_tpu.optimizers import GradSegments
+    rng = np.random.RandomState(seed)
+    n = n_half + n_f32
+    p, m = (jnp.asarray(rng.randn(n), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.rand(n), jnp.float32)
+    g = jnp.asarray(rng.randn(n) * scale, jnp.float32)
+    half = g[:n_half].astype(jnp.bfloat16)
+    whole = jnp.concatenate([half.astype(jnp.float32), g[n_half:]])
+    parts = tuple(x for x in (half, g[n_half:]) if x.shape[0])
+    return p, m, v, GradSegments(parts), whole
+
+
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+@pytest.mark.parametrize("n_half,n_f32", [(2048, 1024), (2048, 0), (0, 1024)])
+def test_adam_on_gradient_segments_equals_the_whole_float32_buffer(
+        n_half, n_f32, mode, monkeypatch):
+    from apex_tpu.optimizers.fused_adam import AdamState
+    monkeypatch.setenv("APEX_TPU_DISABLE_PALLAS", "1" if mode == "jnp" else "0")
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "0" if mode == "jnp" else "1")
+    scale = 128.0
+    p, m, v, segments, whole = _segmented(7, n_half, n_f32, scale)
+    opt = FusedAdam(lr=1e-2, weight_decay=0.01)
+    state = AdamState(step=jnp.asarray(4, jnp.int32), m=m, v=v)
+    with jax.disable_jit():
+        got = opt.step(p, state, segments, scale=scale,
+                       output_params_dtype=jnp.bfloat16)
+        want = opt.step(p, state, whole, scale=scale,
+                        output_params_dtype=jnp.bfloat16)
+    for a, b in zip(jax.tree_util.tree_leaves(got[:2]),
+                    jax.tree_util.tree_leaves(want[:2])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the half copy covers the first piece, the elements the whole one starts with
+    first = segments.parts[0].shape[0]
+    assert got[2].shape == (first,) and got[2].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got[2], np.float32),
+                                  np.asarray(want[2][:first], np.float32))
+
+
+def test_adam_clips_by_the_norm_over_all_segments():
+    from apex_tpu.optimizers.fused_adam import AdamState
+    p, m, v, segments, whole = _segmented(8, 2048, 1024, 64.0)
+    opt = FusedAdam(lr=1e-2, max_grad_norm=0.25)
+    state = AdamState(step=jnp.zeros((), jnp.int32), m=m, v=v)
+    got = opt.step(p, state, segments, scale=64.0)
+    want = opt.step(p, state, whole, scale=64.0)
+    unclipped = FusedAdam(lr=1e-2).step(p, state, segments, scale=64.0)
+    assert not np.allclose(np.asarray(got[0]), np.asarray(unclipped[0]))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+
+def test_adam_refuses_a_segment_off_the_block_boundaries(monkeypatch):
+    from apex_tpu.ops.pallas_adam import fused_adam
+    monkeypatch.setenv("APEX_TPU_DISABLE_PALLAS", "0")
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "1")
+    p = jnp.zeros((4096,), jnp.float32)
+    g = jnp.zeros((1024,), jnp.bfloat16)
+    fused_adam(p, p, p, g, 1e-2, 1.0, 0.9, 0.999, 1e-8, False, 0.0, None, 1024)
+    with pytest.raises(ValueError, match="must lie on blocks"):
+        fused_adam(p, p, p, g, 1e-2, 1.0, 0.9, 0.999, 1e-8, False, 0.0, None, 1000)
