@@ -7,15 +7,19 @@ The configuration says which decoder it is, in the keys of the published
 config files of the families built on this shape (``layer_types``,
 ``mlp_layer_types``, one ``rope_parameters`` group per attention kind,
 ``sliding_window``; ``num_attention_heads_per_layer`` or one
-``num_attention_heads`` for every layer; ``norm_topk_prob``).  Three run in the
+``num_attention_heads`` for every layer; ``norm_topk_prob``;
+``total_ut_steps``).  Four run in the
 benchmark: ``laguna`` (a leading dense layer, two head counts, a gate on the
 attention output, a sigmoid router with a scaling factor and a shared
 expert: the defaults below), ``mellum`` (every layer sparse, one head
-count, no gate, a softmax router, no shared expert) and ``lfm2_moe`` (``conv``
+count, no gate, a softmax router, no shared expert), ``lfm2_moe`` (``conv``
 layers 3:1 with full attention at a head of 64 with QK-norm, a leading dense
-layer, a sigmoid router with a selection bias, a tied head).  Pre-norm residual
-blocks on the Llama parts (models/llama.py: RMSNorm, SwiGLU, the fused
-chunked head), with
+layer, a sigmoid router with a selection bias, a tied head) and ``ouro``
+(every layer dense with full attention, the stack applied ``total_ut_steps``
+times over the same weights, sandwich-normed blocks, a learned exit gate and a
+loss that weighs every pass's head by the exit distribution).  Pre-norm
+residual blocks on the Llama parts (models/llama.py: RMSNorm, SwiGLU, the
+fused chunked head), with
 
 - attention: ``H_l`` query heads over ``num_key_value_heads`` K/V heads,
   causal, a band of ``sliding_window`` keys in a sliding layer (handed to
@@ -40,10 +44,26 @@ chunked head), with
   selection bias that enters the choice and not the weights, the
   experts this chip holds (``experts_held``) and, where
   ``shared_expert_intermediate_size`` is not 0, a shared expert;
-- ``tie_word_embeddings``: the head's matrix is the embedding's, one leaf.
+- ``tie_word_embeddings``: the head's matrix is the embedding's, one leaf;
+- ``sandwich_norm``: a second RMSNorm on what the mixer and what the MLP
+  return (``input_layernorm_2``, ``post_attention_layernorm_2``), each before
+  its residual add;
+- ``total_ut_steps`` R > 1, the looped stack: one ``lax.scan`` over the pass
+  index (scope ``loop``) whose body is the layers and the final norm
+  (``loop.norm``), the weights closed over, so that a compiled step holds each
+  block once a direction; the normed state of a pass is the next pass's input
+  and the R of them are what the head and the exit gate read.  The loss
+  (``_exit_loss``): the fused head on every pass's state (``loss.head``) and,
+  in float32 (``loss.exit``), ``exit_gate`` (``Linear(hidden, 1)`` with a
+  bias, float32 leaves), ``lambda_t = sigmoid(h_t . w + b)``, the exit
+  distribution ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` with the last pass
+  taking what is left, and ``sum_t p_t nll_t - exit_beta H(p)`` a position.
+  ``looped_stack_total{passes, layers}`` and ``exit_gate_calls_total`` count
+  what a traced program holds (docs/observability.md).
 
 Training and full-sequence forward only: a cache for decoding would have to
-hold window and global layers side by side (ROADMAP, Reach).
+hold window and global layers side by side, and one slot a pass and layer
+under a looped stack (ROADMAP, Reach).
 """
 
 from __future__ import annotations
@@ -56,6 +76,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .. import nn
 from ..nn import functional as F
@@ -66,7 +87,7 @@ from ._remat import _MODES, wrap_block
 from ..transformer.short_conv import GatedShortConv
 from .llama import LlamaMLP, RMSNorm, _rotate_half
 
-__all__ = ["LagunaConfig", "Laguna", "rope_inv_freq"]
+__all__ = ["LagunaConfig", "Laguna", "rope_inv_freq", "exit_log_probs"]
 
 FULL, SLIDING, CONV = "full_attention", "sliding_attention", "conv"
 
@@ -78,15 +99,18 @@ class LagunaConfig:
     may be left None where one ``num_attention_heads`` serves every
     layer; ``shared_expert_intermediate_size`` 0 is no shared expert.  A
     ``conv`` entry of ``layer_types`` is a gated short convolution of
-    ``conv_L_cache`` taps (its head count is not read)."""
+    ``conv_L_cache`` taps (its head count is not read).  The three expert
+    sizes are needed only where a layer is ``sparse``.  ``total_ut_steps``
+    above 1 applies the stack that many times (dense layers only) and
+    trains through the exit gate with the entropy weight ``exit_beta``."""
 
     def __init__(self, vocab_size, hidden_size, intermediate_size,
                  layer_types: Sequence[str],
                  num_attention_heads_per_layer: Optional[Sequence[int]],
                  mlp_layer_types: Sequence[str],
                  num_key_value_heads, head_dim, rope_parameters: dict,
-                 sliding_window, num_experts, num_experts_per_tok,
-                 moe_intermediate_size, shared_expert_intermediate_size=0,
+                 sliding_window, num_experts=None, num_experts_per_tok=None,
+                 moe_intermediate_size=None, shared_expert_intermediate_size=0,
                  moe_routed_scaling_factor=1.0, router_experts=None,
                  experts_held_start=0, moe_row_buffer_factor=None,
                  gating=True, rms_norm_eps=1e-6,
@@ -94,7 +118,8 @@ class LagunaConfig:
                  num_attention_heads=None, router_type="sigmoid",
                  norm_topk_prob=True, qk_norm=False, conv_L_cache=3,
                  use_expert_bias=False, tie_word_embeddings=False,
-                 router_out_in=False):
+                 router_out_in=False, total_ut_steps=1, sandwich_norm=False,
+                 exit_beta=0.0):
         n = len(layer_types)
         if num_attention_heads_per_layer is None:
             if num_attention_heads is None:
@@ -114,6 +139,18 @@ class LagunaConfig:
         for kind in mlp_layer_types:
             if kind not in ("dense", "sparse"):
                 raise ValueError(f"unknown mlp layer type {kind!r}")
+        if "sparse" in mlp_layer_types:
+            if None in (num_experts, num_experts_per_tok,
+                        moe_intermediate_size):
+                raise ValueError("a sparse layer needs num_experts, "
+                                 "num_experts_per_tok and "
+                                 "moe_intermediate_size")
+            if total_ut_steps > 1:
+                raise ValueError("total_ut_steps > 1 loops dense layers "
+                                 "only: the expert layers' counters are "
+                                 "not carried out of the loop")
+        if total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps={total_ut_steps}")
         for h in num_attention_heads_per_layer:
             if h % num_key_value_heads:
                 raise ValueError(f"{h} query heads do not divide over "
@@ -151,6 +188,9 @@ class LagunaConfig:
         self.use_expert_bias = use_expert_bias
         self.tie_word_embeddings = tie_word_embeddings
         self.router_out_in = router_out_in
+        self.total_ut_steps = total_ut_steps
+        self.sandwich_norm = sandwich_norm
+        self.exit_beta = exit_beta
         self.rms_norm_eps = rms_norm_eps
         self.max_position_embeddings = max_position_embeddings
         self.remat = remat
@@ -303,6 +343,12 @@ class LagunaBlock(nn.Module):
             self.self_attn = LagunaAttention(cfg, layer)
         self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
                                                 cfg.rms_norm_eps)
+        self.sandwich = cfg.sandwich_norm
+        if self.sandwich:           # on what each branch returns
+            self.input_layernorm_2 = RMSNorm(cfg.hidden_size,
+                                             cfg.rms_norm_eps)
+            self.post_attention_layernorm_2 = RMSNorm(cfg.hidden_size,
+                                                      cfg.rms_norm_eps)
         self.sparse = cfg.mlp_layer_types[layer] == "sparse"
         if self.sparse:
             self.mlp = ExpertParallelMLP(
@@ -321,16 +367,36 @@ class LagunaBlock(nn.Module):
 
     def forward(self, p, x):
         """-> (x, the expert layer's counters or None)."""
-        x = x + getattr(self, self.mixer)(p[self.mixer], self.input_layernorm(
+        y = getattr(self, self.mixer)(p[self.mixer], self.input_layernorm(
             p["input_layernorm"], x))
+        if self.sandwich:
+            y = self.input_layernorm_2(p["input_layernorm_2"], y)
+        x = x + y
         h = self.post_attention_layernorm(p["post_attention_layernorm"], x)
         if self.sparse:
             y, stats = self.mlp(p["mlp"], h, return_stats=True)
-            return x + y, stats
-        return x + self.mlp(p["mlp"], h), None
+        else:
+            y, stats = self.mlp(p["mlp"], h), None
+        if self.sandwich:
+            y = self.post_attention_layernorm_2(
+                p["post_attention_layernorm_2"], y)
+        return x + y, stats
+
+
+def exit_log_probs(z):
+    """Gate logits of the passes but the last, ``(R - 1, ...)``, -> the log
+    of the exit distribution over all ``R`` passes, ``(R, ...)``:
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` with ``lambda = sigmoid(z)``,
+    and the last pass takes what is left (it has no gate to read)."""
+    stay = jax.nn.log_sigmoid(-z)               # log(1 - lambda_t)
+    stayed = jnp.cumsum(stay, axis=0)           # through pass t
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(z) + stayed - stay, stayed[-1:]], axis=0)
 
 
 class Laguna(nn.Module):
+    fp32_param_names = ("exit_gate",)
+
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
         self.cfg = cfg
@@ -343,20 +409,22 @@ class Laguna(nn.Module):
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
                                      bias=False)
 
+    def create_params(self, key):
+        """The exit gate of a looped stack, torch's ``Linear(hidden, 1)``:
+        ``weight`` (1, hidden) and ``bias`` (1,), float32 under O2."""
+        if self.cfg.total_ut_steps == 1:
+            return {}
+        return {"exit_gate": nn.Linear(self.cfg.hidden_size,
+                                       1).create_params(key)}
+
     def _head_weight(self, p):
         """The head's (vocabulary, hidden) matrix: the embedding's own leaf
         where the two are tied."""
         tied = self.cfg.tie_word_embeddings
         return p["embed_tokens" if tied else "lm_head"]["weight"]
 
-    def _backbone(self, p, input_ids):
-        """-> (final hidden states, the step's MoE counters or {})."""
-        T = input_ids.shape[1]
-        if T > self.cfg.max_position_embeddings:
-            raise ValueError(f"sequence length {T} exceeds "
-                             f"max_position_embeddings "
-                             f"{self.cfg.max_position_embeddings}")
-        x = self.embed_tokens(p["embed_tokens"], input_ids)
+    def _stack(self, p, x):
+        """The layers once -> (x, the expert layers' counters, a list)."""
         stats = []
         for i, block in enumerate(self.layers):
             def layer(pp, xx, block=block):
@@ -365,17 +433,90 @@ class Laguna(nn.Module):
             x, s = wrap_block(layer, self.cfg.remat)(p["layers"][str(i)], x)
             if s is not None:
                 stats.append(s)
-        return (self.norm(p["norm"], x),
-                ExpertParallelMLP.reduce_stats(stats) if stats else {})
+        return x, stats
+
+    def _backbone(self, p, input_ids):
+        """-> (final hidden states, the step's MoE counters or {}); under a
+        looped stack the normed state of every pass, (R, B, T, hidden)."""
+        T = input_ids.shape[1]
+        if T > self.cfg.max_position_embeddings:
+            raise ValueError(f"sequence length {T} exceeds "
+                             f"max_position_embeddings "
+                             f"{self.cfg.max_position_embeddings}")
+        x = self.embed_tokens(p["embed_tokens"], input_ids)
+        R = self.cfg.total_ut_steps
+        if R == 1:
+            x, stats = self._stack(p, x)
+            return (self.norm(p["norm"], x),
+                    ExpertParallelMLP.reduce_stats(stats) if stats else {})
+        from ..observability.metrics import get_registry
+        get_registry().counter(
+            "looped_stack_total",
+            help="looped layer stacks traced, by passes and layers"
+        ).labels(passes=str(R), layers=str(len(self.layers))).inc()
+
+        def one_pass(x, _):
+            x, _ = self._stack(p, x)
+            with jax.named_scope("loop.norm"):
+                x = self.norm(p["norm"], x)
+            return x, x
+
+        # one scan over the pass index, the weights closed over: the
+        # compiled step holds each block once a direction
+        with jax.named_scope("loop"):
+            _, states = lax.scan(one_pass, x, None, length=R)
+        return states, {}
 
     def forward(self, p, input_ids):
+        """Logits; of every pass, (R, B, T, V), under a looped stack."""
         x, _ = self._backbone(p, input_ids)
         return F.matmul(x, self._head_weight(p).T.astype(x.dtype))
+
+    def _exit_loss(self, p, states, labels):
+        """The looped stack's training loss from the ``R`` normed states
+        (R, B, T, hidden): every pass's next-token loss weighed by the
+        learned exit distribution, less ``exit_beta`` times its entropy, the
+        mean over every position but each row's last.  -> (loss, the sums
+        over those positions of the expected exit pass, of the last pass's
+        loss, and their count)."""
+        from ..nn.fused_xent import linear_cross_entropy
+        from ..observability.metrics import get_registry
+        get_registry().counter(
+            "exit_gate_calls_total",
+            help="exit gates of a looped stack traced").inc()
+        R, B, T, E = states.shape
+        with jax.named_scope("loss.head"):
+            nll = linear_cross_entropy(
+                states.reshape(R * B * T, E), self._head_weight(p),
+                jnp.tile(labels.reshape(-1), R),
+                int(self.cfg.head_chunk)).reshape(R, B, T)
+        with jax.named_scope("loss.exit"):
+            gate = p["exit_gate"]
+            # float32 on the vector unit, not a matmul in bf16 passes; the
+            # last pass's gate is never read
+            z = jnp.sum(states[:R - 1].astype(jnp.float32)
+                        * gate["weight"][0].astype(jnp.float32), -1)
+            logp = exit_log_probs(z + gate["bias"].astype(jnp.float32))
+            prob = jnp.exp(logp)
+            # sum_t p_t nll_t - beta H(p), H(p) = -sum_t p_t log p_t
+            each = jnp.sum(prob * (nll + self.cfg.exit_beta * logp), 0)
+            valid = jnp.arange(T) < T - 1
+            count = B * (T - 1)
+            loss = jnp.sum(each * valid) / count
+            passes = jnp.arange(1, R + 1, dtype=jnp.float32)
+            stats = {
+                "exit_step_sum": jnp.sum(
+                    jnp.tensordot(passes, prob, 1) * valid),
+                "nll_last_sum": jnp.sum(nll[R - 1] * valid),
+                "exit_positions": jnp.int32(count)}
+        return loss, stats
 
     def loss(self, p, input_ids, return_stats: bool = False):
         """Mean next-token cross-entropy over every position but each row's
         last, through the fused chunked head (scope ``loss``); with
-        ``return_stats`` also the step's MoE counters."""
+        ``return_stats`` also the step's MoE counters.  Under a looped stack
+        the exit-weighted loss of ``_exit_loss`` and its three sums
+        (``exit_step_sum``, ``nll_last_sum``, ``exit_positions``)."""
         B, T = input_ids.shape
         with jax.named_scope("model"):      # the root scope nn.apply opens
             x, stats = self._backbone(p, input_ids)
@@ -383,9 +524,13 @@ class Laguna(nn.Module):
             from ..nn.fused_xent import linear_cross_entropy
             labels = jnp.concatenate(
                 [input_ids[:, 1:], jnp.zeros((B, 1), input_ids.dtype)], 1)
-            nll = linear_cross_entropy(
-                x.reshape(B * T, -1), self._head_weight(p),
-                labels.reshape(-1), int(self.cfg.head_chunk)).reshape(B, T)
-            valid = jnp.arange(T) < T - 1
-            loss = jnp.sum(nll * valid) / (B * (T - 1))
+            if self.cfg.total_ut_steps > 1:
+                loss, stats = self._exit_loss(p, x, labels)
+            else:
+                nll = linear_cross_entropy(
+                    x.reshape(B * T, -1), self._head_weight(p),
+                    labels.reshape(-1),
+                    int(self.cfg.head_chunk)).reshape(B, T)
+                valid = jnp.arange(T) < T - 1
+                loss = jnp.sum(nll * valid) / (B * (T - 1))
         return (loss, stats) if return_stats else loss

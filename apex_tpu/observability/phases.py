@@ -60,13 +60,19 @@ PHASES = (
     "conv.out_proj",       # (C * c) W_out
     # models/laguna.py, attention with ``qk_norm``
     "attn.qk_norm",        # RMSNorm over each head of q and of k, before RoPE
+    # models/laguna.py, the looped stack (``total_ut_steps`` > 1)
+    "loop",                # the scan over the passes (inside ``model``): the
+                           # layers' module paths follow it
+    "loop.norm",           # the final norm at the end of every pass
+    "loss.head",           # the fused head on every pass's state (in ``loss``)
+    "loss.exit",           # exit gate, exit distribution, the weighted loss
 )
 MODEL = "model"
 
 _VOCAB = frozenset(PHASES)
 # op_name components jax itself puts between a module scope and the primitive
 _JAX_STRUCTURE = frozenset((
-    "cond", "while", "scan", "checkpoint", "remat", "pallas_call",
+    "cond", "while", "body", "scan", "checkpoint", "remat", "pallas_call",
     "shard_map", "closed_call", "core_call", "custom_vjp_call",
     "custom_jvp_call", "custom_vjp_call_jaxpr", "custom_lin",
     "rematted_computation"))
@@ -94,7 +100,10 @@ def phase_of_op_name(op_name: str) -> Phase:
     scopes found in it, outermost first, with the module path that follows
     ``model`` kept whole as one element (``("model",
     "BertForPretraining/bert/3/attention/qkv")``); backward when a scope
-    sits inside ``transpose(``.  ``((), False)`` when no scope is found."""
+    sits inside ``transpose(``.  ``((), False)`` when no scope is found.  A
+    scope opened between ``model`` and the module path (``loop``: the scan a
+    stack of layers runs in) follows the module path it encloses:
+    ``("model", "layers/3/mlp", "loop")``."""
     # the compiler joins the names of instructions it merged with ";"
     parts = op_name.split(";")[0].split("/")
     path, last, i = [], -1, 0
@@ -105,15 +114,23 @@ def phase_of_op_name(op_name: str) -> Phase:
                 path.append(base)
             last = i
             if base == MODEL:
-                modules = []
+                modules, around = [], []
                 # the last component is the primitive, never a module
                 while i + 1 < len(parts) - 1:
                     nxt = parts[i + 1]
-                    if not modules and (nxt in _JAX_STRUCTURE or _WRAPPED
-                                        .match(nxt).group(1) == MODEL):
+                    inner = _WRAPPED.match(nxt).group(1)
+                    if not modules and (nxt in _JAX_STRUCTURE
+                                        or inner == MODEL):
                         # a rematerialized block's backward: transpose(
                         # jvp(model))/jvp(model)/checkpoint/<modules>
                         i += 1
+                        continue
+                    if not modules and inner in _VOCAB:
+                        # a scope around the modules (the looped stack's)
+                        if inner not in around:
+                            around.append(inner)
+                        i += 1
+                        last = i
                         continue
                     if not (_PLAIN.match(nxt) and nxt not in _VOCAB
                             and nxt not in _JAX_STRUCTURE
@@ -123,6 +140,7 @@ def phase_of_op_name(op_name: str) -> Phase:
                     modules.append(nxt)
                 if modules:
                     path.append("/".join(modules))
+                path.extend(around)
         i += 1
     backward = any("transpose(" in p for p in parts[:last + 1])
     return tuple(path), backward

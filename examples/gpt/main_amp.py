@@ -19,6 +19,8 @@ The per-layer decoder with routed experts, from a published config file
       --model-config benchmark/configs/mellum2-12b.json
   python examples/gpt/main_amp.py --arch lfm2_moe -b 2 --block-size 8192 \
       --model-config benchmark/configs/lfm2-8b-a1b.json
+  python examples/gpt/main_amp.py --arch ouro -b 1 --block-size 8192 \
+      --model-config benchmark/configs/ouro-2.6b.json
 
 ``build(args)`` returns the model, mesh, state and jitted train step that
 ``main()`` loops over; the benchmark and the tests drive the same objects.
@@ -39,7 +41,7 @@ if os.path.isdir(os.path.join(_repo, "apex_tpu")) and _repo not in sys.path:
     sys.path.insert(0, _repo)
 
 # --arch values that models/laguna.py builds from a --model-config file
-PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe")
+PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe", "ouro")
 
 # enough structure to be learnable at tiny scale: a looping pangram
 _BUILTIN_TEXT = ("the quick brown fox jumps over the lazy dog. " * 200)
@@ -86,16 +88,19 @@ def parse_args(argv=None):
                         "routed experts) built from --model-config: "
                         "laguna (dense first layer, gated attention, "
                         "sigmoid router, shared expert), mellum (every "
-                        "layer sparse, softmax router, no shared expert) "
-                        "or lfm2_moe (gated short-convolution layers 3:1 "
+                        "layer sparse, softmax router, no shared expert), "
+                        "lfm2_moe (gated short-convolution layers 3:1 "
                         "with attention, a selection bias on the router, "
-                        "a tied head)")
+                        "a tied head) or ouro (dense layers applied "
+                        "total_ut_steps times over the same weights, "
+                        "sandwich norms, a learned exit gate)")
     p.add_argument("--model-config", default=None, metavar="JSON",
-                   help="laguna, mellum, lfm2_moe: a config file with "
-                        "the published keys, whose model_type is --arch "
-                        "(benchmark/configs/laguna-xs2.json, "
-                        "mellum2-12b.json, lfm2-8b-a1b.json); the "
-                        "sequence length is --block-size")
+                   help="laguna, mellum, lfm2_moe, ouro: a config file "
+                        "with the published keys, whose model_type is "
+                        "--arch (benchmark/configs/laguna-xs2.json, "
+                        "mellum2-12b.json, lfm2-8b-a1b.json, "
+                        "ouro-2.6b.json); the sequence length is "
+                        "--block-size")
     p.add_argument("--n-kv-head", type=int, default=None,
                    help="grouped-query attention KV heads (llama; "
                         "default MHA)")
@@ -250,7 +255,8 @@ def build(args):
     def put_batch(batch):
         return jax.device_put(batch, batch_sharding)
 
-    # a model whose loss can hand back its expert layers' counters
+    # a model whose loss can hand back its step sums (the expert layers'
+    # counters, a looped stack's exit sums)
     with_stats = "return_stats" in inspect.signature(
         model.loss).parameters
 
@@ -259,7 +265,7 @@ def build(args):
         (ids,) = batch
 
         def loss_fn(p):
-            if with_stats:          # + the expert layers' counters
+            if with_stats:          # + the step's sums
                 return model.loss(p, ids, return_stats=True)
             return model.loss(p, ids), {}
 

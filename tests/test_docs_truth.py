@@ -55,13 +55,14 @@ _FILE_EXT = ("py", "json", "md", "sh", "jsonl", "gz", "cpp", "so", "txt")
 
 def _cell_shaped(text):
     """Backticked ``<configuration>.<traffic>`` names: two hyphenated
-    lower-case words around one dot, neither a file nor a metric."""
+    lower-case words around one dot, neither a file nor a metric nor a
+    declared configuration whose own name holds a dot (``ouro-2.6b``)."""
     metrics = {m["name"] for m in
                MANIFEST["end_to_end"] + MANIFEST["per_layer"]}
     for tok in set(_code_tokens(text)):
         m = _CELL_SHAPED.match(tok)
         if m and "-" in tok and tok not in metrics \
-                and m.group(2) not in _FILE_EXT:
+                and tok not in CONFIGS and m.group(2) not in _FILE_EXT:
             yield tok, m.group(1)
 
 

@@ -142,6 +142,19 @@ def programs():
         text = jax.jit(jax.grad(lambda p: mix.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
             mparams).compile().as_text()
         out["conv"] = (_scopes(text), phases.instruction_phases(text))
+
+        # the same decoder with its stack looped: one scan over the passes, an exit gate
+        loop = models.Laguna(models.LagunaConfig(
+            vocab_size=64, hidden_size=16, intermediate_size=32,
+            layer_types=["full_attention"] * 2, num_attention_heads_per_layer=[2, 2],
+            mlp_layer_types=["dense", "dense"], num_key_value_heads=2, head_dim=8,
+            sliding_window=None, rope_parameters={"full_attention": {"rope_theta": 10000.0}},
+            gating=False, total_ut_steps=3, sandwich_norm=True, exit_beta=0.05,
+            remat="nothing", head_chunk=32))
+        oparams, _ = loop.init(jax.random.PRNGKey(4))
+        text = jax.jit(jax.grad(lambda p: loop.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
+            oparams).compile().as_text()
+        out["loop"] = (_scopes(text), phases.instruction_phases(text))
     finally:
         C.set_ledger(prev)
     return out
@@ -154,7 +167,8 @@ CASES = ([("mesh", s) for s in TRAIN_SCOPES + DDP_SCOPES]
             ("moe", "moe.route"), ("moe", "moe.dispatch"), ("moe", "moe.experts"),
             ("moe", "moe.combine"),
             ("conv", "conv.in_proj"), ("conv", "conv.mix"), ("conv", "conv.out_proj"),
-            ("conv", "attn.qk_norm")])
+            ("conv", "attn.qk_norm"),
+            ("loop", "loop"), ("loop", "loop.norm"), ("loop", "loss.head"), ("loop", "loss.exit")])
 
 
 def test_every_scope_of_the_vocabulary_has_a_case():
@@ -174,6 +188,20 @@ def test_forward_and_backward_of_a_module_are_told_apart(programs, module):
                   if "model" in path and path[path.index("model") + 1:][:1]
                   == ("BertForPretraining/" + module,)}
     assert directions == {False, True}
+
+
+def test_a_looped_stacks_modules_stay_next_to_model_with_the_loop_after_them(programs):
+    """The scope around the scan comes before the module paths in an
+    ``op_name`` and after them in a phase, forward and backward, so that a
+    reader by module finds the looped blocks as it finds any other."""
+    found = {(path, backward) for path, backward in programs["loop"][1].values()}
+    for module in ("layers/0/self_attn/q_proj", "layers/1/mlp/down_proj",
+                   "layers/1/post_attention_layernorm_2"):
+        assert {b for path, b in found if path[:3] == ("model", module, "loop")} == {False, True}
+    assert (("model", "norm", "loop", "loop.norm"), False) in found
+    assert any(path == ("model", "loop") for path, _ in found)          # the scan's own work
+    assert any(path[:2] == ("loss", "loss.head") for path, _ in found)
+    assert any(path[:2] == ("loss", "loss.exit") and b for path, b in found)
 
 
 def test_nested_scopes_come_outermost_first(programs):
