@@ -113,29 +113,41 @@ class CompileWatch:
 
 class Tracer:
     """Traces a few iterations of the steady stretch, never the whole window.
-    The runner calls ``tick(elapsed)`` between iterations: the trace starts at
-    a quarter of the window and stops at the first tick that is both
-    ``TRACE_SECONDS`` and ``TRACE_ITERATIONS`` later.  The first traced
+    The runner calls ``tick(elapsed, step)`` between iterations: the trace
+    starts at a quarter of the window and stops at the first tick that is both
+    ``TRACE_SECONDS`` and ``TRACE_ITERATIONS`` later.  A quarter of the window
+    is a quarter of ``steps`` where the configuration states how many steps
+    its window runs (``window_steps``: a job whose work a step depends on its
+    own trajectory, so both sides of a pair have to trace the same steps), and
+    ``seconds / 4`` of the clock where it states none.  The first traced
     iteration pays the profiler's start and is left out of the stretch."""
 
-    def __init__(self, enabled: bool, seconds: float, out_dir: str):
+    def __init__(self, enabled: bool, seconds: float, out_dir: str,
+                 steps: Optional[int] = None):
         self.enabled, self.dir = enabled, out_dir
         self.start_at = seconds / 4.0
+        self.start_step = None if steps is None else steps // 4
         self.state = "off" if not enabled else "waiting"
         self.iterations = 0
+        self.started = None             # (elapsed, step) of the tick that started the trace
 
-    def tick(self, elapsed: float) -> None:
-        if self.state == "waiting" and elapsed >= self.start_at:
+    def _due(self, elapsed: float, step: int) -> bool:
+        return elapsed >= self.start_at if self.start_step is None else step >= self.start_step
+
+    def tick(self, elapsed: float, step: int) -> None:
+        if self.state == "waiting" and self._due(elapsed, step):
             import jax
             shutil.rmtree(self.dir, ignore_errors=True)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(self.dir, profiler_options=opts)
-            self.state = "tracing"
+            self.state, self.started = "tracing", (elapsed, step)
         elif self.state == "tracing":
             self.iterations += 1
-            if (elapsed >= self.start_at + TRACE_SECONDS
-                    and self.iterations >= TRACE_ITERATIONS):
+            # TRACE_SECONDS from where the trace was due: the clock's quarter, or the
+            # tick of the counted step
+            since = self.start_at if self.start_step is None else self.started[0]
+            if elapsed >= since + TRACE_SECONDS and self.iterations >= TRACE_ITERATIONS:
                 self.stop()
 
     def stop(self) -> None:
@@ -235,9 +247,13 @@ def run_cell(cell: Cell, log: Callable[[dict], None]) -> dict:
     from apex_tpu.observability import compilation
     traces0 = compilation.get_ledger().total_traces()
     tracer = Tracer(cell.trace, cell.seconds,
-                    os.path.join(cell.root, ".bm_trace", cell.name))
+                    os.path.join(cell.root, ".bm_trace", cell.name),
+                    cell.config.get("window_steps"))
     measured = runner.window(cell.seconds, tracer)
     tracer.stop()
+    if tracer.started:
+        log({"traced_from": {"elapsed_s": tracer.started[0], "step": tracer.started[1],
+                             "ticks": tracer.iterations}})
     in_window = {"traces": compilation.get_ledger().total_traces() - traces0,
                  "backend_compiles": watch.compiles - warm["backend_compiles"]}
     log({"inside_window": in_window})

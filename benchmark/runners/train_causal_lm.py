@@ -27,6 +27,7 @@ def causal_lm_batch(params: dict, seed: int, index: int, rows: int, vocab_size: 
 
 
 MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max", "moe_dropped_assignments")
+BY_STEP = ("moe_assignments_held", "moe_expert_load_max")      # logged for every step of the run
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HELD_SHORTFALL_LIMIT = 0.2
 
@@ -83,10 +84,14 @@ class Runner(train_example.Runner):
             "moe_assignments_held": self._counter("moe_assignments_held", np.mean, mine),
             "moe_expert_load_max": self._counter("moe_expert_load_max", max, mine),
             "moe_dropped_assignments": self._counter("moe_dropped_assignments", sum, self.steps)})
-        # the window trains without a balancing loss: how far the routing drifted
+        # the window trains without a balancing loss: how far the routing drifted,
+        # and step by step from the first of set-up's (what a window_steps is chosen from)
         self.log({"moe": {k: out["facts"][k] for k in MOE_COUNTERS},
                   "first_step": {k: int(mine[0][k]) for k in MOE_COUNTERS if k in mine[0]},
                   "last_step": {k: int(mine[-1][k]) for k in MOE_COUNTERS if k in mine[-1]}})
+        if BY_STEP[0] in mine[0]:
+            self.log({"by_step": {k: [int(m[k]) for m in self.steps] for k in BY_STEP},
+                      "setup_steps": before})
         return out
 
     def planned_temp_bytes(self) -> int:

@@ -135,20 +135,30 @@ class Runner:
         return metrics
 
     def window(self, seconds, tracer):
+        """Steps until the clock passes ``seconds``; where the configuration
+        states ``window_steps`` (a job whose work a step depends on its own
+        trajectory), until that many steps have run, if the clock has not come
+        first: then both sides of a pair do the same steps of the same run."""
+        count = self.cell.config.get("window_steps")
         kept, ends, t0 = [], [0.0], time.perf_counter()
         while True:
-            tracer.tick(time.perf_counter() - t0)
+            tracer.tick(time.perf_counter() - t0, len(kept))
             kept.append(self._one_step())
             elapsed = time.perf_counter() - t0
             ends.append(elapsed)
-            if elapsed >= seconds:
+            if len(kept) == count or elapsed >= seconds:
                 break
+        self.log({"window_ended_by": "count" if len(kept) == count else "clock",
+                  "at_step": len(kept), "window_steps": count, "seconds": seconds,
+                  "elapsed_s": elapsed})
         # how the steps spread over the window: a run that reads far off shows
-        # here whether every step was slow or a few stalled
-        step_ms = np.sort(np.diff(ends)) * 1e3
+        # here whether every step was slow or a few stalled, and where
+        each_ms = np.diff(ends) * 1e3
+        self.log({"step_ms_by_step": [round(float(d), 2) for d in each_ms]})
+        step_ms = np.sort(each_ms)
         self.log({"step_ms": {"min": step_ms[0], "median": float(np.median(step_ms)),
                               "p99": step_ms[int(0.99 * (len(step_ms) - 1))], "max": step_ms[-1],
-                              "slowest_at_s": float(ends[1 + int(np.argmax(np.diff(ends)))])}})
+                              "slowest_at_s": float(ends[1 + int(np.argmax(each_ms))])}})
         loss = np.array([float(m["loss"]) for m in kept])
         bad = ~np.isfinite(loss) | np.array([float(m["found_inf"]) > 0 for m in kept])
         self.last = {"loss_scale": float(kept[-1]["loss_scale"]), "loss_last": float(loss[-1])}

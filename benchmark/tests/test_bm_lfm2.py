@@ -51,6 +51,36 @@ def test_lfm2_cell_is_correct_on_four_virtual_devices():
     assert 0 < moe["moe_assignments_held"] < 4 * 32 * 4 * 4 and moe["moe_expert_load_max"] > 0
 
 
+def test_a_stated_window_steps_ends_the_window_and_is_where_the_shortfall_is_read(tmp_path):
+    """A copy of the tiny configuration with ``window_steps``: whatever
+    ``--seconds`` is the window runs that many steps, the series line holds one
+    number a step from the first of set-up's, and ``moe_held_shortfall`` is
+    read at the counted last step."""
+    import shutil
+    from runners import train_causal_lm
+    bench = tmp_path / "bench"
+    shutil.copytree(bm_util.TINY, bench)
+    cfg = dict(_tiny_cfg(), window_steps=5)
+    json.dump(cfg, open(bench / "configs" / "lfm2-tiny.json", "w"))
+    runs = [bm_util.run(CELL, seed=9, seconds=s, man=_manifest(), bench_dir=str(bench))
+            for s in (30.0, 45.0)]
+    for result, lines in runs:
+        assert result["correct"] is True and result["attempted"] == 5
+        ended = next(l for l in lines if "window_ended_by" in l)
+        assert (ended["window_ended_by"], ended["at_step"], ended["window_steps"]) == ("count", 5, 5)
+        series = next(l for l in lines if "by_step" in l)
+        held = series["by_step"]["moe_assignments_held"]
+        assert len(held) == len(series["by_step"]["moe_expert_load_max"]) == series["setup_steps"] + 5
+        last = next(l["last_step"] for l in lines if "last_step" in l and "moe" in l)
+        assert last["moe_assignments_held"] == held[-1]
+        compared = next(l for l in lines if l.get("compared") == "moe_held_shortfall")
+        expected = 4 * 32 * 4 * 4 * 8 / 16     # tokens x choices x sparse layers x held / published
+        assert compared["value"] == max(0.0, 1.0 - held[-1] / expected)
+        assert compared["limit"] == train_causal_lm.HELD_SHORTFALL_LIMIT == 0.2
+    # the same steps of the same run on both: the same series, whatever --seconds
+    assert ([l for l in runs[0][1] if "by_step" in l] == [l for l in runs[1][1] if "by_step" in l])
+
+
 def test_controls_and_a_model_without_its_bias_fail_where_the_stated_precision_passes():
     """At a size a test can hold, relatively (the limits in references/lfm2.py
     are the chip-size cell's): fp8-rounded matmuls move the first gradient at
@@ -204,7 +234,9 @@ def test_every_twin_metric_file_points_at_an_accepted_reader_with_its_twins_para
     assert {m["name"] for m in mine} == {"lfm2." + n for n in twins} | {
         "lfm2.conv_step_ms", "lfm2.conv_mix_ms", "lfm2.mfu", "lfm2.flash_roofline"}
     assert all(m["workloads"] == ["lfm2-8b-a1b.pretrain-8k"] for m in mine)
-    assert len(man["workloads"]) == 5 and sum(w["chips"] == 4 for w in man["workloads"]) == 1
+    cells = {w["name"]: w for w in man["workloads"]}
+    assert cells["lfm2-8b-a1b.pretrain-8k"]["chips"] == 1
+    assert [n for n, w in cells.items() if w["chips"] == 4] == ["bert-large.ddp4-512"]
     assert all(os.path.exists(os.path.join(BENCH_DIR, "metrics", m["name"] + ".json"))
                for m in man["per_layer"])
 
