@@ -10,19 +10,33 @@ FLOPs for memory").
 Modes (the ``remat=`` config field on GPTConfig/LlamaConfig):
 
 - ``None``        — store everything (XLA default).
-- ``"nothing"``   — save only block boundaries; recompute the whole
-                    block in backward (max memory saving).
-- ``"dots"``      — ``dots_with_no_batch_dims_saveable``: keep matmul
-                    outputs, recompute the cheap elementwise/norm ops —
-                    the usual sweet spot on MXU-bound steps.
+- ``"nothing"``   — save only block boundaries and the flash attention
+                    kernel's two results (``o``, one block boundary's
+                    size, and its fp32 row statistics ``lse``);
+                    everything else of the block is recomputed in
+                    backward (max memory saving short of running the
+                    kernel's forward twice).
+- ``"dots"``      — ``dots_with_no_batch_dims_saveable`` and the same
+                    two kernel results: keep matmul outputs, recompute
+                    the cheap elementwise/norm ops — the usual sweet
+                    spot on MXU-bound steps.
 
-Gradients are mathematically identical either way (pinned in
-tests/test_remat.py, along with a backward-FLOPs increase check).
+The kernel's results are kept by name (``FLASH_OUT_NAME``,
+``FLASH_LSE_NAME``, given inside the kernel's forward rule): no policy
+that looks at primitives can see a Mosaic call's result, so without the
+names the backward of every block launched ``flash_fwd`` a second time
+to get ``o`` and ``lse`` back.  A block with no flash call in it holds
+no such name and keeps exactly what it kept.
+
+Gradients are bit-identical either way (pinned in tests/test_remat.py,
+along with a backward-FLOPs increase check and the launch count).
 """
 
 from __future__ import annotations
 
 import jax
+
+from ..ops.pallas_flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
 
 __all__ = ["wrap_block"]
 
@@ -33,10 +47,11 @@ def wrap_block(fn, mode):
     """``fn(params, x) -> out`` wrapped per ``mode`` (see module doc)."""
     if mode is None:
         return fn
-    if mode == "nothing":
-        policy = jax.checkpoint_policies.nothing_saveable
-    elif mode == "dots":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    else:
+    policies = jax.checkpoint_policies
+    policy = policies.save_only_these_names(FLASH_OUT_NAME, FLASH_LSE_NAME)
+    if mode == "dots":
+        policy = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, policy)
+    elif mode != "nothing":
         raise ValueError(f"remat mode {mode!r} not in {_MODES}")
     return jax.checkpoint(fn, policy=policy)

@@ -76,10 +76,20 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_common import LANES, interpret, token_tile_axes
+
+# checkpoint_name tags on the forward kernel's two results, given inside
+# the forward rule so that the residuals the backward rule reads are the
+# named values themselves: a remat policy that saves these names
+# (models/_remat.py) keeps o and lse, and the backward of a rematerialized
+# block does not launch flash_fwd again.  Outside a jax.checkpoint a name
+# is the identity and lowers to nothing.
+FLASH_OUT_NAME = "flash_o"
+FLASH_LSE_NAME = "flash_lse"
 
 _VMEM_BUDGET = 12 * 1024 * 1024
 _BLK = 512          # q/k rows per block (clamped to the padded seq len)
@@ -937,6 +947,11 @@ def _flash_fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
     _count_call(q, k, H, token_major, causal, window, masked, "k")
     o, lse = _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
                   dropout_rate, window, token_major)
+    # named before they part into primal output and residuals: a name on
+    # the output alone leaves the residual o un-named one equation
+    # upstream, and partial evaluation replays the kernel to get it
+    o = checkpoint_name(o, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return o, (q, k, v, o, lse, kvm, idq, idk, seed)
 
 

@@ -559,14 +559,6 @@ def test_index_maps_of_the_folded_triangle_cover_every_pair_once():
 
 # -- compiled for a described (not attached) v5e (``one_chip``: conftest.py) ---
 
-@pytest.fixture
-def for_the_chip(monkeypatch, past_the_cache):
-    """Kernels as the chip runs them (Mosaic, not the interpreter), compiled
-    past the persistent cache (``past_the_cache``: conftest.py)."""
-    from apex_tpu.ops import dispatch
-    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
-
-
 @pytest.mark.parametrize("window,fetched", [(512, 2), (None, 16)])
 def test_v5e_compiles_the_flash_kernels_at_the_decoder_cells_widths(one_chip, for_the_chip,
                                                                     window, fetched):
@@ -584,18 +576,16 @@ def test_v5e_compiles_the_flash_kernels_at_the_decoder_cells_widths(one_chip, fo
     del fetched
 
 
-@pytest.mark.parametrize("kind,heads", [("sliding_attention", 64), ("full_attention", 48)])
-def test_v5e_compiles_the_decoders_attention_layer_without_copies_around_the_kernels(
-        one_chip, for_the_chip, kind, heads):
-    """``LagunaAttention`` forward + gradient under the cell's remat mode at the
-    cell's widths (B 2, T 8192, bf16): between the projections and the kernels
-    the entry computation moves no axis, repeats no K/V head and widens
-    nothing of q's size.  What keeps the copies from coming back with a later
-    edit of the model: a (B, T, H, D) view of a projection's output is a
-    relayout on a TPU, whose tiles are 8 tokens x 128 lanes."""
+_LAYER_STEPS = {}
+
+
+def _attention_layer_step(one_chip, kind, heads):
+    """The text of ``LagunaAttention`` forward + gradient under the cell's remat
+    mode at the cell's widths (B 2, T 8192, bf16), compiled once a layer kind."""
+    if kind in _LAYER_STEPS:
+        return _LAYER_STEPS[kind]
     import json
     import os
-    import re
     from apex_tpu.models import laguna
     from apex_tpu.models._remat import wrap_block
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -610,9 +600,25 @@ def test_v5e_compiles_the_decoders_attention_layer_without_copies_around_the_ker
     x = jax.ShapeDtypeStruct((2, 8192, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
 
     def loss(p, x):
-        return jnp.sum(wrap_block(lambda pp, xx: attn(pp, xx), "dots")(p, x).astype(jnp.float32))
+        return jnp.sum(wrap_block(lambda pp, xx: attn(pp, xx), cfg.remat)(p, x).astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    return _LAYER_STEPS.setdefault(kind, text)
+
+
+LAYER_KINDS = [("sliding_attention", 64), ("full_attention", 48)]
+
+
+@pytest.mark.parametrize("kind,heads", LAYER_KINDS)
+def test_v5e_compiles_the_decoders_attention_layer_without_copies_around_the_kernels(
+        one_chip, for_the_chip, kind, heads):
+    """``LagunaAttention`` forward + gradient under the cell's remat mode at the
+    cell's widths (B 2, T 8192, bf16): between the projections and the kernels
+    the entry computation moves no axis, repeats no K/V head and widens
+    nothing of q's size.  What keeps the copies from coming back with a later
+    edit of the model: a (B, T, H, D) view of a projection's output is a
+    relayout on a TPU, whose tiles are 8 tokens x 128 lanes."""
+    text = _attention_layer_step(one_chip, kind, heads)
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv", "rope"):
         assert re.search(rf"%{kernel}[.\d]* = ", text), kernel
     q_elements = 2 * 8192 * heads * 128
@@ -633,6 +639,18 @@ def test_v5e_compiles_the_decoders_attention_layer_without_copies_around_the_ker
                 f"{op} of q's size: {line[:200]}"
     # K and V reach the kernels at their own 8 heads: dk, dv come back so
     assert re.search(r"%flash_dkv[.\d]* = \(bf16\[2,8192,1024\]", text)
+
+
+@pytest.mark.parametrize("kind,heads", LAYER_KINDS)
+def test_v5e_compiles_the_decoders_rematerialized_attention_layer_with_one_forward_kernel(
+        one_chip, for_the_chip, kind, heads):
+    """The same step launches each flash kernel once: the cell's ``dots`` keeps the
+    forward kernel's ``o`` and ``lse`` by name, so the backward's replay of the
+    layer holds no second ``flash_fwd`` (it held one, the longest of the three
+    kernels in ``laguna-xs2.pretrain-8k``)."""
+    text = _attention_layer_step(one_chip, kind, heads)
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
 
 
 def test_v5e_compiles_the_flash_kernels_at_the_encoder_cells_widths(one_chip, for_the_chip):

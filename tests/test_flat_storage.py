@@ -624,14 +624,6 @@ def test_a_nonfinite_gradient_skips_the_step_and_halves_a_dynamic_scale(
     assert float(info["found_inf"]) == 0.0 and int(state3.inner.step) == 2
 
 
-@pytest.fixture
-def for_the_chip(monkeypatch, past_the_cache):
-    """Kernels as the chip runs them (Mosaic, not the interpreter), compiled
-    past the persistent cache (``past_the_cache``: conftest.py)."""
-    from apex_tpu.ops import dispatch
-    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
-
-
 def _only(tree, keep):
     return {k: v for k, v in tree.items() if keep(v)}
 

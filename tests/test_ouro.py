@@ -371,3 +371,37 @@ def test_the_new_switches_at_their_defaults_leave_the_other_steps_as_they_were(n
 
     absent, present = step_jaxpr(base), step_jaxpr(stated)
     assert absent == present and "ragged_dot" in absent and "exit" not in absent
+
+
+# -- compiled for a described (not attached) v5e (``one_chip``: conftest.py) ---------
+
+def test_v5e_compiles_the_looped_block_with_no_forward_kernel_in_its_replay(one_chip,
+                                                                            for_the_chip):
+    """One sandwich block of ``ouro-2.6b`` under the cell's ``remat: nothing`` at
+    the cell's widths (1 x 8192 tokens, bf16), forward and gradient: the
+    backward's replay of the block recomputes its projections and holds no
+    ``flash_fwd``, whose ``o`` and ``lse`` the mode keeps by name; the step
+    launches each flash kernel once."""
+    import json
+    import re
+    from apex_tpu.models import laguna
+    from apex_tpu.models._remat import wrap_block
+    with open(os.path.join(ROOT, "benchmark", "configs", "ouro-2.6b.json")) as f:
+        cfg = laguna.LagunaConfig.from_dict(json.load(f))
+    block = laguna.LagunaBlock(cfg, 0)
+    shapes = jax.eval_shape(lambda k: block.init(k)[0], jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(wrap_block(lambda pp, xx: block(pp, xx)[0], cfg.remat)(p, x)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    replay = [line for line in text.splitlines() if "rematted_computation" in line]
+    assert any("self_attn/q_proj/dot_general" in line for line in replay)
+    assert any("mlp/gate_proj/dot_general" in line for line in replay)
+    assert not any("flash_fwd" in line for line in replay)

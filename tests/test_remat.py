@@ -1,14 +1,19 @@
 """Per-block rematerialization (remat= config): gradients identical to
 the unremat'd model, backward FLOPs demonstrably higher (the memory is
 bought with recompute), dropout rng correctly replayed, MoE tuple
-outputs handled."""
+outputs handled; a rematerialized block keeps its flash kernel's results
+(one ``flash_fwd`` launch a block in the gradient, as without remat) and
+a block with no flash call keeps what it kept."""
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu import models
+from apex_tpu.models._remat import wrap_block
+from apex_tpu.ops import pallas_flash_attention as pfa
 
 LKW = dict(vocab_size=97, hidden_size=32, intermediate_size=64,
            num_hidden_layers=2, num_attention_heads=4,
@@ -86,6 +91,105 @@ def test_remat_backward_flops_ratio_through_costmodel():
     # and the recompute is visible through both ledgers
     assert a_remat > a_plain * 1.05
     assert x_remat > x_plain * 1.05
+
+
+# -- what a rematerialized block keeps of its flash kernel -------------------
+
+E, HEADS = 256, 2
+
+
+def _attn_block(token_major, name=None):
+    """``x + tanh(flash(x Wq, x Wk, x Wv) Wo)``: the kernels interpreted, in
+    either operand form (token-major needs a head of whole lane tiles);
+    ``name`` names the call's result after it returns."""
+    def block(p, x):
+        B, T, _ = x.shape
+        q, k, v = (jnp.dot(x, p[n]).reshape(B, T, HEADS, E // HEADS) for n in "qkv")
+        if token_major:
+            o = pfa.flash_attention_token_major(q, k, v, causal=True)
+        else:
+            o = pfa.flash_attention(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                                    causal=True).transpose(0, 2, 1, 3)
+        if name:
+            o = checkpoint_name(o, name)
+        return x + jnp.tanh(jnp.dot(o.reshape(B, T, E), p["o"]))
+    return block
+
+
+def _mlp_block(p, x):
+    return x + jnp.dot(jnp.tanh(jnp.dot(x, p["q"])) * jax.nn.sigmoid(jnp.dot(x, p["k"])), p["o"])
+
+
+def _two_blocks(block, wrap):
+    def loss(ps, x):
+        for p in ps:
+            x = wrap(block)(p, x)
+        return jnp.sum(x * x)
+    key = jax.random.PRNGKey(0)
+    ps = [{n: jax.random.normal(jax.random.fold_in(key, 4 * i + j), (E, E)) * 0.05
+           for j, n in enumerate("qkvo")} for i in range(2)]
+    return loss, ps, jax.random.normal(key, (1, 128, E))
+
+
+def _launches(jaxpr, name):
+    """``pallas_call`` equations of that kernel name, sub-jaxprs included (the
+    printed jaxpr shows a jitted callee once however often it is called)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == name
+        n += sum(_launches(sub, name) for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
+
+
+@pytest.mark.parametrize("token_major", [False, True], ids=["head_major", "token_major"])
+@pytest.mark.parametrize("mode", ["nothing", "dots"])
+def test_a_rematerialized_block_launches_flash_fwd_once(mode, token_major):
+    """The gradient of two blocks through ``wrap_block`` holds one
+    ``flash_fwd`` a block, as with no remat (it held two: the replay ran the
+    kernel again for ``o`` and ``lse``), and the same numbers bit for bit."""
+    counts, grads = {}, {}
+    for m in (None, mode):
+        loss, ps, x = _two_blocks(_attn_block(token_major), lambda f: wrap_block(f, m))
+        grad = jax.grad(loss, (0, 1))
+        jaxpr = jax.make_jaxpr(grad)(ps, x).jaxpr
+        counts[m] = [_launches(jaxpr, k) for k in ("flash_fwd", "flash_dq", "flash_dkv")]
+        grads[m] = jax.jit(grad)(ps, x)
+    assert counts[None] == counts[mode] == [2, 2, 2]
+    for a, b in zip(*(jax.tree_util.tree_leaves(grads[m]) for m in (None, mode))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_name_on_the_output_alone_does_not_keep_the_kernels_results():
+    """Why the names are given inside the forward rule: a policy that saves
+    ``o`` under a name given after the call returns still replays the kernel,
+    because the backward rule reads the residual ``o``, one equation upstream
+    of that name."""
+    policy = jax.checkpoint_policies.save_only_these_names("outside")
+    loss, ps, x = _two_blocks(_attn_block(True, name="outside"),
+                              lambda f: jax.checkpoint(f, policy=policy))
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(ps, x).jaxpr
+    assert _launches(jaxpr, "flash_fwd") == 4
+
+
+@pytest.mark.parametrize("mode,old", [
+    ("nothing", jax.checkpoint_policies.nothing_saveable),
+    ("dots", jax.checkpoint_policies.dots_with_no_batch_dims_saveable)])
+def test_a_block_with_no_flash_call_saves_what_it_saved(mode, old, capsys):
+    """No name, nothing more kept: the residuals of a block of matmuls and
+    elementwise work under ``wrap_block`` are those of the policy each mode
+    had by itself."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    def saved(wrap):
+        loss, ps, x = _two_blocks(_mlp_block, wrap)
+        print_saved_residuals(loss, ps, x)
+        return sorted(line.split(" ")[0] for line in capsys.readouterr().out.splitlines())
+
+    now = saved(lambda f: wrap_block(f, mode))
+    assert now and now == saved(lambda f: jax.checkpoint(f, policy=old))
+    if mode == "dots":                                 # and "dots" does keep matmul results
+        assert len(now) > len(saved(lambda f: wrap_block(f, "nothing")))
 
 
 def test_gpt_remat_with_dropout_replays_rng():
