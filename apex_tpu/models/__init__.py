@@ -10,6 +10,7 @@ from .gpt import GPTConfig, GPT, gpt2_small, gpt2_medium
 from .llama import LlamaConfig, Llama, RMSNorm, llama_params_to_tp
 from .mixtral import MixtralConfig, Mixtral
 from .laguna import LagunaConfig, Laguna
+from .nemotron_h import NemotronHConfig, NemotronH
 from .speculative import generate_speculative
 from .beam import beam_search
 from .t5 import T5Config, T5

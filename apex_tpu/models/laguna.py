@@ -258,8 +258,12 @@ class LagunaAttention(nn.Module):
         self.D = cfg.head_dim
         kind = cfg.layer_types[layer]
         self.window = cfg.sliding_window if kind == SLIDING else None
-        self.inv_freq, self.rope_scale = rope_inv_freq(
-            cfg.rope_parameters[kind], self.D)
+        # a kind with no ``rope_parameters`` group rotates nothing (a
+        # position-free attention; LagunaConfig refuses it, the one-branch
+        # decoder of models/nemotron_h.py states it)
+        rope = cfg.rope_parameters.get(kind)
+        self.inv_freq, self.rope_scale = (
+            (None, 1.0) if rope is None else rope_inv_freq(rope, self.D))
         E = cfg.hidden_size
         self.q_proj = nn.Linear(E, self.H * self.D, bias=False)
         self.k_proj = nn.Linear(E, self.Hkv * self.D, bias=False)
@@ -310,7 +314,7 @@ class LagunaAttention(nn.Module):
             y = getattr(self, proj)(p[proj], x)
             if self.qk_norm:
                 y = self._head_norm(getattr(self, norm), p[norm], y)
-            return self._rope(y)
+            return y if self.inv_freq is None else self._rope(y)
 
         q = rotated("q_proj", "q_layernorm")
         k = rotated("k_proj", "k_layernorm")
@@ -396,6 +400,7 @@ def exit_log_probs(z):
 
 class Laguna(nn.Module):
     fp32_param_names = ("exit_gate",)
+    block = LagunaBlock         # what a layer is: (cfg, index) -> a module
 
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
@@ -403,7 +408,7 @@ class Laguna(nn.Module):
         self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.hidden_size,
                                          init_std=0.02)
         self.layers = nn.ModuleList(
-            [LagunaBlock(cfg, i) for i in range(cfg.num_hidden_layers)])
+            [self.block(cfg, i) for i in range(cfg.num_hidden_layers)])
         self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         if not cfg.tie_word_embeddings:
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,

@@ -37,7 +37,8 @@ def _bias_add(y: jax.Array, bias: Optional[jax.Array],
     return y + (b if data_format == "NHWC" else b[None, :, None, None])
 
 __all__ = [
-    "linear", "matmul", "conv2d", "conv_transpose2d", "relu", "leaky_relu",
+    "linear", "matmul", "conv2d", "conv_transpose2d", "relu", "relu2",
+    "leaky_relu",
     "gelu", "gelu_exact", "silu", "sigmoid", "tanh",
     "softmax", "log_softmax", "layer_norm", "batch_norm_stats",
     "batch_norm_apply", "dropout", "max_pool2d", "avg_pool2d",
@@ -151,6 +152,11 @@ def conv_transpose2d(x: jax.Array, weight: jax.Array,
 
 def relu(x: jax.Array) -> jax.Array:
     return jnp.maximum(x, 0)
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """Squared ReLU, ``max(x, 0) ** 2``."""
+    return jnp.square(jnp.maximum(x, 0))
 
 
 def leaky_relu(x: jax.Array, negative_slope: float = 0.01) -> jax.Array:

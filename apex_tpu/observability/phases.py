@@ -58,6 +58,12 @@ PHASES = (
     "conv.in_proj",        # u W_in: the three gates' projection
     "conv.mix",            # B * z, the taps along the sequence, C * c
     "conv.out_proj",       # (C * c) W_out
+    # transformer/mamba2.py, the Mamba-2 mixer (inside ``model``)
+    "mamba.in_proj",       # u W_in: z, x, B, C and dt in one projection
+    "mamba.conv",          # the causal taps over x, B, C, their bias, silu
+    "mamba.scan",          # softplus(dt), the decays and the chunked scan
+    "mamba.gate_norm",     # y * silu(z) and the RMSNorm over each group
+    "mamba.out_proj",      # y W_out
     # models/laguna.py, attention with ``qk_norm``
     "attn.qk_norm",        # RMSNorm over each head of q and of k, before RoPE
     # models/laguna.py, the looped stack (``total_ut_steps`` > 1)

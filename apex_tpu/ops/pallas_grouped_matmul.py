@@ -34,8 +34,13 @@ to the operands' type: the arithmetic of ``lax.ragged_dot`` with
 array between them.
 
 ``row_tile`` chooses the tile from shapes, and 0 where the kernel does not
-take them (K or N not a whole number of lane tiles, a row count no tile
-divides, another dtype): the caller keeps ``lax.ragged_dot`` there.
+take them (K or N neither whole lane tiles nor whole tiles and a half one, a
+row count no tile divides, another dtype): the caller keeps
+``lax.ragged_dot`` there.  A width that ends in half a lane tile (an expert
+width of 1856 = 14.5 x 128) is taken as it is, the leaves at their published
+shapes: a block spans the array's whole width, and inside the kernel the half
+tile is one more, narrower, matrix step (a masked load and store), so no
+padded copy of a weight or of the rows exists anywhere.
 ``moe_grouped_dot_calls_total{impl, tile}`` counts the products traced
 (docs/observability.md).
 """
@@ -91,13 +96,30 @@ def count_product(impl: str, tile: int, products: int = 1) -> None:
                  impl=impl, tile=str(tile)).inc(products)
 
 
+def _takes(K: int, N: int) -> bool:
+    """Widths the kernels take: whole lane tiles, the smaller of the two
+    with or without half a tile at its end (the stack's kernel cuts the
+    larger side into whole tiles)."""
+    return (min(K, N) >= LANES and K % (LANES // 2) == 0
+            and N % (LANES // 2) == 0 and max(K, N) % LANES == 0)
+
+
 def _columns(n: int) -> int:
     """Result columns a matrix step of the rows' kernel writes: the most
-    lane tiles under ``_COLUMNS`` that divide ``n``, so that the fp32
-    result of a step stays a fraction of the tile's."""
+    lane tiles under ``_COLUMNS`` that divide ``n``'s whole tiles, so that
+    the fp32 result of a step stays a fraction of the tile's."""
     tiles = n // LANES
     return LANES * max(c for c in range(1, _COLUMNS // LANES + 1)
                        if tiles % c == 0)
+
+
+def _chunks(n: int) -> tuple:
+    """``(start, width)`` of the matrix steps over ``n`` result columns:
+    runs of ``_columns(n)`` over the whole lane tiles, then the half tile
+    ``n`` may end in."""
+    whole, cols = n - n % LANES, _columns(n)
+    steps = [(c, cols) for c in range(0, whole, cols)]
+    return tuple(steps + ([(whole, n - whole)] if n > whole else []))
 
 
 def _rows_vmem(tm: int, K: int, N: int, itemsize: int) -> int:
@@ -118,6 +140,7 @@ def _stack_blocks(K: int, N: int) -> tuple:
         return LANES * max(
             c for c in range(1, tiles + 1) if tiles % c == 0
             and (c == 1 or c * LANES * other * 4 <= _ACCUMULATOR))
+    # (the side that is cut is whole lane tiles: ``_takes``)
     return (cut(K, N), N) if K >= N else (K, cut(N, K))
 
 
@@ -136,7 +159,7 @@ def row_tile(R: int, K: int, N: int, groups: int, dtype) -> int:
     which the launch then asks for."""
     dtype = jnp.dtype(dtype)
     if (dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-            or K % LANES or N % LANES or groups < 1):
+            or not _takes(K, N) or groups < 1):
         return 0
     for room, tiles in ((_VMEM_BUDGET, (512, 256, 128)),
                         (_VMEM_ASK, _ASK_TILES)):
@@ -224,7 +247,7 @@ def _inside(offsets, group, tile, tm):
 
 
 def _rows_kernel(offsets, group, read, tile, kind, x_ref, w_ref, o_ref, *,
-                 tm, cols, transposed):
+                 tm, transposed):
     i = pl.program_id(0)
     what = kind[i]
 
@@ -236,7 +259,7 @@ def _rows_kernel(offsets, group, read, tile, kind, x_ref, w_ref, o_ref, *,
     def _():
         inside = _inside(offsets, group[i], tile[i], tm)
         x = x_ref[...]
-        for c in range(0, o_ref.shape[1], cols):
+        for c, cols in _chunks(o_ref.shape[1]):
             at = (slice(None), slice(c, c + cols))
             if transposed:      # the block's rows are the result's columns
                 y = _dot(x, w_ref[0, c:c + cols, :], ((1,), (1,)))
@@ -264,11 +287,9 @@ def _rows_product(x, stack, scalars, tm: int, transposed: bool):
     R, A = x.shape
     G, K, N = stack.shape
     B = K if transposed else N
-    cols = _columns(B)
     need = _rows_vmem(tm, A, B, x.dtype.itemsize)
     return pl.pallas_call(
-        functools.partial(_rows_kernel, tm=tm, cols=cols,
-                          transposed=transposed),
+        functools.partial(_rows_kernel, tm=tm, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(R // tm + G - 1,),
@@ -397,13 +418,14 @@ def grouped_matmul(rows: jax.Array, stack: jax.Array, items,
     Differentiable in rows and stack."""
     R, K = rows.shape
     G, _, N = stack.shape
-    if (tile < 8 or R % tile or K % LANES or N % LANES or stack.shape[1] != K
+    if (tile < 8 or R % tile or not _takes(K, N) or stack.shape[1] != K
             or rows.dtype != stack.dtype
             or items[0][0].shape != (G + 1,)
             or items[0][1].shape != (R // tile + G - 1,)):
         raise ValueError(
             "grouped_matmul needs rows (R, K) and a stack (G, K, N) of one "
-            "dtype, K and N whole lane tiles, R a whole number of row tiles "
+            "dtype, K and N whole lane tiles (the smaller may end in half a "
+            "tile), R a whole number of row tiles "
             f"and the work items of G groups over them: got {rows.shape} "
             f"{rows.dtype}, {stack.shape} {stack.dtype}, tile {tile}")
     return _grouped(rows, stack, *items, tile)

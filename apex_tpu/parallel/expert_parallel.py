@@ -45,7 +45,9 @@ would move it is not part of this layer).  The
 auxiliary load-balancing loss (Switch eq. 4: E * sum_e f_e * P_e, fraction
 counted over all k assignments) is returned by ``forward`` when
 ``return_aux_loss`` — add ``aux_weight * aux`` to the task loss.
-``shared_hidden`` adds a SwiGLU expert that every token passes through.
+``shared_hidden`` adds an expert of the routed experts' own kind (SwiGLU
+where they are gated, ``activation`` between two matrices where they are
+not) that every token passes through.
 
 The work is written under the scopes ``moe.route`` / ``moe.dispatch`` /
 ``moe.experts`` / ``moe.combine`` (observability/phases.py), and
@@ -99,7 +101,8 @@ class ExpertParallelMLP(Module):
     (n, d, hidden) and ``w_out`` (n, hidden, d) for the ``n`` experts held
     (all E by default), sharded on the expert dim (see ``param_specs``);
     gated experts add ``w_gate`` (n, d, hidden); ``shared_hidden`` adds
-    ``shared`` = {w_gate, w_in, w_out} without the expert dim.
+    ``shared`` = {w_in, w_out} (and w_gate where the experts are gated)
+    without the expert dim.
     Call inside shard_map with tokens sharded over the same axis;
     outside any mesh the experts held run locally (module docstring).
 
@@ -177,10 +180,12 @@ class ExpertParallelMLP(Module):
             hs = self.shared_hidden
             ks = jax.random.split(k5, 3)
             p["shared"] = {
-                "w_gate": jax.random.normal(ks[0], (d, hs)) * s_in,
                 "w_in": jax.random.normal(ks[1], (d, hs)) * s_in,
                 "w_out": jax.random.normal(ks[2], (hs, d))
                 * (2.0 / hs) ** 0.5}
+            if self.expert_type == "swiglu":
+                p["shared"]["w_gate"] = (jax.random.normal(ks[0], (d, hs))
+                                         * s_in)
         return p
 
     def param_specs(self) -> Dict[str, P]:
@@ -192,7 +197,8 @@ class ExpertParallelMLP(Module):
         if self.router_bias:
             s["expert_bias"] = P()
         if self.shared_hidden:
-            s["shared"] = {"w_gate": P(), "w_in": P(), "w_out": P()}
+            s["shared"] = {k: P() for k in ("w_gate", "w_in", "w_out")
+                           if k != "w_gate" or self.expert_type == "swiglu"}
         return s
 
     def capacity(self, n_tokens: int) -> Optional[int]:
@@ -310,6 +316,14 @@ class ExpertParallelMLP(Module):
             h = getattr(F, self.activation)(gdot(xs, params["w_in"]))
         return gdot(h, params["w_out"])
 
+    def _shared(self, p, x2d):
+        """The expert every token passes through, in the routed experts'
+        kind."""
+        if self.expert_type == "swiglu":
+            return _swiglu(x2d, **p)
+        h = getattr(F, self.activation)(x2d @ p["w_in"].astype(x2d.dtype))
+        return h @ p["w_out"].astype(x2d.dtype)
+
     def _sorted_forward(self, params, x2d, want_aux):
         """The local path: (y (T, d), aux, counters)."""
         T, d = x2d.shape
@@ -349,7 +363,7 @@ class ExpertParallelMLP(Module):
                      "moe_dropped_assignments": held - jnp.sum(kept)}
         with jax.named_scope("moe.experts"):
             ys = self._grouped_mlp(params, xs, sizes_in, live[:, None])
-            shared = (_swiglu(x2d, **params["shared"])
+            shared = (self._shared(params["shared"], x2d)
                       if self.shared_hidden else None)
         with jax.named_scope("moe.combine"):
             y = jnp.zeros((T, d), jnp.float32).at[token].add(

@@ -9,5 +9,6 @@ from .attention import (dot_product_attention,
                         dot_product_attention_token_major,
                         MultiheadAttention)
 from .short_conv import GatedShortConv, gated_short_conv
+from .mamba2 import Mamba2Mixer, ssd_chunked
 from .ring_attention import ring_attention, ring_self_attention
 from .ulysses import ulysses_attention, ulysses_self_attention

@@ -21,6 +21,10 @@ The per-layer decoder with routed experts, from a published config file
       --model-config benchmark/configs/lfm2-8b-a1b.json
   python examples/gpt/main_amp.py --arch ouro -b 1 --block-size 8192 \
       --model-config benchmark/configs/ouro-2.6b.json
+and the one-branch decoder of models/nemotron_h.py (Mamba-2 mixers, routed
+relu2 experts, position-free attention) the same way:
+  python examples/gpt/main_amp.py --arch nemotron_h -b 1 --block-size 8192 \
+      --model-config benchmark/configs/nemotron3-nano-30b-a3b.json
 
 ``build(args)`` returns the model, mesh, state and jitted train step that
 ``main()`` loops over; the benchmark and the tests drive the same objects.
@@ -40,8 +44,9 @@ _repo = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 if os.path.isdir(os.path.join(_repo, "apex_tpu")) and _repo not in sys.path:
     sys.path.insert(0, _repo)
 
-# --arch values that models/laguna.py builds from a --model-config file
-PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe", "ouro")
+# --arch values built from a --model-config file: by models/laguna.py, and
+# (nemotron_h) by models/nemotron_h.py over the same parts
+PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe", "ouro", "nemotron_h")
 
 # enough structure to be learnable at tiny scale: a looping pangram
 _BUILTIN_TEXT = ("the quick brown fox jumps over the lazy dog. " * 200)
@@ -93,13 +98,19 @@ def parse_args(argv=None):
                         "with attention, a selection bias on the router, "
                         "a tied head) or ouro (dense layers applied "
                         "total_ut_steps times over the same weights, "
-                        "sandwich norms, a learned exit gate)")
+                        "sandwich norms, a learned exit gate); or "
+                        "nemotron_h, the one-branch decoder of "
+                        "models/nemotron_h.py (Mamba-2 mixers, routed relu2 "
+                        "experts with a shared one, attention without "
+                        "positions, by hybrid_override_pattern)")
     p.add_argument("--model-config", default=None, metavar="JSON",
-                   help="laguna, mellum, lfm2_moe, ouro: a config file "
+                   help="laguna, mellum, lfm2_moe, ouro, nemotron_h: a "
+                        "config file "
                         "with the published keys, whose model_type is "
                         "--arch (benchmark/configs/laguna-xs2.json, "
                         "mellum2-12b.json, lfm2-8b-a1b.json, "
-                        "ouro-2.6b.json); the sequence length is "
+                        "ouro-2.6b.json, nemotron3-nano-30b-a3b.json); the "
+                        "sequence length is "
                         "--block-size")
     p.add_argument("--n-kv-head", type=int, default=None,
                    help="grouped-query attention KV heads (llama; "
@@ -165,13 +176,16 @@ def _network(args, n_chars):
             raise SystemExit(f"--arch {args.arch}: {args.model_config} is "
                              f"a {file_cfg['model_type']!r} config")
         T = args.block_size or file_cfg.get("seq_len", 512)
-        cfg = models.LagunaConfig.from_dict(
+        config, network = ((models.NemotronHConfig, models.NemotronH)
+                           if args.arch == "nemotron_h"
+                           else (models.LagunaConfig, models.Laguna))
+        cfg = config.from_dict(
             file_cfg, max_position_embeddings=max(
                 T, file_cfg.get("max_position_embeddings", T)))
         if cfg.vocab_size < n_chars:
             raise SystemExit(f"the corpus has {n_chars} characters, the "
                              f"model's vocabulary {cfg.vocab_size}")
-        return models.Laguna(cfg), T
+        return network(cfg), T
     shapes = {"tiny": dict(n_layer=2, n_head=4, n_embd=64, block_size=64),
               "small": dict(n_layer=12, n_head=12, n_embd=768,
                             block_size=512),
@@ -377,7 +391,7 @@ def main(argv=None):
 
     if args.generate:
         if args.arch in PER_LAYER_ARCHS:
-            raise SystemExit("--generate: models/laguna.py has no cached "
+            raise SystemExit("--generate: the per-layer decoders have no cached "
                              "decoding (training and full forward only)")
         params, stoi = state[0], run.stoi
         prompt = run.text[:min(16, T // 2)]

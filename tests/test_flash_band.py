@@ -744,6 +744,38 @@ def test_v5e_compiles_the_grouped_products_at_a_block_that_asks_for_more_vmem(on
     assert sorted(names) == ["grouped_rows"] * 3 + ["grouped_rows_t"] * 3 + ["grouped_stack"] * 3
 
 
+def test_v5e_compiles_the_grouped_products_at_an_expert_width_of_14_and_a_half_lane_tiles(
+        one_chip, for_the_chip):
+    """``nemotron3-nano-30b-a3b``'s expert layer, forward and backward: non-gated
+    relu2 experts of 2688 x 1856 (14.5 lane tiles), 8 held of 128 at 6 a token,
+    the leaves at their published shapes.  The chip's compiler takes the six
+    kernels (2 forward, 4 backward), the half tile a narrower matrix step inside
+    them; nothing of the compiler's own grouped product is left, and no padded
+    copy of a weight stack (1920 wide) exists."""
+    from apex_tpu.ops import pallas_grouped_matmul as pgm
+    from apex_tpu.parallel.expert_parallel import ExpertParallelMLP
+    layer = ExpertParallelMLP(2688, 1856, 128, capacity_factor=None, top_k=6, expert_type="mlp",
+                              activation="relu2", router_type="sigmoid", routed_scaling=2.5,
+                              experts_held=(0, 8), shared_hidden=3712, row_buffer_factor=2.0,
+                              router_bias=True)
+    tokens = 8192
+    rows = 2 * tokens * 6 * 8 // 128
+    assert rows == 6144 and pgm._chunks(1856) == ((0, 896), (896, 896), (1792, 64))
+    assert pgm.row_tile(rows, 2688, 1856, 8, jnp.bfloat16) == 128
+    assert pgm.row_tile(rows, 1856, 2688, 8, jnp.bfloat16) == 128
+    shapes = jax.eval_shape(lambda k: layer.init(k)[0], jax.random.PRNGKey(0))
+    assert shapes["w_in"].shape == (8, 2688, 1856) and shapes["w_out"].shape == (8, 1856, 2688)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32 if s.shape[-1] == 128 else jnp.bfloat16,
+                                       sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((tokens, 2688), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)), (0, 1))).lower(
+        params, x).compile().as_text()
+    assert "ragged-dot" not in text and ",1920]" not in text
+    names = re.findall(r"%(grouped_rows_t|grouped_rows|grouped_stack)[.\d]* = ", text)
+    assert sorted(names) == ["grouped_rows"] * 2 + ["grouped_rows_t"] * 2 + ["grouped_stack"] * 2
+
+
 def test_v5e_compiles_a_grouped_head_of_64_through_the_head_major_kernels(one_chip, for_the_chip):
     """``lfm2-8b-a1b``'s attention call: 32 query heads over 8 K/V heads of 64
     from ``dot_product_attention_token_major``: the three flash kernels with

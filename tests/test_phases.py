@@ -155,6 +155,16 @@ def programs():
         text = jax.jit(jax.grad(lambda p: loop.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
             oparams).compile().as_text()
         out["loop"] = (_scopes(text), phases.instruction_phases(text))
+
+        # a one-branch decoder of Mamba-2 mixers (models/nemotron_h.py)
+        ssm = models.NemotronH(models.NemotronHConfig(
+            vocab_size=64, hidden_size=16, hybrid_override_pattern="MM", mamba_num_heads=2,
+            mamba_head_dim=8, ssm_state_size=8, n_groups=1, num_attention_heads=2,
+            num_key_value_heads=2, head_dim=8, chunk_size=8, head_chunk=32))
+        sparams, _ = ssm.init(jax.random.PRNGKey(5))
+        text = jax.jit(jax.grad(lambda p: ssm.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
+            sparams).compile().as_text()
+        out["mamba"] = (_scopes(text), phases.instruction_phases(text))
     finally:
         C.set_ledger(prev)
     return out
@@ -168,7 +178,9 @@ CASES = ([("mesh", s) for s in TRAIN_SCOPES + DDP_SCOPES]
             ("moe", "moe.combine"),
             ("conv", "conv.in_proj"), ("conv", "conv.mix"), ("conv", "conv.out_proj"),
             ("conv", "attn.qk_norm"),
-            ("loop", "loop"), ("loop", "loop.norm"), ("loop", "loss.head"), ("loop", "loss.exit")])
+            ("loop", "loop"), ("loop", "loop.norm"), ("loop", "loss.head"), ("loop", "loss.exit"),
+            ("mamba", "mamba.in_proj"), ("mamba", "mamba.conv"), ("mamba", "mamba.scan"),
+            ("mamba", "mamba.gate_norm"), ("mamba", "mamba.out_proj")])
 
 
 def test_every_scope_of_the_vocabulary_has_a_case():
