@@ -12,9 +12,20 @@ gathered into one row buffer of a static size; the experts run as grouped
 matrix products over the contiguous row groups (on the chip the Mosaic
 kernels of ``ops/pallas_grouped_matmul.py``, which visit no tile outside
 every group; ``lax.ragged_dot`` off it and for shapes they do not take);
-and a scatter-add with the gate weights brings the rows home.  Nothing of a
-``tokens x experts x slots`` shape exists.  Assignments to experts held
-elsewhere add nothing here — that is the layer of ONE chip of an
+and the rows come home weighted by the gates, summed in fp32 over a token's
+assignments and rounded once.  A row goes out by the sort, ``x2d[token]``.
+The way home has two forms, chosen from shapes (``ops/row_moves.py``).
+Where the ``k`` slots a token may fill are few beside the buffer's rows (a
+layer that holds a quarter of the experts or more at twice the expected
+rows), it is a gather too, by the sort's inverse (``_queue_positions``:
+where each assignment lies in the buffer): a token's ``k`` rows gathered and
+summed in one pass, each move the other's transpose under a ``custom_vjp``,
+so no scatter-add, a read-modify-write of HBM a row at a time, is traced for
+the rows in either direction.  Where most slots would be empty (a sixteenth
+of the experts held) it is ``zeros.at[token].add``, whose cost follows the
+buffer's rows and not the slots.  Nothing of a ``tokens x experts x slots``
+shape exists (the rows gathered home are ``k x tokens``).  Assignments to
+experts held elsewhere add nothing here — that is the layer of ONE chip of an
 expert-parallel group, without its exchange.  With ``capacity_factor=None``
 nothing is dropped; a capacity (``ceil(cf * T * k / E)`` rows an expert)
 drops what queues behind it, as Switch does.  The row buffer is
@@ -59,7 +70,8 @@ selection bias), ``moe_row_buffer_rows_total``, and
 ``moe_experts_held_total`` beside ``moe_router_experts_total``, whose ratio
 says which share of an expert-parallel group a step is, and
 ``moe_grouped_dot_calls_total{impl, tile}``, what implements each grouped
-product (docs/observability.md).
+product, and ``moe_row_move_calls_total{impl, move}``, what moves the rows
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -90,6 +102,40 @@ MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max",
 def _swiglu(x, w_gate, w_in, w_out):
     return (F.silu(x @ w_gate.astype(x.dtype))
             * (x @ w_in.astype(x.dtype))) @ w_out.astype(x.dtype)
+
+
+def _queue_positions(key, starts, n: int, rows: int):
+    """Where the stable sort by ``key`` puts each assignment, without the
+    sort: ``(at, place)``, both (A,) int32; ``place`` an assignment's rank
+    among those of its key, ``at = starts[key] + place`` its row of the
+    buffer, -1 where ``key`` is not under ``n`` (held elsewhere) or the row
+    not under ``rows`` (past the buffer's end).  It is the inverse of
+    ``argsort(key, stable=True)[:rows]`` and is written as no scatter and no
+    second sort: the ranks are running counts of a one-hot ``(n, A)``, taken
+    by two products with triangles of ones, inside blocks of 128 and over
+    the blocks' totals (0/1 and counts to 128 in bf16, sums in fp32: exact)."""
+    A, lanes = key.shape[0], 128
+    blocks = -(-A // lanes)
+    key = jnp.pad(key, (0, blocks * lanes - A), constant_values=n)
+    hot = key[None, :] == jnp.arange(n)[:, None]                  # (n, A)
+
+    def ones_above(m, strict):
+        i = jnp.arange(m)
+        return ((i[:, None] < i[None, :]) if strict
+                else (i[:, None] <= i[None, :])).astype(jnp.bfloat16)
+
+    inside = jnp.einsum("nbl,lm->nbm",
+                        hot.astype(jnp.bfloat16).reshape(n, blocks, lanes),
+                        ones_above(lanes, False),
+                        preferred_element_type=jnp.float32)
+    before = jnp.einsum("nb,bc->nc", inside[..., -1].astype(jnp.bfloat16),
+                        ones_above(blocks, True),
+                        preferred_element_type=jnp.float32)
+    count = (inside + before[..., None]).reshape(n, -1)     # inclusive, own key
+    place = jnp.sum(jnp.where(hot, count - 1.0, 0.0), axis=0).astype(jnp.int32)
+    at = place + starts[jnp.minimum(key, n - 1)]
+    at = jnp.where((key < n) & (at < rows), at, -1)
+    return at[:A], place[:A]
 
 
 class ExpertParallelMLP(Module):
@@ -326,6 +372,7 @@ class ExpertParallelMLP(Module):
 
     def _sorted_forward(self, params, x2d, want_aux):
         """The local path: (y (T, d), aux, counters)."""
+        from ..ops import row_moves
         T, d = x2d.shape
         k, n = self.top_k, self.n_held
         rows = T * min(k, n)
@@ -357,7 +404,19 @@ class ExpertParallelMLP(Module):
             sizes_in = jnp.clip(rows - starts, 0, sizes)
             weight = jnp.where(kept, gates.T.reshape(-1)[order], 0.0)
             token = order % T
-            xs = x2d[token]
+            by_gathers = row_moves.home_by_gathers(T * k, rows)
+            if by_gathers:
+                # the same by assignment: where the sort puts each, and its
+                # place in its expert's queue; the way home is a gather by it
+                at, queued = _queue_positions(key, starts, n, rows)
+                fits = at >= 0 if cap is None else (at >= 0) & (queued < cap)
+                gates_kept = jnp.where(fits.reshape(k, T).T, gates, 0.0)
+                xs = row_moves.gather(x2d, token, at)
+            else:
+                # (with the transposes autodiff writes of the two lines)
+                row_moves.count_move("gather", "rows_from_tokens", 2)
+                row_moves.count_move("scatter_add", "tokens_from_rows", 2)
+                xs = x2d[token]
             stats = {"moe_assignments_held": held,
                      "moe_expert_load_max": jnp.max(sizes),
                      "moe_dropped_assignments": held - jnp.sum(kept)}
@@ -366,11 +425,16 @@ class ExpertParallelMLP(Module):
             shared = (self._shared(params["shared"], x2d)
                       if self.shared_hidden else None)
         with jax.named_scope("moe.combine"):
-            y = jnp.zeros((T, d), jnp.float32).at[token].add(
-                ys.astype(jnp.float32) * weight[:, None])
-            if shared is not None:
-                y = y + shared.astype(jnp.float32)
-        return y.astype(x2d.dtype), aux, stats
+            if by_gathers:
+                y = row_moves.combine(ys, gates_kept, shared, token, weight,
+                                      at)
+            else:
+                y = jnp.zeros((T, d), jnp.float32).at[token].add(
+                    ys.astype(jnp.float32) * weight[:, None])
+                if shared is not None:
+                    y = y + shared.astype(jnp.float32)
+                y = y.astype(x2d.dtype)
+        return y, aux, stats
 
     def _count_traced_layer(self, rows: int) -> None:
         """Host side, once a trace of the sorted dispatch: the router's

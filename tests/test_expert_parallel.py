@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from apex_tpu.ops import row_moves
 from apex_tpu.parallel import expert_parallel as ep
 from conftest import assert_trees_close
 
@@ -257,3 +258,88 @@ def test_moe_top_k_validation():
         ep.ExpertParallelMLP(8, 16, 4, top_k=5)
     with pytest.raises(ValueError, match="expert_type"):
         ep.ExpertParallelMLP(8, 16, 4, expert_type="dense")
+
+
+# -- the sorted dispatch's row moves (ops/row_moves.py) ----------------------
+
+def _row_move_calls():
+    from apex_tpu.observability.metrics import get_registry
+    c = get_registry().get("moe_row_move_calls_total")
+    return ({tuple(v for _, v in sorted(k)): child.value
+             for k, child in c.children().items()} if c else {})
+
+
+def _sorted_layer(expert_type, shared, cap=None, held=4):
+    layer = ep.ExpertParallelMLP(
+        128, 128, 16, capacity_factor=cap, top_k=4, expert_type=expert_type,
+        activation="relu", experts_held=(4, held), shared_hidden=shared,
+        row_buffer_factor=2.0)
+    params = layer.init(jax.random.PRNGKey(3))[0]
+    x = jax.random.normal(jax.random.PRNGKey(4), (128, 128), jnp.float32)
+    return layer, params, x
+
+
+@pytest.mark.parametrize("expert_type,shared,cap,held", [
+    ("swiglu", None, None, 4), ("swiglu", 128, None, 4), ("mlp", None, None, 4),
+    ("mlp", 256, None, 4), ("swiglu", 128, 1.0, 4), ("swiglu", 128, None, 1)],
+    ids=["gated", "gated_shared", "ungated", "ungated_shared",
+         "gated_shared_capacity", "a_sixteenth_held"])
+def test_sorted_forward_by_gathers_equals_sorted_forward_by_scatter_add(
+        monkeypatch, expert_type, shared, cap, held):
+    """``_sorted_forward`` forward and backward, the grouped kernels forced
+    (the production gating, interpreted off the chip), its way home by
+    gathers against its way home by ``.at[token].add``, whichever the shapes
+    would choose: values, what was dropped and every gradient, the router's
+    through the gate weights among them; no scatter is traced for the rows in
+    either direction of the first."""
+    import re
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "prod")
+    monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
+    layer, params, x = _sorted_layer(expert_type, shared, cap, held)
+    rows = 2 * 128 * 4 * held // 16
+    assert row_moves.home_by_gathers(128 * 4, rows) == (held == 4)
+
+    def loss(by_gathers):
+        def of(p, x):
+            # (a fresh function a form: the layer's trace is cached)
+            monkeypatch.setattr(row_moves, "home_by_gathers", lambda *_: by_gathers)
+            y, aux, stats = layer._sorted_forward(p, x, True)
+            return jnp.sum(y ** 2) + aux, stats["moe_dropped_assignments"]
+        return jax.value_and_grad(of, (0, 1), has_aux=True)
+
+    (want, want_dropped), want_g = loss(False)(params, x)
+    (got, got_dropped), got_g = loss(True)(params, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert int(got_dropped) == int(want_dropped) and (int(got_dropped) > 0) == (cap is not None)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g), jax.tree_util.tree_leaves(want_g)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5 * float(jnp.abs(b).max()))
+    # the scatter-add left is the router's own (top-k's gradient), (T, E);
+    # the other form keeps the rows' two and the gates' one
+    for by_gathers, more in ((True, set()), (False, {"f32[128,128]", "f32[512]"})):
+        text = str(jax.make_jaxpr(lambda p, x: loss(by_gathers)(p, x)[1])(params, x))
+        assert f"f32[{rows},128]" in text
+        assert set(re.findall(r"(\w+\[[\d,]*\]) = scatter", text)) == {"f32[128,16]"} | more
+
+
+def test_row_moves_are_counted_where_they_are_traced():
+    """4 a traced layer and gradient, each move and its transpose; 2 a traced
+    forward; and the layer whose slots are mostly empty says that its way
+    home is the scatter-add."""
+    layer, params, x = _sorted_layer("swiglu", None)
+    loss = lambda p, x: jnp.sum(layer(p, x) ** 2)
+    sparse, sparse_params, _ = _sorted_layer("swiglu", None, held=1)
+
+    def grown(trace):
+        before = _row_move_calls()
+        trace()
+        return {k: v - before.get(k, 0) for k, v in _row_move_calls().items()
+                if v != before.get(k, 0)}
+
+    assert grown(lambda: jax.make_jaxpr(lambda p, x: loss(p, x))(params, x)) == {
+        ("gather", "rows_from_tokens"): 1, ("gather", "tokens_from_rows"): 1}
+    assert grown(lambda: jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x), (0, 1)))(
+        params, x)) == {("gather", "rows_from_tokens"): 2, ("gather", "tokens_from_rows"): 2}
+    assert grown(lambda: jax.make_jaxpr(lambda p, x: jnp.sum(sparse(p, x)))(
+        sparse_params, x)) == {("gather", "rows_from_tokens"): 2,
+                               ("scatter_add", "tokens_from_rows"): 2}

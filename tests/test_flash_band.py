@@ -665,6 +665,35 @@ def test_v5e_compiles_the_flash_kernels_at_the_encoder_cells_widths(one_chip, fo
     assert "bf16[128,512,128]" not in text and "f32[128,512,128]" not in text
 
 
+def _the_rows_way_home(text, tokens, k, width, rows):
+    """How the compiled expert layer brings its rows home (PR 45).  Where the
+    ``k * tokens`` slots are few beside the buffer's rows: by the compiler's
+    own gather fusion of the slots (the gather's transpose here; the combine's
+    too where the loss reads its value), each read by ONE fused pass (no fp32
+    array of the slots' size: the convert rides in the pass that sums them),
+    the buffer cut by columns into sources of at most 48 MiB, which the
+    compiler keeps in VMEM, and no scatter at a row's width in either
+    direction.  Where most slots
+    would be empty: by the scatter-add, and no array of the slots' size."""
+    import re
+    from apex_tpu.ops import row_moves
+    at_width = [line for line in text.splitlines() if re.search(r" scatter\(", line)
+                and f",{width}]" in line.split(" scatter(")[0]]
+    entry = text[text.index("ENTRY"):]
+    # the buffer is the source of one gather a chunk of columns that fits VMEM
+    chunks = row_moves._column_chunks(rows, width, 2)
+    wide = width // chunks
+    slots = re.findall(rf"= bf16\[{k * tokens},{wide}\]\S* fusion\(.*kind=kCustom", entry)
+    if row_moves.home_by_gathers(k * tokens, rows):
+        assert not at_width, at_width[0][:200]
+        for shape in (f"[{k * tokens},{wide}]", f"[{k},{tokens},{wide}]", f"[{tokens},{k},{wide}]"):
+            assert "f32" + shape not in text, shape
+        assert chunks <= len(slots) <= 2 * chunks, (chunks, len(slots))
+        assert rows * wide * 2 <= row_moves._SOURCE_BYTES
+    else:
+        assert at_width and not slots and f"[{k * tokens},{wide}]" not in text
+
+
 @pytest.mark.parametrize("cell,width,hidden,scored,router,shared,tokens", [
     ("laguna-xs2", 2048, 512, 256, "sigmoid", 512, 16384),
     ("mellum2-12b", 2304, 896, 64, "softmax", None, 8192)])
@@ -715,6 +744,7 @@ def test_v5e_compiles_the_grouped_expert_products(one_chip, for_the_chip, cell, 
     assert len(forward) == 3 and len(backward) == 6, kernels
     assert sum("grouped_stack" in k for k in backward) == 3, kernels
     assert sum("grouped_rows_t" in k for k in backward) == 3, kernels
+    _the_rows_way_home(text, tokens, 8, width, rows)
 
 
 def test_v5e_compiles_the_grouped_products_at_a_block_that_asks_for_more_vmem(one_chip,
@@ -742,6 +772,7 @@ def test_v5e_compiles_the_grouped_products_at_a_block_that_asks_for_more_vmem(on
     assert "ragged-dot" not in text
     names = re.findall(r"%(grouped_rows_t|grouped_rows|grouped_stack)[.\d]* = ", text)
     assert sorted(names) == ["grouped_rows"] * 3 + ["grouped_rows_t"] * 3 + ["grouped_stack"] * 3
+    _the_rows_way_home(text, tokens, 4, 2048, 2 * tokens * 4 * 8 // 32)
 
 
 def test_v5e_compiles_the_grouped_products_at_an_expert_width_of_14_and_a_half_lane_tiles(
@@ -774,6 +805,7 @@ def test_v5e_compiles_the_grouped_products_at_an_expert_width_of_14_and_a_half_l
     assert "ragged-dot" not in text and ",1920]" not in text
     names = re.findall(r"%(grouped_rows_t|grouped_rows|grouped_stack)[.\d]* = ", text)
     assert sorted(names) == ["grouped_rows"] * 2 + ["grouped_rows_t"] * 2 + ["grouped_stack"] * 2
+    _the_rows_way_home(text, tokens, 6, 2688, rows)
 
 
 def test_v5e_compiles_a_grouped_head_of_64_through_the_head_major_kernels(one_chip, for_the_chip):

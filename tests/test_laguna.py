@@ -256,7 +256,9 @@ def test_the_sorted_dispatch_builds_no_token_by_expert_by_slot_operand():
     params, x = _skewed(layer)
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer(p, x) ** 2)))(params)
     tokens, experts, k, d, h = 40, 16, 4, 8, 16
-    largest = max(tokens * k * experts, tokens * k * max(d, h))   # sizes compare, row buffer
+    # sizes compare (the queue positions count it in blocks of 128, against a
+    # 128 x 128 triangle of ones), row buffer
+    largest = max(-(-tokens * k // 128) * 128 * experts, 128 * 128, tokens * k * max(d, h))
 
     def sizes(jp):
         for eqn in jp.eqns:
