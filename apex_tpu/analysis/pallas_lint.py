@@ -281,11 +281,12 @@ def collect_kernel_sites() -> List[KernelSite]:
     from ..ops import (pallas_adam, pallas_common, pallas_flash_attention,
                        pallas_grouped_matmul, pallas_lamb,
                        pallas_layer_norm, pallas_multi_tensor, pallas_rope,
-                       pallas_syncbn)
+                       pallas_ssd, pallas_syncbn)
 
     _clear_jit_caches(pallas_adam, pallas_flash_attention,
                       pallas_grouped_matmul, pallas_lamb, pallas_layer_norm,
-                      pallas_multi_tensor, pallas_rope, pallas_syncbn)
+                      pallas_multi_tensor, pallas_rope, pallas_ssd,
+                      pallas_syncbn)
     sites: List[KernelSite] = []
     rng = np.random.RandomState(18)
     f32 = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
@@ -345,6 +346,13 @@ def collect_kernel_sites() -> List[KernelSite]:
         ang = f32(64, 32)
         pallas_rope.rope_token_major(
             f32(2, 64, 3 * 128), jnp.cos(ang), jnp.sin(ang), 128)
+        # the selective scan's pair: a chunk of a group of heads a step,
+        # two chunks of two groups of two heads sharing a lane tile, the
+        # backward's index maps walking the chunks in reverse
+        xs, bc = f32(1, 256, 4, 64), f32(1, 256, 2, 128)
+        jax.grad(lambda a: jnp.sum(pallas_ssd.ssd_scan(
+            a, np.abs(f32(1, 256, 4)) * 0.1, -np.abs(f32(4)), bc, bc, f32(4),
+            128)))(xs)
         # the grouped products, forward and both gradients: their index
         # maps look blocks up in work items computed from the groups'
         # sizes, so one trace a representative split of the rows (all in

@@ -33,15 +33,28 @@ running sum inside a chunk (every exponent below is <= 0),
 
 Decays are float32 from a cumulative sum of ``delta A``; the products take
 their operands in the compute type (what ``x`` came in) and accumulate in
-float32.  The backward is autodiff's of this form; the block's
-rematerialization (``models/_remat.py``) decides what of it is kept.
+float32.
+
+**Who runs it** (:func:`selective_scan`, the one call of the mixer).  Where
+``ops.dispatch.pallas_enabled()`` and the shapes fill tiles (``chunk``, ``N``
+and a group's ``R P`` channels multiples of 128, ``P`` 64 or a multiple of
+128, ``T % chunk == 0``: ``ops.pallas_ssd.takes``) the same form runs as a
+Pallas kernel pair, ``ops/pallas_ssd.py``: a chunk's decays and scores live in
+VMEM only, the state goes from chunk to chunk in a VMEM scratch, and the
+backward is a kernel that walks the chunks in reverse from each chunk's saved
+entering state.  Everything else (every backend but the TPU, the tiny test
+shapes) runs :func:`ssd_chunked` as XLA compiles it, and its backward is
+autodiff's of this form.  Either way the block's rematerialization
+(``models/_remat.py``) decides what is kept: nothing of the scan, so a
+rematerialized block runs its forward twice.
 
 ``A_log``, ``dt_bias``, ``D``, the taps, their bias and the gain meet float32
 values and stay float32 under amp (``fp32_param_names``).  Scopes
 ``mamba.in_proj`` / ``mamba.conv`` / ``mamba.scan`` / ``mamba.gate_norm`` /
 ``mamba.out_proj`` (observability/phases.py); ``mamba_mixers_total{heads,
-state, groups}`` and ``ssd_scan_calls_total{impl, chunk}`` count what a
-traced program holds (docs/observability.md).
+state, groups}`` and ``ssd_scan_calls_total{impl, chunk}`` (``impl`` is
+``pallas`` or ``chunked_xla``) count what a traced program holds
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -54,8 +67,10 @@ from jax import lax
 
 from ..nn.layers import Linear
 from ..nn.module import Module
+from ..ops.pallas_common import token_tile_axes
 
-__all__ = ["Mamba2Mixer", "ssd_chunked", "causal_conv_silu", "gated_group_norm"]
+__all__ = ["Mamba2Mixer", "selective_scan", "ssd_chunked", "causal_conv_silu",
+           "gated_group_norm"]
 
 
 def ssd_chunked(x, dt, A, B, C, D, chunk: int):
@@ -109,6 +124,22 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int):
     return y.reshape(b, T, H, P)
 
 
+def selective_scan(x, dt, A, B, C, D, chunk: int):
+    """:func:`ssd_chunked`'s arguments and result, by the kernel pair where
+    the dispatch and the shapes allow it (module docstring); counts the call
+    under what implements it."""
+    from ..observability.metrics import get_registry
+    from ..ops import dispatch, pallas_ssd
+    kernel = dispatch.pallas_enabled() and pallas_ssd.takes(x, B, C, chunk)
+    get_registry().counter(
+        "ssd_scan_calls_total",
+        help="selective state-space scans traced, by what implements them "
+        "and the chunk").labels(
+            impl="pallas" if kernel else "chunked_xla", chunk=str(chunk)).inc()
+    return (pallas_ssd.ssd_scan if kernel else ssd_chunked)(
+        x, dt, A, B, C, D, chunk)
+
+
 def causal_conv_silu(xbc, taps, bias):
     """``xbc`` (b, T, c); ``taps`` (L, c), a tap a row; ``bias`` (c,)
     -> ``silu(conv(xbc) + bias)``, depthwise and causal (zero before a row's
@@ -124,10 +155,13 @@ def causal_conv_silu(xbc, taps, bias):
 def gated_group_norm(y, z, gain, groups: int, eps: float):
     """``RMSNorm(y * silu(z))`` over each of ``groups`` runs of the last axis
     apart, one ``gain`` over the whole axis; float32 inside, -> ``z``'s
-    dtype."""
+    dtype.  The groups are cut in the view ``(b, T / 8, 8, groups, d)``
+    (``token_tile_axes``): a reshape of ``(b, T, groups * d)`` to
+    ``(b, T, groups, d)`` is a relayout on the TPU where ``y`` comes from a
+    kernel, which fixes its layout."""
     zf = z.astype(jnp.float32)
     g = y.astype(jnp.float32) * (zf * jax.nn.sigmoid(zf))
-    parts = g.reshape(*g.shape[:-1], groups, -1)
+    parts = g.reshape(*token_tile_axes(*g.shape[:2]), groups, -1)
     parts = parts * lax.rsqrt(jnp.mean(parts * parts, -1, keepdims=True) + eps)
     return (parts.reshape(g.shape) * gain.astype(jnp.float32)).astype(z.dtype)
 
@@ -181,10 +215,6 @@ class Mamba2Mixer(Module):
                     help="Mamba-2 mixers traced, by heads, state size and "
                     "groups").labels(heads=str(self.H), state=str(self.N),
                                      groups=str(self.G)).inc()
-        reg.counter("ssd_scan_calls_total",
-                    help="selective state-space scans traced, by what "
-                    "implements them and the chunk").labels(
-                        impl="chunked_xla", chunk=str(self.chunk)).inc()
         b, T, _ = u.shape
         d_in, gn = self.d_in, self.G * self.N
         with jax.named_scope("mamba.in_proj"):
@@ -198,7 +228,7 @@ class Mamba2Mixer(Module):
             dt = jax.nn.softplus(
                 zxbcdt[..., d_in + self.conv_dim:].astype(jnp.float32)
                 + p["dt_bias"].astype(jnp.float32))
-            y = ssd_chunked(
+            y = selective_scan(
                 xbc[..., :d_in].reshape(b, T, self.H, self.P), dt,
                 -jnp.exp(p["A_log"].astype(jnp.float32)),
                 xbc[..., d_in:d_in + gn].reshape(b, T, self.G, self.N),

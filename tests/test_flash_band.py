@@ -828,3 +828,48 @@ def test_v5e_compiles_a_grouped_head_of_64_through_the_head_major_kernels(one_ch
     assert set(paths) == {"flash"}
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert "bf16[16,8192,64]" in text and "8192,8192]" not in text
+
+
+@pytest.mark.parametrize("mode,kernels", [
+    ("dots", ["ssd_fwd", "ssd_fwd", "ssd_bwd"]), (None, ["ssd_fwd", "ssd_bwd"])])
+def test_v5e_compiles_a_mamba_mixers_scan_as_the_kernel_pair(one_chip, for_the_chip, mode, kernels):
+    """One Mamba-2 block at ``nemotron3-nano-30b-a3b``'s widths (64 heads of
+    64, 8 groups, state 128, chunks of 128, 1 x 8192 tokens), forward and
+    backward: every Mosaic kernel of the step stands under ``mamba.scan``, the
+    forward, under a ``remat`` its replay (which also writes the chunks'
+    entering states), and the backward under a transposed scope; no array of
+    ``(.., 128, 128)`` decays or scores of any type, which is what XLA's form
+    of the scan writes; the counter names the kernels."""
+    import re
+    from apex_tpu.models import _remat
+    from apex_tpu.observability.metrics import get_registry
+    from apex_tpu.observability.phases import instruction_phases
+    from apex_tpu.transformer import mamba2
+    mixer = mamba2.Mamba2Mixer(2688, 64, 64, 128, 8, taps=4, chunk=128)
+    shapes = jax.eval_shape(lambda: mixer.init(jax.random.PRNGKey(0))[0])
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: jax.ShapeDtypeStruct(
+            s.shape, jnp.float32 if path[0].key in mixer.fp32_param_names else jnp.bfloat16,
+            sharding=one_chip), shapes)
+    u = jax.ShapeDtypeStruct((1, 8192, 2688), jnp.bfloat16, sharding=one_chip)
+    block = _remat.wrap_block(lambda p, x: x + mixer(p, x), mode)
+
+    def loss(p, x):
+        with jax.named_scope("model"):
+            return jnp.sum(block(p, x).astype(jnp.float32) ** 2)
+
+    counted = lambda: get_registry().counter("ssd_scan_calls_total").labels(
+        impl="pallas", chunk="128").value
+    before = counted()
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, u).compile().as_text()
+    assert counted() > before
+    assert not re.search(r"\[[\d,]*128,128\]", text)
+    phases = instruction_phases(text)
+    found = []
+    for name in re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                           text[text.index("ENTRY"):]):
+        path, backward = phases[name]
+        assert "mamba.scan" in path, (name, path)
+        found.append((re.sub(r"[.\d]+$", "", name), backward))
+    assert [k for k, _ in found] == kernels
+    assert [b for _, b in found] == [False] + [True] * (len(kernels) - 1)

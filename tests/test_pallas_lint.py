@@ -108,8 +108,9 @@ def test_real_kernel_family_lints_clean():
     """Every pallas_call the ops package launches — Adam (both
     write-out arities), LAMB stages, layer-norm fwd/bwd, the
     multi-tensor family, fused BN apply fwd/bwd, flash attention
-    fwd/dq/dkv on head-major and on token-major, grouped operands, and
-    the rotary pass — satisfies the block/index/alias preconditions."""
+    fwd/dq/dkv on head-major and on token-major, grouped operands, the
+    rotary pass, and the selective scan's forward and backward —
+    satisfies the block/index/alias preconditions."""
     sites, problems = pallas_lint.lint_pallas_kernels()
     assert problems == []
     names = {s.name for s in sites}
@@ -118,7 +119,7 @@ def test_real_kernel_family_lints_clean():
     for expected in ("_adam_kernel", "_stage1_kernel", "_stage2_kernel",
                      "_scale_kernel", "_axpby_kernel", "_l2norm_kernel",
                      "_dq_kernel", "_dkv_kernel", "_kernel", "_rows_kernel",
-                     "_stack_kernel"):
+                     "_stack_kernel", "_fwd_kernel", "_bwd_kernel"):
         assert expected in names, (expected, sorted(names))
     assert len(sites) >= 12, [s.describe() for s in sites]
     # token-major launches: a (1, blk, hb * D) block of a (B, T, H * D)
