@@ -281,12 +281,11 @@ def collect_kernel_sites() -> List[KernelSite]:
     from ..ops import (pallas_adam, pallas_common, pallas_flash_attention,
                        pallas_grouped_matmul, pallas_lamb,
                        pallas_layer_norm, pallas_multi_tensor, pallas_rope,
-                       pallas_ssd, pallas_syncbn)
+                       pallas_ssd)
 
     _clear_jit_caches(pallas_adam, pallas_flash_attention,
                       pallas_grouped_matmul, pallas_lamb, pallas_layer_norm,
-                      pallas_multi_tensor, pallas_rope, pallas_ssd,
-                      pallas_syncbn)
+                      pallas_multi_tensor, pallas_rope, pallas_ssd)
     sites: List[KernelSite] = []
     rng = np.random.RandomState(18)
     f32 = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
@@ -315,13 +314,6 @@ def collect_kernel_sites() -> List[KernelSite]:
         pallas_multi_tensor.multi_tensor_scale(tree, 2.0)
         pallas_multi_tensor.multi_tensor_axpby(1.0, 2.0, tree, tree)
         pallas_multi_tensor.multi_tensor_l2norm(tree)
-        # fused BN apply fwd + bwd (NCHW rows, per-row stat columns)
-        x4 = f32(2, 4, 6, 6)
-        mean4, var4 = f32(4), np.abs(f32(4)) + 0.5
-        w4, b4 = f32(4), f32(4)
-        jax.grad(lambda xx: jnp.sum(
-            pallas_syncbn.batch_norm_apply_fused(
-                xx, mean4, var4, w4, b4, 1e-5)))(x4)
         # flash attention fwd + bwd (the 3-kernel family with its
         # blocked T x D streaming)
         q = f32(1, 2, 128, 64)

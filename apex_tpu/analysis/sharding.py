@@ -48,7 +48,7 @@ points actually trace):
 Consumers (wired through :mod:`.rules` and the exporters):
 
 - :func:`entry_point_sharding_record` — the **replication ledger**, a
-  schema-v13 ``kind: sharding`` record per train entry point:
+  ``kind: sharding`` record per train entry point:
   ``replicated_bytes`` is what the ZeRO-2/3 stages bring down.
 - :func:`check_shard_map_specs` — spec-vs-mesh consistency (axis-name
   existence, divisibility, replicated-output claims the propagated
@@ -708,7 +708,7 @@ def divergent_output_claims(eqn,
 # -- the replication ledger ----------------------------------------------
 
 def entry_point_sharding_record(ep, top_n: int = 8) -> Dict[str, Any]:
-    """The replication ledger for one entry point, as a schema-v13
+    """The replication ledger for one entry point, as a
     ``kind: sharding`` record.
 
     ``argument_bytes`` counts the shard_map body's LOCAL operands (incl.
@@ -760,7 +760,7 @@ def entry_point_sharding_record(ep, top_n: int = 8) -> Dict[str, Any]:
     argument_bytes = sum(a.argument_bytes for a in analyses)
     replicated = sum(a.replicated_bytes for a in analyses)
     unique = sum(a.unique_bytes for a in analyses)
-    # schema v15: zero EPs name their stage in the registry name
+    # zero EPs name their stage in the registry name
     # (ddp_resnet18_o2_zero3, ddp_mlp_overlap_zero2) — stamp it so the
     # ledger says which stage its replicated_bytes claim measured
     zero_m = re.search(r"zero([123])", ep.name)

@@ -95,7 +95,7 @@ RECOVERY_ACTION_KINDS = (
     "drain", "undrain",
     "cooldown_shorten", "cooldown_extend")
 
-# why a recovery/exit happened, when a record says (schema v7):
+# why a recovery/exit happened, when a record says:
 # fault = an injected/real replica death, verdict = a supervisor
 # anomaly triggered the rollback, preemption = a planned SIGTERM /
 # maintenance notice honored at a step boundary.  Duplicated
@@ -831,7 +831,7 @@ class ElasticTrainer:
         return out
 
     def record(self, **extra) -> Dict[str, Any]:
-        """The training-side ``kind: recovery`` record (schema v7:
+        """The training-side ``kind: recovery`` record (the envelope
         plus ``cause``/``preempted`` and — when a pipeline is
         attached — its ``data_state`` census, so the record names the
         exact sample-stream position the run stood at)."""

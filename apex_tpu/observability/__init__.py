@@ -29,17 +29,13 @@ What the package holds (docs/observability.md), by module:
   nonfinite counts, abs-max, norms, underflow share, overflow
   attribution, the cross-replica divergence digest), in-graph with no
   host sync; ``kind: numerics`` records.
-- ``timeline`` — the stdlib-only Chrome-trace parser over what
-  ``jax.profiler.start_trace`` writes: device busy time, top kernels,
-  compute / collective / gap split and a measured overlap fraction;
-  ``kind: profile`` records and the server's ``/profilez`` capture.
 - ``supervisor`` — the host-side training-run supervisor (stall, loss
   spike, NaN, throughput regression, replica divergence,
   recompilation storm) over each step's already-flushed signals;
   ``wrap_step`` is an identity; ``kind: run`` records.
 - ``server`` — a stdlib ``http.server`` serving ``/healthz``,
   ``/metricsz`` (Prometheus exposition), ``/statusz``, ``/flightz``,
-  ``/tracez``, ``/compilez``, ``/tenantz``, ``/profilez`` off a live
+  ``/tracez``, ``/compilez``, ``/tenantz`` off a live
   registry / ring / recorder.
 - ``exporters`` — schema-versioned JSONL, Prometheus text exposition,
   and one validator per record ``kind`` the library produces.
@@ -71,7 +67,6 @@ from .supervisor import RunSupervisor, SupervisorConfig
 from . import metrics
 from . import tracing
 from . import flightrec
-from . import timeline
 from . import exporters
 from . import costmodel
 from . import memory
@@ -96,7 +91,7 @@ __all__ = [
     "CompilationLedger", "instrumented_jit", "diff_signatures",
     "get_ledger", "set_ledger",
     "ObservabilityServer", "RunSupervisor", "SupervisorConfig",
-    "metrics", "tracing", "flightrec", "timeline",
+    "metrics", "tracing", "flightrec",
     "exporters", "costmodel", "memory", "numerics", "server",
     "supervisor", "compilation",
 ]

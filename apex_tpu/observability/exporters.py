@@ -32,82 +32,13 @@ __all__ = ["SCHEMA_VERSION", "TENANT_COUNTS", "CLASS_COUNTS",
            "validate_fleet_record", "validate_trace_record",
            "validate_memory_record", "validate_numerics_record",
            "validate_run_record", "validate_recovery_record",
-           "validate_profile_record", "validate_sharding_record",
+           "validate_sharding_record",
            "validate_telemetry_record", "validate_telemetry_jsonl"]
 
-# What each version added to the record kinds:
-# v2: ``kind: fleet`` records REQUIRE ``trace_id`` (the fleet-record
-# <-> request-trace join key) and ``kind: trace`` records exist.
-# v3: ``kind: memory`` records exist (cost-model/memory-plan dumps).
-# v4: ``kind: numerics`` records exist (gradient-health dumps from
-# ``NumericsMonitor.to_record``).
-# v5: ``kind: run`` records exist (training-run supervisor verdicts
-# from ``RunSupervisor.record``); ``kind: fleet`` records MAY carry
-# the SLO/goodput fields (``goodput_tokens_per_s`` /
-# ``slo_attainment`` / ``tokens_within_slo`` / ``deadline_exceeded`` /
-# ``deadline_last_sweep``), validated whenever present at any version.
-# v6: ``kind: recovery`` records exist (telemetry→action controller
-# snapshots from ``fleet.recovery.RecoveryLog.record`` — the elastic
-# training controller and the serving SLO-feedback controller);
-# ``kind: fleet`` records MAY carry the ``mttr`` aggregate, validated
-# whenever present.
-# v7: preemption-safe deterministic resume.  ``kind: recovery``
-# records gain ``cause`` (one of RECOVERY_CAUSES — ``preemption`` is
-# the planned-SIGTERM exit), ``preempted`` (bool) and ``data_state``
-# (the checkpointed sample-stream census:
-# samples_consumed/epoch/cursor plus the shard identity), all
-# validated whenever present; RECOVERY_ACTION_KINDS grows
-# ``preempt_snapshot`` (the coordinated emergency snapshot at the
-# step boundary).
-# v8: ``kind: profile`` records exist (the Chrome-trace
-# device-timeline attribution from ``observability.timeline``, via the
-# ``/profilez`` endpoint): span/busy/compute/collective/gap/overlap
-# split in ms plus a MEASURED ``measured_overlap_fraction`` from
-# actual kernel-interval overlap, internally cross-checked by
-# ``validate_profile_record``.  Serving profiles may carry the KV
-# fragmentation fields (``kv_cache_bytes`` / ``kv_waste_bytes`` /
-# ``kv_utilization``), validated whenever present.
-# v10: the compilation plane: ``supervisor`` anomaly kinds grow
-# ``recompilation_storm``.
-# v11: the tenant plane.  ``kind: fleet`` records carry the per-tenant
-# SLO rollup — a ``tenants`` object keyed by tenant name whose buckets
-# hold the TENANT_COUNTS tallies plus ``slo_attainment`` /
-# ``goodput_tokens_per_s`` (same nullability/range contract as the
-# fleet-level pair), and ``tenants_dropped`` (tenant ids folded into
-# the overflow bucket by the label-cardinality cap).  Validated
-# whenever present; REQUIRED on fresh v11 fleet records — a fleet
-# snapshot that cannot say whose requests it served cannot answer
-# "which tenant's p99 regressed".  Untagged requests stay out of the
-# map, so per-tenant sums are <= the fleet totals, never ==.
-# v13: the sharding plane.  ``kind: sharding`` records exist (the
-# static replication ledger from ``analysis.sharding``, via
-# ``python -m apex_tpu.analysis --sharding``): per entry point, the
-# shard_map world and mesh axes, the body-operand byte census split
-# into ``unique_bytes`` + ``replicated_bytes`` (world-total duplicate
-# bytes — on the ZeRO-1 DDP train EPs this names the fully-replicated
-# fp32 master/optimizer state), the per-dtype replicated split, the
-# top replicated arrays with their inferred specs, and the
-# resharding-eqn census.  The arithmetic identity ``unique_bytes +
-# replicated_bytes == world * argument_bytes`` is enforced — a ledger
-# that does not reassemble from its own parts is hand-built, not
-# propagated.
-# v14: the QoS plane.  ``kind: fleet`` records carry the per-class
-# rollup — a ``classes`` object keyed by priority-class name whose
-# buckets hold the CLASS_COUNTS tallies (TENANT_COUNTS plus
-# ``preempted``: requests evicted mid-decode to admit a higher class)
-# alongside ``slo_attainment`` / ``goodput_tokens_per_s`` (fleet-level
-# contract) and the live queue shape (``queue_depth`` / ``queue_cap``
-# / ``weight`` / ``preemptible``), and a fleet-level ``preemptions``
-# total.  Validated whenever present; REQUIRED on fresh v14 fleet
-# records.  RECOVERY_ACTION_KINDS grows ``class_admission_tighten`` /
-# ``class_admission_relax`` (the per-class admission knob — the
-# controller squeezes the lowest-priority class's queue quota, never
-# rank 0's).
-# v15: ``kind: sharding`` ledger records for ZeRO entry points carry
-# ``zero_stage`` in {1, 2, 3} (the stage-3 ledger collapse is a
-# per-stage claim, not a per-EP one); validated whenever present.
-# Validators gate each version's requirements on the record's DECLARED
-# version, so archived v1..v14 streams stay valid.
+# The one schema every record is judged against.  A new OPTIONAL field
+# does not bump it; it changes only when an existing field changes
+# meaning, and a record that declares another version is refused
+# (``_check_envelope``).
 SCHEMA_VERSION = 15
 
 _host_info_cache: Optional[Dict[str, Any]] = None
@@ -427,37 +358,16 @@ def _need(rec, errs, key, types, allow_none=False):
     return v
 
 
-def _check_kv_fields(rec, errs):
-    """The KV fragmentation field contract of serving profile
-    records: byte fields are non-negative ints, waste is a subset of
-    the allocation, utilization is a fraction — all validated
-    whenever present."""
-    for opt in ("kv_cache_bytes", "kv_waste_bytes"):
-        if opt in rec:
-            v = rec[opt]
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                errs.append(f"{opt!r} must be an int >= 0, got {v!r}")
-    kvw, kvc = rec.get("kv_waste_bytes"), rec.get("kv_cache_bytes")
-    if (isinstance(kvw, int) and isinstance(kvc, int)
-            and not isinstance(kvw, bool) and not isinstance(kvc, bool)
-            and kvw > kvc):
-        errs.append(f"kv_waste_bytes ({kvw}) exceeds kv_cache_bytes "
-                    f"({kvc}) — waste is a subset of the allocation")
-    if "kv_utilization" in rec:
-        v = rec["kv_utilization"]
-        if (not isinstance(v, numbers.Number) or isinstance(v, bool)
-                or not (0.0 <= v <= 1.0)):
-            errs.append(f"'kv_utilization' must be in [0, 1], got "
-                        f"{v!r}")
-
-
 def _check_envelope(rec, errs):
     """The common record envelope every exported line carries
     (schema_version / capture host / first-class ``stale``) — one
     implementation for every record kind."""
     sv = _need(rec, errs, "schema_version", int)
-    if isinstance(sv, int) and not isinstance(sv, bool) and sv < 1:
-        errs.append(f"schema_version must be >= 1, got {sv}")
+    if (isinstance(sv, int) and not isinstance(sv, bool)
+            and sv != SCHEMA_VERSION):
+        errs.append(f"schema_version {sv} is not the current schema "
+                    f"({SCHEMA_VERSION}); records of another version "
+                    f"are refused")
     _need(rec, errs, "stale", bool)
     host = _need(rec, errs, "host", dict)
     if isinstance(host, dict):
@@ -524,7 +434,7 @@ def validate_lint_record(rec: Any) -> List[str]:
 _FLEET_COUNTS = ("queue_depth", "submitted", "finished", "failed",
                  "shed", "retries", "failovers", "drains", "tokens")
 
-# the per-tenant bucket tallies a v11 ``tenants`` block carries —
+# the per-tenant bucket tallies a ``tenants`` block carries —
 # the stdlib-side duplicate of fleet.slo's tenant bucket (this module
 # must stay importable without jax; tests pin the shapes equal).
 # Every field is a non-negative int; ``slo_attainment`` /
@@ -534,7 +444,7 @@ TENANT_COUNTS = ("submitted", "finished", "failed", "shed",
                  "deadline_exceeded", "slo_misses", "goodput_tokens",
                  "with_deadline", "within_deadline")
 
-# the per-class bucket tallies a v14 ``classes`` block carries — the
+# the per-class bucket tallies a ``classes`` block carries — the
 # tenant bucket plus ``preempted`` (requests evicted mid-decode to
 # admit a higher-priority class; the evictee is re-queued from its
 # prompt, so ``preempted`` is not a failure count).  Stdlib-side
@@ -543,7 +453,7 @@ CLASS_COUNTS = TENANT_COUNTS + ("preempted",)
 
 
 def _check_tenants_block(rec, errs):
-    """The v11 per-tenant rollup contract, validated whenever present:
+    """The per-tenant rollup contract, validated whenever present:
     ``tenants`` maps non-empty tenant names to buckets of TENANT_COUNTS
     tallies (ints >= 0, internally consistent — finishes cannot exceed
     submissions, within-deadline is a subset of with-deadline), and the
@@ -617,7 +527,7 @@ def _check_tenants_block(rec, errs):
 
 
 def _check_classes_block(rec, errs):
-    """The v14 per-class rollup contract, validated whenever present:
+    """The per-class rollup contract, validated whenever present:
     ``classes`` maps non-empty priority-class names to buckets of
     CLASS_COUNTS tallies (ints >= 0, internally consistent the tenant
     way), each riding with the SLO pair (null-or-fraction attainment,
@@ -738,16 +648,10 @@ def validate_fleet_record(rec: Any) -> List[str]:
     # the flight-recorder cross-reference: every fleet snapshot names
     # the fleet-run trace whose request traces (``kind: trace``,
     # trace_id "<fleet>/r<rid>") it aggregates — a dashboard can join
-    # the two streams on this id.  Schema v2 requirement: archived v1
-    # fleet records (pre-flight-recorder) predate the field and stay
-    # valid at their declared version.
-    sv = rec.get("schema_version", SCHEMA_VERSION)
-    if isinstance(sv, int) and not isinstance(sv, bool) and sv >= 2:
-        # (a non-int schema_version is already an envelope error — no
-        # crash, no v2 requirements)
-        tid = need("trace_id", str)
-        if isinstance(tid, str) and not tid:
-            errs.append("trace_id must be non-empty")
+    # the two streams on this id.
+    tid = need("trace_id", str)
+    if isinstance(tid, str) and not tid:
+        errs.append("trace_id must be non-empty")
     pol = need("policy", str)
     if isinstance(pol, str) and not pol:
         errs.append("policy must be non-empty")
@@ -773,9 +677,8 @@ def validate_fleet_record(rec: Any) -> List[str]:
             and not isinstance(fin, bool) and not isinstance(sub, bool)
             and fin > sub):
         errs.append(f"finished ({fin}) exceeds submitted ({sub})")
-    # SLO / goodput / deadline-sweep fields (schema v5 additions,
-    # OPTIONAL at every version — older records simply predate them,
-    # but whenever present they must be internally consistent: goodput
+    # SLO / goodput / deadline-sweep fields (OPTIONAL, but whenever
+    # present they must be internally consistent: goodput
     # cannot exceed total tokens, attainment is a fraction or null,
     # and the deadline-sweep aggregate mirrors what the flight ring's
     # ``deadline_exceeded`` events carry)
@@ -803,7 +706,7 @@ def validate_fleet_record(rec: Any) -> List[str]:
             errs.append(f"'slo_attainment' must be null or in [0, 1], "
                         f"got {v!r}")
     if "mttr" in rec:
-        # schema-v6 optional: the fleet's failover→first-progress
+        # optional: the fleet's failover→first-progress
         # aggregate ({last, mean, count}), same nullability contract
         # as the recovery record's mttr_s
         mttr = rec["mttr"]
@@ -823,29 +726,14 @@ def validate_fleet_record(rec: Any) -> List[str]:
                         or not (v >= 0)):
                     errs.append(f"mttr.{k} must be null or a finite "
                                 f"number >= 0, got {v!r}")
-    # the v11 tenant plane: validated whenever present, required on
-    # records declaring v11 — Fleet.record() always emits the block
-    # (empty object when no request was tagged), so a fresh record
-    # missing it was hand-built
-    if isinstance(sv, int) and not isinstance(sv, bool) and sv >= 11:
-        if "tenants" not in rec:
-            errs.append("fresh fleet records must carry 'tenants' "
-                        "(schema v11: the per-tenant SLO rollup)")
-        if "tenants_dropped" not in rec:
-            errs.append("fresh fleet records must carry "
-                        "'tenants_dropped' (schema v11)")
+    # the tenant and QoS planes: Fleet.record() always emits both
+    # blocks (an empty object when no request was tagged, zero buckets
+    # for every policy class when nothing ran), so a record missing
+    # one was hand-built
+    for key in ("tenants", "tenants_dropped", "classes", "preemptions"):
+        if key not in rec:
+            errs.append(f"fleet records must carry {key!r}")
     _check_tenants_block(rec, errs)
-    # the v14 QoS plane: validated whenever present, required on
-    # records declaring v14 — Fleet.record() always emits the block
-    # (zero buckets for every policy class when nothing ran), so a
-    # fresh record missing it was hand-built
-    if isinstance(sv, int) and not isinstance(sv, bool) and sv >= 14:
-        if "classes" not in rec:
-            errs.append("fresh fleet records must carry 'classes' "
-                        "(schema v14: the per-QoS-class SLO rollup)")
-        if "preemptions" not in rec:
-            errs.append("fresh fleet records must carry "
-                        "'preemptions' (schema v14)")
     _check_classes_block(rec, errs)
     if "deadline_last_sweep" in rec:
         sweep = rec["deadline_last_sweep"]
@@ -952,7 +840,7 @@ def validate_memory_record(rec: Any) -> List[str]:
 def validate_sharding_record(rec: Any) -> List[str]:
     """Schema check for one ``kind: sharding`` JSONL record (the static
     replication ledger from ``analysis.sharding.
-    entry_point_sharding_record``, schema v13): the common envelope, a
+    entry_point_sharding_record``): the common envelope, a
     non-empty ``entry_point``, a coherent mesh (``world`` equals the
     product of ``mesh_axes``), non-negative byte totals with the
     arithmetic identity ``unique_bytes + replicated_bytes == world *
@@ -1095,23 +983,21 @@ def validate_sharding_record(rec: Any) -> List[str]:
             if not isinstance(n, int) or isinstance(n, bool) or n < 0:
                 errs.append(f"resharding_eqns[{prim!r}] must be an "
                             f"int >= 0, got {n!r}")
-    # v15: a ledger for a ZeRO entry point must say which stage it
+    # a ledger for a ZeRO entry point must say which stage it
     # measured — stage 3's collapse (nothing replicated but BN state
     # and scalars) is only comparable against stage 1/2 ledgers when
-    # each carries its stage; validated whenever present at any
-    # version, required on fresh v15 zero-EP records
+    # each carries its stage; validated whenever present, required on
+    # fresh zero-EP records
     if "zero_stage" in rec:
         zs = rec["zero_stage"]
         if not isinstance(zs, int) or isinstance(zs, bool) \
                 or zs not in (1, 2, 3):
             errs.append(f"'zero_stage' must be 1, 2 or 3 when present, "
                         f"got {zs!r}")
-    sv_rec = rec.get("schema_version")
-    if (isinstance(sv_rec, int) and not isinstance(sv_rec, bool)
-            and sv_rec >= 15 and isinstance(epn, str) and "zero" in epn
+    if (isinstance(epn, str) and "zero" in epn
             and not rec.get("stale") and "zero_stage" not in rec):
         errs.append("fresh sharding records for ZeRO entry points must "
-                    "carry 'zero_stage' (schema v15)")
+                    "carry 'zero_stage'")
     try:
         json.dumps(rec)
     except (TypeError, ValueError) as e:
@@ -1282,7 +1168,7 @@ RUN_ANOMALY_KINDS = ("stall", "loss_spike", "nan",
 
 def validate_run_record(rec: Any) -> List[str]:
     """Schema check for one ``kind: run`` JSONL record
-    (``RunSupervisor.record`` enriched by the exporter, schema v5):
+    (``RunSupervisor.record`` enriched by the exporter):
     the common envelope, a non-empty ``run`` name, the observation /
     watermark tallies, per-kind anomaly counts over the KNOWN kinds,
     a bounded anomaly-detail list whose entries each name a counted
@@ -1411,8 +1297,8 @@ RECOVERY_CAUSES = ("fault", "verdict", "preemption")
 
 def validate_recovery_record(rec: Any) -> List[str]:
     """Schema check for one ``kind: recovery`` JSONL record
-    (``fleet.recovery.RecoveryLog.record`` enriched by the exporter,
-    schema v6): the common envelope, a known controller ``role``, the
+    (``fleet.recovery.RecoveryLog.record`` enriched by the exporter):
+    the common envelope, a known controller ``role``, the
     episode/action tallies, a bounded action-detail list whose entries
     each name a known action kind inside a counted episode, and the
     MTTR aggregate — internally consistent the way a dashboard
@@ -1516,8 +1402,7 @@ def validate_recovery_record(rec: Any) -> List[str]:
         if not isinstance(v, int) or isinstance(v, bool) or v < 1:
             errs.append(f"'world' must be an int >= 1 when present, "
                         f"got {v!r}")
-    # schema-v7 preemption fields, validated whenever present (older
-    # records simply predate them)
+    # preemption fields, validated whenever present
     if "cause" in rec and rec["cause"] is not None:
         if rec["cause"] not in RECOVERY_CAUSES:
             errs.append(f"'cause' must be null or one of "
@@ -1652,151 +1537,6 @@ def validate_trace_record(rec: Any) -> List[str]:
     return errs
 
 
-# -- profile record schema --------------------------------------------------
-
-# observability.timeline.PROFILE_FIELDS (duplicated here so the
-# stdlib-only CI loader never imports the timeline module; the pytest
-# coverage pins the two tuples equal — the RUN_ANOMALY_KINDS
-# discipline)
-PROFILE_TIME_FIELDS = ("span_ms", "device_busy_ms", "compute_ms",
-                       "collective_ms", "gap_ms", "overlap_ms")
-_PROFILE_KERNEL_KINDS = ("compute", "collective")
-
-
-def validate_profile_record(rec: Any) -> List[str]:
-    """Schema check for one ``kind: profile`` JSONL record (the
-    device-timeline attribution from ``observability.timeline`` via
-    ``/profilez``, schema v8): the common
-    envelope, a subject (``metric`` or ``entry_point``), the six
-    non-negative timing fields, and the interval arithmetic a
-    hand-built record gets wrong — busy never exceeds the span, gap
-    reassembles span minus busy, the class unions bound the busy
-    union from both sides, overlap fits inside BOTH classes, and the
-    measured fraction is overlap over collective time.  ``top_kernels``
-    entries must each name a known class; the optional KV fragmentation
-    fields follow ``_check_kv_fields`` (waste is a subset of the
-    allocation, utilization is a fraction)."""
-    errs: List[str] = []
-    if not isinstance(rec, dict):
-        return [f"record is {type(rec).__name__}, not an object"]
-
-    def need(key, types, allow_none=False):
-        return _need(rec, errs, key, types, allow_none)
-
-    _check_envelope(rec, errs)
-    if rec.get("kind") != "profile":
-        errs.append(f"kind must be 'profile', got {rec.get('kind')!r}")
-    subject = rec.get("entry_point", rec.get("metric"))
-    if not isinstance(subject, str) or not subject:
-        errs.append("profile records must carry a non-empty "
-                    "'entry_point' or 'metric'")
-    vals = {}
-    for key in PROFILE_TIME_FIELDS:
-        v = need(key, numbers.Number)
-        if isinstance(v, numbers.Number) and not isinstance(v, bool):
-            if not (v >= 0):           # also rejects NaN
-                errs.append(f"{key!r} must be >= 0, got {v!r}")
-            else:
-                vals[key] = float(v)
-    frac = need("measured_overlap_fraction", numbers.Number)
-    if (isinstance(frac, numbers.Number) and not isinstance(frac, bool)
-            and not (0.0 <= frac <= 1.0)):
-        errs.append(f"'measured_overlap_fraction' must be in [0, 1], "
-                    f"got {frac!r}")
-
-    def tol(x):
-        # the producer rounds every field to 4 decimals independently;
-        # merged-interval arithmetic is exact before rounding
-        return max(0.01, 0.01 * x)
-
-    if len(vals) == len(PROFILE_TIME_FIELDS):
-        span, busy = vals["span_ms"], vals["device_busy_ms"]
-        comp, coll = vals["compute_ms"], vals["collective_ms"]
-        gap, ovl = vals["gap_ms"], vals["overlap_ms"]
-        if busy > span + tol(span):
-            errs.append(f"device_busy_ms ({busy}) exceeds span_ms "
-                        f"({span})")
-        if abs(gap - max(span - busy, 0.0)) > tol(span):
-            errs.append(f"gap_ms ({gap}) != span_ms - device_busy_ms "
-                        f"({span} - {busy})")
-        if busy > comp + coll + tol(busy):
-            errs.append(f"device_busy_ms ({busy}) exceeds compute_ms "
-                        f"+ collective_ms ({comp} + {coll}) — the "
-                        f"busy union is covered by the class unions")
-        if busy + tol(busy) < max(comp, coll):
-            errs.append(f"device_busy_ms ({busy}) below "
-                        f"max(compute_ms, collective_ms) "
-                        f"({comp}, {coll})")
-        if ovl > min(comp, coll) + tol(ovl):
-            errs.append(f"overlap_ms ({ovl}) exceeds a class union it "
-                        f"is an intersection of ({comp}, {coll})")
-        if (isinstance(frac, numbers.Number)
-                and not isinstance(frac, bool)):
-            if coll > 0:
-                expect = min(max(ovl / coll, 0.0), 1.0)
-                if abs(frac - expect) > max(0.01, 0.02 * expect):
-                    errs.append(
-                        f"measured_overlap_fraction ({frac}) "
-                        f"inconsistent with overlap_ms/collective_ms "
-                        f"({ovl}/{coll})")
-            elif frac != 0.0:
-                errs.append(f"measured_overlap_fraction ({frac}) with "
-                            f"zero collective_ms")
-    for opt in ("kernel_count", "lane_count"):
-        if opt in rec:
-            v = rec[opt]
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                errs.append(f"{opt!r} must be an int >= 0 when "
-                            f"present, got {v!r}")
-    if "steps" in rec:
-        v = rec["steps"]
-        if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-            errs.append(f"'steps' must be an int >= 1 when present, "
-                        f"got {v!r}")
-    if "duration_ms" in rec:
-        v = rec["duration_ms"]
-        if (not isinstance(v, numbers.Number) or isinstance(v, bool)
-                or not (v >= 0)):
-            errs.append(f"'duration_ms' must be a number >= 0 when "
-                        f"present, got {v!r}")
-    if "trace_path" in rec and not isinstance(rec["trace_path"], str):
-        errs.append("'trace_path' must be a string when present")
-    if "top_kernels" in rec:
-        top = rec["top_kernels"]
-        if not isinstance(top, list):
-            errs.append("'top_kernels' must be a list when present")
-        else:
-            for i, k in enumerate(top):
-                if not isinstance(k, dict):
-                    errs.append(f"top_kernels[{i}] is not an object")
-                    continue
-                name = k.get("name")
-                if not isinstance(name, str) or not name:
-                    errs.append(f"top_kernels[{i}].name must be a "
-                                f"non-empty string")
-                if k.get("kind") not in _PROFILE_KERNEL_KINDS:
-                    errs.append(f"top_kernels[{i}].kind must be one "
-                                f"of {_PROFILE_KERNEL_KINDS}, got "
-                                f"{k.get('kind')!r}")
-                c = k.get("count")
-                if not isinstance(c, int) or isinstance(c, bool) \
-                        or c < 1:
-                    errs.append(f"top_kernels[{i}].count must be an "
-                                f"int >= 1, got {c!r}")
-                t = k.get("total_ms")
-                if (not isinstance(t, numbers.Number)
-                        or isinstance(t, bool) or not (t >= 0)):
-                    errs.append(f"top_kernels[{i}].total_ms must be a "
-                                f"number >= 0, got {t!r}")
-    # KV fragmentation fields on serving profiles
-    _check_kv_fields(rec, errs)
-    try:
-        json.dumps(rec)
-    except (TypeError, ValueError) as e:
-        errs.append(f"record is not JSON-serializable: {e}")
-    return errs
-
-
 _VALIDATORS = {
     "graph_lint": validate_lint_record,
     "graph_lint_summary": validate_lint_record,
@@ -1806,7 +1546,6 @@ _VALIDATORS = {
     "numerics": validate_numerics_record,
     "run": validate_run_record,
     "recovery": validate_recovery_record,
-    "profile": validate_profile_record,
     "sharding": validate_sharding_record,
 }
 
@@ -1819,8 +1558,8 @@ def validate_telemetry_record(rec: Any) -> List[str]:
     memory``, ``--memory``), gradient-health dumps
     (``NumericsMonitor.to_record``), run verdicts
     (``RunSupervisor.record``), recovery-controller snapshots
-    (``RecoveryLog.record``), device-timeline attributions
-    (``/profilez``) and replication ledgers (``--sharding``) may
+    (``RecoveryLog.record``) and replication ledgers
+    (``--sharding``) may
     interleave in one stream.  A record whose ``kind`` is absent or
     unknown is an error: there is no default schema."""
     if not isinstance(rec, dict):

@@ -15,7 +15,7 @@ found by instruction name in the optimized HLO text of the same program
 (``CompilationLedger.compiled_text(entry)``, or any
 ``compiled.as_text()``): :func:`instruction_phases`.
 
-Stdlib only: readers and ``/profilez`` consumers import it without jax.
+Stdlib only: trace readers import it without jax.
 """
 
 from __future__ import annotations

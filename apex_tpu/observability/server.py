@@ -24,15 +24,6 @@ any hot path, no dependencies:
 - ``/tracez`` — :class:`~apex_tpu.observability.SpanRecorder` records:
   the trace-id index by default, one schema-valid ``kind: trace``
   record with ``?trace_id=``.
-- ``/profilez`` — on-demand device-timeline capture (PR 13): triggers
-  the attached profiler hook (``observability.timeline.make_profiler``
-  builds the standard one — a bounded ``jax.profiler`` window over the
-  live process, parsed into a schema-versioned ``kind: profile``
-  record).  ``?duration_ms=`` bounds the window (the hook clamps);
-  404 when no profiler hook is attached (the jax-free deployment
-  shape, pinned by tests/ci/server_smoke.py), 409 when a capture is
-  already in flight — ``jax.profiler.start_trace`` is a process-wide
-  singleton, so concurrent captures cannot be honored.
 - ``/compilez`` — the compilation-plane ledger
   (:mod:`~apex_tpu.observability.compilation`): per-entry jit
   trace/retrace/compile counts, persistent-cache hit/miss attribution,
@@ -84,18 +75,10 @@ import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, Optional, Tuple
 
-__all__ = ["ObservabilityServer", "serve", "ENDPOINTS",
-           "ProfileInFlight"]
+__all__ = ["ObservabilityServer", "serve", "ENDPOINTS"]
 
 ENDPOINTS = ("/healthz", "/metricsz", "/statusz", "/flightz", "/tracez",
-             "/profilez", "/compilez", "/tenantz")
-
-
-class ProfileInFlight(RuntimeError):
-    """A profiler capture is already running in this process —
-    ``/profilez`` maps it to HTTP 409 (the device profiler is a
-    process-wide singleton; two overlapping captures would corrupt
-    each other's windows)."""
+             "/compilez", "/tenantz")
 
 
 def _json_default(obj):
@@ -138,7 +121,6 @@ class ObservabilityServer:
                  status: Optional[Dict[str, Callable[[], Any]]] = None,
                  health: Optional[Dict[str, Callable[[], Tuple[bool, str]]]]
                  = None,
-                 profiler: Optional[Callable] = None,
                  ledger=None,
                  tenants: Optional[Dict[str, Callable[[], Any]]] = None,
                  host: str = "127.0.0.1", port: int = 0,
@@ -151,8 +133,6 @@ class ObservabilityServer:
         self._status: Dict[str, Callable[[], Any]] = dict(status or {})
         self._health: Dict[str, Callable[[], Tuple[bool, str]]] = \
             dict(health or {})
-        self._profiler = profiler
-        self._profile_lock = threading.Lock()
         self.host = host
         self._want_port = port
         self.tracez_limit = int(tracez_limit)
@@ -177,15 +157,6 @@ class ObservabilityServer:
         a per-tenant rollup dict with a ``tenants`` map
         (``Fleet.tenant_stats`` is the standard one)."""
         self._tenants[str(name)] = fn
-        return self
-
-    def attach_profiler(self, fn: Callable):
-        """Attach the ``/profilez`` capture hook: a callable taking one
-        optional ``duration_ms`` (possibly None) and returning the
-        ``kind: profile`` record body —
-        ``observability.timeline.make_profiler()`` builds the standard
-        one."""
-        self._profiler = fn
         return self
 
     # -- default resolution (per request) ----------------------------------
@@ -371,34 +342,6 @@ class ObservabilityServer:
                                 else sorted(class_names)),
                 "by_source": by_source}
 
-    def profilez(self, duration_ms: Optional[float] = None
-                 ) -> Dict[str, Any]:
-        """Trigger one bounded capture through the attached profiler
-        hook and return the enriched ``kind: profile`` record.  Raises
-        ``KeyError`` with no hook attached (handler → 404) and
-        :class:`ProfileInFlight` when a capture is already running —
-        either detected here (two concurrent ``/profilez`` scrapes) or
-        raised by the hook itself (a foreign trace window is open);
-        handler → 409."""
-        fn = self._profiler
-        if fn is None:
-            raise KeyError("no profiler hook attached (serve with "
-                           "profiler=timeline.make_profiler())")
-        if not self._profile_lock.acquire(blocking=False):
-            raise ProfileInFlight("a /profilez capture is already in "
-                                  "flight")
-        try:
-            rec = fn(duration_ms)
-        finally:
-            self._profile_lock.release()
-        if not isinstance(rec, dict):
-            raise TypeError(f"profiler hook returned "
-                            f"{type(rec).__name__}, not a record dict")
-        from .exporters import JsonlExporter
-        out = dict(rec)
-        out.setdefault("kind", "profile")
-        return JsonlExporter.enrich(out)
-
     # -- the HTTP plumbing --------------------------------------------------
     def _make_handler(self):
         srv = self
@@ -449,31 +392,6 @@ class ObservabilityServer:
                         except KeyError:
                             self._send_json(404, {
                                 "error": f"unknown trace_id {tid!r}"})
-                    elif route == "/profilez":
-                        raw = q.get("duration_ms", [None])[0]
-                        try:
-                            dur = (float(raw) if raw is not None
-                                   else None)
-                            # float() accepts nan/inf, which would
-                            # sail through the hook's min/max clamp
-                            # (NaN compares false) into time.sleep
-                            if dur is not None and not (
-                                    0 <= dur < float("inf")):
-                                raise ValueError
-                        except ValueError:
-                            self._send_json(400, {
-                                "error": f"duration_ms must be a "
-                                         f"finite number >= 0, got "
-                                         f"{raw!r}"})
-                            return
-                        try:
-                            self._send_json(200, srv.profilez(
-                                duration_ms=dur))
-                        except KeyError as e:
-                            self._send_json(404, {
-                                "error": f"no capture available: {e}"})
-                        except ProfileInFlight as e:
-                            self._send_json(409, {"error": str(e)})
                     elif route == "/compilez":
                         ent = q.get("entry", [None])[0]
                         try:
@@ -561,7 +479,7 @@ def serve(engine=None, fleet=None, supervisor=None,
           registry=None, ring=None, recorder=None,
           status: Optional[Dict[str, Callable[[], Any]]] = None,
           health: Optional[Dict[str, Callable[[], Tuple[bool, str]]]] = None,
-          profiler: Optional[Callable] = None, ledger=None,
+          ledger=None,
           host: str = "127.0.0.1", port: int = 0,
           start: bool = True) -> ObservabilityServer:
     """One-call attachment: build (and start) an
@@ -580,11 +498,8 @@ def serve(engine=None, fleet=None, supervisor=None,
       declared sick.
 
     Explicit ``registry``/``ring``/``recorder``/``status``/``health``
-    compose with (and win over) the attachment defaults.  ``profiler``
-    arms ``/profilez`` (``timeline.make_profiler()`` builds the
-    standard hook); without one the endpoint answers 404 — on-demand
-    device captures are an explicit opt-in, never a surprise cost on a
-    serving process.  ``ledger`` overrides the ``/compilez`` source
+    compose with (and win over) the attachment defaults.  ``ledger``
+    overrides the ``/compilez`` source
     (default: the process compilation ledger, resolved per request —
     compilation is process-wide, so engines and fleets share one).
     """
@@ -624,6 +539,6 @@ def serve(engine=None, fleet=None, supervisor=None,
     hc.update(health or {})
     srv = ObservabilityServer(registry=registry, ring=ring,
                               recorder=recorder, status=st, health=hc,
-                              profiler=profiler, ledger=ledger,
+                              ledger=ledger,
                               tenants=tn, host=host, port=port)
     return srv.start() if start else srv

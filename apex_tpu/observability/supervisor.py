@@ -49,7 +49,7 @@ Outputs: flight-ring events (``run_stall`` / ``run_loss_spike`` /
 ``run_nan`` / ``run_throughput_regression`` /
 ``run_replica_divergence``), registry metrics
 (``run_anomalies_total{kind=...}``, loss / step-time EWMAs, the
-watermark gauge), schema-v5 ``kind: run`` JSONL records
+watermark gauge), ``kind: run`` JSONL records
 (:meth:`record`, pinned by ``exporters.validate_run_record``), a
 ``/statusz``-ready :meth:`status` dict with a ``health_check`` the
 introspection server turns into ``/healthz`` 503, and the end-of-run
@@ -654,7 +654,7 @@ class RunSupervisor:
 
     def record(self, metric: Optional[str] = None,
                **extra) -> Dict[str, Any]:
-        """One schema-v5 ``kind: run`` JSONL payload (enrich through
+        """One ``kind: run`` JSONL payload (enrich through
         ``JsonlExporter``; ``exporters.validate_run_record`` pins the
         shape)."""
         rec: Dict[str, Any] = {

@@ -7,8 +7,9 @@ is the pure-jnp path which is bitwise-comparable in tests.
 
 Env vars:
   APEX_TPU_DISABLE_PALLAS=1   force the jnp path everywhere
-  APEX_TPU_FORCE_PALLAS=1     force Pallas (interpret mode off-TPU; slow,
-                              used by kernel parity tests)
+  APEX_TPU_FORCE_PALLAS=1     dispatch off-TPU what the chip dispatches
+                              (interpret mode; slow, used by kernel
+                              parity tests)
 """
 
 from __future__ import annotations
@@ -34,7 +35,6 @@ def kernels_available() -> bool:
             from . import pallas_adam  # noqa: F401
             from . import pallas_layer_norm  # noqa: F401
             from . import pallas_lamb  # noqa: F401
-            from . import pallas_syncbn  # noqa: F401
             from . import pallas_flash_attention  # noqa: F401
             _KERNELS_AVAILABLE = True
         except ImportError:
@@ -49,18 +49,17 @@ def backend() -> str:
 
 
 def pallas_enabled() -> bool:
-    """APEX_TPU_FORCE_PALLAS accepts two values: "1" forces every Pallas
-    path including the parity-test-only ops (pallas_forced), and "prod"
-    reproduces the production TPU gating off-TPU — kernels that are
-    actually dispatched on hardware (fused Adam/LAMB, multi-tensor,
-    flash attention) run Pallas while ops XLA fuses better (BN apply)
-    stay jnp.  The L1 cross-product driver trains under "prod" so its
+    """True on a TPU, and off-TPU under APEX_TPU_FORCE_PALLAS=1, which
+    reproduces the chip's gating: the kernels dispatched on hardware
+    (fused Adam/LAMB, multi-tensor, flash attention) run Pallas in
+    interpret mode while what XLA fuses better (the BatchNorm apply) is
+    jnp everywhere.  The L1 cross-product driver trains under it so its
     bitwise comparison matches what hardware executes."""
     if os.environ.get("APEX_TPU_DISABLE_PALLAS") == "1":
         return False
     if not kernels_available():
         return False
-    if os.environ.get("APEX_TPU_FORCE_PALLAS") in ("1", "prod"):
+    if os.environ.get("APEX_TPU_FORCE_PALLAS") == "1":
         return True
     return backend() == "tpu"
 
@@ -68,22 +67,6 @@ def pallas_enabled() -> bool:
 def interpret_mode() -> bool:
     """Pallas interpret=True is needed off-TPU (CPU tests)."""
     return backend() != "tpu"
-
-
-def pallas_forced() -> bool:
-    """True only under APEX_TPU_FORCE_PALLAS=1 (kernel parity tests).
-
-    Ops whose jnp form XLA fuses into neighbouring computation for free
-    (e.g. the BatchNorm scale+shift apply) gate on this instead of
-    ``pallas_enabled()``: a standalone kernel there forces an extra HBM
-    round-trip and an (8,128)-misaligned NCHW tiling — measured at ~3x
-    the whole ResNet-50 forward (round-3 profiling).  The fused kernels
-    that *beat* XLA (flash attention, fused Adam, multi-tensor scale over
-    one flat buffer) keep using ``pallas_enabled()``."""
-    if os.environ.get("APEX_TPU_DISABLE_PALLAS") == "1":
-        return False
-    return (os.environ.get("APEX_TPU_FORCE_PALLAS") == "1"
-            and kernels_available())
 
 
 def use_pallas_for(tree: Any) -> bool:

@@ -15,7 +15,6 @@ from .distributed import (DistributedDataParallel, Reducer,
                           zero_update_comm_plan,
                           predivide_factors, flat_dist_call,
                           staged_grads, overlap_comm_schedule,
-                          overlap_schedule_fields,
                           overlap_collective_expectations, OVERLAP_MODES)
 from .sync_batchnorm import SyncBatchNorm
 from .LARC import LARC
@@ -24,7 +23,6 @@ from .tensor_parallel import (ColumnParallelLinear, RowParallelLinear,
                               ParallelMLP, ParallelSelfAttention)
 from . import pipeline
 from . import expert_parallel
-from .adasum import adasum_grads, adasum_pair, adasum_comm_plan
 from .expert_parallel import ExpertParallelMLP
 
 

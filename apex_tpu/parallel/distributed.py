@@ -56,8 +56,8 @@ __all__ = ["DistributedDataParallel", "Reducer", "allreduce_grads_tree",
            "allreduce_comm_plan", "plan_collective_expectations",
            "plan_resharding_expectations", "zero_update_comm_plan",
            "predivide_factors", "flat_dist_call", "staged_grads",
-           "overlap_comm_schedule", "overlap_schedule_fields",
-           "overlap_collective_expectations", "OVERLAP_MODES"]
+           "overlap_comm_schedule", "overlap_collective_expectations",
+           "OVERLAP_MODES"]
 
 # where the gradient bytes travel: "flat" is one psum over the whole
 # axis (every byte crosses the slowest link in it), "hierarchical" is
@@ -946,22 +946,6 @@ def overlap_comm_schedule(stage_trees: Sequence[Any],
             "buckets": buckets}
 
 
-def overlap_schedule_fields(schedule: Optional[Dict[str, Any]]
-                            ) -> Dict[str, Any]:
-    """How a step issues its bucket reductions, as three fields: mode,
-    stage count, and stage-level issue order.  ``None`` describes a
-    classic un-staged step — one stage, reduced after backward."""
-    if schedule is None:
-        return {"overlap_mode": "reduce_after_backward",
-                "n_stages": 1, "issue_order": [0]}
-    out = {"overlap_mode": schedule["overlap_mode"],
-           "n_stages": int(schedule["n_stages"]),
-           "issue_order": [int(s) for s in schedule["issue_order"]]}
-    if schedule.get("zero_stage") is not None:
-        out["zero_stage"] = int(schedule["zero_stage"])
-    return out
-
-
 def overlap_collective_expectations(schedule: Dict[str, Any],
                                     extra_psums: int = 0,
                                     extra_psum_bytes: int = 0) -> dict:
@@ -1054,7 +1038,6 @@ class DistributedDataParallel:
                  gradient_average: bool = True,
                  gradient_predivide_factor: float = 1.0,
                  axis_name: str = "data",
-                 adasum: bool = False,
                  comm_topology: str = "flat",
                  allreduce_compress_bf16: bool = False,
                  ici_size: Optional[int] = None,
@@ -1079,49 +1062,21 @@ class DistributedDataParallel:
         self.comm_topology = comm_topology
         self.allreduce_compress_bf16 = allreduce_compress_bf16
         self.ici_size = ici_size
-        # adasum=True swaps the psum for the adaptive-summation
-        # butterfly (parallel/adasum.py, arXiv:2006.02924) — a
-        # beyond-reference combiner for conflict-aware large-batch DP.
-        # It REPLACES the sum-then-average pipeline wholesale, so the
-        # psum-shaping knobs are meaningless with it: reject loudly
-        # instead of silently ignoring them.  comm_topology DOES
-        # compose: hierarchical adasum averages within the ICI slice
-        # and runs the butterfly across slices (the paper's
-        # average-within-node recipe) — see adasum_grads(ici_size=).
-        self.adasum = adasum
-        if adasum:
-            clashes = [name for name, bad in (
-                ("delay_allreduce", delay_allreduce),
-                ("allreduce_trigger_params",
-                 bool(allreduce_trigger_params)),
-                ("retain_allreduce_buffers", retain_allreduce_buffers),
-                ("allreduce_always_fp32", allreduce_always_fp32),
-                ("allreduce_compress_bf16", allreduce_compress_bf16),
-                ("gradient_average=False", not gradient_average),
-                ("gradient_predivide_factor",
-                 gradient_predivide_factor != 1.0)) if bad]
-            if clashes:
-                raise ValueError(
-                    f"adasum=True replaces the psum pipeline; these "
-                    f"options have no effect with it: {clashes}")
         # overlap=True selects the overlapped bucket schedule for
         # staged_allreduce_grads: each stage's reduction is issued
         # while earlier stages' gradients are still being computed.
         # It contradicts delay_allreduce (ONE fused reduce after
         # backward is the opposite schedule) and allreduce_trigger_
         # params (stage boundaries ARE the bucket boundaries in the
-        # staged world); adasum's butterfly replaces the bucket
-        # pipeline wholesale, so staging it is not wired.  Topology /
-        # compression / predivide all compose — the per-bucket
-        # reduction is the unchanged hierarchical chain, only its
-        # issue position moves.
+        # staged world).  Topology / compression / predivide all
+        # compose — the per-bucket reduction is the unchanged
+        # hierarchical chain, only its issue position moves.
         self.overlap = bool(overlap)
         if self.overlap:
             clashes = [name for name, bad in (
                 ("delay_allreduce", delay_allreduce),
                 ("allreduce_trigger_params",
-                 bool(allreduce_trigger_params)),
-                ("adasum", adasum)) if bad]
+                 bool(allreduce_trigger_params))) if bad]
             if clashes:
                 raise ValueError(
                     f"overlap=True issues per-stage bucket reductions "
@@ -1144,10 +1099,6 @@ class DistributedDataParallel:
                 raise ValueError(
                     "zero_stage=2 shards the update over the ICI "
                     "slice; comm_topology must be 'hierarchical'")
-            if adasum:
-                raise ValueError("zero_stage=2 does not compose with "
-                                 "adasum (the butterfly replaces the "
-                                 "reduce-scatter the shard rides on)")
         self.zero_stage = zero_stage
         self.allreduce_buffers: list = []
         # trace-time comm accounting (observability): one record per
@@ -1157,20 +1108,8 @@ class DistributedDataParallel:
         # the most recently traced overlap schedule
         # (staged_allreduce_grads): overlap_mode / n_stages /
         # issue_order / stage-stamped bucket records — None until a
-        # staged step traces, or when the compute twin elides comm
+        # staged step traces
         self.last_overlap_schedule: Optional[dict] = None
-        # numerics observability (PR 9): the most recently FLUSHED
-        # gradient-health summary — host-side plain python, set by
-        # record_numerics() after the step's NumericsMonitor.flush()
-        # (the in-step device stats ride the carry, never this attr)
-        self.last_numerics: dict = {}
-        # comm_enabled=False builds the COMPUTE TWIN of a step (the
-        # same step timed without its wire): the gradient
-        # collectives are elided while the local average a psum would
-        # have applied stays, so the twin's per-element work matches
-        # the full step minus the wire.  Numerically it trains on
-        # local gradients — a measurement device, not a training mode.
-        self.comm_enabled = True
 
     # -- forward passthrough (wrapper parity) ------------------------------
     def __call__(self, *args, **kwargs):
@@ -1192,39 +1131,6 @@ class DistributedDataParallel:
                 "allreduce would gather bytes the shard update never "
                 "reads; use staged_zero2_allreduce_grads (or "
                 "amp.AmpOptimizer's zero_axis step)")
-        if not self.comm_enabled:
-            self.last_comm_stats = []
-            if self.gradient_average and not self.adasum:
-                # static axis size, NOT _axis_size (a psum): the twin
-                # must trace to a collective-free graph or the
-                # decomposition measures comm it claims to elide
-                world = int(lax.axis_size(self.axis_name))
-                grads = jax.tree_util.tree_map(
-                    lambda g: g / jnp.asarray(world, g.dtype)
-                    if jnp.issubdtype(g.dtype, jnp.floating) else g,
-                    grads)
-            return grads
-        if self.adasum:
-            from .adasum import adasum_grads, adasum_comm_plan
-            if axis_index_groups is not None:
-                raise NotImplementedError(
-                    "adasum over axis_index_groups is not wired")
-            topo, _ = _resolve_topology(self.comm_topology, False)
-            world = int(lax.axis_size(self.axis_name))
-            ici = 1
-            if topo == "hierarchical":
-                ici = (int(self.ici_size) if self.ici_size is not None
-                       else _topology.default_ici_size(world))
-            # TRUE exchanged bytes from the static plan (the cost side
-            # of the VERDICT "justify Adasum" experiment): log2(slices)
-            # full-buffer fp32 ppermute stages + the in-slice pmean —
-            # per-leaf accounting under-reported this by the stage
-            # count before PR 9
-            (plan_b,) = adasum_comm_plan(grads, world=world,
-                                         ici_size=ici)
-            self.last_comm_stats = [{**plan_b, "topology": topo}]
-            self._record_comm_stats()
-            return adasum_grads(grads, self.axis_name, ici_size=ici)
         retain = [] if self.retain_allreduce_buffers else None
         triggers = (set(self.allreduce_trigger_params)
                     if self.allreduce_trigger_params else None)
@@ -1270,15 +1176,7 @@ class DistributedDataParallel:
         ``stage``/``issue_order`` in exactly
         :func:`overlap_comm_schedule` bucket order (the plan-order
         contract PR 9's per-bucket scalars ride on), and
-        ``self.last_overlap_schedule`` keeps the traced schedule.
-
-        ``comm_enabled=False`` builds the compute twin: the SAME staged
-        backward with every collective elided and the local 1/world
-        average kept (static axis size), for step-time attribution."""
-        if self.adasum:
-            raise ValueError("staged_allreduce_grads does not compose "
-                             "with adasum (the butterfly replaces the "
-                             "bucket pipeline)")
+        ``self.last_overlap_schedule`` keeps the traced schedule."""
         if self.zero_stage is not None:
             raise ValueError(
                 "zero_stage=2 replaces the per-stage gather-back of "
@@ -1289,22 +1187,6 @@ class DistributedDataParallel:
                 "staged_allreduce_grads: stage boundaries define the "
                 "buckets; delay_allreduce / allreduce_trigger_params "
                 "contradict the staged schedule")
-        if not self.comm_enabled:
-            self.last_comm_stats = []
-            self.last_overlap_schedule = None
-            loss, grads = staged_grads(stage_fns, loss_head,
-                                       stage_params, x,
-                                       reduce_stage=None,
-                                       overlap=self.overlap)
-            if self.gradient_average:
-                # static axis size, like allreduce_grads: the twin
-                # must trace collective-free
-                world = int(lax.axis_size(self.axis_name))
-                grads = [jax.tree_util.tree_map(
-                    lambda g: g / jnp.asarray(world, g.dtype)
-                    if jnp.issubdtype(g.dtype, jnp.floating) else g,
-                    gs) for gs in grads]
-            return loss, grads
         world_static = int(lax.axis_size(self.axis_name))
         world_scalar = _axis_size(self.axis_name)
         retain = [] if self.retain_allreduce_buffers else None
@@ -1393,11 +1275,6 @@ class DistributedDataParallel:
                 "staged_zero2_allreduce_grads requires "
                 "DistributedDataParallel(zero_stage=2, "
                 "comm_topology='hierarchical')")
-        if not self.comm_enabled:
-            raise ValueError(
-                "the ZeRO-2 compute twin is not wired: eliding the "
-                "scatter-reduce would update each shard with local "
-                "grads and the gathered params would diverge")
         world_static = int(lax.axis_size(self.axis_name))
         ici = (int(self.ici_size) if self.ici_size is not None
                else _topology.default_ici_size(world_static))
@@ -1489,8 +1366,8 @@ class DistributedDataParallel:
         registry: per-(dtype, cause) bucket counts and per-dtype bytes.
         Runs at TRACE time — totals count compiled traces, not executed
         steps (per-step totals = these x steps on that executable); the
-        adaptive-summation / cross-replica sharding comm work in
-        PAPERS.md plans against exactly this per-bucket record."""
+        cross-replica sharding comm work in PAPERS.md plans against
+        exactly this per-bucket record."""
         from ..observability import get_registry
         reg = get_registry()
         buckets = reg.counter(
@@ -1511,40 +1388,6 @@ class DistributedDataParallel:
                 b.get("ici_wire_bytes", b["bytes"]))
             lvl.labels(level="dcn", dtype=b["comm_dtype"]).inc(
                 b.get("dcn_wire_bytes", b["bytes"]))
-
-    def supervisor_signals(self) -> Dict[str, Any]:
-        """The wrapper's host-side signal bundle for a training-run
-        supervisor (``observability.supervisor.RunSupervisor``): the
-        trace-time comm accounting and the last flushed numerics
-        summary.  Everything here is plain python the wrapper already
-        holds — feeding it to ``observe_step(comm_stats=...,
-        numerics=...)`` costs no device traffic, which is the whole
-        supervisor contract."""
-        return {"comm_stats": list(self.last_comm_stats),
-                "numerics": dict(self.last_numerics)}
-
-    def record_numerics(self, flushed: Dict[str, Any]) -> Dict[str, Any]:
-        """Fold a flushed ``NumericsMonitor`` summary into the wrapper's
-        observability surface: ``ddp.last_numerics`` (the
-        ``Engine.stats()``-style host view) plus the per-bucket
-        compression-error gauges in the process registry — what the
-        PR 5 bf16 DCN hop actually loses on the wire, next to the
-        byte counters that say what it saves."""
-        self.last_numerics = dict(flushed)
-        from ..observability import get_registry
-        reg = get_registry()
-        for b in flushed.get("buckets", ()):
-            reg.gauge(
-                "ddp_allreduce_compression_sq_error",
-                help="squared bf16 quantization error of one replica's "
-                     "DCN-hop shard, accumulated over observed steps"
-            ).labels(bucket=b["label"]).set(
-                b.get("compression_sq_error", 0.0))
-            reg.counter(
-                "ddp_allreduce_bucket_nonfinite_total",
-                help="nonfinite gradient elements seen per comm bucket"
-            ).labels(bucket=b["label"]).set_total(b["nonfinite"])
-        return self.last_numerics
 
     def broadcast_params(self, params: Any) -> Any:
         """Rank-0 parameter broadcast (reference DDP does this at

@@ -94,9 +94,8 @@ _trace_depth = 0
 # Every outermost window captures into a UNIQUE subdirectory of the
 # requested logdir: start_trace names its session dir by wall-clock
 # SECOND, so repeated captures into one shared logdir used to land in
-# the same session dir and overwrite each other's trace files — the
-# timeline parser (observability.timeline) needs unambiguous capture
-# dirs.  pid + a process-local counter keeps the names unique across
+# the same session dir and overwrite each other's trace files.  pid +
+# a process-local counter keeps the names unique across
 # forks and across captures.
 _capture_dir: Optional[str] = None
 _capture_seq = itertools.count()
@@ -120,9 +119,9 @@ def start_profile(logdir: str = "/tmp/apex_tpu_profile") -> str:
             # start first, increment after: a failed start_trace (e.g. a
             # foreign trace already active) must not leave a phantom
             # refcount that makes every later call a silent no-op —
-            # nor an orphaned empty capture dir (a monitor retrying
-            # /profilez against a long-lived foreign trace would grow
-            # one per attempt)
+            # nor an orphaned empty capture dir (a caller retrying
+            # against a long-lived foreign trace would grow one per
+            # attempt)
             try:
                 jax.profiler.start_trace(cap)
             except BaseException:
@@ -167,8 +166,8 @@ def current_capture_dir() -> Optional[str]:
 
 def last_capture_dir() -> Optional[str]:
     """The most recent window's capture directory — still set after
-    ``stop_profile``, which is when the trace file exists and the
-    timeline parser wants it.  None before the first window."""
+    ``stop_profile``, which is when the trace file exists.  None
+    before the first window."""
     with _trace_lock:
         return _capture_dir
 
@@ -178,9 +177,8 @@ def profile(logdir: str = "/tmp/apex_tpu_profile"):
     """Context-manager trace window; nesting-safe — an inner profile()
     joins the outer window instead of racing jax.profiler.start_trace
     or closing the outer window early.  Yields the window's unique
-    capture directory (parse it with
-    ``observability.timeline.analyze_capture`` AFTER the block exits —
-    the trace file is written at stop)."""
+    capture directory (read it AFTER the block exits — the trace file
+    is written at stop; ``benchmark/lib/trace.py`` is the reader)."""
     cap = start_profile(logdir)
     try:
         yield cap

@@ -27,15 +27,15 @@ def train_one(opt_level: str, loss_scale: Optional[str],
     from apex_tpu import amp, models, optimizers
     from apex_tpu.nn import functional as F
 
-    # "prod" reproduces the production TPU dispatch (fused optimizer /
-    # multi-tensor / flash kernels Pallas, BN jnp) rather than the
-    # parity-test-only FORCE=1 mode: Adam turns any sub-ulp grad
-    # difference near zero into a full ±lr step, so bitwise trajectories
-    # require the fwd/bwd to be the *same* XLA program in both runs
+    # FORCE_PALLAS=1 reproduces the production TPU dispatch (fused
+    # optimizer / multi-tensor / flash kernels Pallas, BN jnp): Adam
+    # turns any sub-ulp grad difference near zero into a full ±lr step,
+    # so bitwise trajectories require the fwd/bwd to be the *same* XLA
+    # program in both runs
     old = {k: os.environ.pop(k, None)
            for k in ("APEX_TPU_FORCE_PALLAS", "APEX_TPU_DISABLE_PALLAS")}
     if pallas:
-        os.environ["APEX_TPU_FORCE_PALLAS"] = "prod"
+        os.environ["APEX_TPU_FORCE_PALLAS"] = "1"
     else:
         os.environ["APEX_TPU_DISABLE_PALLAS"] = "1"
     env_key = ("APEX_TPU_FORCE_PALLAS" if pallas

@@ -9,8 +9,9 @@ apex_tpu.analysis``, ``tests/ci/graph_lint.py``), cost-model dumps
 (``kind: memory``, ``--memory``), replication ledgers (``kind:
 sharding``, ``--sharding``), and what a program writes from
 ``Fleet.record()``, ``SpanRecorder.trace_record``,
-``NumericsMonitor.to_record``, ``RunSupervisor.record``,
-``RecoveryLog.record`` and ``timeline.profile_record``.  The kinds may
+``NumericsMonitor.to_record``, ``RunSupervisor.record`` and
+``RecoveryLog.record``.  A record that declares another
+``schema_version`` than the current one is refused.  The kinds may
 interleave in one stream; a line without a known ``kind`` is an error.
 Usage:
 

@@ -11,10 +11,8 @@ content — including label values that NEED exposition escaping — then:
 
 1. starts :class:`ObservabilityServer` on ``127.0.0.1:0``;
 2. scrapes ``/healthz`` ``/metricsz`` ``/statusz`` ``/flightz``
-   ``/tracez`` (and ``/tracez?trace_id=``) over real HTTP, the
-   ``/profilez`` no-capture shape — with no profiler hook attached
-   (the jax-free deployment) the endpoint must answer 404, never 500 —
-   and ``/compilez`` against a jax-free compilation ledger seeded with
+   ``/tracez`` (and ``/tracez?trace_id=``) over real HTTP,
+   ``/compilez`` against a jax-free compilation ledger seeded with
    a shape retrace, whose differ verdict (culprit argument) must be on
    the snapshot, and ``/tenantz`` in both deployment shapes — with no
    tenant source attached it must serve the valid empty rollup (200,
@@ -195,22 +193,6 @@ def main(argv):
         if code != 404:
             errs.append(f"/tracez unknown trace expected 404, got {code}")
 
-        # /profilez — no profiler hook attached (this loader is
-        # jax-free by design): 404 with a JSON error, not a 500
-        code, _, body = _get(base + "/profilez")
-        if code != 404:
-            errs.append(f"/profilez with no hook expected 404, got "
-                        f"{code}")
-        else:
-            pz = json.loads(body)
-            if "error" not in pz:
-                errs.append(f"/profilez 404 body carries no error: "
-                            f"{pz}")
-        code, _, _ = _get(base + "/profilez?duration_ms=bogus")
-        if code != 400:
-            errs.append(f"/profilez with bad duration expected 400, "
-                        f"got {code}")
-
         # /compilez — the ledger snapshot with the seeded retrace's
         # differ verdict (jax-free: record_trace is pure host python)
         code, _, body = _get(base + "/compilez")
@@ -294,7 +276,7 @@ def main(argv):
                         f"{code}")
 
         # /tenantz?class= — a QoS-aware source adds a per-class
-        # ``classes`` rollup (schema v14) next to its tenants; the
+        # ``classes`` rollup next to its tenants; the
         # filter narrows it per source, 404s only when NO source
         # knows the class, and composes with ?tenant=
         cbucket = dict(bucket, preempted=1, queue_depth=0,
@@ -354,8 +336,8 @@ def main(argv):
         print(f"server_smoke: {e}", file=sys.stderr)
     if errs:
         return 1
-    print("server_smoke: all 8 endpoints OK (exposition conformant, "
-          "schemas valid, profilez no-capture 404, compilez retrace "
+    print("server_smoke: all 7 endpoints OK (exposition conformant, "
+          "schemas valid, compilez retrace "
           "differ verdict served, tenantz empty shape + per-tenant "
           "rollup + per-class ?class= filter + 404, sick-run 503)")
     return 0

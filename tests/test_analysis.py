@@ -1155,7 +1155,7 @@ def test_sharding_ledger_zero3_collapses_replicated_fraction():
     (fraction > 0.8) becomes the parameter store's ICI shard, leaving
     only BN state, scaler scalars and gather tables replicated
     (fraction < 0.01, within the declared ratchet budget).  Records
-    carry the ``zero_stage`` stamp the v15 exporters gate on."""
+    carry the ``zero_stage`` stamp the exporters require."""
     for name in ("ddp_resnet18_o2_zero1", "ddp_resnet18_o2_zero2",
                  "ddp_resnet18_o2_zero3", "ddp_mlp_overlap_zero2"):
         assert name in analysis.ENTRY_POINTS
@@ -1248,7 +1248,8 @@ def test_lint_record_schema_roundtrip():
     rec = _enriched(f)
     assert exporters.validate_lint_record(rec) == []
     assert rec["kind"] == "graph_lint"
-    assert rec["schema_version"] >= 1 and rec["stale"] is False
+    assert rec["schema_version"] == exporters.SCHEMA_VERSION
+    assert rec["stale"] is False
 
     bad = dict(rec)
     bad["severity"] = "catastrophic"
@@ -1281,9 +1282,9 @@ def test_telemetry_jsonl_validates_mixed_stream():
          "healthy": 1, "degraded": 0, "dead": 1, "queue_depth": 0,
          "submitted": 8, "finished": 8, "failed": 0, "shed": 0,
          "retries": 1, "failovers": 3, "drains": 0, "tokens": 64,
-         # the per-tenant rollup, required fresh at schema v11
+         # the per-tenant rollup, required
          "tenants": {}, "tenants_dropped": 0,
-         # the per-QoS-class rollup, required fresh at schema v14
+         # the per-QoS-class rollup, required
          "classes": {}, "preemptions": 0})
     trace_rec = exporters.JsonlExporter.enrich(
         {"kind": "trace", "trace_id": "fleet-1f-1/r0", "span_count": 2,
@@ -1363,7 +1364,7 @@ def test_memory_record_schema_and_dispatch():
 
 
 def test_sharding_record_schema_and_dispatch():
-    """``kind: sharding`` record contract (schema v13): the ledger
+    """``kind: sharding`` record contract: the ledger
     identity must reassemble, the fraction must be consistent, and the
     telemetry dispatcher routes it by kind."""
     import json
@@ -1516,7 +1517,7 @@ def test_cli_entry_and_rule_filters(capsys):
 
 def test_cli_sharding_flag(capsys):
     """`python -m apex_tpu.analysis --sharding`: one `kind: sharding`
-    record per entry point, schema-valid at v13, serving engines
+    record per entry point, schema-valid, serving engines
     skipped via the bare-RuntimeError gate rather than failing."""
     import json
     from apex_tpu.analysis.__main__ import main

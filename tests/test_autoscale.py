@@ -465,8 +465,8 @@ def test_fleet_record_mttr_field_validated():
             "queue_depth": 0, "submitted": 0, "finished": 0,
             "failed": 0, "shed": 0, "retries": 0, "failovers": 0,
             "drains": 0, "tokens": 0, "deadline_exceeded": 0,
-            "tenants": {}, "tenants_dropped": 0,  # required fresh at v11
-            "classes": {}, "preemptions": 0,      # required fresh at v14
+            "tenants": {}, "tenants_dropped": 0,  # required
+            "classes": {}, "preemptions": 0,      # required
             "mttr": {"last": None, "mean": None, "count": 0}}
     assert validate_fleet_record(JsonlExporter.enrich(good)) == []
     bad = dict(good, mttr={"last": -1.0, "mean": 1.0, "count": 1})

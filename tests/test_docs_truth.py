@@ -133,3 +133,39 @@ def test_nothing_points_at_the_harness_that_went():
             text = f.read()
         hits += [f"{rel}: {g}" for g in GONE if g in text]
     assert hits == []
+
+
+# what PR 47 took out, as a reader would meet it in a sentence that
+# offers it: a pattern each, matched without regard to case
+REMOVED = {
+    "timeline": r"observability\.timeline|timeline\.py|make_profiler"
+                r"|analyze_capture|kind: profile",
+    "/profilez": r"/profilez",
+    "adasum": r"adasum",
+    "pallas_syncbn": r"pallas_syncbn|pallas_forced",
+    "comm_enabled": r"comm_enabled|supervisor_signals|record_numerics"
+                    r"|last_numerics|overlap_schedule_fields",
+    "FORCE_PALLAS=prod": r"FORCE_PALLAS\W{1,3}prod",
+}
+
+
+def _offering_docs():
+    yield "README.md"
+    yield "PAPERS.md"
+    for f in sorted(os.listdir(os.path.join(ROOT, "docs"))):
+        if f.endswith(".md"):
+            yield os.path.join("docs", f)
+
+
+@pytest.mark.parametrize("name", list(REMOVED))
+def test_documents_do_not_offer_what_was_removed(name):
+    """``README.md``, ``docs/*.md`` and ``PAPERS.md`` name nothing PR 47
+    deleted.  ``CHANGES.md``, ``ROADMAP.md``, ``PERF.md`` and ``ISSUE.md``
+    are history and may."""
+    pattern = re.compile(REMOVED[name], re.I)
+    hits = []
+    for doc in _offering_docs():
+        for n, line in enumerate(_read(doc).splitlines(), 1):
+            if pattern.search(line):
+                hits.append(f"{doc}:{n}: {line.strip()[:80]}")
+    assert hits == []

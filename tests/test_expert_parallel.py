@@ -293,7 +293,7 @@ def test_sorted_forward_by_gathers_equals_sorted_forward_by_scatter_add(
     through the gate weights among them; no scatter is traced for the rows in
     either direction of the first."""
     import re
-    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "prod")
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "1")
     monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
     layer, params, x = _sorted_layer(expert_type, shared, cap, held)
     rows = 2 * 128 * 4 * held // 16

@@ -521,13 +521,9 @@ def test_fleet_record_schema_and_gauges():
     assert validate_fleet_record({**rec, "finished": 9})  # > submitted
     assert validate_fleet_record(
         {k: v for k, v in rec.items() if k != "shed"})
-    # trace_id is a schema-v2 requirement: missing at v2 errors, but
-    # an archived v1 record (pre-flight-recorder) re-validates clean
+    # trace_id is required: the join key to the request traces
     assert any("trace_id" in e for e in validate_fleet_record(
         {k: v for k, v in rec.items() if k != "trace_id"}))
-    assert validate_fleet_record(
-        {k: v for k, v in rec.items()
-         if k != "trace_id"} | {"schema_version": 1}) == []
     # a malformed schema_version reports, never raises
     assert validate_fleet_record({**rec, "schema_version": None})
     assert validate_fleet_record({**rec, "schema_version": "2"})
@@ -1192,9 +1188,8 @@ def test_tenant_sums_equal_untagged_totals_under_concurrency():
     # both tenants missed their one deadlined request
     assert ts["acme"]["slo_attainment"] == 0.0
     assert ts["zeta"]["slo_attainment"] == 0.0
-    # the v11 record carries the same partition and validates
+    # the record carries the same partition and validates
     rec = JsonlExporter.enrich(fl.record())
-    assert rec["schema_version"] >= 11
     assert validate_fleet_record(rec) == []
     assert sum(b["goodput_tokens"] for b in rec["tenants"].values()) \
         == rec["tokens_within_slo"]
@@ -1204,14 +1199,11 @@ def test_tenant_sums_equal_untagged_totals_under_concurrency():
         "acme": {**rec["tenants"]["acme"],
                  "goodput_tokens": rec["tokens_within_slo"] + 1}}}
     assert validate_fleet_record(broken)
-    # v11 gating: a fresh record WITHOUT the tenant block is rejected;
-    # the same record declaring v10 (an archived stream) stays clean
+    # a record WITHOUT the tenant block is rejected
     stripped = {k: v for k, v in rec.items()
                 if k not in ("tenants", "tenants_dropped")}
     assert any("tenants" in e
                for e in validate_fleet_record(stripped))
-    assert validate_fleet_record(
-        {**stripped, "schema_version": 10}) == []
 
 
 def test_tenant_cardinality_flood_stays_bounded_and_conserved():
@@ -1250,7 +1242,7 @@ def test_tenant_cardinality_flood_stays_bounded_and_conserved():
         # slo folds BEFORE the registry sees the label, so no metric
         # hit its own cap — the fleet surface reports no label drops
         assert fl.tenant_stats()["label_sets_dropped"] == {}
-        # the v11 record stays schema-valid mid-fold
+        # the record stays schema-valid mid-fold
         out = JsonlExporter.enrich(fl.record())
         assert validate_fleet_record(out) == []
         assert out["tenants_dropped"] == 5

@@ -2,6 +2,7 @@
 span tracing / Chrome-trace export, exporters, engine stats, and the
 record schemas."""
 
+import functools
 import json
 import threading
 
@@ -485,31 +486,6 @@ def test_amp_scaler_skip_lands_in_flight_ring():
         obs.set_ring(prev)
 
 
-def test_ddp_comm_enabled_compute_twin_is_collective_free():
-    """comm_enabled=False (the compute twin of a step) elides every
-    gradient collective while keeping the local average, so the twin
-    graph is collective-free and its values are the local mean."""
-    from apex_tpu import parallel
-    mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
-    ddp = parallel.DistributedDataParallel()
-    ddp.comm_enabled = False
-    grads = {"a": jnp.ones((64,), jnp.float32)}
-
-    def step(g):
-        return ddp.allreduce_grads(g)
-
-    mapped = jax.shard_map(step, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_vma=False)
-    txt = str(jax.make_jaxpr(mapped)(grads))
-    assert not any(p in txt for p in ("psum", "all_gather",
-                                      "reduce_scatter", "all_to_all",
-                                      "ppermute")), txt
-    out = jax.jit(mapped)(grads)
-    # local gradient averaged by the axis size, no cross-replica sum
-    assert float(out["a"][0]) == pytest.approx(1.0 / 8)
-    assert ddp.last_comm_stats == []
-
-
 def test_validate_trace_record_pins_causal_shape():
     """kind: trace records — the per-request flight record — must hold
     the causal invariants: unique positive span ids, parents strictly
@@ -913,6 +889,60 @@ def test_profiler_nesting_and_threads(monkeypatch):
     assert depth == 0
 
 
+def test_profiler_unique_capture_dirs(tmp_path):
+    """Repeated captures into ONE logdir land in distinct
+    subdirectories, each holding its own trace file — start_trace names
+    sessions by wall-clock second, so two captures in one second used
+    to overwrite each other."""
+    import glob
+    import os
+    from apex_tpu.utils import profiler
+    f = jax.jit(lambda a: (a @ a).sum())
+    x = jnp.ones((16, 16))
+    f(x).block_until_ready()
+    dirs = []
+    for _ in range(2):
+        with profiler.profile(str(tmp_path)) as cap:
+            assert profiler.current_capture_dir() == cap
+            f(x).block_until_ready()
+        dirs.append(cap)
+    assert dirs[0] != dirs[1]
+    assert all(d.startswith(str(tmp_path)) for d in dirs)
+    assert profiler.current_capture_dir() is None
+    assert profiler.last_capture_dir() == dirs[1]
+    # both captures kept their own trace file — nothing overwritten
+    traces = [glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                     "*.xplane.pb")) for d in dirs]
+    assert all(len(t) == 1 for t in traces), traces
+    assert traces[0] != traces[1]
+    # nested profile() joins the outer window: same dir, refcount
+    # semantics preserved (the nesting test above monkeypatches the
+    # trace calls; this one exercises the real window)
+    with profiler.profile(str(tmp_path)) as outer:
+        with profiler.profile(str(tmp_path / "inner")) as inner:
+            assert inner == outer
+            assert profiler.profiling_active()
+        assert profiler.profiling_active()
+    assert not profiler.profiling_active()
+
+
+def test_failed_start_trace_leaves_no_orphan_dir(tmp_path, monkeypatch):
+    """A foreign trace already active makes start_trace raise; the
+    pre-created unique capture dir must not be left behind (a caller
+    retrying would otherwise grow one orphan per attempt) and the
+    refcount must stay clean."""
+    import os
+    from apex_tpu.utils import profiler
+
+    def boom(d):
+        raise RuntimeError("Only one profile may be run at a time.")
+    monkeypatch.setattr(jax.profiler, "start_trace", boom)
+    with pytest.raises(RuntimeError, match="one profile"):
+        profiler.start_profile(str(tmp_path))
+    assert os.listdir(str(tmp_path)) == []
+    assert not profiler.profiling_active()
+
+
 def test_data_loader_records_wait_times():
     from apex_tpu.data import DataLoader
     reg = obs.MetricsRegistry()
@@ -1261,7 +1291,7 @@ _RECOVERY_BASE = {"kind": "recovery", "role": "training",
     ({"cause": "preemption", "preempted": True,
       "data_state": {"samples_consumed": 80, "epoch": 1, "cursor": 16,
                      "shard_id": 0, "num_shards": 4}}, None),
-    # the v7 action kind is known to the validator
+    # the snapshot action kind is known to the validator
     ({"episodes": 1, "actions_total": 1, "max_actions_in_episode": 1,
       "actions": [{"kind": "preempt_snapshot", "episode": 1,
                    "t_s": 0.5}]}, None),
@@ -1271,12 +1301,10 @@ _RECOVERY_BASE = {"kind": "recovery", "role": "training",
     ({"preempted": "yes"}, "preempted"),
 ], ids=["preempted_ok", "preempt_snapshot_ok", "bad_cause",
         "negative_samples", "shard_out_of_range", "preempted_not_bool"])
-def test_v7_requirements_gate_on_declared_version(extra, named):
-    """Schema v7: recovery records validate cause / preempted /
-    data_state whenever present, and know the ``preempt_snapshot``
-    action kind."""
+def test_recovery_preemption_fields_are_value_checked(extra, named):
+    """Recovery records validate cause / preempted / data_state
+    whenever present, and know the ``preempt_snapshot`` action kind."""
     rec = exporters.JsonlExporter.enrich({**_RECOVERY_BASE, **extra})
-    assert rec["schema_version"] >= 7
     errs = exporters.validate_recovery_record(rec)
     if named is None:
         assert errs == []
@@ -1284,55 +1312,13 @@ def test_v7_requirements_gate_on_declared_version(extra, named):
         assert any(named in e for e in errs), errs
 
 
-def test_v8_profile_records_and_version_gating():
-    """Schema v8: ``kind: profile`` records dispatch to their own
-    validator, alone and in a mixed stream; the KV fragmentation
-    fields a serving profile may carry are value-checked."""
-    prof = exporters.JsonlExporter.enrich(
-        {"kind": "profile", "metric": "resnet18_o2_ddp_flat_profile",
-         "span_ms": 10.0, "device_busy_ms": 8.0, "compute_ms": 7.0,
-         "collective_ms": 3.0, "gap_ms": 2.0, "overlap_ms": 2.0,
-         "measured_overlap_fraction": 0.6667, "kernel_count": 42,
-         "lane_count": 8, "steps": 3,
-         "top_kernels": [{"name": "all-reduce", "kind": "collective",
-                          "count": 24, "total_ms": 3.0}]})
-    assert prof["schema_version"] >= 8
-    assert exporters.validate_profile_record(prof) == []
-    # the dispatcher routes on kind
-    assert exporters.validate_telemetry_record(prof) == []
-    broken = dict(prof, device_busy_ms=99.0)
-    assert exporters.validate_telemetry_record(broken) != []
-    # a mixed stream with a profile line stays clean; a line without a
-    # kind in it does not
-    lint = exporters.JsonlExporter.enrich(
-        {"kind": "graph_lint", "rule": "donation", "severity": "error",
-         "entry_point": "e", "message": "m"})
-    assert exporters.validate_telemetry_jsonl(
-        [json.dumps(prof), json.dumps(lint)]) == []
-    no_kind = exporters.JsonlExporter.enrich(
-        {"metric": "m", "value": 1.0, "unit": "x"})
-    errs = exporters.validate_telemetry_jsonl(
-        [json.dumps(prof), json.dumps(no_kind)])
-    assert len(errs) == 1 and "line 2" in errs[0] and "kind" in errs[0]
-    # KV fragmentation fields on a serving profile (_check_kv_fields)
-    kv = dict(prof, kv_cache_bytes=16384, kv_waste_bytes=4096,
-              kv_utilization=0.75)
-    assert exporters.validate_profile_record(kv) == []
-    for key, bad in (("kv_cache_bytes", -5),
-                     ("kv_waste_bytes", 999_999),     # > the allocation
-                     ("kv_utilization", 1.2)):
-        assert any(key in e for e in exporters.validate_profile_record(
-            dict(kv, **{key: bad}))), key
-
-
 # -- PR 15: the compilation plane ------------------------------------------
 
 
-def test_v11_tenant_fields_and_version_gating():
-    """Schema v11 (the tenant plane): TENANT_COUNTS is pinned to the
-    SLO tracker's actual bucket keys so the fleet-record validator and
-    the producer cannot drift."""
-    assert exporters.SCHEMA_VERSION >= 11
+def test_tenant_counts_are_the_slo_trackers_bucket_keys():
+    """The tenant plane: TENANT_COUNTS is pinned to the SLO tracker's
+    actual bucket keys so the fleet-record validator and the producer
+    cannot drift."""
     from apex_tpu.fleet import slo as fleet_slo
     assert exporters.TENANT_COUNTS == tuple(
         k for k in fleet_slo._new_tenant_bucket()
@@ -1340,7 +1326,7 @@ def test_v11_tenant_fields_and_version_gating():
 
 
 def _ledger_rec(entry_point="ddp_resnet18_o2", repl=7000, **kw):
-    """A schema-complete v13 replication-ledger record (what the
+    """A schema-complete replication-ledger record (what the
     --sharding CLI emits)."""
     arg = 1000
     return exporters.JsonlExporter.enrich({
@@ -1353,12 +1339,10 @@ def _ledger_rec(entry_point="ddp_resnet18_o2", repl=7000, **kw):
         "top_replicated": [], "resharding_eqns": {}, **kw})
 
 
-def test_v13_sharding_records_and_version_gating():
-    """Schema v13 (the sharding plane): ``kind: sharding`` records
-    dispatch to their own validator, the ledger identity must
-    reassemble, and archived streams declaring v1..v12 — which never
-    carry the kind — re-validate clean at their declared versions."""
-    assert exporters.SCHEMA_VERSION >= 13
+def test_sharding_records_reassemble_and_dispatch():
+    """The sharding plane: ``kind: sharding`` records dispatch to
+    their own validator, alone and in a mixed stream, and the ledger
+    identity must reassemble."""
     good = _ledger_rec()
     assert exporters.validate_sharding_record(good) == []
     assert exporters.validate_telemetry_record(good) == []
@@ -1368,34 +1352,31 @@ def test_v13_sharding_records_and_version_gating():
                exporters.validate_sharding_record(
                    dict(good, replicated_bytes=6999,
                         replicated_bytes_by_dtype={"float32": 6999})))
-    # an archived pre-v13 lint record stays valid at its declared
-    # version after the bump
-    rec = exporters.JsonlExporter.enrich(
+    # a mixed stream stays clean; a line without a kind in it does not
+    lint = exporters.JsonlExporter.enrich(
         {"kind": "graph_lint", "rule": "donation",
          "severity": "error", "entry_point": "e", "message": "m"})
-    for v in range(1, 13):
-        archived = dict(rec, schema_version=v)
-        assert exporters.validate_telemetry_record(archived) == [], v
+    assert exporters.validate_telemetry_jsonl(
+        [json.dumps(good), json.dumps(lint)]) == []
+    no_kind = exporters.JsonlExporter.enrich(
+        {"metric": "m", "value": 1.0, "unit": "x"})
+    errs = exporters.validate_telemetry_jsonl(
+        [json.dumps(good), json.dumps(no_kind)])
+    assert len(errs) == 1 and "line 2" in errs[0] and "kind" in errs[0]
 
 
-def test_v15_zero_stage_records_and_version_gating():
-    """Schema v15 (the ZeRO weight-update plane): zero-EP sharding
-    ledgers must carry ``zero_stage`` in {1, 2, 3}; the field is
-    value-checked; archived v14 ledgers re-validate clean at their
-    declared version."""
-    assert exporters.SCHEMA_VERSION == 15
-    # sharding plane: fresh v15 ledgers for zero EPs carry the stage
+def test_zero_ledgers_carry_their_stage():
+    """The ZeRO weight-update plane: zero-EP sharding ledgers must
+    carry ``zero_stage`` in {1, 2, 3}; the field is value-checked."""
     zled = _ledger_rec("ddp_resnet18_o2_zero2", zero_stage=2)
     assert exporters.validate_sharding_record(zled) == []
     missing = {k: v for k, v in zled.items() if k != "zero_stage"}
     assert any("zero_stage" in e for e in
                exporters.validate_sharding_record(missing))
-    archived = dict(missing, schema_version=14)
-    assert exporters.validate_sharding_record(archived) == []
     assert any("zero_stage" in e for e in
                exporters.validate_sharding_record(
                    dict(zled, zero_stage=7)))
-    # non-zero EPs stay exempt at v15
+    # non-zero EPs stay exempt
     assert exporters.validate_sharding_record(_ledger_rec()) == []
 
 
@@ -1492,21 +1473,6 @@ def _recovery_record():
     return log.record()
 
 
-def _profile_record():
-    from apex_tpu.observability import timeline
-
-    def kernel(name, ts, dur, tid):
-        return {"ph": "X", "pid": 7, "tid": tid, "ts": ts, "dur": dur,
-                "name": name,
-                "args": {"hlo_op": name, "hlo_module": "jit_step"}}
-
-    doc = {"traceEvents": [kernel("dot.1", 0.0, 100.0, 2),
-                           kernel("all-reduce.2", 200.0, 100.0, 3),
-                           kernel("fusion.7", 200.0, 50.0, 2)]}
-    att = timeline.attribute_timeline(timeline.device_events(doc))
-    return timeline.profile_record(att, metric="producer")
-
-
 # kind -> the library's own producer of it, at the smallest input that
 # producer's own test builds
 _PRODUCERS = {
@@ -1518,10 +1484,19 @@ _PRODUCERS = {
     "numerics": _numerics_record,
     "run": _run_record,
     "recovery": _recovery_record,
-    "profile": _profile_record,
     "sharding": lambda: _ledger_record(
         analysis.entry_point_sharding_record),
 }
+
+
+@functools.lru_cache(maxsize=None)
+def _produced_json(kind):
+    return json.dumps(exporters.JsonlExporter.enrich(_PRODUCERS[kind]()))
+
+
+def _produced(kind):
+    """The enriched record of ``kind``, made once a process."""
+    return json.loads(_produced_json(kind))
 
 
 @pytest.mark.parametrize("kind", list(_PRODUCERS))
@@ -1530,7 +1505,7 @@ def test_library_producer_passes_its_validator(kind):
     dispatcher as its producer makes it, and the same record with
     ``kind`` removed or misspelt is rejected by name: the dispatcher
     has no default schema."""
-    rec = exporters.JsonlExporter.enrich(_PRODUCERS[kind]())
+    rec = _produced(kind)
     assert rec["kind"] == kind
     assert exporters.validate_telemetry_record(rec) == []
     assert json.loads(json.dumps(rec)) == rec      # a JSONL line
@@ -1538,6 +1513,22 @@ def test_library_producer_passes_its_validator(kind):
                    dict(rec, kind=kind + "s"), dict(rec, kind=None)):
         errs = exporters.validate_telemetry_record(broken)
         assert len(errs) == 1 and "'kind'" in errs[0], errs
+
+
+@pytest.mark.parametrize("kind", list(_PRODUCERS))
+def test_a_record_of_another_schema_version_is_refused(kind):
+    """One schema: the record its producer makes, declaring the
+    version before the current one, gets exactly one error, and that
+    error names both versions.  Nothing else about it is judged more
+    leniently or more strictly."""
+    cur = exporters.SCHEMA_VERSION
+    rec = _produced(kind)
+    assert rec["schema_version"] == cur
+    for other in (cur - 1, cur + 1):
+        errs = exporters.validate_telemetry_record(
+            dict(rec, schema_version=other))
+        assert len(errs) == 1, errs
+        assert str(other) in errs[0] and str(cur) in errs[0], errs
 
 
 def test_dispatcher_knows_exactly_the_produced_kinds():
@@ -1576,7 +1567,7 @@ def _lint_finding():
 def test_record_envelope_is_required(mutate, named):
     """The envelope every kind shares (schema_version / capture host /
     boolean ``stale``), held on a lint record: ``_check_envelope`` is
-    one implementation for all ten kinds."""
+    one implementation for all nine kinds."""
     rec = _lint_finding()
     assert exporters.validate_telemetry_record(rec) == []
     mutate(rec)

@@ -16,8 +16,7 @@ The staged DDP backward issues bucket *i*'s reduction while bucket
   the traced ``comm_stats`` agree bucket-for-bucket (stage,
   issue_order, wire bytes) — the shared-helper contract that keeps a
   schedule change from desyncing plan from graph;
-- **observability contracts** survive the new schedule: the
-  ``comm_enabled=False`` compute twin traces collective-free, and
+- **observability contracts** survive the new schedule:
   ``numerics_out=`` per-bucket scalars arrive in schedule order.
 """
 
@@ -47,14 +46,13 @@ def _mesh():
     return Mesh(np.array(jax.devices()[:8]), ("data",))
 
 
-def make_staged_step(overlap, compress=False, comm_enabled=True,
+def make_staged_step(overlap, compress=False,
                      numerics=False, topo="hierarchical", ici=4):
     """(ddp, mapped_fn) for the staged train step; the mapped fn
     returns (per-stage grads, loss)."""
     ddp = parallel.DistributedDataParallel(
         comm_topology=topo, allreduce_compress_bf16=compress,
         ici_size=ici, overlap=overlap)
-    ddp.comm_enabled = comm_enabled
 
     def step(params_list, batch):
         xb, yb = batch
@@ -175,33 +173,6 @@ def test_overlap_shares_one_axis_size_scalar():
         (e.primitive.name, G.eqn_payload_bytes(e)) for e in scalars]
 
 
-def test_overlap_compute_twin_is_collective_free():
-    """ddp.comm_enabled=False under the staged schedule: the twin
-    traces ZERO collective eqns and computes the local 1/world mean —
-    the step-time attribution contract survives overlapping."""
-    ddp, f_twin = make_staged_step(True, comm_enabled=False)
-    jx = jax.make_jaxpr(f_twin)(STAGE_PARAMS, (X, Y))
-    assert G.collective_eqns(jx) == []
-    assert ddp.last_comm_stats == []
-    assert ddp.last_overlap_schedule is None
-
-    # numerics: twin grads == unreduced local grads / world
-    def local_step(params_list, batch):
-        xb, yb = batch
-        loss, grads = parallel.staged_grads(
-            STAGE_FNS, lambda a: jnp.mean((a - yb) ** 2), params_list,
-            xb)
-        return [jax.tree_util.tree_map(lambda g: g / 8.0, gs)
-                for gs in grads], loss
-
-    local = jax.shard_map(local_step, mesh=_mesh(),
-                          in_specs=(P(), (P("data"), P("data"))),
-                          out_specs=(P(), P()), check_vma=False)
-    for a, b in zip(_grads(f_twin), _grads(local)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6)
-
-
 def test_overlap_schedule_matches_runtime_comm_stats():
     """The shared-helper contract: overlap_comm_schedule (static, from
     shapes) and the traced comm_stats agree bucket-for-bucket on
@@ -227,12 +198,10 @@ def test_overlap_schedule_matches_runtime_comm_stats():
     ls = ddp.last_overlap_schedule
     assert ls["overlap_mode"] == "overlapped" and ls["n_stages"] == S
     assert ls["issue_order"] == sched["issue_order"]
-    fields = parallel.overlap_schedule_fields(ls)
-    assert fields == {"overlap_mode": "overlapped", "n_stages": S,
-                      "issue_order": [3, 2, 1, 0]}
-    assert parallel.overlap_schedule_fields(None) == {
-        "overlap_mode": "reduce_after_backward", "n_stages": 1,
-        "issue_order": [0]}
+    assert ls["issue_order"] == [3, 2, 1, 0]
+    # the plain schedule carries NO zero_stage key at all — absent,
+    # not None, so a reader can gate on presence
+    assert "zero_stage" not in ls
 
 
 def test_overlap_numerics_out_arrives_in_schedule_order():
@@ -270,7 +239,7 @@ def test_overlap_numerics_out_arrives_in_schedule_order():
 
 
 def test_overlap_knob_clashes():
-    for kw in ({"delay_allreduce": True}, {"adasum": True},
+    for kw in ({"delay_allreduce": True},
                {"allreduce_trigger_params": ["w"]}):
         with pytest.raises(ValueError, match="overlap"):
             parallel.DistributedDataParallel(overlap=True, **kw)
@@ -364,9 +333,8 @@ def test_staged_zero2_schedule_tag_and_runtime_stats():
     """Plan/runtime consistency for the fused path: the static
     ``overlap_comm_schedule(zero_stage=2)`` and the traced
     ``comm_stats`` agree bucket-for-bucket (stage, issue order, cause,
-    topology, wire bytes, both fabric levels), the traced schedule is
-    tagged ``zero_stage=2``, and the tag rides into
-    ``overlap_schedule_fields``."""
+    topology, wire bytes, both fabric levels), and the traced schedule
+    is tagged ``zero_stage=2``."""
     ddp, fz = make_zero2_step(True)
     jax.make_jaxpr(fz)(STAGE_PARAMS, (X, Y))
     sched = parallel.overlap_comm_schedule(
@@ -384,30 +352,18 @@ def test_staged_zero2_schedule_tag_and_runtime_stats():
         assert pb["dcn_wire_bytes"] == rb["dcn_wire_bytes"]
     ls = ddp.last_overlap_schedule
     assert ls["zero_stage"] == 2
-    fields = parallel.overlap_schedule_fields(ls)
-    assert fields["zero_stage"] == 2
-    assert fields["overlap_mode"] == "overlapped"
-    # the non-zero schedule carries NO zero_stage key at all — absent,
-    # not None, so a reader can gate on presence
-    assert "zero_stage" not in parallel.overlap_schedule_fields(
-        ddp.last_overlap_schedule | {"zero_stage": None})
+    assert ls["overlap_mode"] == "overlapped"
+    assert ls["issue_order"] == sched["issue_order"]
 
 
 def test_staged_zero2_knob_clashes():
-    """The fused path's guard rails: stage 2 only, hierarchical only,
-    no adasum; the method refuses a DDP without zero_stage=2 armed and
-    refuses the comm-disabled twin (eliding the scatter-reduce would
-    update each shard with LOCAL grads and the gathered params would
-    diverge)."""
+    """The fused path's guard rails: stage 2 only, hierarchical only;
+    the method refuses a DDP without zero_stage=2 armed."""
     with pytest.raises(ValueError, match="stage 2 only"):
         parallel.DistributedDataParallel(
             comm_topology="hierarchical", ici_size=4, zero_stage=3)
     with pytest.raises(ValueError, match="hierarchical"):
         parallel.DistributedDataParallel(zero_stage=2)
-    with pytest.raises(ValueError, match="adasum"):
-        parallel.DistributedDataParallel(
-            comm_topology="hierarchical", ici_size=4, zero_stage=2,
-            adasum=True)
     with pytest.raises(ValueError, match="zero_stage"):
         parallel.overlap_comm_schedule(
             STAGE_PARAMS, comm_topology="hierarchical", ici_size=4,
@@ -422,11 +378,6 @@ def test_staged_zero2_knob_clashes():
 
     armed = parallel.DistributedDataParallel(
         comm_topology="hierarchical", ici_size=4, zero_stage=2)
-    armed.comm_enabled = False
-    with pytest.raises(ValueError, match="compute twin"):
-        armed.staged_zero2_allreduce_grads(
-            STAGE_FNS, lambda a: jnp.sum(a), STAGE_PARAMS, X,
-            lambda stage, p, g: p)
     # a full-gradient allreduce on a zero_stage=2 DDP is refused too
     with pytest.raises(ValueError, match="shards the update"):
         armed.allreduce_grads({"w": X})

@@ -107,7 +107,7 @@ def test_smem_scalar_spec_is_exempt():
 def test_real_kernel_family_lints_clean():
     """Every pallas_call the ops package launches — Adam (both
     write-out arities), LAMB stages, layer-norm fwd/bwd, the
-    multi-tensor family, fused BN apply fwd/bwd, flash attention
+    multi-tensor family, flash attention
     fwd/dq/dkv on head-major and on token-major, grouped operands, the
     rotary pass, and the selective scan's forward and backward —
     satisfies the block/index/alias preconditions."""

@@ -510,7 +510,7 @@ def test_class_action_kinds_registered():
     assert RECOVERY_ACTION_KINDS == exporters.RECOVERY_ACTION_KINDS
 
 
-# -- schema v14: the validator learns the class plane ---------------------
+# -- the validator knows the class plane ---------------------------------
 
 def _fleet_record():
     """A real multi-class fleet record off the stub fleet."""
@@ -523,8 +523,7 @@ def _fleet_record():
     return exporters.JsonlExporter.enrich(fl.record())
 
 
-def test_v14_fleet_record_validates_and_mutations_reject():
-    assert exporters.SCHEMA_VERSION >= 14
+def test_class_plane_fleet_record_validates_and_mutations_reject():
     # CLASS_COUNTS is the class bucket minus its window timestamps —
     # pinned across the package boundary like TENANT_COUNTS
     assert exporters.CLASS_COUNTS == tuple(
@@ -536,16 +535,11 @@ def test_v14_fleet_record_validates_and_mutations_reject():
     assert exporters.validate_fleet_record(good) == []
     assert exporters.validate_telemetry_record(good) == []
 
-    # fresh v14 records REQUIRE the class plane
+    # fleet records REQUIRE the class plane
     for missing in ("classes", "preemptions"):
         bad = {k: v for k, v in good.items() if k != missing}
         assert any(missing in e for e in
                    exporters.validate_fleet_record(bad)), missing
-    # ...but the same record declaring v13 rolls back clean
-    v13 = {k: v for k, v in good.items()
-           if k not in ("classes", "preemptions")}
-    v13["schema_version"] = 13
-    assert exporters.validate_fleet_record(v13) == []
 
     def mutated(**kw):
         rec = json.loads(json.dumps(good))

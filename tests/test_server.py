@@ -124,10 +124,7 @@ def test_all_endpoints_respond(basic_server):
     srv, *_ = basic_server
     for ep in server.ENDPOINTS:
         code, ctype, _ = _get(srv.url + ep)
-        # /profilez is the one opt-in endpoint: without a profiler
-        # hook attached it answers 404 (the no-capture contract), the
-        # rest always serve
-        assert code == (404 if ep == "/profilez" else 200), ep
+        assert code == 200, ep
         want = "text/plain" if ep == "/metricsz" else "application/json"
         assert ctype.startswith(want), (ep, ctype)
     code, idx = _get_json(srv.url + "/")
@@ -284,9 +281,7 @@ def test_live_scrape_of_running_fleet_during_traffic():
         while True:
             for ep in server.ENDPOINTS:
                 code, ctype, body = _get(srv.url + ep)
-                # /profilez has no hook on this fleet server: the
-                # no-capture 404 is its healthy answer
-                assert code == (404 if ep == "/profilez" else 200), ep
+                assert code == 200, ep
                 if ep == "/metricsz":
                     assert exporters.validate_prometheus_text(
                         body.decode()) == []
@@ -350,133 +345,19 @@ def test_live_scrape_of_running_fleet_during_traffic():
         assert list(tzf["by_source"]["fleet"]["tenants"]) == ["batch"]
         code, _ = _get_json(srv2.url + "/tenantz?tenant=nope")
         assert code == 404
-        # the fleet's v11 record (per-tenant block included) is
+        # the fleet's record (per-tenant block included) is
         # schema-clean end to end
         rec = exporters.JsonlExporter.enrich(fleet.record())
-        assert rec["schema_version"] >= 11
+        assert rec["schema_version"] == exporters.SCHEMA_VERSION
         assert exporters.validate_fleet_record(rec) == []
     finally:
         srv2.stop()
 
 
-def test_profilez_404_409_and_success():
-    """/profilez semantics (PR 13): 404 with no hook, 409 while a
-    capture is in flight, 400 on a bad duration, and a hook's record
-    comes back enriched + schema-valid (``kind: profile``)."""
-    fake = {"metric": "fake_capture", "span_ms": 2.0,
-            "device_busy_ms": 1.5, "compute_ms": 1.0,
-            "collective_ms": 0.75, "gap_ms": 0.5, "overlap_ms": 0.25,
-            "measured_overlap_fraction": 0.3333,
-            "kernel_count": 3, "lane_count": 1}
-    seen = []
-
-    def hook(duration_ms=None):
-        seen.append(duration_ms)
-        return dict(fake)
-
-    srv = server.ObservabilityServer(registry=None, profiler=hook
-                                     ).start()
-    try:
-        code, rec = _get_json(srv.url + "/profilez?duration_ms=50")
-        assert code == 200, rec
-        assert seen == [50.0]
-        assert rec["kind"] == "profile"
-        assert rec["schema_version"] >= 8
-        assert exporters.validate_profile_record(rec) == []
-        # bad duration: 400 before the hook runs
-        code, _, _ = _get(srv.url + "/profilez?duration_ms=fast")
-        assert code == 400
-        assert seen == [50.0]
-
-        # in-flight: a hook blocked on a capture turns the second
-        # scrape into 409, not a second concurrent capture
-        gate, entered = threading.Event(), threading.Event()
-
-        def slow_hook(duration_ms=None):
-            entered.set()
-            gate.wait(timeout=10)
-            return dict(fake)
-
-        srv.attach_profiler(slow_hook)
-        results = []
-        t = threading.Thread(target=lambda: results.append(
-            _get(srv.url + "/profilez")))
-        t.start()
-        assert entered.wait(timeout=10)
-        code, _, body = _get(srv.url + "/profilez")
-        assert code == 409, body
-        assert b"in flight" in body
-        gate.set()
-        t.join(timeout=10)
-        assert results and results[0][0] == 200
-        # a hook raising ProfileInFlight itself (foreign trace window)
-        # also maps to 409
-        def foreign(duration_ms=None):
-            raise server.ProfileInFlight("foreign trace window open")
-        srv.attach_profiler(foreign)
-        code, _, body = _get(srv.url + "/profilez")
-        assert code == 409 and b"foreign" in body
-    finally:
-        srv.stop()
-
-
-def test_profilez_live_capture_real_engine():
-    """End-to-end /profilez: a server attached to a live engine with
-    the real timeline hook captures a bounded window WHILE the engine
-    decodes, and the returned record is schema-valid with device
-    kernels attributed."""
-    from apex_tpu import models, serving
-    from apex_tpu.observability import timeline
-    import jax
-    import jax.numpy as jnp
-
-    cfg = models.GPTConfig(vocab_size=64, block_size=16, n_layer=1,
-                           n_head=2, n_embd=16, dropout=0.0)
-    m = models.GPT(cfg)
-    params, _ = m.init(jax.random.PRNGKey(0))
-    eng = serving.Engine(m, params, slots=2, buf_len=16, window=4)
-    eng.add_request([1, 2, 3], max_new_tokens=64)
-    eng.step()                              # compile outside the window
-
-    srv = server.serve(engine=eng,
-                       profiler=timeline.make_profiler(
-                           subject="live_engine",
-                           default_duration_ms=80.0))
-    stop = threading.Event()
-
-    def churn():
-        import time
-        while not stop.is_set():
-            eng.step()
-            if not eng.live():
-                eng.add_request([1, 2, 3], max_new_tokens=64)
-            # throttled: an unthrottled tiny-engine loop dispatches
-            # thousands of programs per second and the capture's
-            # python tracer makes the trace file (and its parse) huge
-            time.sleep(0.01)
-
-    t = threading.Thread(target=churn, daemon=True)
-    t.start()
-    try:
-        code, _, body = _get(srv.url + "/profilez", timeout=120)
-        rec = json.loads(body)
-        assert code == 200, rec
-        assert exporters.validate_profile_record(rec) == []
-        assert rec["metric"] == "live_engine"
-        # the engine was decoding during the window: device kernels
-        # landed in the capture
-        assert rec["kernel_count"] > 0
-        assert rec["device_busy_ms"] > 0
-    finally:
-        stop.set()
-        t.join(timeout=10)
-        srv.stop()
-
-
 def test_ci_server_smoke_gate():
     """The tier-1 wiring of tests/ci/server_smoke.py (like the trend
     gate): the jax-free smoke script boots the server, scrapes all
-    eight endpoints (incl. the /profilez no-capture 404, the /compilez
+    seven endpoints (incl. the /compilez
     ledger snapshot with a seeded retrace verdict, and the /tenantz
     empty shape + seeded per-tenant rollup), and validates exposition
     + JSON schemas."""
@@ -488,7 +369,7 @@ def test_ci_server_smoke_gate():
     r = subprocess.run([sys.executable, script], capture_output=True,
                        text=True, timeout=120)
     assert r.returncode == 0, r.stdout + r.stderr
-    assert "all 8 endpoints OK" in r.stdout
+    assert "all 7 endpoints OK" in r.stdout
 
 
 def test_compilez_live_ledger():
