@@ -334,6 +334,15 @@ def collect_kernel_sites() -> List[KernelSite]:
                 f32(2, 512, 2, 128).astype(jnp.bfloat16))
         jax.grad(lambda a: jnp.sum(
             tm(a, k, k, causal=True, window=200).astype(jnp.float32)))(q)
+        # a score head of a lane tile and a half: q and k at the value
+        # head's 128 with the trailing 64 beside them, two heads a lane
+        # tile, one rope key head for all query heads (dk's rope part
+        # leaves as a float32 tile a leading step)
+        q, qr = (f32(2, 256, 4, d).astype(jnp.bfloat16) for d in (128, 64))
+        kr = f32(2, 256, 1, 64).astype(jnp.bfloat16)
+        jax.grad(lambda a, b: jnp.sum(tm(
+            a, q, q, causal=True, q_rope=qr, k_rope=b).astype(jnp.float32)),
+            (0, 1))(q, kr)
         # rotary embedding on a projection's output, half of each head
         ang = f32(64, 32)
         pallas_rope.rope_token_major(

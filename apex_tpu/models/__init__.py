@@ -11,6 +11,7 @@ from .llama import LlamaConfig, Llama, RMSNorm, llama_params_to_tp
 from .mixtral import MixtralConfig, Mixtral
 from .laguna import LagunaConfig, Laguna
 from .nemotron_h import NemotronHConfig, NemotronH
+from .deepseek_v3 import DeepseekV3Config, DeepseekV3
 from .speculative import generate_speculative
 from .beam import beam_search
 from .t5 import T5Config, T5

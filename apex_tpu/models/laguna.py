@@ -8,8 +8,10 @@ config files of the families built on this shape (``layer_types``,
 ``mlp_layer_types``, one ``rope_parameters`` group per attention kind,
 ``sliding_window``; ``num_attention_heads_per_layer`` or one
 ``num_attention_heads`` for every layer; ``norm_topk_prob``;
-``total_ut_steps``).  Four run in the
-benchmark: ``laguna`` (a leading dense layer, two head counts, a gate on the
+``total_ut_steps``).  Four run in the benchmark as they are built here, and
+two more over these parts (``models/nemotron_h.py``, its one-branch blocks;
+``models/deepseek_v3.py``, which swaps the attention for a latent one):
+``laguna`` (a leading dense layer, two head counts, a gate on the
 attention output, a sigmoid router with a scaling factor and a shared
 expert: the defaults below), ``mellum`` (every layer sparse, one head
 count, no gate, a softmax router, no shared expert), ``lfm2_moe`` (``conv``
@@ -337,6 +339,8 @@ class LagunaAttention(nn.Module):
 
 
 class LagunaBlock(nn.Module):
+    attention = LagunaAttention     # what attends: (cfg, index) -> a module
+
     def __init__(self, cfg: LagunaConfig, layer: int):
         super().__init__()
         self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
@@ -344,7 +348,7 @@ class LagunaBlock(nn.Module):
         if self.mixer == "conv":
             self.conv = GatedShortConv(cfg.hidden_size, cfg.conv_L_cache)
         else:
-            self.self_attn = LagunaAttention(cfg, layer)
+            self.self_attn = self.attention(cfg, layer)
         self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
                                                 cfg.rms_norm_eps)
         self.sandwich = cfg.sandwich_norm

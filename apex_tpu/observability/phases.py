@@ -64,6 +64,14 @@ PHASES = (
     "mamba.scan",          # softplus(dt), the decays and the chunked scan
     "mamba.gate_norm",     # y * silu(z) and the RMSNorm over each group
     "mamba.out_proj",      # y W_out
+    # transformer/mla.py, latent attention (inside ``model``; the flash
+    # kernels between ``mla.rope`` and ``mla.o_proj`` are their own names)
+    "mla.q_proj",          # u W_q, the two parts of the score heads
+    "mla.kv_down",         # u W_kva: the latent and the shared key head
+    "mla.kv_norm",         # RMSNorm over the latent
+    "mla.kv_up",           # the latent to every head's k (no position) and v
+    "mla.rope",            # the rotation of q's rope parts and of the key head
+    "mla.o_proj",          # [o_1 .. o_H] W_o
     # models/laguna.py, attention with ``qk_norm``
     "attn.qk_norm",        # RMSNorm over each head of q and of k, before RoPE
     # models/laguna.py, the looped stack (``total_ut_steps`` > 1)

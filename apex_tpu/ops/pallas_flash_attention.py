@@ -30,8 +30,8 @@ visible (below the diagonal, inside the band, no padded key, no
 ``kv_mask``, segments or dropout) and runs a body with no iota, compare
 or select; an *edge* pair runs the masked body.  The counters
 ``flash_block_pairs_total{kind}``, ``flash_pad_copies_total`` and
-``flash_calls_total{layout, kv}`` say at trace time what a call's grids
-visit, what it copies and which form its operands took.
+``flash_calls_total{layout, kv[, rope]}`` say at trace time what a call's
+grids visit, what it copies and which form its operands took.
 
 Operands come in one of two forms (``_Layout``), named by the entry the
 caller takes, and the kernels address both through the block shape and the
@@ -47,6 +47,20 @@ adds the group's query heads up in its fp32 accumulator and writes each
 K/V head once.  A caller with token-major, grouped operands moves no axis
 and repeats no head on the way in or out; a head-major call with K/V at
 q's head count launches what it launched before there was a choice.
+
+A score head may be wider than the value head by half a lane tile (a
+latent-attention head: 192 = 128 + 64 against values of 128).  It comes in
+two parts, each where its projection wrote it: q and k at the value head's
+width, and the trailing ``_ROPE`` = 64 numbers as ``q_rope`` (B, T, H*64)
+and ``k_rope``, one head for all query heads (B, T, 64).  A score is
+``q . k + q_rope . k_rope``: two contractions a head, the second against a
+(blk, 128) tile that holds the rope keys in the half its head reads and
+zeros in the other, so that two heads share a lane tile of ``q_rope`` and no
+operand is padded or sliced off a lane boundary.  dq's rope part leaves the
+dq kernel beside dq; dk's leaves the dk/dv kernel as one float32 partial sum
+a grid step (its ``hb`` heads), which ``_bwd`` adds up over the steps of a
+batch entry: the gradient of the one head is the sum over all query heads.
+Token-major only, K/V at q's head count, an even number of heads a step.
 
 Operands reach the kernels as they are when T is a whole number of blocks
 and D is under 128 or a multiple of it (a block's last dimension may equal
@@ -94,6 +108,7 @@ FLASH_LSE_NAME = "flash_lse"
 _VMEM_BUDGET = 12 * 1024 * 1024
 _BLK = 512          # q/k rows per block (clamped to the padded seq len)
 _NEG = -1e30
+_ROPE = 64          # a score head's trailing part: half a lane tile
 
 
 def _dot(a, b, contract):
@@ -160,9 +175,11 @@ def _block_for(T: int, window: Optional[int] = None) -> int:
 
 
 def _head_width(D: int) -> int:
-    """The head size the kernels take: ``D`` itself when it is under one
-    lane tile or a whole number of them (a block's last dimension may
-    equal the array's, so nothing is padded), else the next multiple."""
+    """The head size the kernels take of a head-major operand: ``D`` itself
+    when it is under one lane tile or a whole number of them (a block's last
+    dimension may equal the array's, so nothing is padded), else the next
+    multiple.  (A token-major head is whole lane tiles; one a tile and a half
+    wide, 192, comes as 128 and a ``_ROPE`` part and is never padded.)"""
     return D if D < LANES or D % LANES == 0 else -(-D // LANES) * LANES
 
 
@@ -201,10 +218,14 @@ def _band_blocks(window: int, blk: int, n: int) -> int:
 
 
 def fits_vmem(T: int, D: int, dropout: bool = False,
-              segments: bool = False, window: Optional[int] = None) -> bool:
+              segments: bool = False, window: Optional[int] = None,
+              rope: int = 0) -> bool:
     """VMEM needed per grid step and head — independent of T now that K/V
     stream through the grid, at the block ``window`` selects (how many
     heads share a step is ``_heads_per_step``'s, from compiled sizes).
+    ``D``: the value head's width, which q and k have too; ``rope``: what
+    the score head has beyond it (0, or ``_ROPE``: q_rope, k_rope and dk's
+    rope part then ride along, a lane tile each for two heads).
     Sized for the worst pass (backward dK/dV): six double-buffered operand
     blocks (q, k, v, do in; dk, dv out; a head under 128 lanes still fills
     a lane tile in VMEM), the double-buffered (8, blk) row tiles of lse
@@ -216,6 +237,8 @@ def fits_vmem(T: int, D: int, dropout: bool = False,
     blk = _block_for(T, window)
     Dp = -(-D // LANES) * LANES
     operands = 6 * blk * Dp          # q, k, v, do, dk, dv blocks
+    if rope:
+        operands += 3 * blk * LANES  # q_rope, k_rope in; dk's rope part out
     stats = 2 * 8 * blk              # lse + delta row tiles
     resident = 2 * (operands + stats) * 4          # double-buffered
     scratch = 2 * blk * Dp * 4                     # dk/dv fp32 accumulators
@@ -269,11 +292,16 @@ class _Layout:
     on the sequential axis, ``chunks`` of them a streamed block, adding
     into the one dk/dv accumulator.
 
+    ``rope``: the score head has a trailing part of ``_ROPE`` numbers: q's
+    as a block of ``hb * _ROPE`` lanes, the keys' as one head for all query
+    heads, a (1, blk, LANES) block holding it twice.  Two heads share a lane
+    tile of q's block, so a step then takes an even number of heads.
+
     Index maps and kernels address through this class alone; ``_Sweep``
     knows nothing of it."""
 
     def __init__(self, q, k, H: int, token_major: bool, masked: bool,
-                 blk: int):
+                 blk: int, rope: bool = False):
         """``q``, ``k``: the operands as the kernels get them (padded)."""
         if token_major:
             self.B, _, HD = q.shape
@@ -287,6 +315,9 @@ class _Layout:
         self.rep = H // self.Hkv
         self.hb = hb = _heads_per_step(H, self.D, q.dtype.itemsize, masked,
                                        blk, self.rep)
+        self.rope = rope
+        if rope:        # float32 heads go one a step; a rope tile needs two
+            self.hb = hb = max(hb, 2)
         self.hkv = hb if self.rep == 1 else 1      # K/V heads a step holds
         self.chunks = max(1, self.rep // hb)       # steps sharing a K/V block
         self.entry = H // hb                       # leading steps a batch entry
@@ -310,6 +341,21 @@ class _Layout:
                 return entry, at(g, t), heads if c == 1 else heads // c
             return b if c == 1 else b // c, at(g, t), 0
         return pl.BlockSpec(self.block(blk, kv), index)
+
+    def rope_spec(self, blk, at, step, shared=False):
+        """BlockSpec of a rope part: ``hb * _ROPE`` lanes of the step's
+        heads, or (``shared``) the batch entry's one (blk, LANES) tile."""
+        def index(b, g, p):
+            b, t = step(b, p)
+            return b // self.entry, at(g, t), 0 if shared else b % self.entry
+        return pl.BlockSpec(
+            (1, blk, LANES if shared else self.hb * _ROPE), index)
+
+    def rope_tile(self, h, shared=False):
+        """Index of the (blk, LANES) tile that holds head ``h``'s rope part
+        in one of its halves, the lower for an even ``h``; ``shared``: of
+        the one key head's tile, which holds it in both."""
+        return (0, slice(None), pl.ds(0 if shared else h // 2 * LANES, LANES))
 
     def head(self, h):
         """Index of query head ``h``'s (blk, D) in a q-like block."""
@@ -513,15 +559,36 @@ def _column(ref):
     return None if ref is None else ref[0][:, :1]
 
 
+def _rope_halves(kr_ref, lay, blk):
+    """(``keys(h)``, ``half(h, x)``) of one block pair.  ``keys(h)``: the
+    (blk, LANES) rope keys head ``h`` reads, the tile that holds them with
+    the other half zeroed, so that a contraction over the tile's lanes is
+    over the head's own ``_ROPE`` numbers; ``half(h, x)``: a (blk, LANES)
+    ``x`` with all but head ``h``'s half zeroed."""
+    lower = lax.broadcasted_iota(jnp.int32, (blk, LANES), 1) < _ROPE
+    made = {}
+
+    def half(h, x):
+        return jnp.where(lower if h % 2 == 0 else ~lower, x,
+                         jnp.zeros_like(x))
+
+    def keys(h):        # the one key head's tile: two forms, even and odd
+        if h % 2 not in made:
+            made[h % 2] = half(h, kr_ref[lay.rope_tile(h, True)])
+        return made[h % 2]
+
+    return keys, half
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
                 dropout_rate, T_real, Tp):
-    ((q_ref, k_ref, v_ref), kvm_ref, qseg_ref, kseg_ref, seed_ref,
+    ((q_ref, k_ref, v_ref, *rope), kvm_ref, qseg_ref, kseg_ref, seed_ref,
      (o_ref, lse_ref, m_ref, l_ref, acc_ref)) = _split_refs(
-        refs, 3, has_mask, has_segments, dropout_rate)
+        refs, 5 if lay.rope else 3, has_mask, has_segments, dropout_rate)
     hb, blk, D = lay.hb, sweep.blk, lay.D
     b = pl.program_id(0)
     i, j, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
@@ -542,13 +609,20 @@ def _fwd_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
                 window=sweep.window, T_real=T_real, Tp=Tp,
                 kvm=_row(kvm_ref), qseg=_column(qseg_ref),
                 kseg=_row(kseg_ref))
+        if lay.rope:
+            qr_ref, kr_ref = rope
+            rope_keys, _ = _rope_halves(kr_ref, lay, blk)
         for h in range(hb):
             # m, l and alpha are (blk, LANES) tiles with equal lanes from
             # scratch to scratch: they meet the score tile as whole copies
             # of their vregs and the accumulator as they are
             q_h, kv_h = lay.head(h), lay.kv_head(h)
             v = v_ref[kv_h]
-            s = _dot(q_ref[q_h], k_ref[kv_h], ((1,), (1,))) * scale
+            s = _dot(q_ref[q_h], k_ref[kv_h], ((1,), (1,)))
+            if lay.rope:
+                s = s + _dot(qr_ref[lay.rope_tile(h)], rope_keys(h),
+                             ((1,), (1,)))
+            s = s * scale
             if valid is not None:
                 s = jnp.where(valid, s, _NEG)
             m_prev = m_ref[h]
@@ -660,6 +734,24 @@ _SEM = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _rope_operands(qr, kr, Tp):
+    """[q_rope, k_rope] as the kernels take them, or [] where there are none:
+    T a whole number of blocks, and the one key head twice, a lane tile whose
+    lower half the even heads read and whose upper half the odd."""
+    if kr is None:
+        return []
+    qr, kr = (_pad_to(x, Tp, x.shape[-1]) for x in (qr, kr))
+    return [qr, jnp.concatenate([kr, kr], -1)]
+
+
+def _rope_heads_sum(parts):
+    """The one key head's gradient from the dk/dv kernel's float32 partial
+    sums, (B, a batch entry's leading steps, Tp, a tile's halves, _ROPE): a
+    step's even heads added up in the lower half and its odd ones in the
+    upper -> (B, Tp, _ROPE), the sum over every query head."""
+    return parts.sum((1, 3))
+
+
 def _padded(x, Tp, token_major):
     """An operand as the kernels take it: T a whole number of blocks, a
     head-major head of the width ``_head_width`` names."""
@@ -671,23 +763,29 @@ def _padded(x, Tp, token_major):
                                              "dropout_rate", "window",
                                              "token_major"))
 def _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H, dropout_rate,
-         window=None, token_major=False):
+         window=None, token_major=False, qr=None, kr=None):
     """q, k, v: head-major (B*H, T, D), (B*Hkv, T, D) or token-major
     (B, T, H*D), (B, T, Hkv*D).  kvm: (B, Tp) fp32 key validity or None.
     idq/idk: (B, Tp) int32 segment ids as the query and the key side see
-    them, or None.  seed: (1, 2) int32 dropout seed or None.  Returns o in
-    q's form and the logsumexp as (B*H, 8, Tp) sublane-broadcast row
-    tiles."""
+    them, or None.  seed: (1, 2) int32 dropout seed or None.  qr, kr: the
+    score head's rope part, (B, T, H*_ROPE) and (B, T, _ROPE), or None.  Returns o in q's form and the logsumexp as
+    (B*H, 8, Tp) sublane-broadcast row tiles."""
     T = q.shape[1]
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
     qp, kp, vp = (_padded(x, Tp, token_major) for x in (q, k, v))
     masked = kvm is not None or idq is not None or seed is not None
-    lay = _Layout(qp, kp, H, token_major, masked, blk)
+    ropes = _rope_operands(qr, kr, Tp)
+    lay = _Layout(qp, kp, H, token_major, masked, blk, bool(ropes))
     hb, BH = lay.hb, lay.B * H
     sweep = _Sweep(Tp // blk, blk, causal, window, "k", Tp != T, masked)
     specs = row, stream, q_like, kv_like, row_tile, _ = _specs(sweep, lay)
     more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
+    if ropes:
+        more_specs = [lay.rope_spec(blk, row, _each_step),
+                      lay.rope_spec(blk, stream, _each_step, True),
+                      *more_specs]
+        more = [*ropes, *more]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, sweep=sweep, lay=lay,
                           has_mask=kvm is not None,
@@ -715,9 +813,14 @@ def _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H, dropout_rate,
 
 def _dq_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
                dropout_rate, T_real, Tp):
-    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), kvm_ref, qseg_ref,
-     kseg_ref, seed_ref, (dq_ref, dq_acc, lse_w, delta_w)) = _split_refs(
-        refs, 6, has_mask, has_segments, dropout_rate)
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rope), kvm_ref,
+     qseg_ref, kseg_ref, seed_ref, out) = _split_refs(
+        refs, 8 if lay.rope else 6, has_mask, has_segments, dropout_rate)
+    if lay.rope:        # dq's rope part beside dq, and its accumulator last
+        (qr_ref, kr_ref), (dq_ref, dqr_ref, dq_acc, lse_w, delta_w,
+                           dqr_acc) = rope, out
+    else:
+        dq_ref, dq_acc, lse_w, delta_w = out
     hb, blk = lay.hb, sweep.blk
     b = pl.program_id(0)
     i, j, live, first, last = sweep.qk(pl.program_id(1), pl.program_id(2))
@@ -725,6 +828,8 @@ def _dq_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
     @pl.when(first)
     def _init():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+        if lay.rope:
+            dqr_acc[...] = jnp.zeros(dqr_acc.shape, jnp.float32)
         # the row's statistics arrive as (8, blk) row tiles and are
         # widened once a row to the equal-lane columns the pairs use
         for h in range(hb):
@@ -739,10 +844,16 @@ def _dq_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
                 window=sweep.window, T_real=T_real, Tp=Tp,
                 kvm=_row(kvm_ref), qseg=_column(qseg_ref),
                 kseg=_row(kseg_ref))
+        if lay.rope:
+            rope_keys, _ = _rope_halves(kr_ref, lay, blk)
         for h in range(hb):
             q_h, kv_h = lay.head(h), lay.kv_head(h)
             k = k_ref[kv_h]
-            s = _dot(q_ref[q_h], k, ((1,), (1,))) * scale
+            s = _dot(q_ref[q_h], k, ((1,), (1,)))
+            if lay.rope:
+                s = s + _dot(qr_ref[lay.rope_tile(h)], rope_keys(h),
+                             ((1,), (1,)))
+            s = s * scale
             p = jnp.exp(s - _tile_lanes(lse_w[h], blk))
             if valid is not None:
                 p = jnp.where(valid, p, 0.0)
@@ -756,12 +867,17 @@ def _dq_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
                     1.0 / (1.0 - dropout_rate))
             ds = (p * (dp - _tile_lanes(delta_w[h], blk))).astype(k.dtype)
             dq_acc[q_h] += _dot(ds, k, ((1,), (0,)))
+            if lay.rope:    # lands in the head's half: the other is zeros
+                dqr_acc[lay.rope_tile(h)] += _dot(ds, rope_keys(h),
+                                                  ((1,), (0,)))
 
     _run_pair(pair, live, sweep.interior(i, j))
 
     @pl.when(last)
     def _done():
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+        if lay.rope:
+            dqr_ref[...] = (dqr_acc[...] * scale).astype(dqr_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
@@ -771,9 +887,14 @@ def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
     want, and a query's statistics are (1, blk) rows that broadcast along
     sublanes.  The query heads of a K/V group add into one accumulator in
     fp32: ``lay.chunks`` steps a streamed block, ``hb`` heads each."""
-    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), kvm_ref, qseg_ref,
-     kseg_ref, seed_ref, (dk_ref, dv_ref, dk_acc, dv_acc)) = _split_refs(
-        refs, 6, has_mask, has_segments, dropout_rate)
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rope), kvm_ref,
+     qseg_ref, kseg_ref, seed_ref, out) = _split_refs(
+        refs, 8 if lay.rope else 6, has_mask, has_segments, dropout_rate)
+    if lay.rope:        # dk's rope part beside dk and dv
+        (qr_ref, kr_ref), (dk_ref, dv_ref, dkr_ref, dk_acc, dv_acc,
+                           dkr_acc) = rope, out
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = out
     hb, blk = lay.hb, sweep.blk
     b, g, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     b, t = _dkv_step(lay)(b, p)
@@ -787,6 +908,8 @@ def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
     def _init():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+        if lay.rope:
+            dkr_acc[...] = jnp.zeros(dkr_acc.shape, jnp.float32)
 
     def pair(edge):
         valid = None
@@ -798,11 +921,17 @@ def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
                 window=sweep.window, T_real=T_real, Tp=Tp,
                 kvm=_column(kvm_ref), qseg=_row(qseg_ref),
                 kseg=_column(kseg_ref))
+        if lay.rope:
+            rope_keys, half = _rope_halves(kr_ref, lay, blk)
         for h in range(hb):
             q_h, kv_h = lay.head(h), lay.kv_head(h)
             q = q_ref[q_h]
             do = do_ref[q_h]
-            s = _dot(k_ref[kv_h], q, ((1,), (1,))) * scale     # (bk, bq)
+            s = _dot(k_ref[kv_h], q, ((1,), (1,)))             # (bk, bq)
+            if lay.rope:
+                qr = qr_ref[lay.rope_tile(h)]
+                s = s + _dot(rope_keys(h), qr, ((1,), (1,)))
+            s = s * scale
             # padded q rows contribute nothing: their do rows are zero
             p = jnp.exp(s - lse_ref[h][:1, :])
             if valid is not None:
@@ -819,6 +948,12 @@ def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
             dv_acc[kv_h] += _dot(p_acc.astype(do.dtype), do, ((1,), (0,)))
             ds = (p * (dp - delta_ref[h][:1, :])).astype(q.dtype)
             dk_acc[kv_h] += _dot(ds, q, ((1,), (0,)))
+            if lay.rope:
+                # the head's half of ds^T [q_rope of two heads]; the one
+                # key head's tile takes every head of the step, the even
+                # ones in its lower half
+                dkr_acc[lay.rope_tile(h, True)] += half(
+                    h, _dot(ds, qr, ((1,), (0,))))
 
     _run_pair(pair, live, sweep.interior(j, i))
 
@@ -826,22 +961,28 @@ def _dkv_kernel(*refs, scale, sweep, lay, has_mask, has_segments,
     def _done():
         dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if lay.rope:
+            dkr_ref[...] = (dkr_acc[...] * scale).astype(dkr_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "H",
                                              "dropout_rate", "window",
                                              "token_major"))
 def _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed, scale, causal, H,
-         dropout_rate, window=None, token_major=False):
+         dropout_rate, window=None, token_major=False, qr=None, kr=None):
     """lse: the forward's (B*H, 8, Tp) row tiles; the rest as in _fwd.
     dk and dv come back in k's form: one head a K/V head, the sum over its
-    query heads taken in the kernel's fp32 accumulator."""
+    query heads taken in the kernel's fp32 accumulator.  With a rope part
+    also dq_rope and dk_rope in qr's and kr's forms: the one shared head's
+    as the sum over all query heads, a grid step's heads in the kernel's
+    accumulator and the steps of a batch entry here, in float32."""
     T = q.shape[1]
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
     qp, kp, vp, dop = (_padded(x, Tp, token_major) for x in (q, k, v, do))
     masked = kvm is not None or idq is not None or seed is not None
-    lay = _Layout(qp, kp, H, token_major, masked, blk)
+    ropes = _rope_operands(qr, kr, Tp)
+    lay = _Layout(qp, kp, H, token_major, masked, blk, bool(ropes))
     hb, BH = lay.hb, lay.B * H
     prod = do.astype(jnp.float32) * o.astype(jnp.float32)
     if token_major:         # (B, T, H*D) -> a row of T a head
@@ -862,19 +1003,33 @@ def _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed, scale, causal, H,
     sweep = _Sweep(Tp // blk, blk, causal, window, "k", Tp != T, masked)
     specs = row, stream, q_like, kv_like, row_tile, _ = _specs(sweep, lay)
     more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
+    out_specs, out_shape = q_like(row), jax.ShapeDtypeStruct(qp.shape, q.dtype)
+    scratch = [acc(*lay.block(blk)), acc(hb, blk, LANES), acc(hb, blk, LANES)]
+    if ropes:
+        q_rope = lay.rope_spec(blk, row, _each_step)
+        more_specs = [q_rope, lay.rope_spec(blk, stream, _each_step, True),
+                      *more_specs]
+        more = [*ropes, *more]
+        out_specs = [out_specs, q_rope]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct(ropes[0].shape, qr.dtype)]
+        scratch.append(acc(1, blk, hb * _ROPE))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sweep=sweep, **kinds),
         grid=(BH // hb, sweep.rows, sweep.steps),
         in_specs=[q_like(row), kv_like(stream), kv_like(stream),
                   q_like(row), row_tile(row), row_tile(row), *more_specs],
-        out_specs=q_like(row),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        scratch_shapes=[acc(*lay.block(blk)), acc(hb, blk, LANES),
-                        acc(hb, blk, LANES)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=_SEM,
         interpret=interpret(),
         name="flash_dq",
     )(qp, kp, vp, dop, lse, delta, *more)
+    dqr = None
+    if ropes:
+        dq, dqr = dq
+        dqr = _unpad(dqr, T, qr.shape[-1])
 
     # dk/dv: a row is a k block (with v and the key-side tiles); Q, dO,
     # their statistics and the q ids stream, a K/V group's query heads
@@ -883,34 +1038,52 @@ def _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed, scale, causal, H,
     specs = row, stream, q_like, kv_like, row_tile, _ = _specs(
         sweep, lay, _dkv_step(lay))
     more_specs, more = _optional(sweep, specs, kvm, idq, idk, seed)
-    dk, dv = pl.pallas_call(
+    out_specs = [kv_like(row), kv_like(row)]
+    out_shape = [jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                 jax.ShapeDtypeStruct(kp.shape, v.dtype)]
+    scratch = [acc(*lay.block(blk, kv=True))] * 2
+    if ropes:
+        more_specs = [lay.rope_spec(blk, stream, _each_step),
+                      lay.rope_spec(blk, row, _each_step, True), *more_specs]
+        more = [*ropes, *more]
+        # a float32 partial sum a leading step (its hb heads)
+        out_specs.append(pl.BlockSpec(
+            (1, blk, LANES), lambda b, g, p: (b, row(g, p), 0)))
+        out_shape.append(jax.ShapeDtypeStruct((BH // hb, Tp, LANES),
+                                              jnp.float32))
+        scratch = [*scratch, acc(1, blk, LANES)]
+    dk, dv, *dkr = pl.pallas_call(
         functools.partial(_dkv_kernel, sweep=sweep, **kinds),
         grid=(BH // hb // lay.chunks, sweep.rows, sweep.steps * lay.chunks),
         in_specs=[q_like(stream), kv_like(row), kv_like(row),
                   q_like(stream), row_tile(stream), row_tile(stream),
                   *more_specs],
-        out_specs=[kv_like(row), kv_like(row)],
-        out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                   jax.ShapeDtypeStruct(kp.shape, v.dtype)],
-        scratch_shapes=[acc(*lay.block(blk, kv=True))] * 2,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=_SEM,
         interpret=interpret(),
         name="flash_dkv",
     )(qp, kp, vp, dop, lse, delta, *more)
+    if ropes:
+        dkr = _unpad(_rope_heads_sum(dkr[0].reshape(
+            lay.B, lay.entry, Tp, 2, _ROPE)).astype(kr.dtype), T, _ROPE)
     return (_unpad(dq, T, q.shape[-1]), _unpad(dk, T, k.shape[-1]),
-            _unpad(dv, T, k.shape[-1]))
+            _unpad(dv, T, k.shape[-1]), dqr, dkr if ropes else None)
 
 
 # ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
 
-def _count_call(q, k, H, token_major, causal, window, masked, launches):
+def _count_call(q, k, H, token_major, causal, window, masked, launches,
+                kr=None):
     """Trace-time counters of one flash call (docs/observability.md): the
-    form its operands took, the block pairs each launch's grid visits by
-    kind, and the operands padded and results sliced around the kernels
-    (forward: q, k, v in and o out; backward: q, k, v, do in and dq, dk,
-    dv out)."""
+    form its operands took (``rope="shared"`` where the score head has a
+    rope part: one key head for all query heads),
+    the block pairs each launch's grid visits by kind, and the operands
+    padded and results sliced around the kernels (forward: q, k, v in and o
+    out; backward: q, k, v, do in and dq, dk, dv out; the rope parts too)."""
     T, last = q.shape[1:]
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
@@ -920,7 +1093,8 @@ def _count_call(q, k, H, token_major, causal, window, masked, launches):
            "form of their operands: token_major (B, T, H*D) or head_major "
            "(B*H, T, D); K/V once per K/V head (grouped) or per query head",
            layout="token_major" if token_major else "head_major",
-           kv="grouped" if grouped else "per_query_head")
+           kv="grouped" if grouped else "per_query_head",
+           **({} if kr is None else {"rope": "shared"}))
     heads = q.shape[0] * (H if token_major else 1)      # B * H
     for streams in launches:
         _Sweep(Tp // blk, blk, causal, window, streams, Tp != T,
@@ -930,53 +1104,57 @@ def _count_call(q, k, H, token_major, causal, window, masked, launches):
            "flash-attention operands padded and results sliced, per "
            "traced call; 0 when T is a whole number of blocks and D is "
            "under 128 or a multiple of it",
-           0 if fits else {"k": 4, "kq": 7}[launches])
+           0 if fits else ({"k": 4, "kq": 7}[launches]
+                           + (0 if kr is None else 2 * len(launches))))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, kvm, idq, idk, seed, scale: float, causal: bool,
            H: int, dropout_rate: float, window: Optional[int],
-           token_major: bool):
+           token_major: bool, qr=None, kr=None):
     return _flash_fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
-                      dropout_rate, window, token_major)[0]
+                      dropout_rate, window, token_major, qr, kr)[0]
 
 
 def _flash_fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
-               dropout_rate, window, token_major):
+               dropout_rate, window, token_major, qr=None, kr=None):
     masked = kvm is not None or idq is not None or seed is not None
-    _count_call(q, k, H, token_major, causal, window, masked, "k")
+    _count_call(q, k, H, token_major, causal, window, masked, "k", kr)
     o, lse = _fwd(q, k, v, kvm, idq, idk, seed, scale, causal, H,
-                  dropout_rate, window, token_major)
+                  dropout_rate, window, token_major, qr, kr)
     # named before they part into primal output and residuals: a name on
     # the output alone leaves the residual o un-named one equation
     # upstream, and partial evaluation replays the kernel to get it
     o = checkpoint_name(o, FLASH_OUT_NAME)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
-    return o, (q, k, v, o, lse, kvm, idq, idk, seed)
+    return o, (q, k, v, o, lse, kvm, idq, idk, seed, qr, kr)
 
 
 def _flash_bwd(scale, causal, H, dropout_rate, window, token_major, res,
                do):
-    q, k, v, o, lse, kvm, idq, idk, seed = res
+    q, k, v, o, lse, kvm, idq, idk, seed, qr, kr = res
     masked = kvm is not None or idq is not None or seed is not None
-    _count_call(q, k, H, token_major, causal, window, masked, "kq")
-    dq, dk, dv = _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed,
-                      scale, causal, H, dropout_rate, window, token_major)
+    _count_call(q, k, H, token_major, causal, window, masked, "kq", kr)
+    dq, dk, dv, dqr, dkr = _bwd(q, k, v, o, lse, do, kvm, idq, idk, seed,
+                                scale, causal, H, dropout_rate, window,
+                                token_major, qr, kr)
     dkvm = None if kvm is None else jnp.zeros_like(kvm)
     # int primals -> float0 cotangents
     f0 = lambda a: (None if a is None
                     else np.zeros(a.shape, jax.dtypes.float0))
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-            dkvm, f0(idq), f0(idk), f0(seed))
+            dkvm, f0(idq), f0(idk), f0(seed), dqr, dkr)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _attend(q, k, v, token_major, causal, scale, kv_mask, dropout_rate,
-            dropout_seed, segment_ids, window):
+            dropout_seed, segment_ids, window, q_rope=None, k_rope=None):
     """The checks and operand preparation both entries share.  q, k, v
-    arrive 4-D, heads on axis 1 (head-major) or 2 (token-major)."""
+    arrive 4-D, heads on axis 1 (head-major) or 2 (token-major); the rope
+    part of a wider score head (token-major only) as (B, T, H, _ROPE) and
+    (B, T, 1, _ROPE)."""
     h_axis, t_axis = (2, 1) if token_major else (1, 2)
     if q.ndim != 4:
         raise ValueError("expected "
@@ -993,7 +1171,19 @@ def _attend(q, k, v, token_major, causal, scale, kv_mask, dropout_rate,
             f"{v.shape}")
     if token_major and D % LANES:
         raise ValueError("token-major operands need a head of whole lane "
-                         f"tiles (D % {LANES} == 0), got D = {D}")
+                         f"tiles (D % {LANES} == 0; a score head of a tile "
+                         f"and a half comes as q_rope and k_rope beside "
+                         f"it), got D = {D}")
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together")
+    if q_rope is not None and (
+            q_rope.shape != (B, T, H, _ROPE) or Hkv != H or H % 2
+            or k_rope.shape != (B, T, 1, _ROPE)):
+        raise ValueError(
+            f"a score head's rope part is q_rope (B, T, H, {_ROPE}) and "
+            f"k_rope (B, T, 1, {_ROPE}) beside token-major q, k, v at "
+            f"one, even head count: got {q.shape}, {k.shape}, "
+            f"{q_rope.shape}, {k_rope.shape}")
     dropout_rate = float(dropout_rate)
     if not 0.0 <= dropout_rate < 1.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got "
@@ -1005,8 +1195,8 @@ def _attend(q, k, v, token_major, causal, scale, kv_mask, dropout_rate,
         if not causal or window < 1:
             raise ValueError("window needs causal=True and window >= 1, "
                              f"got causal={causal}, window={window}")
-    if scale is None:
-        scale = 1.0 / math.sqrt(D)
+    if scale is None:       # over the whole score head
+        scale = 1.0 / math.sqrt(D + (0 if q_rope is None else _ROPE))
     blk = _block_for(T, window)
     Tp = -(-T // blk) * blk
     kvm = None
@@ -1040,9 +1230,10 @@ def _attend(q, k, v, token_major, causal, scale, kv_mask, dropout_rate,
         fold = lambda x: x.reshape(B, T, -1)
     else:
         fold = lambda x: x.reshape(-1, T, D)
+    ropes = () if q_rope is None else (fold(q_rope), fold(k_rope))
     out = _flash(fold(q), fold(k), fold(v), kvm, idq, idk, seed,
                  float(scale), bool(causal), H, dropout_rate, window,
-                 token_major)
+                 token_major, *ropes)
     return out.reshape(q.shape)
 
 
@@ -1097,12 +1288,22 @@ def flash_attention_token_major(q: jax.Array, k: jax.Array, v: jax.Array,
                                 dropout_rate: float = 0.0,
                                 dropout_seed: Optional[jax.Array] = None,
                                 segment_ids: Optional[jax.Array] = None,
-                                window: Optional[int] = None) -> jax.Array:
+                                window: Optional[int] = None,
+                                q_rope: Optional[jax.Array] = None,
+                                k_rope: Optional[jax.Array] = None
+                                ) -> jax.Array:
     """``flash_attention`` on operands where the projections wrote them:
     q (B, T, H, D); k, v (B, T, Hkv, D); the result (B, T, H, D).  The
     kernels read them as (B, T, H*D) — no axis is moved and no K/V head
     repeated on the way in or out, forward or backward.  Needs
     ``D % 128 == 0`` (a head is then whole lane tiles of a token's row);
-    everything else as in ``flash_attention``, the same three kernels."""
+    everything else as in ``flash_attention``, the same three kernels.
+
+    ``q_rope`` (B, T, H, 64) with ``k_rope`` (B, T, 1, 64): the trailing part of a score head wider than the value head (latent
+    attention: 192 = 128 + 64 against values of 128), each part where its
+    projection wrote it.  A score is ``q . k + q_rope . k_rope`` over
+    ``sqrt(D + 64)`` unless ``scale`` says otherwise; one ``k_rope`` head
+    serves every query head and takes the sum of their gradients.  K/V at
+    q's head count, an even one."""
     return _attend(q, k, v, True, causal, scale, kv_mask, dropout_rate,
-                   dropout_seed, segment_ids, window)
+                   dropout_seed, segment_ids, window, q_rope, k_rope)

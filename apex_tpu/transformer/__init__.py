@@ -10,5 +10,6 @@ from .attention import (dot_product_attention,
                         MultiheadAttention)
 from .short_conv import GatedShortConv, gated_short_conv
 from .mamba2 import Mamba2Mixer, ssd_chunked
+from .mla import LatentAttention, rope_interleaved
 from .ring_attention import ring_attention, ring_self_attention
 from .ulysses import ulysses_attention, ulysses_self_attention

@@ -174,7 +174,9 @@ def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
                                       scale: Optional[float] = None,
                                       window: Optional[int] = None,
                                       kv_mask: Optional[jax.Array] = None,
-                                      segment_ids: Optional[jax.Array] = None
+                                      segment_ids: Optional[jax.Array] = None,
+                                      q_rope: Optional[jax.Array] = None,
+                                      k_rope: Optional[jax.Array] = None
                                       ) -> jax.Array:
     """``dot_product_attention`` on operands where the projections wrote
     them: q (B, T, H, D); k, v (B, T, Hkv, D) with ``Hkv`` dividing ``H``
@@ -194,15 +196,40 @@ def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
     Elsewhere the dense path groups the query heads over the K/V heads in one
     einsum, softmax in fp32.  ``causal``, ``window``, ``segment_ids`` as in
     ``dot_product_attention``; ``kv_mask``: (B, T) bool key validity (True
-    = attend), with its caveat on fully-masked rows."""
+    = attend), with its caveat on fully-masked rows.
+
+    A value head narrower than the score head (latent attention: scores over
+    192 = 128 + 64, values of 128): q, k and v at the value head's width
+    ``D`` and the score head's trailing part beside them, ``q_rope``
+    (B, T, H, R) and ``k_rope`` (B, T, 1, R), one head that every query head
+    reads; a score is ``q . k + q_rope . k_rope`` over ``sqrt(D + R)`` and
+    the result is (B, T, H, D).  Each part stays where its projection wrote
+    it: on TPU at ``D % 128 == 0`` and ``R == 64`` the flash kernels take the
+    parts as they are (two heads' rope parts a lane tile;
+    ``flash_calls_total{rope="shared"}``), nothing is padded to 256 and the
+    one shared head's gradient is the kernels' sum over the query heads.
+    Elsewhere, and for a ``k_rope`` of a head a query head (B, T, H, R), the
+    dense path joins the parts."""
     B, T, H, D = q.shape
     Hkv = k.shape[2]
     if k.shape != v.shape or k.shape != (B, T, Hkv, D) or H % Hkv:
         raise ValueError("expected q (B, T, H, D) and k, v (B, T, Hkv, D) "
-                         f"with Hkv dividing H, got {q.shape}, {k.shape}, "
-                         f"{v.shape}")
+                         "with Hkv dividing H (a wider score head's trailing "
+                         "part comes as q_rope and k_rope), got "
+                         f"{q.shape}, {k.shape}, {v.shape}")
+    R = 0
+    if q_rope is not None or k_rope is not None:
+        if q_rope is None or k_rope is None:
+            raise ValueError("q_rope and k_rope come together")
+        R = q_rope.shape[-1]
+        if (q_rope.shape != (B, T, H, R) or Hkv != H
+                or k_rope.shape not in ((B, T, 1, R), (B, T, H, R))):
+            raise ValueError(
+                "expected q_rope (B, T, H, R) and k_rope (B, T, 1 or H, R) "
+                "beside q, k, v at one head count, got "
+                f"{q.shape}, {k.shape}, {q_rope.shape}, {k_rope.shape}")
     if scale is None:
-        scale = 1.0 / math.sqrt(D)
+        scale = 1.0 / math.sqrt(D + R)
     if window is not None and (not causal or window < 1):
         raise ValueError("window needs causal=True and window >= 1, got "
                          f"causal={causal}, window={window}")
@@ -210,14 +237,24 @@ def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
     # 'dot_product_attention' is in amp.lists.FP16_FUNCS), on either path
     from ..amp import policy as _pol
     (q, k, v), _ = _pol.cast_op_args("dot_product_attention", (q, k, v), {})
+    if R:
+        (q_rope, k_rope), _ = _pol.cast_op_args(
+            "dot_product_attention", (q_rope, k_rope), {})
     from ..ops import dispatch
-    if dispatch.use_pallas_for(q) and (D % 128 == 0 or D < 128):
+    # what the kernels take: a head under a lane tile or of whole ones; with a
+    # rope part, whole ones, the part half a tile and two heads to share it
+    takes = (D % 128 == 0 and R == 64 and H % 2 == 0
+             and k_rope.shape[2] == 1) if R else (D % 128 == 0 or D < 128)
+    if dispatch.use_pallas_for(q) and takes:
         from ..ops import pallas_flash_attention as pfa
         if pfa.fits_vmem(T, D, segments=segment_ids is not None,
-                         window=window):
+                         window=window, rope=R):
             _note_path("flash")
             how = dict(causal=causal, scale=scale, kv_mask=kv_mask,
                        segment_ids=segment_ids, window=window)
+            if R:
+                return pfa.flash_attention_token_major(
+                    q, k, v, q_rope=q_rope, k_rope=k_rope, **how)
             if D % 128 == 0:
                 return pfa.flash_attention_token_major(q, k, v, **how)
             heads_first = lambda x: jnp.swapaxes(x, 1, 2)
@@ -234,7 +271,11 @@ def dot_product_attention_token_major(q: jax.Array, k: jax.Array,
     if segment_ids is not None:
         see = both(see, (segment_ids[:, None, None, :, None]
                          == segment_ids[:, None, None, None, :]))
-    grouped = q.reshape(B, T, Hkv, H // Hkv, D)
+    if R:       # the score head whole: (B, T, H, D + R) on both sides
+        q = jnp.concatenate([q, q_rope], -1)
+        k = jnp.concatenate(
+            [k, jnp.broadcast_to(k_rope, (B, T, H, R))], -1)
+    grouped = q.reshape(B, T, Hkv, H // Hkv, D + R)
     scores = jnp.einsum("bqgrd,bkgd->bgrqk", grouped, k,
                         preferred_element_type=jnp.float32) * scale
     if see is not None:

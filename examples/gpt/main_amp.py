@@ -25,6 +25,9 @@ and the one-branch decoder of models/nemotron_h.py (Mamba-2 mixers, routed
 relu2 experts, position-free attention) the same way:
   python examples/gpt/main_amp.py --arch nemotron_h -b 1 --block-size 8192 \
       --model-config benchmark/configs/nemotron3-nano-30b-a3b.json
+and the latent-attention decoder of models/deepseek_v3.py:
+  python examples/gpt/main_amp.py --arch deepseek_v3 -b 2 --block-size 8192 \
+      --model-config benchmark/configs/kanana-2-30b-a3b.json
 
 ``build(args)`` returns the model, mesh, state and jitted train step that
 ``main()`` loops over; the benchmark and the tests drive the same objects.
@@ -45,8 +48,9 @@ if os.path.isdir(os.path.join(_repo, "apex_tpu")) and _repo not in sys.path:
     sys.path.insert(0, _repo)
 
 # --arch values built from a --model-config file: by models/laguna.py, and
-# (nemotron_h) by models/nemotron_h.py over the same parts
-PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe", "ouro", "nemotron_h")
+# (nemotron_h, deepseek_v3) by the modules of those names over the same parts
+PER_LAYER_ARCHS = ("laguna", "mellum", "lfm2_moe", "ouro", "nemotron_h",
+                   "deepseek_v3")
 
 # enough structure to be learnable at tiny scale: a looping pangram
 _BUILTIN_TEXT = ("the quick brown fox jumps over the lazy dog. " * 200)
@@ -102,14 +106,19 @@ def parse_args(argv=None):
                         "nemotron_h, the one-branch decoder of "
                         "models/nemotron_h.py (Mamba-2 mixers, routed relu2 "
                         "experts with a shared one, attention without "
-                        "positions, by hybrid_override_pattern)")
+                        "positions, by hybrid_override_pattern); or "
+                        "deepseek_v3, models/deepseek_v3.py (multi-head "
+                        "latent attention in every layer, a leading dense "
+                        "layer, routed experts with shared ones behind a "
+                        "sigmoid router with a selection bias)")
     p.add_argument("--model-config", default=None, metavar="JSON",
-                   help="laguna, mellum, lfm2_moe, ouro, nemotron_h: a "
-                        "config file "
+                   help="laguna, mellum, lfm2_moe, ouro, nemotron_h, "
+                        "deepseek_v3: a config file "
                         "with the published keys, whose model_type is "
                         "--arch (benchmark/configs/laguna-xs2.json, "
                         "mellum2-12b.json, lfm2-8b-a1b.json, "
-                        "ouro-2.6b.json, nemotron3-nano-30b-a3b.json); the "
+                        "ouro-2.6b.json, nemotron3-nano-30b-a3b.json, "
+                        "kanana-2-30b-a3b.json); the "
                         "sequence length is "
                         "--block-size")
     p.add_argument("--n-kv-head", type=int, default=None,
@@ -176,9 +185,10 @@ def _network(args, n_chars):
             raise SystemExit(f"--arch {args.arch}: {args.model_config} is "
                              f"a {file_cfg['model_type']!r} config")
         T = args.block_size or file_cfg.get("seq_len", 512)
-        config, network = ((models.NemotronHConfig, models.NemotronH)
-                           if args.arch == "nemotron_h"
-                           else (models.LagunaConfig, models.Laguna))
+        config, network = {
+            "nemotron_h": (models.NemotronHConfig, models.NemotronH),
+            "deepseek_v3": (models.DeepseekV3Config, models.DeepseekV3),
+        }.get(args.arch, (models.LagunaConfig, models.Laguna))
         cfg = config.from_dict(
             file_cfg, max_position_embeddings=max(
                 T, file_cfg.get("max_position_embeddings", T)))

@@ -9,7 +9,9 @@ reference, and the block-pair counters; the token-major operand form with
 K/V once per K/V head against the same reference and against the head-major
 call on the same data moved and repeated by hand; and, compiled for a
 described v5e, the kernels at the widths of the benchmark's cells and the
-decoder's attention layer around them."""
+decoder's attention layer around them; and a score head of a lane tile and a
+half against a value head of one (192 / 128, latent attention), its trailing 64
+as ``q_rope`` / ``k_rope``, one key head for all query heads or one a head."""
 
 import math
 import re
@@ -417,6 +419,102 @@ def test_dot_product_attention_token_major_dispatches_like_the_head_major_entry(
         attention.dot_product_attention_token_major(*qkv, window=8)
 
 
+# -- a score head wider than the value head: 192 = 128 + 64 against 128 ---------
+
+def _two_widths(dtype, rope_heads, H, T, B=2):
+    ks = jax.random.split(jax.random.PRNGKey(T + rope_heads), 6)
+    q, k, v, do = (jax.random.normal(kk, (B, T, H, 128), dtype) for kk in ks[:4])
+    return (q, k, v, jax.random.normal(ks[4], (B, T, H, 64), dtype),
+            jax.random.normal(ks[5], (B, T, rope_heads, 64), dtype)), do
+
+
+def _dense_two_widths(q, k, v, q_rope, k_rope, **features):
+    """``_dense_all`` on the score head whole: (B, H, T, 192) against values of
+    128 (it scales by the width of q, and v's may be another)."""
+    heads = lambda x: jnp.moveaxis(x.astype(jnp.float32), 2, 1)
+    k_rope = jnp.broadcast_to(k_rope, q_rope.shape)
+    qq, kk = (heads(jnp.concatenate(parts, -1)) for parts in ((q, q_rope), (k, k_rope)))
+    return jnp.moveaxis(_dense_all(qq, kk, heads(v), **features), 1, 2)
+
+
+TWO_WIDTHS = {      # name: (dtype, rope key heads, H, T, features, tolerance)
+    "shared-bf16-4-heads-a-step-folded-triangle": (jnp.bfloat16, 1, 4, 1024, dict(causal=True), 2e-2),
+    "shared-fp32-2-heads-a-step": (jnp.float32, 1, 2, 512, dict(causal=True), 2e-5),
+    "shared-fp32-ragged": (jnp.float32, 1, 4, 200, dict(causal=True), 2e-5),
+    "shared-fp32-masked-and-packed": (jnp.float32, 1, 2, 256, dict(
+        causal=True, kv_mask=lambda B, T: _half_masked(B, T).at[-1].set(True),
+        segment_ids=lambda B, T: _thirds(B, T)), 2e-5),
+    # a key head a query head: the entry's dense path alone takes it
+    "per-head-fp32-ragged-dense": (jnp.float32, 4, 4, 200, dict(causal=True), 2e-5),
+    "per-head-bf16-whole-dense": (jnp.bfloat16, 2, 2, 256, dict(), 2e-2),
+}
+
+
+@pytest.mark.parametrize("name", TWO_WIDTHS)
+def test_a_score_head_of_192_against_values_of_128_matches_dense_outputs_and_every_gradient(name):
+    """o, dq, dk, dv and both rope parts' gradients against the dense path on the
+    joined heads; the one shared key head's gradient is the sum over the query
+    heads (the kernel's over a step's heads, ``_bwd``'s over the steps).  Without
+    the shared head (a key head a query head) the kernels refuse and the entry's
+    dense path joins the parts."""
+    dtype, rope_heads, H, T, features, tol = TWO_WIDTHS[name]
+    operands, do = _two_widths(dtype, rope_heads, H, T)
+    features = {f: (x(2, T) if callable(x) else x) for f, x in features.items()}
+    entry = (pfa.flash_attention_token_major if rope_heads == 1
+             else attention.dot_product_attention_token_major)
+    flash = lambda q, k, v, qr, kr: entry(q, k, v, q_rope=qr, k_rope=kr, **features)
+    f32 = lambda x: x.astype(jnp.float32)
+    both = lambda fn: jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(f32(fn(*a)) * f32(do)), (0, 1, 2, 3, 4)))(*operands)
+    got = jax.jit(flash)(*operands)
+    want = _dense_two_widths(*operands, **features)
+    assert got.shape == (2, T, H, 128) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(want), atol=tol)
+    (_, gg), (_, gw) = both(flash), both(lambda *a: _dense_two_widths(*a, **features))
+    for x, g, w in zip(("q", "k", "v", "q_rope", "k_rope"), gg, gw):
+        assert g.shape == w.shape and g.dtype == dtype and np.isfinite(np.asarray(f32(g))).all()
+        scale = max(1.0, float(jnp.abs(f32(w)).max()))
+        np.testing.assert_allclose(np.asarray(f32(g)), np.asarray(f32(w)), atol=5 * tol * scale,
+                                   err_msg=f"d{x}")
+
+
+def test_the_dispatch_takes_the_two_parts_to_the_kernels_and_joins_them_on_the_dense_path(
+        monkeypatch):
+    operands, _ = _two_widths(jnp.float32, 1, 2, 256)
+    want = _dense_two_widths(*operands, causal=True)
+    call = lambda: attention.dot_product_attention_token_major(
+        *operands[:3], causal=True, q_rope=operands[3], k_rope=operands[4])
+    paths = []
+    attention.set_path_hook(paths.append)
+    try:
+        dense = call()
+        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "1")
+        monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
+        flash = call()
+        # a rope part that is not half a lane tile stays off the kernels
+        narrow = attention.dot_product_attention_token_major(
+            *operands[:3], causal=True, q_rope=operands[3][..., :32], k_rope=operands[4][..., :32])
+    finally:
+        attention.set_path_hook(None)
+    assert paths == ["dense", "flash", "dense"] and narrow.shape == dense.shape
+    for got in (dense, flash):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-6)
+    q, k, v, qr, kr = operands
+    with pytest.raises(ValueError, match="come together"):
+        attention.dot_product_attention_token_major(q, k, v, q_rope=qr)
+    with pytest.raises(ValueError, match="k_rope"):
+        attention.dot_product_attention_token_major(q, k, v, q_rope=qr, k_rope=kr[:, :, :, :32])
+    with pytest.raises(ValueError, match="rope part"):       # K/V at q's head count
+        pfa.flash_attention_token_major(q, k[:, :, :1], v[:, :, :1], q_rope=qr, k_rope=kr)
+    with pytest.raises(ValueError, match="q_rope and k_rope"):
+        pfa.flash_attention_token_major(q, k, v, k_rope=kr)
+    # the value head's width is q's and k's: a wider score head comes in two parts
+    with pytest.raises(ValueError, match="q_rope and k_rope"):
+        attention.dot_product_attention_token_major(
+            jnp.concatenate([q, qr], -1), jnp.concatenate([k, jnp.broadcast_to(kr, qr.shape)], -1),
+            v)
+
+
 # -- the counters: what a call's grids visit, and what it copies ---------------
 
 def _counted(fn, *shapes):
@@ -503,6 +601,46 @@ def test_counter_says_which_form_a_call_took():
     # a grouped call visits the pairs of its query heads, and pads nothing
     got = _counted(jax.grad(loss(tm)), x(1, 8192, 6, 128), x(1, 8192, 1, 128), x(1, 8192, 1, 128))
     assert got == {"interior": 6 * 3 * 120, "edge": 6 * 3 * 16, "dead": 0, "pads": 0}
+
+
+def test_a_call_with_a_rope_part_is_told_apart_and_launches_the_cells_grids():
+    """The latent-attention cell's call (2, 8192, 32, 128 + 64 / 128, one key
+    head): counted with ``rope="shared"``, nothing padded, the pairs of the
+    folded triangle, 4 heads a step as the accepted token-major calls at 128,
+    the rope parts two heads a lane tile, dk's rope part one float32 tile a
+    step; the accepted calls' counts carry no third label."""
+    from apex_tpu.analysis import pallas_lint
+    x = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    shapes = (x(2, 8192, 32, 128),) * 3 + (x(2, 8192, 32, 64), x(2, 8192, 1, 64))
+    loss = lambda q, k, v, qr, kr: jnp.sum(pfa.flash_attention_token_major(
+        q, k, v, causal=True, q_rope=qr, k_rope=kr).astype(jnp.float32))
+    pfa._fwd.clear_cache(), pfa._bwd.clear_cache()
+    sites = []
+    with pallas_lint.capture_kernel_sites(sites):
+        assert _calls_counted(jax.grad(loss, (0, 1, 2, 3, 4)), *shapes) == {
+            ("per_query_head", "token_major", "shared"): 2}
+    pfa._fwd.clear_cache(), pfa._bwd.clear_cache()
+    assert _counted(jax.grad(loss), *shapes) == {
+        "interior": 64 * 3 * 120, "edge": 64 * 3 * 16, "dead": 0, "pads": 0}
+    with pytest.raises(ValueError, match="rope part"):       # one key head, not one a head
+        jax.eval_shape(loss, *shapes[:4], x(2, 8192, 32, 64))
+    assert [s.name for s in sites] == ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+    fwd, dq, dkv = sites
+    for site in sites:
+        assert site.grid == (16, 8, 17) and pallas_lint.check_site(site) == []
+        blocks = [spec.block_shape for spec in site.in_specs]
+        assert blocks[:3] == [(1, 512, 512)] * 3            # 4 heads of 128, q, k and v alike
+    rope = lambda site: [spec.block_shape for spec, (shape, _) in zip(site.in_specs, site.in_shapes)
+                         if shape[-1] in (2048, 128) and len(shape) == 3 and shape[1] == 8192]
+    assert rope(fwd) == rope(dq) == rope(dkv) == [(1, 512, 256), (1, 512, 128)]
+    assert [tuple(s) for s, _ in dkv.out_shapes] == [(2, 8192, 4096), (2, 8192, 4096),
+                                                     (16, 8192, 128)]
+    assert [tuple(s) for s, _ in dq.out_shapes] == [(2, 8192, 4096), (2, 8192, 2048)]
+    assert pfa.fits_vmem(8192, 128, rope=64) and pfa.fits_vmem(8192, 128)
+    # what the accepted token-major cells launch is what they launched: counted as before
+    plain = _calls_counted(lambda q, k, v: pfa.flash_attention_token_major(q, k, v, causal=True),
+                           *shapes[:3])
+    assert plain == {("per_query_head", "token_major"): 1}
 
 
 # what the parent of PR 29 launched for these head-major calls: grid, q block, k block
@@ -651,6 +789,84 @@ def test_v5e_compiles_the_decoders_rematerialized_attention_layer_with_one_forwa
     text = _attention_layer_step(one_chip, kind, heads)
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_v5e_compiles_the_flash_kernels_at_the_latent_attention_cells_widths(one_chip, for_the_chip,
+                                                                             dtype):
+    """(2, 8192, 32, 128 + 64 / 128), one key head for all query heads, in bf16
+    (the cell's call, 4 heads a step) and in float32 (2 a step: a rope tile
+    needs two): three Mosaic kernels inside the 16 MiB a kernel may use, no
+    operand padded, no (T, T) array."""
+    x = lambda *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, qr, kr):
+        return jnp.sum(pfa.flash_attention_token_major(q, k, v, causal=True, q_rope=qr, k_rope=kr)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        x(2, 8192, 32, 128), x(2, 8192, 32, 128), x(2, 8192, 32, 128), x(2, 8192, 32, 64),
+        x(2, 8192, 1, 64)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert kernel in text
+    assert _largest_pad(text) <= 2 * 8192 * 128 and "8192,8192]" not in text
+
+
+def _largest_pad(text):
+    """Elements of the largest array any ``pad`` of a compiled program writes (the
+    one key head written twice into a lane tile is a pad and a maximum, 2 x 8192
+    x 128; an operand padded to the next lane tile would be q's size and more)."""
+    sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"= \w+\[([\d,]+)\][^=]*? pad\(", text)]
+    return max(sizes, default=0)
+
+
+def test_v5e_compiles_the_latent_attention_layer_without_pads_or_copies_around_the_kernels(
+        one_chip, for_the_chip):
+    """``transformer.LatentAttention`` forward + gradient under the cell's remat
+    mode at the cell's widths (B 2, T 8192, bf16): each flash kernel once, q's
+    two parts, k, v and the one key head reach them where the projections (and
+    the rotation) wrote them: the entry computation pads nothing, and copies,
+    moves, widens or repeats nothing of q's size (2 x 8192 x 32 x 128)."""
+    import json
+    import os
+    from apex_tpu import models
+    from apex_tpu.models._remat import wrap_block
+    from apex_tpu.models.deepseek_v3 import DeepseekV3Block
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "kanana-2-30b-a3b.json")) as f:
+        cfg = models.DeepseekV3Config.from_dict(json.load(f))
+    attn = DeepseekV3Block.attention(cfg, 1)
+    shapes = jax.eval_shape(lambda k: attn.init(k)[0], jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(wrap_block(lambda pp, xx: attn(pp, xx), cfg.remat)(p, x).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert _largest_pad(text) <= 2 * 8192 * 128 and "8192,8192]" not in text
+    q_elements = 2 * 8192 * 32 * 128
+    entry = text[text.index("ENTRY"):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?.*?\)?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", shape):
+            if int(np.prod([int(d) for d in dims.split(",")])) < q_elements:
+                continue
+            assert dtype != "f32", f"an fp32 array of q's size outside a fusion: {line[:200]}"
+            assert op not in ("copy", "transpose", "reshape", "convert", "broadcast", "pad",
+                              "concatenate"), f"{op} of q's size: {line[:200]}"
+    # dq's rope part beside dq, dk's as one float32 tile a grid step (8 of them a batch entry)
+    assert re.search(r"%flash_dq[.\d]* = \(bf16\[2,8192,4096\]\S*, bf16\[2,8192,2048\]", text)
+    assert re.search(r"%flash_dkv[.\d]* = \(bf16\[2,8192,4096\]\S*, bf16\[2,8192,4096\]\S*, "
+                     r"f32\[16,8192,128\]", text)
 
 
 def test_v5e_compiles_the_flash_kernels_at_the_encoder_cells_widths(one_chip, for_the_chip):

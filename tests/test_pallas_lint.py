@@ -126,10 +126,14 @@ def test_real_kernel_family_lints_clean():
     # array, and dk/dv's sequential axis six times its sweep where six
     # query heads go one a step
     dkv = [s for s in sites if s.name == "_dkv_kernel"]
-    assert [s.grid for s in dkv] == [(2, 1, 1), (1, 2, 18), (4, 2, 2)]
+    assert [s.grid for s in dkv] == [(2, 1, 1), (1, 2, 18), (4, 2, 2), (2, 1, 1)]
     assert dkv[1].in_specs[0].block_shape == (1, 128, 128)
     assert dkv[2].in_specs[0].block_shape == (1, 256, 4 * 128)
     assert dkv[2].in_specs[1].block_shape == (1, 256, 128)
+    # a score head with a rope part: the step's four heads' 64 each as two lane
+    # tiles, the one shared key head as one, dk's rope part a float32 tile a step
+    assert [spec.block_shape for spec in dkv[3].in_specs[6:8]] == [(1, 256, 256), (1, 256, 128)]
+    assert dkv[3].out_shapes[2] == ((2, 256, 128), "float32")
 
 
 def test_grouped_products_are_linted_with_their_work_items():

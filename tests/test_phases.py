@@ -165,6 +165,16 @@ def programs():
         text = jax.jit(jax.grad(lambda p: ssm.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
             sparams).compile().as_text()
         out["mamba"] = (_scopes(text), phases.instruction_phases(text))
+
+        # the per-layer decoder with latent attention (models/deepseek_v3.py)
+        lat = models.DeepseekV3(models.DeepseekV3Config.from_dict(dict(
+            vocab_size=64, hidden_size=16, intermediate_size=32, num_hidden_layers=1,
+            first_k_dense_replace=1, num_attention_heads=2, qk_nope_head_dim=8,
+            qk_rope_head_dim=4, v_head_dim=8, kv_lora_rank=8, rope_theta=10000.0, head_chunk=32)))
+        aparams, _ = lat.init(jax.random.PRNGKey(6))
+        text = jax.jit(jax.grad(lambda p: lat.loss(p, jnp.ones((1, 16), jnp.int32)))).lower(
+            aparams).compile().as_text()
+        out["mla"] = (_scopes(text), phases.instruction_phases(text))
     finally:
         C.set_ledger(prev)
     return out
@@ -180,7 +190,9 @@ CASES = ([("mesh", s) for s in TRAIN_SCOPES + DDP_SCOPES]
             ("conv", "attn.qk_norm"),
             ("loop", "loop"), ("loop", "loop.norm"), ("loop", "loss.head"), ("loop", "loss.exit"),
             ("mamba", "mamba.in_proj"), ("mamba", "mamba.conv"), ("mamba", "mamba.scan"),
-            ("mamba", "mamba.gate_norm"), ("mamba", "mamba.out_proj")])
+            ("mamba", "mamba.gate_norm"), ("mamba", "mamba.out_proj"),
+            ("mla", "mla.q_proj"), ("mla", "mla.kv_down"), ("mla", "mla.kv_norm"),
+            ("mla", "mla.kv_up"), ("mla", "mla.rope"), ("mla", "mla.o_proj")])
 
 
 def test_every_scope_of_the_vocabulary_has_a_case():
