@@ -22,7 +22,9 @@ Checks per site:
 - **block divisibility**: every blocked operand's (padded) shape must
   divide by its ``BlockSpec`` block shape — the kernels pre-pad via
   ``to_2d``/``_pad2`` exactly so this holds, and a refactor that drops
-  the pad reads partial tiles;
+  the pad reads partial tiles; a dim that does not divide is a fault
+  where a grid step reaches its partial block (an operand read by column
+  blocks out of a wider array, the short convolution's, never does);
 - **index-map bounds**: the block index the spec's ``index_map``
   returns at every grid corner must stay within
   ``[0, shape[d] // block[d])`` for every dim; a map that reads
@@ -154,21 +156,25 @@ def _check_operand(site: KernelSite, kind: str, i: int, spec,
             f"{site.name}: {kind}[{i}] block shape {block} rank != "
             f"operand shape {shape}")
         return
-    n_blocks = []
+    n_blocks, ragged = [], {}
     for d, (s, b) in enumerate(zip(shape, block)):
         if b < 1:
             problems.append(
                 f"{site.name}: {kind}[{i}] block dim {d} is {b}")
             return
         if s % b != 0:
-            problems.append(
+            ragged[d] = (
                 f"{site.name}: {kind}[{i}] dim {d} (= {s}) not "
                 f"divisible by block {b} — the kernel reads/writes "
                 f"partial tiles (missing pad?)")
         n_blocks.append(max(1, s // b))
     index_map = getattr(spec, "index_map", None)
     if index_map is None or not site.grid:
+        problems.extend(ragged.values())
         return
+    # with an index map the whole blocks bound the indices below, so a
+    # ragged dim is a fault only where a step reaches its partial block
+    # (an operand read by column blocks out of a wider array is not)
     # evaluate the index map at every grid corner: the extremes bound
     # the affine maps these kernels use, so a step past the last block
     # shows up at a corner.  A map that looks its block up in
@@ -197,7 +203,10 @@ def _check_operand(site: KernelSite, kind: str, i: int, spec,
                 f"{len(idx)} indices for a rank-{len(block)} block")
             return
         for d, (v, n) in enumerate(zip(idx, n_blocks)):
-            if not (0 <= v < n):
+            if v == n and d in ragged:
+                if ragged[d] not in problems:
+                    problems.append(ragged[d])
+            elif not (0 <= v < n):
                 problems.append(
                     f"{site.name}: {kind}[{i}] index_map at grid "
                     f"point {corner} returns block index {v} for dim "
@@ -281,11 +290,12 @@ def collect_kernel_sites() -> List[KernelSite]:
     from ..ops import (pallas_adam, pallas_common, pallas_flash_attention,
                        pallas_grouped_matmul, pallas_lamb,
                        pallas_layer_norm, pallas_multi_tensor, pallas_rope,
-                       pallas_ssd)
+                       pallas_short_conv, pallas_ssd)
 
     _clear_jit_caches(pallas_adam, pallas_flash_attention,
                       pallas_grouped_matmul, pallas_lamb, pallas_layer_norm,
-                      pallas_multi_tensor, pallas_rope, pallas_ssd)
+                      pallas_multi_tensor, pallas_rope, pallas_short_conv,
+                      pallas_ssd)
     sites: List[KernelSite] = []
     rng = np.random.RandomState(18)
     f32 = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
@@ -354,6 +364,18 @@ def collect_kernel_sites() -> List[KernelSite]:
         jax.grad(lambda a: jnp.sum(pallas_ssd.ssd_scan(
             a, np.abs(f32(1, 256, 4)) * 0.1, -np.abs(f32(4)), bc, bc, f32(4),
             128)))(xs)
+        # the short convolution's pair in both forms: a step's block and
+        # the 16-row halo blocks before and after it, clamped at the
+        # sequence's ends (three blocks of tokens: both clamps and an
+        # interior step); the gated backward's last axis walks the three
+        # parts of the one cotangent; the silu form reads its columns from
+        # an offset inside a projection whose width is not whole blocks
+        rows3 = 3 * pallas_short_conv._ROWS
+        jax.grad(lambda a: jnp.sum(pallas_short_conv.short_conv(
+            a, f32(3, 128), form="gated")))(f32(2, rows3, 3 * 128))
+        jax.grad(lambda a: jnp.sum(pallas_short_conv.short_conv(
+            a, f32(4, 256), f32(256), form="silu", offset=128)))(
+                f32(1, rows3, 128 + 256 + 64))
         # the grouped products, forward and both gradients: their index
         # maps look blocks up in work items computed from the groups'
         # sizes, so one trace a representative split of the rows (all in

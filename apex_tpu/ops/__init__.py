@@ -14,6 +14,8 @@ Kernel inventory (TPU-native equivalents of the reference csrc/ tree):
   pallas_grouped_matmul — the routed experts' grouped matrix products over
                         rows sorted by group, forward and both gradients
   pallas_ssd          — the selective scan as a kernel pair, chunk by chunk
+  pallas_short_conv   — the short causal convolution along the sequence with
+                        its gates or SiLU, one pass forward and one backward
   row_moves           — the routed experts' rows to the row buffer and home
                         by gathers (no kernel of ours: the compiler's gather)
   pallas_common       — block arithmetic the kernels share

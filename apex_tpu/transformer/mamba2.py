@@ -48,12 +48,22 @@ autodiff's of this form.  Either way the block's rematerialization
 (``models/_remat.py``) decides what is kept: nothing of the scan, so a
 rematerialized block runs its forward twice.
 
+**The convolution** (:func:`causal_conv_silu`) reads ``xBC`` where the
+projection wrote it, columns ``d_in .. d_in + d_in + 2 G N`` of ``u W_in``:
+where the dispatch and ``ops.pallas_short_conv.takes`` allow it (the offset
+and the channels whole lane tiles, the tokens whole blocks) it is that
+module's kernel pair in its ``silu`` form, one pass a direction with the
+shift done in VMEM; elsewhere a ``pad`` and ``L`` static slices, which the
+TPU's compiler does not fuse into one pass (it writes float32 arrays of
+``xBC``'s size; PERF.md section 6, PR 49).
+
 ``A_log``, ``dt_bias``, ``D``, the taps, their bias and the gain meet float32
 values and stay float32 under amp (``fp32_param_names``).  Scopes
 ``mamba.in_proj`` / ``mamba.conv`` / ``mamba.scan`` / ``mamba.gate_norm`` /
 ``mamba.out_proj`` (observability/phases.py); ``mamba_mixers_total{heads,
-state, groups}`` and ``ssd_scan_calls_total{impl, chunk}`` (``impl`` is
-``pallas`` or ``chunked_xla``) count what a traced program holds
+state, groups}``, ``ssd_scan_calls_total{impl, chunk}`` (``impl`` is
+``pallas`` or ``chunked_xla``) and ``short_conv_calls_total{taps, impl}``
+(``pallas`` or ``xla``) count what a traced program holds
 (docs/observability.md).
 """
 
@@ -70,7 +80,7 @@ from ..nn.module import Module
 from ..ops.pallas_common import token_tile_axes
 
 __all__ = ["Mamba2Mixer", "selective_scan", "ssd_chunked", "causal_conv_silu",
-           "gated_group_norm"]
+           "causal_conv_silu_xla", "gated_group_norm"]
 
 
 def ssd_chunked(x, dt, A, B, C, D, chunk: int):
@@ -140,16 +150,36 @@ def selective_scan(x, dt, A, B, C, D, chunk: int):
         x, dt, A, B, C, D, chunk)
 
 
-def causal_conv_silu(xbc, taps, bias):
-    """``xbc`` (b, T, c); ``taps`` (L, c), a tap a row; ``bias`` (c,)
-    -> ``silu(conv(xbc) + bias)``, depthwise and causal (zero before a row's
-    first token), in ``xbc``'s dtype with float32 between: a ``pad`` in front
-    and ``L`` static slices, as ``short_conv.gated_short_conv`` shifts."""
+def causal_conv_silu_xla(xbc, taps, bias):
+    """:func:`causal_conv_silu` as XLA compiles it: a ``pad`` in front and
+    ``L`` static slices, as ``short_conv.gated_short_conv_xla`` shifts."""
     L, T = taps.shape[0], xbc.shape[1]
     g = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (L - 1, 0), (0, 0)))
     w = taps.astype(jnp.float32)
     y = sum(w[k] * g[:, k:k + T] for k in range(L)) + bias.astype(jnp.float32)
     return (y * jax.nn.sigmoid(y)).astype(xbc.dtype)
+
+
+def causal_conv_silu(x, taps, bias, offset: int = 0):
+    """``x`` (b, T, W), of which the convolution reads the ``c`` columns from
+    ``offset`` (the mixer hands over its projection's whole output);
+    ``taps`` (L, c), a tap a row; ``bias`` (c,) -> ``silu(conv(x) + bias)``,
+    (b, T, c), depthwise and causal (zero before a row's first token), in
+    ``x``'s dtype with float32 between: by ``ops/pallas_short_conv.py``'s
+    kernel pair (the ``silu`` form, the columns picked by its index maps)
+    where the dispatch and the shapes allow it, else
+    :func:`causal_conv_silu_xla` over the slice; counts the call under what
+    implements it."""
+    from ..ops import dispatch, pallas_short_conv
+    from .short_conv import count_short_conv
+    kernel = dispatch.pallas_enabled() and pallas_short_conv.takes(
+        x, taps, bias, form="silu", offset=offset)
+    count_short_conv(taps.shape[0], kernel)
+    if kernel:
+        return pallas_short_conv.short_conv(x, taps, bias, form="silu",
+                                            offset=offset)
+    return causal_conv_silu_xla(x[..., offset:offset + taps.shape[1]], taps,
+                                bias)
 
 
 def gated_group_norm(y, z, gain, groups: int, eps: float):
@@ -221,9 +251,8 @@ class Mamba2Mixer(Module):
             zxbcdt = self.in_proj(p["in_proj"], u)
         z = zxbcdt[..., :d_in]
         with jax.named_scope("mamba.conv"):
-            xbc = causal_conv_silu(
-                zxbcdt[..., d_in:d_in + self.conv_dim], p["conv1d"]["weight"],
-                p["conv1d"]["bias"])
+            xbc = causal_conv_silu(zxbcdt, p["conv1d"]["weight"],
+                                   p["conv1d"]["bias"], offset=d_in)
         with jax.named_scope("mamba.scan"):
             dt = jax.nn.softplus(
                 zxbcdt[..., d_in + self.conv_dim:].astype(jnp.float32)

@@ -1046,16 +1046,19 @@ def test_v5e_compiles_a_grouped_head_of_64_through_the_head_major_kernels(one_ch
     assert "bf16[16,8192,64]" in text and "8192,8192]" not in text
 
 
-@pytest.mark.parametrize("mode,kernels", [
-    ("dots", ["ssd_fwd", "ssd_fwd", "ssd_bwd"]), (None, ["ssd_fwd", "ssd_bwd"])])
-def test_v5e_compiles_a_mamba_mixers_scan_as_the_kernel_pair(one_chip, for_the_chip, mode, kernels):
+@pytest.mark.parametrize("mode,passes", [
+    ("dots", ["fwd", "fwd", "bwd"]), (None, ["fwd", "bwd"])])
+def test_v5e_compiles_a_mamba_mixers_scan_as_the_kernel_pair(one_chip, for_the_chip, mode, passes):
     """One Mamba-2 block at ``nemotron3-nano-30b-a3b``'s widths (64 heads of
     64, 8 groups, state 128, chunks of 128, 1 x 8192 tokens), forward and
-    backward: every Mosaic kernel of the step stands under ``mamba.scan``, the
-    forward, under a ``remat`` its replay (which also writes the chunks'
-    entering states), and the backward under a transposed scope; no array of
-    ``(.., 128, 128)`` decays or scores of any type, which is what XLA's form
-    of the scan writes; the counter names the kernels."""
+    backward: every Mosaic kernel of the step stands under ``mamba.scan`` or
+    under ``mamba.conv`` (the convolution's pair reads its 6144 columns from
+    column 4096 of the projection's 10304), the forward, under a ``remat``
+    its replay (the scan's also writes the chunks' entering states), and the
+    backward under a transposed scope; no array of ``(.., 128, 128)`` decays
+    or scores of any type, which is what XLA's form of the scan writes, and no
+    float32 array of the convolved columns' size, which is what its form of
+    the shift writes; the counters name the kernels."""
     import re
     from apex_tpu.models import _remat
     from apex_tpu.observability.metrics import get_registry
@@ -1074,18 +1077,65 @@ def test_v5e_compiles_a_mamba_mixers_scan_as_the_kernel_pair(one_chip, for_the_c
         with jax.named_scope("model"):
             return jnp.sum(block(p, x).astype(jnp.float32) ** 2)
 
-    counted = lambda: get_registry().counter("ssd_scan_calls_total").labels(
-        impl="pallas", chunk="128").value
+    reg = get_registry()
+    counted = lambda: (
+        reg.counter("ssd_scan_calls_total").labels(impl="pallas", chunk="128").value,
+        reg.counter("short_conv_calls_total").labels(taps="4", impl="pallas").value)
     before = counted()
     text = jax.jit(jax.grad(loss, (0, 1))).lower(params, u).compile().as_text()
-    assert counted() > before
+    assert all(a > b for a, b in zip(counted(), before))
     assert not re.search(r"\[[\d,]*128,128\]", text)
+    assert "= f32[1,8192,6144]" not in text[text.index("ENTRY"):]
     phases = instruction_phases(text)
-    found = []
+    found = {"mamba.scan": [], "mamba.conv": []}
     for name in re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
                            text[text.index("ENTRY"):]):
         path, backward = phases[name]
-        assert "mamba.scan" in path, (name, path)
+        scope = next((s for s in found if s in path), None)
+        assert scope, (name, path)
+        found[scope].append((re.sub(r"[.\d]+$", "", name), backward))
+    for scope, stem in (("mamba.scan", "ssd_"), ("mamba.conv", "short_conv_")):
+        assert [k for k, _ in found[scope]] == [stem + p for p in passes]
+        assert [b for _, b in found[scope]] == [False] + [True] * (len(passes) - 1)
+
+
+def test_v5e_compiles_a_gated_short_convolution_as_one_pass_a_direction(one_chip, for_the_chip):
+    """One operator at ``lfm2-8b-a1b``'s width (2048 channels, 3 taps, 2 x 8192
+    tokens), forward and backward: ``conv.mix`` is the kernel pair, the
+    backward's cotangent the projection's whole ``(2, 8192, 6144)`` written by
+    the kernel, and no float32 array of ``g``'s size exists in the step (XLA's
+    form of the shift writes one forward and three backward)."""
+    import re
+    from apex_tpu.observability.metrics import get_registry
+    from apex_tpu.observability.phases import instruction_phases
+    from apex_tpu.transformer import short_conv
+    op = short_conv.GatedShortConv(2048, 3)
+    shapes = jax.eval_shape(lambda: op.init(jax.random.PRNGKey(0))[0])
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: jax.ShapeDtypeStruct(
+            s.shape, jnp.float32 if path[0].key in op.fp32_param_names else jnp.bfloat16,
+            sharding=one_chip), shapes)
+    u = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        with jax.named_scope("model"):
+            return jnp.sum((x + op(p, x)).astype(jnp.float32) ** 2)
+
+    counted = lambda: get_registry().counter("short_conv_calls_total").labels(
+        taps="3", impl="pallas").value
+    before = counted()
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, u).compile().as_text()
+    assert counted() == before + 1
+    entry = text[text.index("ENTRY"):]      # what the step writes to HBM
+    assert "= f32[2,8192,2048]" not in entry and "= f32[2,8192,6144]" not in entry
+    phases = instruction_phases(text)
+    found = []
+    for line in re.findall(r"%[\w.\-]+ = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                           text[text.index("ENTRY"):]):
+        name = re.match(r"%([\w.\-]+) = ", line).group(1)
+        path, backward = phases[name]
+        assert "conv.mix" in path, (name, path)
         found.append((re.sub(r"[.\d]+$", "", name), backward))
-    assert [k for k, _ in found] == kernels
-    assert [b for _, b in found] == [False] + [True] * (len(kernels) - 1)
+        if backward:
+            assert "bf16[2,8192,6144]" in line.split("custom-call(")[0]
+    assert found == [("short_conv_fwd", False), ("short_conv_bwd", True)]

@@ -389,7 +389,8 @@ def test_traced_layers_count_their_convolutions_and_their_biased_routers(tiny):
         routers = reg.get("moe_router_calls_total")
         by = ({tuple(v for _, v in sorted(k)): c.value for k, c in routers.children().items()}
               if routers else {})
-        return taps.get(("3",), 0), bias.value if bias else 0, by.get(("sigmoid", "4"), 0)
+        # labels sorted by name: (impl, taps); off the chip the XLA form
+        return taps.get(("xla", "3"), 0), bias.value if bias else 0, by.get(("sigmoid", "4"), 0)
 
     model, params, ids = tiny
     before = read()

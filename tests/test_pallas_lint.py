@@ -52,6 +52,18 @@ def test_non_divisible_block_flags():
     assert any("not divisible" in p for p in problems), problems
 
 
+def test_a_ragged_dim_is_a_fault_only_where_a_step_reaches_its_partial_block():
+    """Column blocks 0-2 of an array 448 wide leave its last 64 columns
+    alone; a fourth step would read the partial block."""
+    cols = lambda n: _site(
+        grid=(n,), in_specs=[_spec((8, 128), lambda j: (0, j))],
+        in_shapes=[((8, 448), "float32")],
+        out_specs=[_spec((8, 128), lambda j: (0, j))],
+        out_shapes=[((8, 128 * n), "float32")], input_output_aliases={})
+    assert check_site(cols(3)) == []
+    assert any("not divisible" in p for p in check_site(cols(4)))
+
+
 def test_out_of_bounds_index_map_flags():
     """An off-by-one index map (i+1) steps past the last block at the
     top grid corner."""
@@ -109,8 +121,9 @@ def test_real_kernel_family_lints_clean():
     write-out arities), LAMB stages, layer-norm fwd/bwd, the
     multi-tensor family, flash attention
     fwd/dq/dkv on head-major and on token-major, grouped operands, the
-    rotary pass, and the selective scan's forward and backward —
-    satisfies the block/index/alias preconditions."""
+    rotary pass, the selective scan's forward and backward, and the
+    short convolution's pair in both forms — satisfies the
+    block/index/alias preconditions."""
     sites, problems = pallas_lint.lint_pallas_kernels()
     assert problems == []
     names = {s.name for s in sites}
@@ -119,8 +132,18 @@ def test_real_kernel_family_lints_clean():
     for expected in ("_adam_kernel", "_stage1_kernel", "_stage2_kernel",
                      "_scale_kernel", "_axpby_kernel", "_l2norm_kernel",
                      "_dq_kernel", "_dkv_kernel", "_kernel", "_rows_kernel",
-                     "_stack_kernel", "_fwd_kernel", "_bwd_kernel"):
+                     "_stack_kernel", "_fwd_kernel", "_bwd_kernel",
+                     "_conv_fwd", "_gated_bwd", "_silu_bwd"):
         assert expected in names, (expected, sorted(names))
+    # the short convolution: three blocks of tokens a sequence; the gated
+    # backward's last axis walks the cotangent's three parts; the silu form
+    # reads 128-lane column blocks 1-2 of a projection 448 wide, whose
+    # ragged last block no step reaches
+    conv = {s.name: s for s in sites if s.name in ("_gated_bwd", "_silu_bwd")}
+    assert conv["_gated_bwd"].grid == (2, 1, 3, 3)
+    assert conv["_silu_bwd"].grid == (1, 2, 3, 1)
+    assert conv["_silu_bwd"].in_shapes[0][0][2] % 128 == 64
+    assert conv["_silu_bwd"].in_specs[1].block_shape == (1, 16, 128)
     assert len(sites) >= 12, [s.describe() for s in sites]
     # token-major launches: a (1, blk, hb * D) block of a (B, T, H * D)
     # array, and dk/dv's sequential axis six times its sweep where six
